@@ -1,0 +1,366 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py            # on a TPU host; anything else exits non-zero
+    JAX_PLATFORMS=cpu python chip_smoke.py --tiny-cpu   # debug the script itself
+
+One process, no child that imports JAX. It drives the engine's main path
+(``from_pydict -> with_column(embed_image) -> UDFProject -> Flax forward ->
+collect``) at the full width of CLIP ViT-L/14 with seeded random weights,
+then the text embedder, the prompter, the relational device path and the
+Pallas attention kernel, and checks every result. Phases run in order and the
+first failure ends the run: nothing here turns a device, compile or Pallas
+failure into a result. The last line of stdout is one JSON object; on a pass
+it carries ``"ok": true`` and ``"chip_smoke": "pass"``.
+
+The times it prints are smoke timings — set-up (instantiate + first call,
+compilation included) and run (steady calls, ended by the fetch that forces
+the device). They are not metrics and are recorded nowhere under that name.
+
+``--tiny-cpu`` runs the same phases on the ``tiny`` configurations with the
+Pallas kernel interpreted, to debug this file without a chip. It refuses to
+run unless ``JAX_PLATFORMS=cpu`` and ends with ``"chip_smoke": "dry"``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import os
+import sys
+import time
+from typing import Callable, Dict, Tuple
+
+import numpy as np
+
+# Full-width sizes, and the tiny ones --tiny-cpu swaps in.
+FULL = dict(image_model="ViT-L/14", image_px=224, image_rows=1024,
+            image_batch=256, embed_dim=768,
+            text_model="all-MiniLM-L6-v2", text_rows=1536, text_warm=512,
+            text_dim=384, lm_model="default-lm", prompts=16,
+            chain_rows=200_000,
+            attn_shapes=((8, 257, 16, 64), (8, 256, 12, 32)))
+TINY = dict(image_model="tiny", image_px=32, image_rows=40, image_batch=8,
+            embed_dim=32,
+            text_model="tiny", text_rows=40, text_warm=8, text_dim=64,
+            lm_model="tiny-lm", prompts=16, chain_rows=20_000,
+            attn_shapes=((2, 257, 4, 64), (2, 256, 4, 32)))
+
+
+def _timed(fn: Callable):
+    t0 = time.perf_counter()
+    out = fn()
+    return out, round(time.perf_counter() - t0, 3)
+
+
+def _engine_instance(expr):
+    """The provider instance the engine created for this expression's UDF
+    (one per replica slot; the smoke runs outside any replica scope)."""
+    return expr._expr.udf._instances[0]
+
+
+def _release(expr) -> None:
+    """Drop the engine's provider instances, and with them their device
+    memory: a cached plan may keep the UDF itself alive."""
+    expr._expr.udf._instances.clear()
+    gc.collect()
+
+
+def _check_embeddings(emb: np.ndarray, rows: int, dim: int) -> None:
+    assert emb.shape == (rows, dim), f"shape {emb.shape} != {(rows, dim)}"
+    assert np.isfinite(emb).all(), "non-finite embedding values"
+    norms = np.linalg.norm(emb, axis=1)
+    assert np.allclose(norms, 1.0, atol=1e-2), \
+        f"rows are not unit-norm: [{norms.min()}, {norms.max()}]"
+
+
+def _min_cosine(a: np.ndarray, b: np.ndarray) -> float:
+    return float(np.min(np.sum(a * b, axis=1)))
+
+
+def _embed_image_df(cfg, imgs: np.ndarray):
+    import daft_tpu
+    from daft_tpu.datatype import DataType
+
+    px = cfg["image_px"]
+    series = daft_tpu.Series.from_numpy(
+        imgs.reshape(len(imgs), -1), "img", DataType.image("RGB", px, px))
+    return daft_tpu.from_pydict({"img": series})
+
+
+def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]:
+    """embed_image through the engine under one staging mode. Returns the
+    record, the embeddings and the expression (for its engine instance)."""
+    from daft_tpu import col
+    from daft_tpu.functions.ai import embed_image
+
+    rows, batch = len(imgs), cfg["image_batch"]
+    df = _embed_image_df(cfg, imgs)
+    expr = embed_image(col("img"), provider="flax_random",
+                       model=cfg["image_model"], batch_size=batch,
+                       staging_mode=mode)
+    _, setup_s = _timed(lambda: df.limit(batch).with_column("emb", expr)
+                        .select("emb").collect())
+
+    def run():
+        out = df.with_column("emb", expr).select("emb").collect()
+        return np.asarray(out.to_pydict()["emb"], dtype=np.float32)
+
+    emb, run_s = _timed(run)
+    _check_embeddings(emb, rows, cfg["embed_dim"])
+    inst = _engine_instance(expr)
+    assert inst.staging_mode == mode
+    assert inst.last_forward_stats["mode"] == mode
+    assert inst.last_forward_stats["chunks"] == -(-rows // batch) >= 4
+    return {"setup_s": setup_s, "run_s": run_s, "rows": rows}, emb, expr
+
+
+def check_placement(inst, cfg, tiny: bool) -> None:
+    """Parameters resident on every device the instance claims, and batches
+    dp-sharded over them: one device and no mesh on a one-chip host, every
+    visible device under the default dp mesh on a larger one."""
+    import jax
+
+    devices = list(inst.mesh.devices.flat) if inst.mesh is not None \
+        else [jax.devices()[0]]
+    assert len(devices) == len(jax.devices()), \
+        f"instance claims {len(devices)} of {len(jax.devices())} devices"
+    assert inst.last_forward_stats["n_devices"] == len(devices)
+    if not tiny:  # the CPU backend reports no memory statistics
+        for d in devices:
+            used = d.memory_stats()["bytes_in_use"]
+            assert used > 0, f"no parameters resident on {d}"
+    px, batch = cfg["image_px"], cfg["image_batch"]
+    staged = inst.stage_batch(np.zeros((batch, px, px, 3), np.uint8))
+    assert staged.sharding.device_set == set(devices)
+    assert staged.addressable_shards[0].data.shape[0] == batch // len(devices)
+
+
+def phase_b(cfg) -> dict:
+    """embed_text at MiniLM widths over strings of mixed length."""
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.functions.ai import embed_text
+
+    rng = np.random.default_rng(1)
+    rows = cfg["text_rows"]
+    texts = [" ".join(f"w{rng.integers(0, 5000)}"
+                      for _ in range(int(rng.integers(1, 200))))
+             for _ in range(rows)]
+    df = daft_tpu.from_pydict({"t": texts})
+    expr = embed_text(col("t"), provider="flax_random",
+                      model=cfg["text_model"])
+    _, setup_s = _timed(lambda: df.limit(cfg["text_warm"])
+                        .with_column("emb", expr).select("emb").collect())
+
+    def run():
+        out = df.with_column("emb", expr).select("emb").collect()
+        return np.asarray(out.to_pydict()["emb"], dtype=np.float32)
+
+    emb, run_s = _timed(run)
+    _check_embeddings(emb, rows, cfg["text_dim"])
+    _release(expr)
+    return {"setup_s": setup_s, "run_s": run_s, "rows": rows}
+
+
+def phase_c(cfg) -> dict:
+    """prompt: prefill with donated caches, the decode loop, retire."""
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.functions.ai import prompt
+
+    n = cfg["prompts"]
+    prompts = [f"question {i}: what is {i} plus {i}?" for i in range(n)]
+    expr = prompt(col("p"), provider="flax_random", model=cfg["lm_model"],
+                  max_new_tokens=8)
+    _, setup_s = _timed(
+        lambda: daft_tpu.from_pydict({"p": ["warm up the decoder"]})
+        .with_column("a", expr).select("a").collect())
+
+    def run():
+        out = daft_tpu.from_pydict({"p": prompts}).with_column("a", expr) \
+            .select("a").collect()
+        return out.to_pydict()["a"]
+
+    answers, run_s = _timed(run)
+    assert len(answers) == n and all(a for a in answers), \
+        f"expected {n} non-empty answers, got {answers}"
+    steps = _engine_instance(expr)._batcher.decode_steps
+    assert steps > 0, "the decode loop never ran"
+    _release(expr)
+    return {"setup_s": setup_s, "run_s": run_s, "rows": n,
+            "decode_steps": steps}
+
+
+def phase_d(cfg) -> dict:
+    """A q06-shaped float32 chain (filter -> project -> sum) on the device,
+    against the same query on the host. The counters are the only way to see
+    past the relational path's host fallbacks."""
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.ops.device_eval import device_eval_metrics
+
+    n = cfg["chain_rows"]
+    rng = np.random.default_rng(2)
+    df = daft_tpu.from_pydict({
+        "x": (rng.random(n, dtype=np.float32) * 10.0),
+        "y": rng.random(n, dtype=np.float32)})
+
+    def query():
+        return (df.where((col("y") < 0.8) & (col("x") > 5.0))
+                .agg((col("x") * col("y")).sum().alias("rev"))
+                .to_pydict()["rev"][0])
+
+    before = device_eval_metrics.snapshot()
+    with daft_tpu.execution_config_ctx(device_eval=True):
+        dev, setup_s = _timed(query)
+        dev2, run_s = _timed(query)
+    after = device_eval_metrics.snapshot()
+    with daft_tpu.execution_config_ctx(device_eval=False,
+                                       compiled_eval_enabled=False):
+        host = query()
+    np.testing.assert_allclose(dev, host, rtol=1e-4)
+    np.testing.assert_allclose(dev2, host, rtol=1e-4)
+    fused = after["fused_rows"] - before["fused_rows"]
+    assert fused > 0, f"nothing ran on the device: {after}"
+    assert after["device_errors"] == 0, f"device errors: {after}"
+    return {"setup_s": setup_s, "run_s": run_s, "rows": n,
+            "fused_rows": fused}
+
+
+def phase_e(cfg, imgs: np.ndarray, reference: np.ndarray, tiny: bool) -> dict:
+    """The Pallas kernel compiled by the TPU compiler (interpreted only under
+    --tiny-cpu), then the image forward once more with the kernel forced on."""
+    import jax
+    import jax.numpy as jnp
+
+    from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder
+    from daft_tpu.ops.pallas_attention import flash_attention
+    from daft_tpu.parallel.replica import replica_scope
+
+    rng = np.random.default_rng(3)
+
+    def kernel_vs_xla():
+        for shape in cfg["attn_shapes"]:
+            q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                       for _ in range(3))
+            out = np.asarray(flash_attention(q, k, v, interpret=tiny),
+                             dtype=np.float32)
+            ref = np.asarray(jax.nn.dot_product_attention(q, k, v),
+                             dtype=np.float32)
+            np.testing.assert_allclose(out, ref, atol=3e-2, rtol=3e-2,
+                                       err_msg=f"flash_attention {shape}")
+
+    batch = cfg["image_batch"]
+
+    def setup():
+        kernel_vs_xla()
+        # The kernel is not partitioned over a mesh, so this embedder is a
+        # single-chip replica whatever the host holds.
+        with replica_scope(0, jax.devices()[:1]):
+            emb = FlaxCLIPImageEmbedder(cfg["image_model"], batch_size=batch)
+        assert emb.mesh is None
+        emb.embed_image(imgs[:batch])
+        return emb
+
+    os.environ["DAFT_PALLAS_ATTENTION"] = "1"  # read when the forward traces
+    try:
+        emb, setup_s = _timed(setup)
+        out, run_s = _timed(lambda: emb.embed_image(imgs[:batch]))
+    finally:
+        del os.environ["DAFT_PALLAS_ATTENTION"]
+    _check_embeddings(out, batch, cfg["embed_dim"])
+    cos = _min_cosine(out, reference[:batch])
+    assert cos > 0.99, f"Pallas forward disagrees with XLA's: min cosine {cos}"
+    return {"setup_s": setup_s, "run_s": run_s, "rows": batch,
+            "min_cosine_vs_xla": round(cos, 5)}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--tiny-cpu", action="store_true",
+                    help="debug run: tiny configurations on the CPU, Pallas "
+                         "interpreted; requires JAX_PLATFORMS=cpu and never "
+                         "reports a pass")
+    tiny = ap.parse_args(argv).tiny_cpu
+    if tiny and os.environ.get("JAX_PLATFORMS") != "cpu":
+        print("chip_smoke: --tiny-cpu refuses to run unless JAX_PLATFORMS=cpu",
+              file=sys.stderr)
+        return 2
+    cfg = TINY if tiny else FULL
+
+    from daft_tpu.device import (
+        describe_devices,
+        require_tpu,
+        setup_compile_cache,
+    )
+
+    cache_dir = setup_compile_cache()
+    import jax
+
+    # No chip: require_tpu raises, and nothing runs on the CPU in its place.
+    device = describe_devices() if tiny else require_tpu()
+    print(f"chip_smoke: platform={device['platform']} "
+          f"device_kind={device['kind']!r} n_devices={device['count']} "
+          f"jax={jax.__version__} compile_cache_dir={cache_dir}", flush=True)
+
+    import daft_tpu
+    from daft_tpu import _native
+
+    rng = np.random.default_rng(0)
+    px = cfg["image_px"]
+    imgs = rng.integers(0, 255, (cfg["image_rows"], px, px, 3), dtype=np.uint8)
+    phases: Dict[str, dict] = {}
+    current = "start"
+
+    def done(name: str, rec: dict) -> None:
+        phases[name] = rec
+        print(f"chip_smoke: {name} ok {json.dumps(rec)}", flush=True)
+
+    try:
+        # The result cache would answer a repeated query without the device.
+        with daft_tpu.execution_config_ctx(result_cache_enabled=False):
+            current = "A_overlap"
+            rec, emb_overlap, expr = phase_a(cfg, imgs, "overlap")
+            inst = _engine_instance(expr)
+            check_placement(inst, cfg, tiny)
+            np.testing.assert_allclose(
+                emb_overlap, inst.embed_image(imgs), atol=1e-3,
+                err_msg="engine result != direct embed_image call")
+            done(current, rec)
+            del inst
+            _release(expr)
+
+            current = "A_separated"
+            rec, emb_separated, expr = phase_a(cfg, imgs, "separated")
+            cos = _min_cosine(emb_overlap, emb_separated)
+            assert cos > 0.999, f"staging modes disagree: min cosine {cos}"
+            done(current, rec)
+            _release(expr)
+
+            current = "B_embed_text"
+            done(current, phase_b(cfg))
+            current = "C_prompt"
+            done(current, phase_c(cfg))
+            current = "D_device_chain"
+            done(current, phase_d(cfg))
+            current = "E_pallas"
+            done(current, phase_e(cfg, imgs, emb_overlap, tiny))
+    except BaseException:
+        print(f"chip_smoke: FAILED in phase {current}", file=sys.stderr,
+              flush=True)
+        raise
+
+    print(json.dumps({
+        "ok": not tiny, "device": device,
+        "chip_smoke": "dry" if tiny else "pass",
+        "platform": device["platform"], "device_kind": device["kind"],
+        "n_devices": device["count"], "jax": jax.__version__,
+        "compile_cache_dir": cache_dir,
+        "native_lib_loaded": _native.get_lib() is not None,
+        "phases": phases}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

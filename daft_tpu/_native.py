@@ -1,7 +1,11 @@
 """Loader for the native C++ kernel library (native/daft_native.cpp).
 
 Builds the shared library on first use when a compiler is available (the
-image bakes g++); falls back silently to the numpy kernels otherwise.
+image bakes g++); falls back to the numpy kernels otherwise, with a warning.
+The artifact is named by a hash of the source, the compiler flags and the
+CPU it was built for, so a library is only ever loaded by the checkout and
+the machine that built it — a copied tree rebuilds instead of running code
+compiled with ``-march=native`` for another CPU.
 Disable with DAFT_NATIVE=0. Hash outputs are bit-identical across the native
 and numpy paths (cross-host hash-partitioning requirement).
 """
@@ -9,12 +13,17 @@ and numpy paths (cross-host hash-partitioning requirement).
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
 
 import numpy as np
+
+_log = logging.getLogger(__name__)
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -22,30 +31,53 @@ _lock = threading.Lock()
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "native", "daft_native.cpp")
-_SO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_daft_native.so")
+_FLAG_SETS = (["-O3", "-march=native"], ["-O3"])
 
 
-def _build() -> bool:
+def _cpu_identity() -> str:
+    """What ``-march=native`` resolves against: the architecture plus the
+    CPU's feature flags."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return platform.machine() + line
+    except OSError:
+        pass  # no procfs: the architecture and processor name must do
+    return platform.machine() + platform.processor()
+
+
+def _so_path() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(repr(_FLAG_SETS).encode())
+    h.update(_cpu_identity().encode())
+    return os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        f"_daft_native.{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
     """Compile to a temp path and os.rename into place (atomic on POSIX), with
     an flock so concurrent worker processes never dlopen a half-written .so."""
     import fcntl
 
-    lock_path = _SO + ".lock"
-    tmp_path = f"{_SO}.{os.getpid()}.tmp"
+    lock_path = os.path.join(os.path.dirname(so), "_daft_native.so.lock")
+    tmp_path = f"{so}.{os.getpid()}.tmp"
     try:
         with open(lock_path, "w") as lock_f:
             fcntl.flock(lock_f, fcntl.LOCK_EX)
             # Another process may have finished the build while we waited.
-            if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+            if os.path.exists(so):
                 return True
-            for flags in (["-O3", "-march=native"], ["-O3"]):
+            for flags in _FLAG_SETS:
                 try:
                     subprocess.run(
                         ["g++", *flags, "-shared", "-fPIC", "-std=c++17",
                          _SRC, "-o", tmp_path],
                         check=True, capture_output=True, timeout=120,
                     )
-                    os.rename(tmp_path, _SO)
+                    os.rename(tmp_path, so)
                     return True
                 except Exception:
                     continue
@@ -72,13 +104,17 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
         if not daft_env_flag("DAFT_NATIVE", True):
             return None
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC) and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
-            if not os.path.exists(_SRC) or not _build():
-                return None
+        if not os.path.exists(_SRC):
+            _log.warning("native kernels off: %s is missing; numpy kernels "
+                         "run instead", _SRC)
+            return None
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            _log.warning("native kernels off: building %s failed; numpy "
+                         "kernels run instead", so)
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             if lib.daft_native_abi_version() != 1:
                 return None
             u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -93,6 +129,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
             lib.hll_build.argtypes = [u64p, ctypes.c_int64, ctypes.c_int32, u8p]
             _lib = lib
         except Exception:
+            _log.warning("native kernels off: loading %s failed; numpy "
+                         "kernels run instead", so, exc_info=True)
             _lib = None
         return _lib
 

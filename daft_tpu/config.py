@@ -127,7 +127,6 @@ class ExecutionConfig:
     # thread-count — so the determinism contract holds. DAFT_STAGE_FUSION=0
     # disables.
     stage_fusion_enabled: bool = True
-    tpu_chips_per_host: int = 0  # 0 = autodetect
     # Distributed
     num_workers: int = 0  # 0 = autodetect / local
     autoscaling_threshold: float = 1.25

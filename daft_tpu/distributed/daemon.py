@@ -636,11 +636,15 @@ class RemoteWorker(Worker):
 # Spawning helpers (single-machine clusters for tests / dev)           #
 # ------------------------------------------------------------------ #
 def spawn_local_daemon(port: int = 0, slots: int = 2,
-                       jax_platforms: Optional[str] = None,
+                       device_index: Optional[int] = None,
                        fault_injection: bool = False,
                        advertise_host: str = "localhost") -> "subprocess.Popen":
     """Launch a daemon subprocess on localhost; returns the Popen. The port
-    is written to stdout line 1 (`PORT <n>`) when 0 is requested."""
+    is written to stdout line 1 (`PORT <n>`) when 0 is requested. The child
+    is given chip ``device_index`` of this host, or CPU, before it imports
+    JAX (device.child_device_env)."""
+    from daft_tpu.device import child_device_env
+
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     # daftlint: disable=DTL007 -- constructs the child process environment, not a config read
     env = dict(os.environ)
@@ -649,16 +653,7 @@ def spawn_local_daemon(port: int = 0, slots: int = 2,
     extra = [p for p in sys.path if p and os.path.isdir(p)]
     env["PYTHONPATH"] = os.pathsep.join([repo_root, *extra,
                                          env.get("PYTHONPATH", "")])
-    if jax_platforms is None:
-        try:
-            import jax
-
-            if jax.config.jax_platforms == "cpu":
-                jax_platforms = "cpu"
-        except (ImportError, AttributeError):
-            pass  # no jax on the driver: child picks its own platform
-    if jax_platforms:
-        env["DAFT_CHILD_JAX_PLATFORMS"] = jax_platforms
+    env.update(child_device_env(device_index))
     if fault_injection:
         env["DAFT_DAEMON_ALLOW_FAULT_INJECTION"] = "1"
     return subprocess.Popen(
@@ -712,13 +707,9 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                              "$DAFT_ADVERTISE_HOST or gethostname())")
     args = parser.parse_args(argv)
 
-    from daft_tpu.config import daft_env
+    from daft_tpu.device import enter_child
 
-    platforms = daft_env("DAFT_CHILD_JAX_PLATFORMS")
-    if platforms:
-        import jax
-
-        jax.config.update("jax_platforms", platforms)
+    enter_child()
 
     daemon = WorkerDaemon(port=args.port, slots=args.slots, data_dir=args.data_dir,
                           host=args.host, advertise_host=args.advertise_host)

@@ -63,13 +63,9 @@ def _recv_exact(sock: socket.socket, n: int) -> bytes:
 
 def _worker_entry(fd: int) -> None:
     """Subprocess loop (invoked via `python -c`)."""
-    from daft_tpu.config import daft_env
+    from daft_tpu.device import enter_child
 
-    platforms = daft_env("DAFT_CHILD_JAX_PLATFORMS")
-    if platforms:
-        import jax
-
-        jax.config.update("jax_platforms", platforms)
+    enter_child()
     sock = socket.socket(fileno=fd)
     from daft_tpu.distributed.worker import bind_task_fragment, collect_task_outputs
     from daft_tpu.execution.executor import Executor
@@ -193,8 +189,9 @@ class ProcessWorker(Worker):
     the per-chip ownership model)."""
 
     def __init__(self, worker_id: Optional[str] = None, cfg=None,
-                 jax_platforms: Optional[str] = None):
+                 device_index: Optional[int] = None):
         from daft_tpu.context import get_context
+        from daft_tpu.device import child_device_env
 
         self.worker_id = worker_id or f"proc-{uuid.uuid4().hex[:8]}"
         self.num_slots = 1
@@ -204,18 +201,9 @@ class ProcessWorker(Worker):
         env = dict(os.environ)
         repo_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-        if jax_platforms is None:
-            # Propagate a parent-side CPU override (tests force jax to CPU via
-            # config, which does not survive into a fresh process).
-            try:
-                import jax
-
-                if jax.config.jax_platforms == "cpu":
-                    jax_platforms = "cpu"
-            except (ImportError, AttributeError):
-                pass  # no jax on the driver: child picks its own platform
-        if jax_platforms:
-            env["DAFT_CHILD_JAX_PLATFORMS"] = jax_platforms
+        # The child's device is decided here, before it imports JAX: chip
+        # ``device_index`` of this host, or CPU.
+        env.update(child_device_env(device_index))
         self._proc = subprocess.Popen(
             [sys.executable, "-c",
              "import sys; from daft_tpu.distributed.process_worker import _worker_entry; "

@@ -8,6 +8,7 @@ under jit.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -15,6 +16,16 @@ import jax
 import jax.numpy as jnp
 
 Dtype = Any
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def init_params(model: nn.Module, *args):
+    """``model.init(*args)`` as one jitted program. Run eagerly, init is the
+    whole forward dispatched op by op: most of a model's set-up time on a
+    cold chip, in programs too small for the persistent compile cache to
+    keep. Jitted, it is compiled once per (model config, shapes) in a process
+    and once per cache directory across processes."""
+    return model.init(*args)
 
 
 class MLP(nn.Module):
@@ -47,21 +58,15 @@ class MultiHeadAttention(nn.Module):
             return t.reshape(t.shape[:-1] + (self.num_heads, head_dim))
 
         q, k, v = split_heads(q), split_heads(k), split_heads(v)
-        # Fused attention: avoids materialising (B,H,T,T) f32 logits in HBM —
-        # the difference between 17% and 2x-better MXU utilisation at ViT-L
-        # scale, and what lets batch 256 fit in 16G HBM. With
-        # DAFT_PALLAS_ATTENTION=1 the unmasked path uses the hand-written
-        # pallas flash kernel (daft_tpu/ops/pallas_attention).
-        out = None
-        if mask is None:
-            from daft_tpu.ops.pallas_attention import flash_attention, pallas_attention_enabled
+        # Fused attention: avoids materialising (B,H,T,T) f32 logits in HBM.
+        # With DAFT_PALLAS_ATTENTION on, the unmasked path uses the
+        # hand-written pallas flash kernel (daft_tpu/ops/pallas_attention);
+        # a kernel that fails to trace or compile fails the forward.
+        from daft_tpu.ops.pallas_attention import flash_attention, pallas_attention_enabled
 
-            if pallas_attention_enabled():
-                try:
-                    out = flash_attention(q, k, v)
-                except Exception:
-                    out = None
-        if out is None:
+        if mask is None and pallas_attention_enabled():
+            out = flash_attention(q, k, v)
+        else:
             if mask is not None and mask.ndim == 4:
                 # Broadcast (1|B, 1, T, T) or (B, 1, 1, T) to (B, H, T, T).
                 B, T = q.shape[0], q.shape[1]

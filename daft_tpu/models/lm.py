@@ -16,7 +16,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from daft_tpu.models.layers import MLP, causal_mask
+from daft_tpu.models.layers import MLP, causal_mask, init_params
 
 
 @dataclass(frozen=True)
@@ -139,7 +139,7 @@ def init_lm_params(cfg: DecoderLMConfig, seed: int = 0, batch: int = 2, prompt_l
     tokens = jnp.zeros((batch, prompt_len), jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(prompt_len), (batch, prompt_len))
     caches = init_caches(cfg, batch, cfg.max_seq_len)
-    params = model.init(rng, tokens, caches, positions)
+    params = init_params(model, rng, tokens, caches, positions)
     return model, params
 
 

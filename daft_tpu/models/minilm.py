@@ -14,7 +14,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from daft_tpu.models.layers import TransformerBlock
+from daft_tpu.models.layers import TransformerBlock, init_params
 
 
 @dataclass(frozen=True)
@@ -67,4 +67,4 @@ def init_minilm_params(cfg: MiniLMConfig, seed: int = 0):
     model = MiniLMEncoder(cfg)
     rng = jax.random.PRNGKey(seed)
     tokens = jnp.zeros((2, cfg.max_length), jnp.int32)
-    return model, model.init(rng, tokens)
+    return model, init_params(model, rng, tokens)

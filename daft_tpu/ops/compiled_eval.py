@@ -158,13 +158,15 @@ def compile_cache_snapshot() -> dict:
     }
 
 
-def _compiled_executable(shape_key: tuple, run_fn, example_args: tuple):
+def _compiled_executable(shape_key: tuple, run_fn, example_args: tuple,
+                         donate: bool = True):
     """The AOT-compiled executable for this (fingerprint, shapes) key —
     compiling (and timing the compile) on first sight. ``jit().lower()``
     + ``.compile()`` gives an exact trace+compile wall measurement and an
     executable the cache hands straight back on hits (the pjit AOT
     pattern, SNIPPETS [1]); input column buffers are donated — each morsel
-    stages fresh arrays, so XLA may reuse them for outputs."""
+    stages fresh arrays, so XLA may reuse them for outputs. ``donate=False``
+    is for programs whose outputs cannot take an input's buffer."""
     from daft_tpu import metrics
 
     with _cache_lock:
@@ -175,9 +177,9 @@ def _compiled_executable(shape_key: tuple, run_fn, example_args: tuple):
     # Donation lets XLA alias morsel input buffers into outputs (they are
     # staged fresh per call, never reused) — a real win on TPU HBM; the
     # CPU backend can't use it and would warn per compile.
-    donate = (0,) if jax.default_backend() != "cpu" else ()
+    argnums = (0,) if donate and jax.default_backend() != "cpu" else ()
     t0 = time.perf_counter()
-    fn = jax.jit(run_fn, donate_argnums=donate).lower(*example_args).compile()
+    fn = jax.jit(run_fn, donate_argnums=argnums).lower(*example_args).compile()
     dt = time.perf_counter() - t0
     metrics.COMPILE_CACHE_MISSES.inc()
     metrics.COMPILE_SECONDS.observe(dt)
@@ -804,8 +806,10 @@ class AggChainSpec:
             valids_dev = {nm: jnp.asarray(v) for nm, v in valids.items()}
             shape_key = (self.fingerprint, padded, _dtype_sig(cols_np),
                          tuple(sorted(valids)))
+            # An aggregation's outputs are scalars: nothing can alias a
+            # donated column, and the TPU compiler warns on every compile.
             fn = _compiled_executable(shape_key, self._build_run(),
-                                      (cols_dev, valids_dev))
+                                      (cols_dev, valids_dev), donate=False)
             host = jax.device_get(fn(cols_dev, valids_dev))
         except Exception:
             device_eval_metrics.record_device_error()

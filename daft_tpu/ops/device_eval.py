@@ -42,10 +42,13 @@ from daft_tpu.expressions.expr import (
     Literal,
     UnaryOp,
 )
+from daft_tpu.device import setup_compile_cache
 from daft_tpu.series import Series
 
 import jax
 import jax.numpy as jnp
+
+setup_compile_cache()
 
 _FUSABLE_BINARY = {
     "add", "sub", "mul", "truediv", "floordiv", "mod", "pow",

@@ -76,10 +76,7 @@ def sequence_parallel_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ring attention; returns the globally-assembled [B, T, D] result."""
     import functools
 
-    try:
-        from jax import shard_map  # jax >= 0.6: top-level export
-    except ImportError:
-        from jax.experimental.shard_map import shard_map  # deprecated alias
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, axis, None)

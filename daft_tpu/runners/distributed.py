@@ -50,8 +50,10 @@ class DistributedRunner(Runner):
             try:
                 if not addrs:
                     # No cluster given: spawn a local one (dev/CI convenience).
-                    self._daemon_procs = [spawn_local_daemon(slots=slots_per_worker)
-                                          for _ in range(n)]
+                    self._daemon_procs = [
+                        spawn_local_daemon(slots=slots_per_worker,
+                                           device_index=i)
+                        for i in range(n)]
                     addrs = [wait_for_daemon(p) for p in self._daemon_procs]
                 workers = [RemoteWorker(a) for a in addrs]
             except BaseException:
@@ -86,11 +88,14 @@ class DistributedRunner(Runner):
             self._maybe_start_fleet(cfg)
             return
         if backend == "process":
-            # True process isolation (reference: per-node Ray actors; on TPU
-            # hosts, one process per chip — libtpu single-owner).
+            # True process isolation (reference: per-node Ray actors). A
+            # chip belongs to one process: worker i is started on chip i of
+            # this host while chips remain, every further worker (and every
+            # one the factory mints later) on CPU — device.child_device_env.
             from daft_tpu.distributed.process_worker import ProcessWorker
 
-            workers = [ProcessWorker(f"proc-{i}") for i in range(n)]
+            workers = [ProcessWorker(f"proc-{i}", device_index=i)
+                       for i in range(n)]
             self.manager = WorkerManager(workers, factory=lambda: ProcessWorker())
             self._start_heartbeat(cfg)
         else:

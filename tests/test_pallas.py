@@ -38,9 +38,9 @@ def test_flash_attention_bf16():
     )
 
 
-def test_env_toggle_fallback(monkeypatch):
-    """With the flag on but pallas unavailable, the model layer silently falls
-    back to XLA attention and still computes."""
+def test_env_toggle_off_tpu_keeps_kernel_off(monkeypatch):
+    """With the flag on, a backend that is not a TPU keeps the kernel off
+    (the backend gate) and the model layer computes through XLA attention."""
     monkeypatch.setenv("DAFT_PALLAS_ATTENTION", "1")
     from daft_tpu.models.clip import CLIPConfig, init_clip_params
 
@@ -48,6 +48,30 @@ def test_env_toggle_fallback(monkeypatch):
     model, params = init_clip_params(cfg)
     px = jnp.zeros((2, cfg.image_size, cfg.image_size, 3), jnp.uint8)
     out = model.apply(params, px, method=model.encode_image)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_forced_kernel_failure_propagates(monkeypatch):
+    """DAFT_PALLAS_ATTENTION=1 on a TPU backend with a kernel that raises:
+    the error leaves MultiHeadAttention — XLA's result is not substituted."""
+    from daft_tpu.models.layers import MultiHeadAttention
+    from daft_tpu.ops import pallas_attention as pa
+
+    mha = MultiHeadAttention(num_heads=2, dtype=jnp.float32)
+    x = jnp.ones((1, 8, 16), jnp.float32)
+    params = mha.init(jax.random.PRNGKey(0), x)  # kernel off: XLA path
+
+    def broken_kernel(q, k, v):
+        raise RuntimeError("mosaic refused the kernel")
+
+    monkeypatch.setenv("DAFT_PALLAS_ATTENTION", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pa, "flash_attention", broken_kernel)
+    assert pa.pallas_attention_enabled() is True
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        mha.apply(params, x)
+    # The masked path never takes the kernel.
+    out = mha.apply(params, x, jnp.ones((1, 1, 8, 8), bool))
     assert np.isfinite(np.asarray(out)).all()
 
 

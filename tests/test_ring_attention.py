@@ -43,7 +43,7 @@ def test_ring_attention_jits_over_mesh(mesh):
     program with ppermute collectives inside a scan."""
     import functools
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from daft_tpu.ops.ring_attention import ring_attention
