@@ -9,8 +9,11 @@ collect``) at the full width of CLIP ViT-L/14 with seeded random weights,
 then the text embedder, the prompter, the relational device path and the
 Pallas attention kernel, and checks every result. Phases run in order and the
 first failure ends the run: nothing here turns a device, compile or Pallas
-failure into a result. The last line of stdout is one JSON object; on a pass
-it carries ``"ok": true`` and ``"chip_smoke": "pass"``.
+failure into a result. On a pass the last two lines of stdout are JSON
+objects: the record of the run (``"chip_smoke": "pass"``, the device, the cache
+directory and every phase's timings), then the verdict alone,
+``{"ok": true, "device": {"platform", "kind", "count"}}``. The verdict is
+printed only after every phase passed on a TPU.
 
 The times it prints are smoke timings — set-up (instantiate + first call,
 compilation included) and run (steady calls, ended by the fetch that forces
@@ -18,7 +21,8 @@ the device). They are not metrics and are recorded nowhere under that name.
 
 ``--tiny-cpu`` runs the same phases on the ``tiny`` configurations with the
 Pallas kernel interpreted, to debug this file without a chip. It refuses to
-run unless ``JAX_PLATFORMS=cpu`` and ends with ``"chip_smoke": "dry"``.
+run unless ``JAX_PLATFORMS=cpu``; its last line is the record with
+``"chip_smoke": "dry"`` and it prints no verdict.
 """
 
 from __future__ import annotations
@@ -352,13 +356,14 @@ def main(argv=None) -> int:
         raise
 
     print(json.dumps({
-        "ok": not tiny, "device": device,
         "chip_smoke": "dry" if tiny else "pass",
         "platform": device["platform"], "device_kind": device["kind"],
         "n_devices": device["count"], "jax": jax.__version__,
         "compile_cache_dir": cache_dir,
         "native_lib_loaded": _native.get_lib() is not None,
         "phases": phases}), flush=True)
+    if not tiny:
+        print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -124,8 +124,9 @@ def test_chip_smoke_fails_without_a_chip():
 def test_chip_smoke_tiny_cpu_is_a_dry_run():
     proc = _run(["chip_smoke.py", "--tiny-cpu"], JAX_PLATFORMS="cpu")
     assert proc.returncode == 0, proc.stderr[-3000:]
+    # The record is the last line: a dry run prints no verdict after it.
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["chip_smoke"] == "dry" and rec["ok"] is False
+    assert rec["chip_smoke"] == "dry" and '"ok"' not in proc.stdout
     assert rec["platform"] == "cpu"
     assert set(rec["phases"]) == {"A_overlap", "A_separated", "B_embed_text",
                                   "C_prompt", "D_device_chain", "E_pallas"}
