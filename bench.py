@@ -51,9 +51,9 @@ def _bench_engine(num_images: int, batch_size: int) -> dict:
 
     import daft_tpu
     from daft_tpu import col
-    from daft_tpu.ai import flax_provider
     from daft_tpu.datatype import DataType
     from daft_tpu.functions.ai import embed_image
+    from daft_tpu.profiling import newest_device_span
 
     rng = np.random.default_rng(0)
     imgs = rng.integers(0, 255, (num_images, IMAGE_SIZE, IMAGE_SIZE, 3),
@@ -79,11 +79,10 @@ def _bench_engine(num_images: int, batch_size: int) -> dict:
         elapsed = time.perf_counter() - start
 
     assert total == num_images, f"expected {num_images} rows, got {total}"
-    # Publish the phase split of the last forward (device_put vs
-    # forward+fetch) + which staging mode ran, so results are attributable.
-    with flax_provider._STATS_LOCK:
-        stats = dict(flax_provider.LAST_FORWARD_STATS)
-    sys.stderr.write(f"phase breakdown: {stats}, engine wall {elapsed:.2f}s\n")
+    # Publish the last forward's counters (rows, chunks, staging mode,
+    # devices), so results are attributable.
+    stats = newest_device_span("provider.forward").count
+    sys.stderr.write(f"last forward: {stats}, engine wall {elapsed:.2f}s\n")
     from daft_tpu.perf_report import resolved_compute_threads
 
     # Per chip = per device the model's parameters occupy, not per device

@@ -97,6 +97,7 @@ def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]
     record, the embeddings and the expression (for its engine instance)."""
     from daft_tpu import col
     from daft_tpu.functions.ai import embed_image
+    from daft_tpu.profiling import newest_device_span
 
     rows, batch = len(imgs), cfg["image_batch"]
     df = _embed_image_df(cfg, imgs)
@@ -114,8 +115,9 @@ def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]
     _check_embeddings(emb, rows, cfg["embed_dim"])
     inst = _engine_instance(expr)
     assert inst.staging_mode == mode
-    assert inst.last_forward_stats["mode"] == mode
-    assert inst.last_forward_stats["chunks"] == -(-rows // batch) >= 4
+    forward = newest_device_span("provider.forward").count
+    assert forward["mode"] == mode
+    assert forward["chunks"] == -(-rows // batch) >= 4
     return {"setup_s": setup_s, "run_s": run_s, "rows": rows}, emb, expr
 
 
@@ -125,11 +127,13 @@ def check_placement(inst, cfg, tiny: bool) -> None:
     visible device under the default dp mesh on a larger one."""
     import jax
 
+    from daft_tpu.profiling import newest_device_span
+
     devices = list(inst.mesh.devices.flat) if inst.mesh is not None \
         else [jax.devices()[0]]
     assert len(devices) == len(jax.devices()), \
         f"instance claims {len(devices)} of {len(jax.devices())} devices"
-    assert inst.last_forward_stats["n_devices"] == len(devices)
+    assert newest_device_span("provider.forward").count["n_devices"] == len(devices)
     if not tiny:  # the CPU backend reports no memory statistics
         for d in devices:
             used = d.memory_stats()["bytes_in_use"]

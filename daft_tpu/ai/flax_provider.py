@@ -17,6 +17,7 @@ encoder and a decoder LM, all served as jitted XLA computations with
 from __future__ import annotations
 
 import threading
+import weakref
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -36,6 +37,7 @@ from daft_tpu.ai.protocols import (
 from daft_tpu.ai.provider import Provider
 from daft_tpu.device import setup_compile_cache
 from daft_tpu.errors import DaftValueError
+from daft_tpu.profiling import device_span
 from daft_tpu.utils.tokenizer import HashingTokenizer
 
 setup_compile_cache()
@@ -56,6 +58,19 @@ def _pad_batch(arr: np.ndarray, to: int) -> np.ndarray:
     pad = [(0, to - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
     return np.pad(arr, pad)
 
+
+def _initialised(init, *args):
+    """``init(*args)`` -> (model, params) as the span ``provider.init_params``.
+    The init program is dispatched, not waited for: the wait falls to whoever
+    reads the parameters first (the instance's first forward)."""
+    with device_span("provider.init_params") as sp:
+        model, params = init(*args)
+        sp.count["param_bytes"] = _tree_bytes(params)
+    return model, params
+
+
+def _tree_bytes(tree) -> int:
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
 
 
 def load_checkpoint(path: str, params):
@@ -83,13 +98,6 @@ def _load_clip(model_name: str, weights_path: str):
     return load_params(weights_path, CLIPConfig.from_name(model_name))
 
 
-# Phase breakdown of the most recent _chunked_forward call (seconds),
-# DIAGNOSTICS ONLY: instances record their own split in
-# ``self.last_forward_stats``; this module-level mirror is lock-protected and
-# only meaningful when a single replica runs (e.g. bench.py).
-LAST_FORWARD_STATS: Dict[str, float] = {}
-_STATS_LOCK = threading.Lock()
-
 #: Host-to-device staging policies of ``_chunked_forward``. Both stay
 #: selectable (``staging_mode=`` / ``DAFT_STAGING_MODE``) until a benchmark
 #: cell deletes one with a number.
@@ -111,9 +119,13 @@ def resolve_staging_mode(requested: Optional[str] = None) -> str:
     return mode
 
 
+#: Jitted forwards that ``_chunked_forward`` has run: an instance's first call
+#: traces, loads or compiles its executable and runs it, and is set-up.
+_FORWARDS_RUN = weakref.WeakSet()
+
+
 def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
-                     stage=None, pad_mult: int = 1, mode: str = "separated",
-                     stats_out: Optional[Dict[str, float]] = None) -> np.ndarray:
+                     stage=None, pad_mult: int = 1, mode: str = "separated") -> np.ndarray:
     """Chunk to max_batch and run the forwards under the given staging policy.
 
     Neither mode queues more than one forward ahead of the fetch.
@@ -123,56 +135,63 @@ def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
       bounded by the engine's UDF morsel size).
     * ``overlap``: depth-1 pipeline — dispatch forward for chunk i, stage
       chunk i+1 while it computes, then fetch chunk i.
-    """
-    import time as _time
 
+    The call is the span ``provider.forward``; each chunk's pad, stage,
+    dispatch and fetch are spans of their own below it (profiling.py).
+    """
     n = arr.shape[0]
     if n == 0:
         return np.zeros((0, out_dim), dtype=np.float32)
-    if stage is None:
-        stage = jax.device_put
-    chunks = []
-    for start in range(0, n, max_batch):
-        chunk = arr[start:start + max_batch]
-        b = _bucket(min(len(chunk), max_batch))
-        if b % pad_mult:  # dp-sharded batches must divide the dp axis
-            b = ((b + pad_mult - 1) // pad_mult) * pad_mult
-        chunks.append((len(chunk), chunk, b))
-    # n_devices: the devices the parameters occupy — what a per-chip rate
-    # divides by, whatever else the host can see.
-    leaf = jax.tree_util.tree_leaves(params)[0]
-    stats = {"stage_s": 0.0, "fwd_fetch_s": 0.0, "chunks": len(chunks),
-             "rows": n, "mode": mode,
-             "n_devices": len(leaf.sharding.device_set)}
-    outs = []
-    if mode == "overlap":
-        t0 = _time.perf_counter()
-        nxt = stage(_pad_batch(chunks[0][1], chunks[0][2]))
-        for i, (cn, _, _) in enumerate(chunks):
-            cur, nxt = nxt, None
-            f = fwd(params, cur)  # async dispatch
-            if i + 1 < len(chunks):  # stage i+1 while chunk i computes
-                nxt = stage(_pad_batch(chunks[i + 1][1], chunks[i + 1][2]))
-            outs.append(np.asarray(f)[:cn])  # forces + fetches chunk i
-        stats["fwd_fetch_s"] = _time.perf_counter() - t0
-    else:
-        t0 = _time.perf_counter()
-        staged = [stage(_pad_batch(c, b)) for _, c, b in chunks]
-        for s in staged:
-            s.block_until_ready()
-        stats["stage_s"] = _time.perf_counter() - t0
-        t0 = _time.perf_counter()
-        for i, (cn, _, _) in enumerate(chunks):
-            f = fwd(params, staged[i])
-            staged[i] = None  # free the HBM reference once consumed
-            outs.append(np.asarray(f)[:cn])
-        stats["fwd_fetch_s"] = _time.perf_counter() - t0
-    if stats_out is not None:
-        stats_out.clear()
-        stats_out.update(stats)
-    with _STATS_LOCK:
-        LAST_FORWARD_STATS.clear()
-        LAST_FORWARD_STATS.update(stats)
+    with device_span("provider.forward", rows=n, mode=mode) as sp:
+        if fwd not in _FORWARDS_RUN:
+            _FORWARDS_RUN.add(fwd)
+            sp.count["first"] = 1
+        if stage is None:
+            stage = jax.device_put
+        chunks = []
+        for start in range(0, n, max_batch):
+            chunk = arr[start:start + max_batch]
+            b = _bucket(min(len(chunk), max_batch))
+            if b % pad_mult:  # dp-sharded batches must divide the dp axis
+                b = ((b + pad_mult - 1) // pad_mult) * pad_mult
+            chunks.append((len(chunk), chunk, b))
+        sp.count["chunks"] = len(chunks)
+        # n_devices: the devices the parameters occupy — what a per-chip rate
+        # divides by, whatever else the host can see.
+        leaf = jax.tree_util.tree_leaves(params)[0]
+        sp.count["n_devices"] = len(leaf.sharding.device_set)
+
+        def staged(chunk, b):
+            with device_span("provider.pad", rows=len(chunk), padded_rows=b):
+                padded = _pad_batch(chunk, b)
+            with device_span("provider.stage", bytes=padded.nbytes):
+                return stage(padded)
+
+        def fetched(f, cn):
+            with device_span("provider.fetch") as fetch:
+                out = np.asarray(f)  # forces + fetches the chunk
+                fetch.count["bytes"] = out.nbytes
+            return out[:cn]
+
+        outs = []
+        if mode == "overlap":
+            nxt = staged(chunks[0][1], chunks[0][2])
+            for i, (cn, _, _) in enumerate(chunks):
+                cur, nxt = nxt, None
+                with device_span("provider.dispatch"):
+                    f = fwd(params, cur)  # async dispatch
+                if i + 1 < len(chunks):  # stage i+1 while chunk i computes
+                    nxt = staged(chunks[i + 1][1], chunks[i + 1][2])
+                outs.append(fetched(f, cn))
+        else:
+            on_device = [staged(c, b) for _, c, b in chunks]
+            for s in on_device:
+                s.block_until_ready()
+            for i, (cn, _, _) in enumerate(chunks):
+                with device_span("provider.dispatch"):
+                    f = fwd(params, on_device[i])
+                on_device[i] = None  # free the HBM reference once consumed
+                outs.append(fetched(f, cn))
     return np.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
@@ -186,9 +205,6 @@ class _FlaxModelBase:
         self.mesh = None
         self._param_specs = None
         self.staging_mode = resolve_staging_mode(staging_mode)
-        # Per-instance phase breakdown of the most recent forward (replicas
-        # each own their dict; the module-level mirror is diagnostics-only).
-        self.last_forward_stats: Dict[str, float] = {}
 
     def setup_mesh(self, mesh_axes: Optional[Dict[str, int]] = None):
         """Build this replica's mesh over its device slot.
@@ -219,20 +235,24 @@ class _FlaxModelBase:
     def place_params(self, params):
         """Shard params onto the mesh (tp rules when a "tp" axis exists,
         replicated otherwise); plain device_put without a mesh."""
-        if self.mesh is None:
-            return jax.device_put(params)
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        n_devices = 1 if self.mesh is None else self.mesh.size
+        with device_span("provider.place_params", n_devices=n_devices,
+                         param_bytes=_tree_bytes(params)):
+            # Not waited for: the transfers end inside the first forward.
+            if self.mesh is None:
+                return jax.device_put(params)
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from daft_tpu.parallel.mesh import DEFAULT_TP_RULES, match_partition_rules
+            from daft_tpu.parallel.mesh import DEFAULT_TP_RULES, match_partition_rules
 
-        if "tp" in self.mesh.axis_names:
-            specs = match_partition_rules(DEFAULT_TP_RULES, params, self.mesh)
-        else:
-            specs = jax.tree_util.tree_map(lambda _: P(), params)
-        self._param_specs = specs
-        return jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            params, specs)
+            if "tp" in self.mesh.axis_names:
+                specs = match_partition_rules(DEFAULT_TP_RULES, params, self.mesh)
+            else:
+                specs = jax.tree_util.tree_map(lambda _: P(), params)
+            self._param_specs = specs
+            return jax.tree_util.tree_map(
+                lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
+                params, specs)
 
     def stage_batch(self, arr):
         """Put one padded host batch onto the device(s): dp-sharded along
@@ -269,7 +289,7 @@ class FlaxCLIPImageEmbedder(_FlaxModelBase):
             self.cfg = self.model.cfg
         else:
             self.cfg = CLIPConfig.from_name(model_name)
-            self.model, params = init_clip_params(self.cfg, seed)
+            self.model, params = _initialised(init_clip_params, self.cfg, seed)
         self.params = self.place_params(params)
         model = self.model
 
@@ -295,8 +315,7 @@ class FlaxCLIPImageEmbedder(_FlaxModelBase):
         return _chunked_forward(self._fwd, self.params, images, self.max_batch,
                                 self.cfg.embed_dim, stage=self.stage_batch,
                                 pad_mult=self.batch_multiple(),
-                                mode=self.staging_mode,
-                                stats_out=self.last_forward_stats)
+                                mode=self.staging_mode)
 
 
 class FlaxCLIPTextEmbedder(_FlaxModelBase):
@@ -326,7 +345,7 @@ class FlaxCLIPTextEmbedder(_FlaxModelBase):
                         f"are required for text embedding")
         else:
             self.cfg = CLIPConfig.from_name(model_name)
-            self.model, params = init_clip_params(self.cfg, seed)
+            self.model, params = _initialised(init_clip_params, self.cfg, seed)
         self.params = jax.device_put(params)
         self.tokenizer = tokenizer or HashingTokenizer(
             self.cfg.vocab_size, self.cfg.context_length)
@@ -346,8 +365,7 @@ class FlaxCLIPTextEmbedder(_FlaxModelBase):
     def embed_text(self, texts: Sequence[Optional[str]]) -> np.ndarray:
         tokens, _ = self.tokenizer.encode_batch(texts)
         return _chunked_forward(self._fwd, self.params, tokens, self.max_batch,
-                                self.cfg.embed_dim, mode=self.staging_mode,
-                                stats_out=self.last_forward_stats)
+                                self.cfg.embed_dim, mode=self.staging_mode)
 
 
 class FlaxMiniLMTextEmbedder(_FlaxModelBase):
@@ -385,7 +403,7 @@ class FlaxMiniLMTextEmbedder(_FlaxModelBase):
             self.tokenizer = tok
         else:
             self.cfg = MiniLMConfig.from_name(model_name)
-            self.model, params = init_minilm_params(self.cfg, seed)
+            self.model, params = _initialised(init_minilm_params, self.cfg, seed)
             if weights_path:
                 params = load_checkpoint(weights_path, params)
             self.tokenizer = HashingTokenizer(self.cfg.vocab_size,
@@ -401,8 +419,7 @@ class FlaxMiniLMTextEmbedder(_FlaxModelBase):
     def embed_text(self, texts: Sequence[Optional[str]]) -> np.ndarray:
         tokens, _ = self.tokenizer.encode_batch(texts)
         return _chunked_forward(self._fwd, self.params, tokens, self.max_batch,
-                                self.cfg.embed_dim, mode=self.staging_mode,
-                                stats_out=self.last_forward_stats)
+                                self.cfg.embed_dim, mode=self.staging_mode)
 
 
 class FlaxCLIPClassifier(_FlaxModelBase):
@@ -448,7 +465,7 @@ class FlaxPrompter(_FlaxModelBase):
         from daft_tpu.models.lm import DecoderLMConfig, init_lm_params
 
         self.cfg = DecoderLMConfig.from_name(model_name)
-        self.model, self.params = init_lm_params(self.cfg, seed)
+        self.model, self.params = _initialised(init_lm_params, self.cfg, seed)
         if weights_path:
             self.params = load_checkpoint(weights_path, self.params)
         self.params = jax.device_put(self.params)

@@ -976,9 +976,30 @@ class Executor:
                 out = mp.eval_expression_list(exprs)
                 batch_state.record(len(mp), _time.perf_counter() - t0)
                 return out
+        from daft_tpu.profiling import device_span
+
+        def pulled(it):
+            # Each pull of the child as a span; it is closed before the
+            # morsel is handed on (a generator never yields inside a span).
+            it = iter(it)
+            while True:
+                with device_span("udf.pull") as sp:
+                    mp = next(it, None)
+                    if mp is not None:
+                        sp.count["rows"] = len(mp)
+                if mp is None:
+                    return
+                yield mp
+
+        def call_mp(mp):
+            # The root that one morsel's device-path spans name as their cause.
+            with device_span("udf.call", rows=len(mp)):
+                return eval_mp(mp)
+
+        child_iter = pulled(child_iter)
         if concurrency == 1:
             for mp in child_iter:
-                yield eval_mp(mp)
+                yield call_mp(mp)
             return
         # Ordered stage over morsels (actor-pool analogue). UDFs get their
         # OWN pool: replica-slot acquisition can block a worker, which
@@ -987,7 +1008,7 @@ class Executor:
 
         udf_pool = ThreadPoolExecutor(max_workers=concurrency,
                                       thread_name_prefix="daft-udf")
-        yield from run_stage(child_iter, eval_mp, pool=udf_pool,
+        yield from run_stage(child_iter, call_mp, pool=udf_pool,
                              workers=concurrency, name="UDFProject",
                              owns_pool=True, timer=self._stage_frame(node),
                              ledger=self._stage_ledger("UDFProject"))

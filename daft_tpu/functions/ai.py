@@ -10,6 +10,7 @@ TPU chips and the models are jitted Flax forwards (daft_tpu/ai/flax_provider).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, List, Optional, Sequence, Union
 
 import numpy as np
@@ -18,6 +19,7 @@ from daft_tpu.ai.provider import load_provider
 from daft_tpu.datatype import DataType, TypeId
 from daft_tpu.errors import DaftTypeError
 from daft_tpu.expressions.expression import Expression
+from daft_tpu.profiling import device_span
 from daft_tpu.series import Series
 from daft_tpu.udf import Udf
 
@@ -85,56 +87,96 @@ class _ProtocolUdf(Udf):
         self._instance_lock = threading.Lock()
 
 
+class _RowClock:
+    """The tail of a PIL loop's row (resize, copy into the batch) and where the
+    loop's time goes, for the ``image.preprocess`` span: per row,
+    bytes to an RGB image (``decode_ns``), the resize (``resize_ns``) and the
+    copy into the batch (``copy_ns``), summed over rows, and the slowest row
+    (``slowest_row_ns``). Three clock reads a row: a row starts where the last
+    one ended, so the sums leave out only what precedes the loop."""
+
+    __slots__ = ("decode_ns", "resize_ns", "copy_ns", "slowest_row_ns", "_t", "_filter")
+
+    def __init__(self):
+        from PIL import Image as PILImage
+
+        self.decode_ns = self.resize_ns = self.copy_ns = self.slowest_row_ns = 0
+        self._filter = PILImage.BILINEAR
+        self._t = time.perf_counter_ns()
+
+    def resize_into(self, out: np.ndarray, i: int, img) -> None:
+        """The row's decoded RGB image, resized to the batch's size and copied
+        into ``out[i]``; the time since the last row ended was its decode."""
+        decoded_ns = time.perf_counter_ns()
+        img = img.resize(out.shape[1:3][::-1], self._filter)
+        resized_ns = time.perf_counter_ns()
+        out[i] = np.asarray(img)
+        now = time.perf_counter_ns()
+        self.decode_ns += decoded_ns - self._t
+        self.resize_ns += resized_ns - decoded_ns
+        self.copy_ns += now - resized_ns
+        self.slowest_row_ns = max(self.slowest_row_ns, now - self._t)
+        self._t = now
+
+    def counters(self) -> dict:
+        return {k: getattr(self, k) for k in self.__slots__[:4]}
+
+
 def _images_to_numpy(series: Series, size: int) -> np.ndarray:
     """Convert an image-bearing Series to a dense (B, size, size, 3) uint8
     batch. Fixed-shape columns are zero-copy reshapes; variable-shape images
     host-resize (PIL) first — matching the reference's preprocessing
-    transform step."""
+    transform step. The whole call is the span ``image.preprocess``."""
     dt = series.dtype
-    if dt.id == TypeId.FIXED_SHAPE_IMAGE:
-        vals, _ = series.to_numpy_masked()
-        h, w, c = dt.shape
-        if (h, w) != (size, size) or c != 3:
-            vals = _host_resize_batch(vals, size)
-        return np.ascontiguousarray(vals)
-    if dt.id in (TypeId.FIXED_SHAPE_TENSOR, TypeId.EMBEDDING, TypeId.FIXED_SIZE_LIST):
-        vals, _ = series.to_numpy_masked()
-        if vals.ndim == 2 and vals.shape[1] == size * size * 3:
-            return vals.reshape(-1, size, size, 3).astype(np.uint8)
-        if vals.ndim == 4:
-            return vals.astype(np.uint8)
-        raise DaftTypeError(f"Cannot interpret {dt!r} as {size}x{size}x3 images")
-    if dt.id == TypeId.IMAGE:
-        from PIL import Image as PILImage
+    with device_span("image.preprocess", rows=len(series), path="tensor") as sp:
+        if dt.id == TypeId.FIXED_SHAPE_IMAGE:
+            vals, _ = series.to_numpy_masked()
+            h, w, c = dt.shape
+            if (h, w) != (size, size) or c != 3:
+                vals = _host_resize_batch(vals, size)
+            return np.ascontiguousarray(vals)
+        if dt.id in (TypeId.FIXED_SHAPE_TENSOR, TypeId.EMBEDDING, TypeId.FIXED_SIZE_LIST):
+            vals, _ = series.to_numpy_masked()
+            if vals.ndim == 2 and vals.shape[1] == size * size * 3:
+                return vals.reshape(-1, size, size, 3).astype(np.uint8)
+            if vals.ndim == 4:
+                return vals.astype(np.uint8)
+            raise DaftTypeError(f"Cannot interpret {dt!r} as {size}x{size}x3 images")
+        if dt.id == TypeId.IMAGE:
+            from PIL import Image as PILImage
 
-        out = np.zeros((len(series), size, size, 3), dtype=np.uint8)
-        for i, row in enumerate(series.to_arrow().to_pylist()):
-            if row is None:
-                continue
             from daft_tpu.datatype import ImageMode
 
-            m = ImageMode(row["mode"])
-            arr = np.frombuffer(row["data"], dtype=m.pixel_dtype.to_numpy()).reshape(
-                row["height"], row["width"], row["channel"]
-            )
-            img = PILImage.fromarray(arr.squeeze(-1) if arr.shape[2] == 1 else arr)
-            img = img.convert("RGB").resize((size, size), PILImage.BILINEAR)
-            out[i] = np.asarray(img)
-        return out
-    if dt.is_binary():
-        # Encoded images: decode+resize on host.
-        from PIL import Image as PILImage
-        import io
+            sp.count["path"] = "image"
+            out = np.zeros((len(series), size, size, 3), dtype=np.uint8)
+            rows = series.to_arrow().to_pylist()
+            clock = _RowClock()
+            for i, row in enumerate(rows):
+                if row is None:
+                    continue
+                m = ImageMode(row["mode"])
+                arr = np.frombuffer(row["data"], dtype=m.pixel_dtype.to_numpy()).reshape(
+                    row["height"], row["width"], row["channel"]
+                )
+                img = PILImage.fromarray(arr.squeeze(-1) if arr.shape[2] == 1 else arr).convert("RGB")
+                clock.resize_into(out, i, img)
+            sp.count.update(clock.counters())
+            return out
+        if dt.is_binary():
+            # Encoded images: decode+resize on host.
+            from PIL import Image as PILImage
+            import io
 
-        out = np.zeros((len(series), size, size, 3), dtype=np.uint8)
-        for i, raw in enumerate(series.to_pylist()):
-            if raw is None:
-                continue
-            img = PILImage.open(io.BytesIO(raw)).convert("RGB").resize(
-                (size, size), PILImage.BILINEAR
-            )
-            out[i] = np.asarray(img)
-        return out
+            sp.count["path"] = "encoded"
+            out = np.zeros((len(series), size, size, 3), dtype=np.uint8)
+            rows = series.to_pylist()
+            clock = _RowClock()
+            for i, raw in enumerate(rows):
+                if raw is None:
+                    continue
+                clock.resize_into(out, i, PILImage.open(io.BytesIO(raw)).convert("RGB"))
+            sp.count.update(clock.counters())
+            return out
     raise DaftTypeError(f"embed_image expects an image column, got {dt!r}")
 
 

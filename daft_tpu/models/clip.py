@@ -117,11 +117,12 @@ class CLIPImageEncoder(nn.Module):
         4x less host->device bandwidth than shipping f32.
         """
         cfg = self.cfg
-        x = pixels.astype(jnp.float32)
-        if jnp.issubdtype(pixels.dtype, jnp.integer):  # static at trace time
-            x = x / 255.0
-        x = (x - CLIP_IMAGE_MEAN) / CLIP_IMAGE_STD
-        x = x.astype(cfg.dtype)
+        with jax.named_scope("pixel_norm"):
+            x = pixels.astype(jnp.float32)
+            if jnp.issubdtype(pixels.dtype, jnp.integer):  # static at trace time
+                x = x / 255.0
+            x = (x - CLIP_IMAGE_MEAN) / CLIP_IMAGE_STD
+            x = x.astype(cfg.dtype)
         # Patchify via conv (lowered to one big matmul on the MXU).
         x = nn.Conv(cfg.vision_width, kernel_size=(cfg.patch_size, cfg.patch_size),
                     strides=(cfg.patch_size, cfg.patch_size), use_bias=False,

@@ -64,14 +64,18 @@ class MultiHeadAttention(nn.Module):
         # a kernel that fails to trace or compile fails the forward.
         from daft_tpu.ops.pallas_attention import flash_attention, pallas_attention_enabled
 
+        # Flax names every module's operations after the module; the attention
+        # core is no module, so it gets its scope here (metadata only).
         if mask is None and pallas_attention_enabled():
-            out = flash_attention(q, k, v)
+            with jax.named_scope("attn_core"):
+                out = flash_attention(q, k, v)
         else:
             if mask is not None and mask.ndim == 4:
                 # Broadcast (1|B, 1, T, T) or (B, 1, 1, T) to (B, H, T, T).
                 B, T = q.shape[0], q.shape[1]
                 mask = jnp.broadcast_to(mask, (B, self.num_heads if mask.shape[1] == 1 else mask.shape[1], T, T))
-            out = jax.nn.dot_product_attention(q, k, v, mask=mask)
+            with jax.named_scope("attn_core"):
+                out = jax.nn.dot_product_attention(q, k, v, mask=mask)
         out = out.reshape(x.shape)
         return nn.Dense(d, dtype=self.dtype, name="out")(out)
 

@@ -29,6 +29,13 @@ step-timeline profiler demonstrated a dataflow engine needs:
   tid = operator lane) loadable in Perfetto / chrome://tracing, and the
   dashboard serves the same span store as a per-query Gantt timeline
   (``/api/queries/<id>/timeline``).
+* **Device-path spans** — below the operator, the UDF path down to the
+  jitted forward is recorded at device-batch granularity by
+  :class:`device_span`: every finished span lands in a bounded
+  process-wide ring (:func:`recent_device_spans`, the flight recorder of
+  the device path, on whether or not a query is profiled) and, under an
+  ambient :class:`TaskProfiler`, also in the query's trace as a child of
+  the operator span.
 
 Spans are ALWAYS opened through context managers (daftlint DTL009): an
 un-ended span silently drops from export and leaks the thread-local parent
@@ -45,9 +52,10 @@ import os
 import random
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from daft_tpu.cancellation import current_token
 from daft_tpu.tracing import Span, span_clock_ns
 
 # Span ids: one urandom read per PROCESS, then a counter — secrets.token_hex
@@ -59,8 +67,12 @@ _ID_BASE = int.from_bytes(os.urandom(8), "big")
 _id_counter = itertools.count()
 
 
+def _span_id_hex(n: int) -> str:
+    return f"{(_ID_BASE ^ n) & 0xFFFFFFFFFFFFFFFF:016x}"
+
+
 def new_span_id() -> str:
-    return f"{(_ID_BASE ^ next(_id_counter)) & 0xFFFFFFFFFFFFFFFF:016x}"
+    return _span_id_hex(next(_id_counter))
 
 
 # Trace ids (one per query) come from a PRNG seeded once from urandom —
@@ -664,6 +676,23 @@ class TaskProfiler:
             self._finish(span)
 
 
+    def _emit_device_span(self, sp: "device_span") -> None:
+        """A finished :class:`device_span` as a span of this task's trace: the
+        child of its enclosing device span, else of the operator whose pull is
+        on this thread, and drawn on that operator's lane."""
+        attrs = dict(sp.count, thread=sp.thread)
+        frames = getattr(_tls, "stack", None)
+        if frames:
+            attrs["operator"] = frames[-1].span.attributes.get("operator")
+        if sp.error:
+            attrs["error"] = True
+        parent_id = _span_id_hex(sp.parent) if sp.parent else self._parent_id()
+        self._finish(Span(name=sp.name, trace_id=self.trace_id,
+                          span_id=_span_id_hex(sp.span_id), parent_id=parent_id,
+                          start_ns=sp.start_ns, end_ns=sp.end_ns, attributes=attrs,
+                          status="ERROR" if sp.error else "OK"))
+
+
 def task_profiler_for(trace_ctx, query_id: str, worker_id: str,
                       sink: Optional[Callable[[List[dict]], None]] = None
                       ) -> Optional[TaskProfiler]:
@@ -696,6 +725,104 @@ def profiled_task_scope(prof: Optional[TaskProfiler], task=None, **kw):
         return contextlib.nullcontext()
     # daftlint: disable=DTL009 -- returned into the caller's with-statement
     return prof.task_scope(task, **kw)
+
+
+# --------------------------------------------------------------------- #
+# Device-path spans (flight recorder + children of the operator span)   #
+# --------------------------------------------------------------------- #
+#: The newest finished device-path spans of this process, oldest first.
+#: At 8 to 16 spans a device batch that is the last 500 to 1,000 batches.
+DEVICE_SPAN_RING = 8192
+# No lock: a deque's append is atomic, and the one reader copies with a retry.
+_device_ring: "deque[device_span]" = deque(maxlen=DEVICE_SPAN_RING)
+
+
+class device_span:
+    """``with device_span("provider.stage", bytes=n) as sp:`` records one
+    span of the device path: the UDF operator's pull and call, image
+    preprocessing, model set-up, and pad/stage/dispatch/fetch of each device
+    batch. Batch granularity only (at most 16 a device batch); there is no
+    switch, and a span costs about two microseconds.
+
+    The finished span is appended to the process-wide ring
+    (:func:`recent_device_spans`) always, and under an ambient
+    :class:`TaskProfiler` it is also emitted into the query's trace, as a
+    child of the enclosing device span or else of the operator span whose
+    pull is on this thread. ``count`` holds the span's counters; the caller
+    may add to it until the span closes (``sp.count["rows"] = n``). Times
+    are on :func:`~daft_tpu.tracing.span_clock_ns`; ``span_id`` and
+    ``parent`` are process-local integers (``parent`` is 0 for a span that
+    no device span encloses on its thread); a span left by an exception has
+    ``error`` true.
+    """
+
+    __slots__ = ("name", "count", "start_ns", "end_ns", "span_id", "parent",
+                 "query_id", "thread", "error", "_open")
+
+    def __init__(self, name: str, **counters):
+        self.name = name
+        self.count = counters
+        self.end_ns = 0
+        self.error = False
+
+    def __enter__(self) -> "device_span":
+        try:
+            open_spans = _tls.device_spans
+        except AttributeError:
+            open_spans = _tls.device_spans = []
+        self._open = open_spans
+        self.parent = open_spans[-1].span_id if open_spans else 0
+        self.span_id = next(_id_counter)
+        self.thread = threading.get_ident()
+        prof = _current_profiler.get()
+        if prof is not None:
+            self.query_id = prof.query_id
+        else:
+            token = current_token()
+            self.query_id = token.query_id if token is not None else ""
+        open_spans.append(self)
+        self.start_ns = span_clock_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end_ns = span_clock_ns()
+        self.error = exc_type is not None and exc_type is not GeneratorExit
+        open_spans = self._open
+        if open_spans and open_spans[-1] is self:
+            open_spans.pop()
+        _device_ring.append(self)
+        prof = _current_profiler.get()
+        if prof is not None:
+            prof._emit_device_span(self)
+        return False
+
+    def as_dict(self) -> dict:
+        return {k: getattr(self, k) for k in self.__slots__[:-1]}
+
+
+def recent_device_spans() -> List[device_span]:
+    """A copy of the ring: the newest ``DEVICE_SPAN_RING`` finished device-path
+    spans of this process, oldest first (by the time they closed, so a parent
+    follows its children). Read it after a slow or stalled batch, or after a
+    measured window, without having asked for a profile beforehand."""
+    while True:
+        try:
+            return list(_device_ring)
+        except RuntimeError:  # another thread appended during the copy
+            continue
+
+
+def newest_device_span(name: str) -> Optional[device_span]:
+    """The span called ``name`` that closed last, or None: the counters of the
+    last forward are ``newest_device_span("provider.forward").count``."""
+    return next((sp for sp in reversed(recent_device_spans()) if sp.name == name), None)
+
+
+def span_clock_offset_ns() -> int:
+    """``time.time_ns() - span_clock_ns()``, the two read back to back: add it
+    to a device span's times to place them on the wall clock that
+    ``jax.profiler`` stamps a device trace with."""
+    return time.time_ns() - span_clock_ns()
 
 
 # --------------------------------------------------------------------- #

@@ -164,9 +164,10 @@ def test_weights_path_orbax_dir(tmp_path):
 
 
 def test_staging_modes_agree():
-    """Both staging policies produce identical embeddings; per-instance
-    stats record which mode ran."""
+    """Both staging policies produce identical embeddings; the forward's
+    span records which mode ran."""
     from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder, resolve_staging_mode
+    from daft_tpu.profiling import newest_device_span
 
     imgs = np.random.default_rng(1).integers(0, 255, (10, 32, 32, 3), dtype=np.uint8)
     outs = {}
@@ -174,9 +175,10 @@ def test_staging_modes_agree():
         emb = FlaxCLIPImageEmbedder("tiny", batch_size=4, staging_mode=mode)
         outs[mode] = emb.embed_image(imgs)
         assert emb.staging_mode == mode
-        assert emb.last_forward_stats["mode"] == mode
-        assert emb.last_forward_stats["rows"] == 10
-        assert emb.last_forward_stats["chunks"] == 3
+        forward = newest_device_span("provider.forward").count
+        assert forward["mode"] == mode
+        assert forward["rows"] == 10
+        assert forward["chunks"] == 3
     np.testing.assert_allclose(outs["overlap"], outs["separated"], rtol=1e-5)
     assert resolve_staging_mode(None) == "overlap"
     for bad in ("auto", "bogus"):

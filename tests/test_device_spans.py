@@ -1,0 +1,337 @@
+"""The recorder of the device path (``profiling.device_span``): the ring, the
+span tree of an ``embed_image`` query from the UDF operator down to dispatch and
+fetch, the counters, the spans under ``collect(profile=True)``, and the two
+scopes the model adds to the ones Flax writes. CPU, tiny CLIP; nothing here
+asserts a time."""
+
+from __future__ import annotations
+
+import contextlib
+import io
+import re
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+import daft_tpu
+from daft_tpu import col, profiling
+from daft_tpu.functions.ai import embed_image
+from daft_tpu.profiling import device_span, recent_device_spans
+
+ROWS, MORSEL, BATCH = 20, 10, 4
+CHUNK_SPANS = ("provider.pad", "provider.stage", "provider.dispatch", "provider.fetch")
+
+
+def _jpegs(n: int, seed: int = 0):
+    from PIL import Image
+
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        buf = io.BytesIO()
+        Image.fromarray(rng.integers(0, 255, (40, 48, 3), dtype=np.uint8)).save(buf, format="JPEG")
+        out.append(buf.getvalue())
+    return out
+
+
+def _since(mark: int):
+    """Spans recorded since ``mark`` = the id of the newest span before."""
+    return [s for s in recent_device_spans() if s.span_id > mark]
+
+
+def _mark() -> int:
+    with device_span("test.mark") as sp:
+        pass
+    return sp.span_id
+
+
+def _run_query(mode: str, profile: bool = False, seed: int = 1):
+    df = daft_tpu.from_pydict({"id": list(range(ROWS)), "jpg": _jpegs(ROWS)})
+    expr = embed_image(col("jpg"), provider="flax_random", model="tiny", batch_size=BATCH,
+                       seed=seed, staging_mode=mode)
+    mark = _mark()
+    with daft_tpu.execution_config_ctx(default_morsel_size=MORSEL, result_cache_enabled=False):
+        out = df.with_column("emb", expr).select("id", "emb").collect(profile=profile)
+    assert sum(len(p) for p in out.iter_partitions()) == ROWS  # the collected result, no second query
+    return _since(mark), out
+
+
+@pytest.fixture(scope="module", params=["overlap", "separated"])
+def query_spans(request):
+    spans, _ = _run_query(request.param)
+    return request.param, spans
+
+
+def _named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+# -- the span tree of one query ----------------------------------------------------
+def test_span_tree_names_and_parentage(query_spans):
+    _, spans = query_spans
+    by_id = {s.span_id: s for s in spans}
+    calls = _named(spans, "udf.call")
+    assert len(calls) == ROWS // MORSEL  # one udf.call per morsel
+    assert all(s.parent == 0 for s in calls + _named(spans, "udf.pull"))
+    for name in ("image.preprocess", "provider.forward"):
+        mine = _named(spans, name)
+        assert len(mine) == len(calls)
+        assert all(by_id[s.parent].name == "udf.call" for s in mine)
+    for name in CHUNK_SPANS:
+        assert all(by_id[s.parent].name == "provider.forward" for s in _named(spans, name))
+    # a child lies within its parent, on one thread, and the ring is in closing order
+    for s in spans:
+        if s.parent:
+            p = by_id[s.parent]
+            assert p.start_ns <= s.start_ns <= s.end_ns <= p.end_ns and p.thread == s.thread
+    assert [s.end_ns for s in spans] == sorted(s.end_ns for s in spans)
+    assert len({s.query_id for s in spans}) == 1 and spans[0].query_id  # one query, one id
+
+
+def test_one_pad_stage_dispatch_fetch_per_chunk_in_both_modes(query_spans):
+    mode, spans = query_spans
+    forwards = _named(spans, "provider.forward")
+    chunks = sum(f.count["chunks"] for f in forwards)
+    assert chunks == (ROWS // MORSEL) * -(-MORSEL // BATCH)
+    for name in CHUNK_SPANS:
+        assert len(_named(spans, name)) == chunks
+    assert {f.count["mode"] for f in forwards} == {mode}
+    first = forwards[0]
+    order = [s.name for s in spans if s.parent == first.span_id]  # in closing order
+    if mode == "separated":  # every chunk staged before the first dispatch
+        assert order[:6] == ["provider.pad", "provider.stage"] * 3
+    else:  # chunk 1 is staged while chunk 0 computes, then chunk 0 is fetched
+        assert order[:6] == ["provider.pad", "provider.stage", "provider.dispatch",
+                             "provider.pad", "provider.stage", "provider.fetch"]
+
+
+def test_pull_spans_count_the_rows_handed_on(query_spans):
+    _, spans = query_spans
+    pulls = _named(spans, "udf.pull")
+    assert sum(p.count.get("rows", 0) for p in pulls) == ROWS
+    assert "rows" not in pulls[-1].count  # the pull that found the child exhausted
+
+
+# -- counters -----------------------------------------------------------------------
+def test_rows_sum_to_the_rows_delivered_and_padding_is_counted(query_spans):
+    import jax
+
+    _, spans = query_spans
+    for name in ("udf.call", "image.preprocess", "provider.forward", "provider.pad"):
+        assert sum(s.count["rows"] for s in _named(spans, name)) == ROWS, name
+    pads = _named(spans, "provider.pad")
+    # 10 rows in chunks of 4: 4, 4 and a ragged 2, each padded to the bucket (8, or the mesh's multiple)
+    assert sorted(p.count["rows"] for p in pads) == [2, 2, 4, 4, 4, 4]
+    assert all(p.count["padded_rows"] >= 8 and p.count["padded_rows"] % 8 == 0 for p in pads)
+    for f in _named(spans, "provider.forward"):
+        assert f.count["n_devices"] in (1, len(jax.devices()))
+    image_bytes = 8 * 32 * 32 * 3
+    assert all(s.count["bytes"] == image_bytes * (p.count["padded_rows"] // 8)
+               for s, p in zip(_named(spans, "provider.stage"), pads))
+    assert all(s.count["bytes"] > 0 for s in _named(spans, "provider.fetch"))
+
+
+def test_preprocess_splits_decode_resize_and_copy(query_spans):
+    _, spans = query_spans
+    for s in _named(spans, "image.preprocess"):
+        c = s.count
+        assert c["path"] == "encoded"
+        parts = c["decode_ns"] + c["resize_ns"] + c["copy_ns"]
+        assert 0 < parts <= s.end_ns - s.start_ns
+        assert min(c["decode_ns"], c["resize_ns"], c["copy_ns"]) > 0
+        assert parts / c["rows"] <= c["slowest_row_ns"] <= parts  # at least the mean row
+
+
+@pytest.mark.parametrize("path", ["tensor", "image"])
+def test_preprocess_names_its_path(path):
+    from daft_tpu.datatype import DataType
+    from daft_tpu.functions.ai import _images_to_numpy
+
+    rng = np.random.default_rng(0)
+    if path == "tensor":
+        series = daft_tpu.Series.from_numpy(
+            rng.integers(0, 255, (3, 32 * 32 * 3), dtype=np.uint8), "img", DataType.image("RGB", 32, 32))
+    else:
+        decoded = daft_tpu.from_pydict({"jpg": _jpegs(3)}).select(col("jpg").image.decode().alias("img")).collect()
+        series = next(decoded.iter_partitions()).combined().get_column("img")
+    mark = _mark()
+    assert _images_to_numpy(series, 32).shape == (3, 32, 32, 3)
+    (sp,) = _named(_since(mark), "image.preprocess")
+    assert sp.count["path"] == path and sp.count["rows"] == 3
+    assert ("decode_ns" in sp.count) == (path == "image")
+
+
+def test_set_up_spans_appear_once_per_instance():
+    from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder
+
+    imgs = np.random.default_rng(2).integers(0, 255, (6, 32, 32, 3), dtype=np.uint8)
+    mark = _mark()
+    for _ in range(2):  # two instances, three calls each
+        emb = FlaxCLIPImageEmbedder("tiny", batch_size=4)
+        for _ in range(3):
+            emb.embed_image(imgs)
+    spans = _since(mark)
+    inits, places = _named(spans, "provider.init_params"), _named(spans, "provider.place_params")
+    assert len(inits) == len(places) == 2
+    assert inits[0].count["param_bytes"] == places[0].count["param_bytes"] > 1_000_000
+    assert places[0].count["n_devices"] >= 1
+    forwards = _named(spans, "provider.forward")
+    assert [f.count.get("first", 0) for f in forwards] == [1, 0, 0, 1, 0, 0]
+    assert profiling.newest_device_span("provider.forward") is forwards[-1]
+
+
+# -- the ring -----------------------------------------------------------------------
+def test_ring_is_bounded_and_oldest_first():
+    for i in range(profiling.DEVICE_SPAN_RING + 50):
+        with device_span("test.fill", i=i):
+            pass
+    ring = recent_device_spans()
+    assert len(ring) == profiling.DEVICE_SPAN_RING
+    assert [s.count["i"] for s in ring[-3:]] == [profiling.DEVICE_SPAN_RING + 47 + k for k in range(3)]
+    assert ring[0].count["i"] == 50  # the oldest 50 fell out
+    ring.clear()  # a copy: the recorder's own ring is untouched
+    assert len(recent_device_spans()) == profiling.DEVICE_SPAN_RING
+
+
+def test_ring_is_safe_under_two_threads():
+    n, errors = 4000, []
+
+    def worker(tag):
+        try:
+            for i in range(n):
+                with device_span("test.outer", tag=tag, i=i) as outer:
+                    with device_span("test.inner", tag=tag) as inner:
+                        pass
+                    assert inner.parent == outer.span_id
+        except Exception as e:  # noqa: BLE001 -- reported below
+            errors.append(e)
+
+    def reader(stop):
+        try:
+            while not stop.is_set():
+                recent_device_spans()
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    stop = threading.Event()
+    threads = [threading.Thread(target=worker, args=(t,)) for t in ("a", "b")]
+    watcher = threading.Thread(target=reader, args=(stop,))
+    try:
+        mark = _mark()
+        watcher.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        stop.set()
+        watcher.join(timeout=10)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and not any(t.is_alive() for t in threads + [watcher])
+    assert len(recent_device_spans()) == profiling.DEVICE_SPAN_RING  # 16,000 spans went through
+    spans = [s for s in recent_device_spans() if s.span_id > mark and s.name.startswith("test.")]
+    by_id = {s.span_id: s for s in spans}
+    inners = [s for s in spans if s.name == "test.inner" and s.parent in by_id]
+    assert inners and all(by_id[s.parent].count["tag"] == s.count["tag"] and
+                          by_id[s.parent].thread == s.thread for s in inners)
+    assert len({s.span_id for s in spans}) == len(spans)
+
+
+def test_a_span_that_raises_is_recorded_with_error():
+    mark = _mark()
+    with pytest.raises(ZeroDivisionError):
+        with device_span("test.outer"):
+            with device_span("test.raises", rows=3):
+                1 / 0
+    inner, outer = _since(mark)
+    assert (inner.name, inner.error, inner.count) == ("test.raises", True, {"rows": 3})
+    assert outer.error and inner.parent == outer.span_id
+    with device_span("test.after") as after:  # the thread's stack was unwound
+        pass
+    assert after.parent == 0 and not after.error
+    assert inner.as_dict()["error"] is True and "_open" not in inner.as_dict()
+
+
+def test_span_clock_offset_places_the_ring_on_the_wall_clock():
+    import time
+
+    with device_span("test.clock") as sp:
+        wall = time.time_ns()
+    assert sp.start_ns <= wall - profiling.span_clock_offset_ns() + 5_000_000
+    assert abs(profiling.span_clock_offset_ns()) < 60e9  # wall anchor at import, drift since
+
+
+# -- under a profiled query ---------------------------------------------------------
+def test_under_collect_profile_the_spans_hang_below_the_udf_operator():
+    spans, out = _run_query("overlap", profile=True, seed=3)
+    trace = out.query_profile.to_chrome_trace()
+    events = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    (op,) = by_name["daft.op.UDFProject"]
+    for name in ("udf.pull", "udf.call", "image.preprocess", "provider.forward") + CHUNK_SPANS:
+        assert len(by_name[name]) == len(_named(spans, name)), name
+        for e in by_name[name]:  # on the operator's lane, inside its span
+            assert (e["pid"], e["tid"]) == (op["pid"], op["tid"])
+            assert op["ts"] - 1 <= e["ts"] and e["ts"] + e["dur"] <= op["ts"] + op["dur"] + 1
+    # parentage as span ids: a call is the operator's child, a chunk's spans the forward's
+    wires = {s.span_id: s for s in out.query_profile.spans()}
+    op_id = next(s.span_id for s in wires.values() if s.name == "daft.op.UDFProject")
+    calls = [s for s in wires.values() if s.name == "udf.call"]
+    assert calls and all(s.parent_id == op_id for s in calls)
+    stage = next(s for s in wires.values() if s.name == "provider.stage")
+    assert wires[stage.parent_id].name == "provider.forward"
+    assert wires[wires[stage.parent_id].parent_id].name == "udf.call"
+    assert stage.attributes["bytes"] > 0 and stage.attributes["operator"] == "UDFProject"
+    # the operator table still counts operators only
+    assert {r["operator"] for r in out.query_profile.operator_table()} >= {"UDFProject"}
+    assert not any(r["operator"].startswith("provider") for r in out.query_profile.operator_table())
+
+
+def test_unprofiled_query_emits_nothing_but_fills_the_ring():
+    spans, out = _run_query("overlap", profile=False, seed=4)
+    assert out.query_profile is None and _named(spans, "provider.dispatch")
+
+
+# -- the scopes the model adds -------------------------------------------------------
+def _compiled_forward_text(monkeypatch, without=()):
+    import jax
+    import jax.numpy as jnp
+
+    from daft_tpu.models.clip import CLIPConfig, CLIPModel
+
+    real = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext() if name in without else real(name))
+    cfg = CLIPConfig.tiny()
+    model = CLIPModel(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((2, 32, 32, 3), jnp.uint8), jnp.zeros((2, 16), jnp.int32))
+
+    def fwd(p, pixels):
+        return model.apply(p, pixels, method=model.encode_image)
+
+    pixels = jax.ShapeDtypeStruct((8, 32, 32, 3), jnp.uint8)
+    return jax.jit(fwd).lower(params, pixels).compile().as_text()
+
+
+def test_named_scopes_change_op_name_only(monkeypatch):
+    with_scopes = _compiled_forward_text(monkeypatch)
+    without = _compiled_forward_text(monkeypatch, without=("attn_core", "pixel_norm"))
+    assert "/attn/attn_core/" in with_scopes and "/vision/pixel_norm/" in with_scopes
+    assert "attn_core" not in without and "pixel_norm" not in without
+
+    def instructions(text):
+        rows = [re.sub(r", metadata=\{[^}]*\}", "", line) for line in text.splitlines() if " = " in line]
+        assert len(rows) > 50
+        return rows
+
+    assert instructions(with_scopes) == instructions(without)
+    # every module of the block is named by Flax without our help
+    for scope in ("block_1/ln1/", "block_1/attn/qkv/", "block_1/attn/out/", "block_1/mlp/fc1/", "ln_post/"):
+        assert f"/vision/{scope}" in without
