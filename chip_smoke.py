@@ -7,11 +7,12 @@ One process, no child that imports JAX. It drives the engine's main path
 (``from_pydict -> with_column(embed_image) -> UDFProject -> Flax forward ->
 collect``) at the full width of CLIP ViT-L/14 with seeded random weights,
 then the text embedder, the prompter, the relational device path and the
-Pallas attention kernel, and checks every result. Phases run in order and the
-first failure ends the run: nothing here turns a device, compile or Pallas
-failure into a result. On a pass the last two lines of stdout are JSON
-objects: the record of the run (``"chip_smoke": "pass"``, the device, the cache
-directory and every phase's timings), then the verdict alone,
+fused Pallas attention kernel against XLA's attention, and checks every
+result. Phases run in order and the first failure ends the run: nothing here
+turns a device, compile or Pallas failure into a result. On a pass the last
+two lines of stdout are JSON objects: the record of the run
+(``"chip_smoke": "pass"``, the device, the cache directory and every phase's
+timings), then the verdict alone,
 ``{"ok": true, "device": {"platform", "kind", "count"}}``. The verdict is
 printed only after every phase passed on a TPU.
 
@@ -43,12 +44,12 @@ FULL = dict(image_model="ViT-L/14", image_px=224, image_rows=1024,
             text_model="all-MiniLM-L6-v2", text_rows=1536, text_warm=512,
             text_dim=384, lm_model="default-lm", prompts=16,
             chain_rows=200_000,
-            attn_shapes=((8, 257, 16, 64), (8, 256, 12, 32)))
+            attn_shapes=((8, 257, 16, 64), (8, 197, 12, 64), (8, 256, 4, 128)))
 TINY = dict(image_model="tiny", image_px=32, image_rows=40, image_batch=8,
             embed_dim=32,
             text_model="tiny", text_rows=40, text_warm=8, text_dim=64,
             lm_model="tiny-lm", prompts=16, chain_rows=20_000,
-            attn_shapes=((2, 257, 4, 64), (2, 256, 4, 32)))
+            attn_shapes=((2, 257, 4, 64), (2, 197, 2, 64), (2, 130, 1, 128)))
 
 
 def _timed(fn: Callable):
@@ -236,51 +237,64 @@ def phase_d(cfg) -> dict:
             "fused_rows": fused}
 
 
-def phase_e(cfg, imgs: np.ndarray, reference: np.ndarray, tiny: bool) -> dict:
-    """The Pallas kernel compiled by the TPU compiler (interpreted only under
-    --tiny-cpu), then the image forward once more with the kernel forced on."""
+def phase_e(cfg, imgs: np.ndarray, tiny: bool) -> dict:
+    """The fused attention kernel against XLA's attention: first alone on
+    projection outputs of ``attn_shapes`` (compiled by the TPU compiler;
+    interpreted only under --tiny-cpu), then one batch through the image
+    forward of a one-chip replica, which selects the kernel by itself on a
+    TPU, against the same parameters through the forward told it is
+    partitioned, which takes XLA's path. Both times are printed."""
     import jax
     import jax.numpy as jnp
 
     from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder
-    from daft_tpu.ops.pallas_attention import flash_attention
+    from daft_tpu.ops.pallas_attention import fused_attention
     from daft_tpu.parallel.replica import replica_scope
+    from daft_tpu.profiling import newest_device_span
 
     rng = np.random.default_rng(3)
+    for B, T, H, D in cfg["attn_shapes"]:
+        qkv = jnp.asarray(rng.standard_normal((B, T, 3 * H * D)), jnp.bfloat16)
+        q, k, v = (t.reshape(B, T, H, D) for t in jnp.split(qkv, 3, axis=-1))
+        np.testing.assert_allclose(
+            np.asarray(fused_attention(qkv, H, interpret=tiny), dtype=np.float32),
+            np.asarray(jax.nn.dot_product_attention(q, k, v), dtype=np.float32)
+            .reshape(B, T, H * D), atol=3e-2, rtol=3e-2,
+            err_msg=f"fused_attention {(B, T, H, D)}")
 
-    def kernel_vs_xla():
-        for shape in cfg["attn_shapes"]:
-            q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-                       for _ in range(3))
-            out = np.asarray(flash_attention(q, k, v, interpret=tiny),
-                             dtype=np.float32)
-            ref = np.asarray(jax.nn.dot_product_attention(q, k, v),
-                             dtype=np.float32)
-            np.testing.assert_allclose(out, ref, atol=3e-2, rtol=3e-2,
-                                       err_msg=f"flash_attention {shape}")
-
-    batch = cfg["image_batch"]
+    batch = imgs[:cfg["image_batch"]]
 
     def setup():
-        kernel_vs_xla()
-        # The kernel is not partitioned over a mesh, so this embedder is a
-        # single-chip replica whatever the host holds.
+        # A pallas_call is not partitioned over a mesh: the kernel is for
+        # one-chip replicas, whatever the host holds.
         with replica_scope(0, jax.devices()[:1]):
-            emb = FlaxCLIPImageEmbedder(cfg["image_model"], batch_size=batch)
+            emb = FlaxCLIPImageEmbedder(cfg["image_model"], batch_size=len(batch))
         assert emb.mesh is None
-        emb.embed_image(imgs[:batch])
-        return emb
+        emb.embed_image(batch)
+        xla_model = emb.model.clone(partitioned=True)
 
-    os.environ["DAFT_PALLAS_ATTENTION"] = "1"  # read when the forward traces
-    try:
-        emb, setup_s = _timed(setup)
-        out, run_s = _timed(lambda: emb.embed_image(imgs[:batch]))
-    finally:
-        del os.environ["DAFT_PALLAS_ATTENTION"]
-    _check_embeddings(out, batch, cfg["embed_dim"])
-    cos = _min_cosine(out, reference[:batch])
-    assert cos > 0.99, f"Pallas forward disagrees with XLA's: min cosine {cos}"
-    return {"setup_s": setup_s, "run_s": run_s, "rows": batch,
+        @jax.jit
+        def xla_fwd(p, pixels):
+            e = xla_model.apply(p, pixels, method=xla_model.encode_image)
+            return e / jnp.linalg.norm(e, axis=-1, keepdims=True).clip(1e-6)
+
+        xla = lambda: np.asarray(xla_fwd(emb.params, emb.stage_batch(batch)))
+        xla()
+        return emb, xla
+
+    (emb, xla), setup_s = _timed(setup)
+    out, run_s = _timed(lambda: emb.embed_image(batch))
+    attn = newest_device_span("provider.forward").count["attn"]
+    assert attn == ("xla" if tiny else "fused"), f"the forward took {attn!r}"
+    ref, xla_run_s = _timed(xla)
+    _check_embeddings(out, len(batch), cfg["embed_dim"])
+    np.testing.assert_allclose(out, ref, atol=5e-3,
+                               err_msg="fused forward != XLA forward")
+    cos = _min_cosine(out, ref)
+    assert cos > 0.999, f"fused forward disagrees with XLA's: min cosine {cos}"
+    return {"setup_s": setup_s, "run_s": run_s, "xla_run_s": xla_run_s,
+            "rows": len(batch), "attn": attn,
+            "max_abs_diff_vs_xla": round(float(np.abs(out - ref).max()), 6),
             "min_cosine_vs_xla": round(cos, 5)}
 
 
@@ -353,7 +367,7 @@ def main(argv=None) -> int:
             current = "D_device_chain"
             done(current, phase_d(cfg))
             current = "E_pallas"
-            done(current, phase_e(cfg, imgs, emb_overlap, tiny))
+            done(current, phase_e(cfg, imgs, tiny))
     except BaseException:
         print(f"chip_smoke: FAILED in phase {current}", file=sys.stderr,
               flush=True)

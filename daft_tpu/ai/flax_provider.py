@@ -120,8 +120,10 @@ def resolve_staging_mode(requested: Optional[str] = None) -> str:
 
 
 #: Jitted forwards that ``_chunked_forward`` has run: an instance's first call
-#: traces, loads or compiles its executable and runs it, and is set-up.
-_FORWARDS_RUN = weakref.WeakSet()
+#: traces, loads or compiles its executable and runs it, and is set-up. The
+#: value is the attention path its newest trace took (``fused`` | ``xla``;
+#: None for a model that notes none).
+_FORWARDS_RUN = weakref.WeakKeyDictionary()
 
 
 def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
@@ -136,15 +138,16 @@ def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
     * ``overlap``: depth-1 pipeline — dispatch forward for chunk i, stage
       chunk i+1 while it computes, then fetch chunk i.
 
-    The call is the span ``provider.forward``; each chunk's pad, stage,
-    dispatch and fetch are spans of their own below it (profiling.py).
+    The call is the span ``provider.forward`` (``attn`` says which attention
+    path the forward took); each chunk's pad, stage, dispatch and fetch are
+    spans of their own below it (profiling.py).
     """
     n = arr.shape[0]
     if n == 0:
         return np.zeros((0, out_dim), dtype=np.float32)
     with device_span("provider.forward", rows=n, mode=mode) as sp:
         if fwd not in _FORWARDS_RUN:
-            _FORWARDS_RUN.add(fwd)
+            _FORWARDS_RUN[fwd] = None
             sp.count["first"] = 1
         if stage is None:
             stage = jax.device_put
@@ -192,6 +195,12 @@ def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
                     f = fwd(params, on_device[i])
                 on_device[i] = None  # free the HBM reference once consumed
                 outs.append(fetched(f, cn))
+        # The model notes the attention path on this span while a forward
+        # traces (layers.MultiHeadAttention); a call that traces nothing
+        # repeats what the newest trace chose.
+        attn = _FORWARDS_RUN[fwd] = sp.count.get("attn") or _FORWARDS_RUN[fwd]
+        if attn:
+            sp.count["attn"] = attn
     return np.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
@@ -218,17 +227,8 @@ class _FlaxModelBase:
         devs = replica_devices()
         if len(devs) <= 1 and not mesh_axes:
             return None
-        from daft_tpu.ops.pallas_attention import pallas_attention_enabled
         from daft_tpu.parallel.mesh import make_mesh
 
-        if pallas_attention_enabled():
-            # GSPMD does not partition a pallas_call: under a mesh the
-            # kernel would run replicated on every device, silently.
-            raise DaftValueError(
-                f"DAFT_PALLAS_ATTENTION is on, but this replica spans "
-                f"{len(devs)} devices and the Pallas attention kernel is "
-                f"not partitioned over a mesh; turn it off or use "
-                f"single-chip replicas")
         self.mesh = make_mesh(dict(mesh_axes or {"dp": -1}), devices=devs)
         return self.mesh
 
@@ -291,7 +291,9 @@ class FlaxCLIPImageEmbedder(_FlaxModelBase):
             self.cfg = CLIPConfig.from_name(model_name)
             self.model, params = _initialised(init_clip_params, self.cfg, seed)
         self.params = self.place_params(params)
-        model = self.model
+        # GSPMD does not partition a pallas_call: under a mesh the model is
+        # told, and its attention takes XLA's path (same parameters).
+        model = self.model = self.model.clone(partitioned=self.mesh is not None)
 
         def fwd(p, pixels):
             emb = model.apply(p, pixels, method=model.encode_image)

@@ -107,6 +107,7 @@ CLIP_IMAGE_STD = np.array([0.26862954, 0.26130258, 0.27577711], dtype=np.float32
 
 class CLIPImageEncoder(nn.Module):
     cfg: CLIPConfig
+    partitioned: bool = False  # see layers.MultiHeadAttention
 
     @nn.compact
     def __call__(self, pixels: jax.Array) -> jax.Array:
@@ -139,7 +140,8 @@ class CLIPImageEncoder(nn.Module):
         for i in range(cfg.vision_layers):
             x = TransformerBlock(cfg.vision_heads, mlp_ratio=cfg.vision_mlp_ratio,
                                  dtype=cfg.dtype, act=cfg.hidden_act,
-                                 ln_eps=cfg.ln_eps, name=f"block_{i}")(x)
+                                 ln_eps=cfg.ln_eps, partitioned=self.partitioned,
+                                 name=f"block_{i}")(x)
         x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.ln_eps, name="ln_post")(x[:, 0])
         x = nn.Dense(cfg.embed_dim, use_bias=False, dtype=jnp.float32, name="proj")(x)
         return x
@@ -189,9 +191,13 @@ class CLIPModel(nn.Module):
     step target for the multi-chip dry run)."""
 
     cfg: CLIPConfig
+    #: The forward is partitioned over a mesh (the provider says so): the
+    #: vision tower's attention then takes XLA's path. The text tower is
+    #: masked and takes it always.
+    partitioned: bool = False
 
     def setup(self):
-        self.vision = CLIPImageEncoder(self.cfg)
+        self.vision = CLIPImageEncoder(self.cfg, self.partitioned)
         self.text = CLIPTextEncoder(self.cfg)
         self.logit_scale = self.param("logit_scale", nn.initializers.constant(2.6592), ())
 

@@ -43,40 +43,53 @@ class MLP(nn.Module):
 
 
 class MultiHeadAttention(nn.Module):
+    """Self-attention over ``x``. Unmasked attention on a TPU is one fused
+    kernel that reads the ``qkv`` projection's output in place
+    (``ops/pallas_attention.py``); masked attention, other backends, head
+    sizes that do not fill 128-lane tiles, a partitioned forward and ``init``
+    (which wants shapes only) take ``jax.nn.dot_product_attention``. The choice
+    is made here, when the forward traces, and noted on the open
+    ``provider.forward`` span as ``attn``."""
+
     num_heads: int
     dtype: Dtype = jnp.bfloat16
+    #: Set by the provider, never by a user: this forward is partitioned over a
+    #: mesh. GSPMD does not partition a ``pallas_call``, so the kernel is not
+    #: taken there.
+    partitioned: bool = False
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None):
+        from daft_tpu.ops import pallas_attention
+        from daft_tpu.profiling import open_device_span
+
         d = x.shape[-1]
         assert d % self.num_heads == 0
         head_dim = d // self.num_heads
         qkv = nn.Dense(3 * d, dtype=self.dtype, name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def split_heads(t):  # (B, T, H, hd) — dot_product_attention layout
-            return t.reshape(t.shape[:-1] + (self.num_heads, head_dim))
-
-        q, k, v = split_heads(q), split_heads(k), split_heads(v)
-        # Fused attention: avoids materialising (B,H,T,T) f32 logits in HBM.
-        # With DAFT_PALLAS_ATTENTION on, the unmasked path uses the
-        # hand-written pallas flash kernel (daft_tpu/ops/pallas_attention);
-        # a kernel that fails to trace or compile fails the forward.
-        from daft_tpu.ops.pallas_attention import flash_attention, pallas_attention_enabled
-
+        fused = (mask is None and not self.partitioned
+                 and not self.is_initializing()
+                 and pallas_attention.fused_attention_applies(
+                     qkv.shape, qkv.dtype, self.num_heads))
+        forward = open_device_span("provider.forward")
+        if forward is not None:
+            forward.count["attn"] = "fused" if fused else "xla"
         # Flax names every module's operations after the module; the attention
         # core is no module, so it gets its scope here (metadata only).
-        if mask is None and pallas_attention_enabled():
+        if fused:
+            # A kernel that fails to trace or lower fails the forward.
             with jax.named_scope("attn_core"):
-                out = flash_attention(q, k, v)
+                out = pallas_attention.fused_attention(qkv, self.num_heads)
         else:
+            q, k, v = (t.reshape(t.shape[:-1] + (self.num_heads, head_dim))
+                       for t in jnp.split(qkv, 3, axis=-1))  # (B, T, H, hd)
             if mask is not None and mask.ndim == 4:
                 # Broadcast (1|B, 1, T, T) or (B, 1, 1, T) to (B, H, T, T).
                 B, T = q.shape[0], q.shape[1]
                 mask = jnp.broadcast_to(mask, (B, self.num_heads if mask.shape[1] == 1 else mask.shape[1], T, T))
             with jax.named_scope("attn_core"):
                 out = jax.nn.dot_product_attention(q, k, v, mask=mask)
-        out = out.reshape(x.shape)
+            out = out.reshape(x.shape)
         return nn.Dense(d, dtype=self.dtype, name="out")(out)
 
 
@@ -114,13 +127,15 @@ class TransformerBlock(nn.Module):
     dtype: Dtype = jnp.bfloat16
     act: str = "gelu"
     ln_eps: float = 1e-6
+    partitioned: bool = False  # see MultiHeadAttention
 
     @nn.compact
     def __call__(self, x, mask: Optional[jax.Array] = None):
         d = x.shape[-1]
         h = nn.LayerNorm(dtype=jnp.float32, epsilon=self.ln_eps,
                          name="ln1")(x).astype(self.dtype)
-        x = x + MultiHeadAttention(self.num_heads, self.dtype, name="attn")(h, mask)
+        x = x + MultiHeadAttention(self.num_heads, self.dtype, self.partitioned,
+                                   name="attn")(h, mask)
         h = nn.LayerNorm(dtype=jnp.float32, epsilon=self.ln_eps,
                          name="ln2")(x).astype(self.dtype)
         # round(): converted checkpoints carry intermediate/hidden as a float

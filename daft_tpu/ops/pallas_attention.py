@@ -1,186 +1,159 @@
-"""Pallas flash attention for the model towers.
+"""Fused attention for the unmasked towers (the ViT of CLIP): one Pallas TPU
+kernel between the ``qkv`` projection and the ``out`` projection.
 
-A TPU-native fused attention kernel (online softmax — logits never
-materialise in HBM), used by ``models/layers.MultiHeadAttention`` when
-``DAFT_PALLAS_ATTENTION=1``. Handles non-causal (ViT/BERT) and key-padding
-via an explicit valid-length: ViT-L's 257-token sequence pads to a lane-tiled
-384 and the padded keys are masked inside the kernel.
+``fused_attention`` reads q, k and v where the projection wrote them, as
+column blocks of its ``[B, T, 3d]`` output, and writes its result as columns
+of ``[B, T, d]``, heads in the order the projection laid them out. The
+compiled forward therefore holds no split, head reshape, transpose or copy
+around attention, and no ``[B, H, T, T]`` tensor exists in HBM: one grid step
+holds one batch row's columns in VMEM and computes, head by head, the whole
+``[T, T]`` score tile, one max, one exp, one sum and the product with v.
+There is no online softmax: the sequences this serves (197 and 257 tokens)
+fit VMEM many times over, and ``fused_attention_applies`` sends anything
+that does not to XLA.
 
-Grid: (batch*heads, q_blocks, kv_blocks) with the kv dimension innermost —
-each (bh, q) output block is revisited across kv steps, with running max /
-denominator / accumulator kept in VMEM scratch (the canonical pallas flash
-pattern). f32 accumulation over bf16 inputs.
+Arithmetic is that of ``jax.nn.dot_product_attention``: operands enter the
+MXU in the dtype they arrive in, scores and softmax are float32, the
+probabilities are cast to v's dtype for the second product. The row sum
+divides the ``[T, head_dim]`` result and not the ``[T, T]`` tile.
 
-The kernel runs on a TPU backend only (``pallas_attention_enabled`` gates on
-it); there a kernel that fails to compile fails its caller. Tests run it in
-interpret mode on CPU for exactness against the reference attention.
+Heads narrower than a 128-lane tile are handled without a lane shuffle. A
+128-column slice of q, k or v holds ``128 // head_dim`` adjacent heads. For
+head h the other heads' lanes of q are zeroed and the scores contract over
+all 128 lanes (the MXU is 128 deep whether 64 are used or not); the
+probabilities multiply the whole 128-wide v slice and head h's lanes are
+selected from the result.
+
+Which path a forward takes is decided when it is traced, from what the code
+can see (``fused_attention_applies`` and, in ``models/layers.py``, the mask
+and whether the forward is partitioned over a mesh): there is no switch. A
+kernel that fails to lower fails the forward. Forward only: no VJP is
+defined. Tests run the kernel in interpret mode on the CPU.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_KV = 128
-_NEG_INF = float(-1e30)
-
-
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                 *, valid_len: int, block_kv: int, scale: float):
-    from jax.experimental import pallas as pl
-
-    kv_idx = pl.program_id(2)
-    n_kv = pl.num_programs(2)
-
-    @pl.when(kv_idx == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)           # (block_q, d)
-    k = k_ref[0].astype(jnp.float32)           # (block_kv, d)
-    v = v_ref[0].astype(jnp.float32)           # (block_kv, d)
-    logits = (q * scale) @ k.T                 # (block_q, block_kv) on the MXU
-
-    # Mask padded key positions (global kv index >= valid_len).
-    kv_positions = kv_idx * block_kv + jax.lax.broadcasted_iota(
-        jnp.int32, logits.shape, 1
-    )
-    logits = jnp.where(kv_positions < valid_len, logits, _NEG_INF)
-
-    m_prev = m_ref[:]                          # (block_q, 1)
-    m_cur = jnp.max(logits, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(logits - m_new)                # (block_q, block_kv)
-    correction = jnp.exp(m_prev - m_new)
-    l_ref[:] = l_ref[:] * correction + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * correction + p @ v
-    m_ref[:] = m_new
-
-    @pl.when(kv_idx == n_kv - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+_LANES = 128
+#: What one grid step may hold in VMEM, by ``_step_bytes``' reckoning: a quarter
+#: of a v5e core's 128 MiB, and the limit handed to the compiler. ViT-L/14's
+#: step (T 257, d 1024) is reckoned at 6.6 MiB; at T 1024 a step takes half a
+#: row of d 1024, and past T 1.1k the score tiles alone exceed it.
+VMEM_BUDGET = 32 << 20
 
 
-@functools.partial(jax.jit, static_argnames=("block_q", "block_kv", "interpret"))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    block_q: int = DEFAULT_BLOCK_Q, block_kv: int = DEFAULT_BLOCK_KV,
-                    interpret: bool = False) -> jax.Array:
-    """Non-causal attention. q/k/v: (B, T, H, D) -> (B, T, H, D)."""
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _step_bytes(T: int, width: int, itemsize: int) -> int:
+    """VMEM of one grid step over ``width`` columns of one batch row: the q, k, v
+    and result blocks, double-buffered, and the float32 score tiles (scores,
+    exponentials and their cast copy, of two heads in flight)."""
+    blocks = 2 * 4 * _round_up(T, 16) * width * itemsize
+    scores = 2 * 3 * _round_up(T, 8) * _round_up(T, _LANES) * 4
+    return blocks + scores
+
+
+def _block_width(T: int, d: int, itemsize: int) -> int:
+    """The widest column block that divides ``d`` into whole 128-lane tiles and
+    fits the budget, the whole row first (its DMAs are contiguous and the grid is
+    shortest); 0 when not even one tile fits."""
+    tiles = d // _LANES
+    for n in range(1, tiles + 1):
+        if tiles % n == 0 and _step_bytes(T, d // n, itemsize) <= VMEM_BUDGET:
+            return d // n
+    return 0
+
+
+def backend_is_tpu() -> bool:
+    """The kernel lowers for a TPU only, and is baked into the jaxpr when the
+    forward traces: the rule is the backend the process computes on."""
+    return jax.default_backend() == "tpu"
+
+
+def fused_attention_applies(qkv_shape, dtype, num_heads: int) -> bool:
+    """Whether ``fused_attention`` serves this projection output: a TPU backend,
+    bf16 or f32 operands, whole heads filling 128-lane tiles, and a step's
+    working set inside ``VMEM_BUDGET``. Otherwise the caller takes XLA's path."""
+    _, T, d3 = qkv_shape
+    d = d3 // 3
+    dtype = jnp.dtype(dtype)
+    return (backend_is_tpu()
+            and dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and d % num_heads == 0 and _LANES % (d // num_heads) == 0
+            and d % _LANES == 0
+            and _block_width(T, d, dtype.itemsize) > 0)
+
+
+def _attention_kernel(q_ref, k_ref, v_ref, o_ref, *, head_dim: int):
+    """Blocks are ``(1, T, width)``: one batch row, ``width // head_dim`` heads."""
+    width = q_ref.shape[-1]
+    scale = head_dim ** -0.5
+    # A power of two (head_dim 64) scales q exactly in any float dtype;
+    # otherwise the float32 scores are scaled, as XLA's path does.
+    scale_q = math.frexp(scale)[0] == 0.5
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    contract_lanes = (((1,), (1,)), ((), ()))
+    for c in range(width // _LANES):
+        cols = slice(c * _LANES, (c + 1) * _LANES)
+        q, k, v = q_ref[0, :, cols], k_ref[0, :, cols], v_ref[0, :, cols]
+        if scale_q:
+            q = q * jnp.asarray(scale, q.dtype)
+        out = None
+        for h in range(_LANES // head_dim):
+            in_head = (lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+            q_h = q if head_dim == _LANES else jnp.where(in_head, q, jnp.zeros_like(q))
+            s = jax.lax.dot_general(q_h, k, contract_lanes,
+                                    preferred_element_type=jnp.float32)  # [T, T]
+            if not scale_q:
+                s = s * scale
+            p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            row_sum = jnp.sum(p, axis=-1, keepdims=True)
+            o = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            o = o * (1.0 / row_sum)  # [T, 128]; head h's lanes are its result
+            out = o if out is None else jnp.where(in_head, o, out)
+        o_ref[0, :, cols] = out.astype(o_ref.dtype)
+
+
+# Jitted so that the blocks of a tower share one trace and one lowering of the
+# kernel: traced bare, 24 blocks cost a process 2 to 4 s more set-up each time
+# a forward traces, whatever the compile cache holds (my chip runs, PR 27).
+@functools.partial(jax.jit, static_argnames=("num_heads", "interpret"))
+def fused_attention(qkv: jax.Array, num_heads: int, interpret: bool = False) -> jax.Array:
+    """Unmasked attention over the ``qkv`` projection's output.
+
+    ``qkv``: ``[B, T, 3d]``, columns ``[0, d)`` q, ``[d, 2d)`` k, ``[2d, 3d)`` v,
+    each head ``d // num_heads`` adjacent columns. Returns ``[B, T, d]`` in the
+    same head order: what ``MultiHeadAttention``'s ``out`` projection reads.
+    The caller has asked ``fused_attention_applies``.
+    """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    import math
-
-    B, T, H, D = q.shape
-    scale = D ** -0.5
-    # Pad T up to a common multiple of BOTH block sizes (a kv block count of
-    # T_pad // block_kv must cover every key); padded keys are masked, padded
-    # queries produce garbage rows sliced off at the end.
-    step = math.lcm(block_q, block_kv)
-    T_pad = ((T + step - 1) // step) * step
-    if T_pad != T:
-        pad = [(0, 0), (0, T_pad - T), (0, 0), (0, 0)]
-        q = jnp.pad(q, pad)
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
-
-    # (B, T, H, D) -> (B*H, T, D)
-    def to_bh(t):
-        return t.transpose(0, 2, 1, 3).reshape(B * H, T_pad, D)
-
-    qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
-    n_q = T_pad // block_q
-    n_kv = T_pad // block_kv
-
-    kernel = functools.partial(_attn_kernel, valid_len=T, block_kv=block_kv, scale=scale)
-    out = pl.pallas_call(
-        kernel,
-        grid=(B * H, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda bh, i, j: (bh, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, T_pad, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
+    B, T, d3 = qkv.shape
+    d = d3 // 3
+    width = _block_width(T, d, qkv.dtype.itemsize)
+    if width == 0:
+        raise ValueError(f"fused_attention: one step over T={T}, d={d} exceeds "
+                         f"the VMEM budget of {VMEM_BUDGET} bytes")
+    n = d // width  # column blocks of each of q, k and v
+    # T is the array's full extent, which a block dimension may be.
+    column_block = lambda part: pl.BlockSpec(
+        (1, T, width), lambda b, j: (b, 0, part * n + j))
+    return pl.pallas_call(
+        functools.partial(_attention_kernel, head_dim=d // num_heads),
+        grid=(B, n),
+        in_specs=[column_block(0), column_block(1), column_block(2)],
+        out_specs=column_block(0),
+        out_shape=jax.ShapeDtypeStruct((B, T, d), qkv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
-    )(qb, kb, vb)
-    out = out.reshape(B, H, T_pad, D).transpose(0, 2, 1, 3)
-    return out[:, :T]
-
-
-_AUTO_PROBE: "bool | None" = None
-
-
-def _probe_pallas_wins() -> bool:
-    """One-shot real-device A/B: compile+run the pallas kernel and
-    jax.nn.dot_product_attention at a ViT-L-shaped slice; enable pallas only
-    when it is numerically consistent AND not slower. A kernel that does
-    not compile raises out of the probe."""
-    import logging
-    import time
-
-    log = logging.getLogger(__name__)
-    B, T, H, D = 4, 257, 16, 64
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.bfloat16)
-    ref_fn = jax.jit(lambda a, b, c: jax.nn.dot_product_attention(a, b, c))
-    out_p = np.asarray(flash_attention(q, k, v))
-    out_r = np.asarray(ref_fn(q, k, v))
-    if not np.allclose(out_p.astype(np.float32), out_r.astype(np.float32),
-                       atol=3e-2, rtol=3e-2):
-        log.warning("pallas attention probe: numeric mismatch; disabled")
-        return False
-
-    def best_of(fn, n=3):
-        times = []
-        for _ in range(n):
-            t0 = time.perf_counter()
-            # daftlint: disable=DTL005 -- microbenchmark: the sync IS the measurement
-            jax.block_until_ready(fn())
-            times.append(time.perf_counter() - t0)
-        return min(times)
-
-    tp = best_of(lambda: flash_attention(q, k, v))
-    tr = best_of(lambda: ref_fn(q, k, v))
-    win = tp <= tr * 1.05
-    log.info("pallas attention probe: pallas %.4fs vs xla %.4fs -> %s",
-             tp, tr, "on" if win else "off")
-    return win
-
-
-def pallas_attention_enabled() -> bool:
-    """Gate for the model towers. ``DAFT_PALLAS_ATTENTION``:
-    ``1``/``true`` force-on (TPU only), ``0``/``false`` force-off (default),
-    ``auto`` probes the real device once per process and enables pallas only
-    when it matches XLA numerically and is not slower. The kernel is baked
-    into jaxprs at trace time and cannot lower on other platforms, so the
-    gate is the actual backend."""
-    from daft_tpu.config import daft_env
-
-    env = daft_env("DAFT_PALLAS_ATTENTION", "0")
-    if env in ("0", "false"):
-        return False
-    if jax.default_backend() != "tpu":
-        return False
-    if env in ("1", "true"):
-        return True
-    if env == "auto":
-        global _AUTO_PROBE
-        if _AUTO_PROBE is None:
-            _AUTO_PROBE = _probe_pallas_wins()
-        return _AUTO_PROBE
-    return False
+    )(qkv, qkv, qkv)

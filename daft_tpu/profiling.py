@@ -818,6 +818,14 @@ def newest_device_span(name: str) -> Optional[device_span]:
     return next((sp for sp in reversed(recent_device_spans()) if sp.name == name), None)
 
 
+def open_device_span(name: str) -> Optional[device_span]:
+    """The innermost span called ``name`` that is open on this thread, or None:
+    for code that runs below a span without being handed it (a model's choice
+    of path while its forward traces is a counter of ``provider.forward``)."""
+    open_spans = getattr(_tls, "device_spans", ())
+    return next((sp for sp in reversed(open_spans) if sp.name == name), None)
+
+
 def span_clock_offset_ns() -> int:
     """``time.time_ns() - span_clock_ns()``, the two read back to back: add it
     to a device span's times to place them on the wall clock that

@@ -99,21 +99,6 @@ assert not xla_bridge.backends_are_initialized()
     assert "['cpu|']" in proc.stdout
 
 
-def test_pallas_kernel_is_refused_under_a_mesh(monkeypatch):
-    """A pallas_call is not partitioned by GSPMD: with the kernel forced on,
-    a replica that spans several devices raises instead of replicating."""
-    import jax
-
-    from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder
-    from daft_tpu.errors import DaftValueError
-
-    assert len(jax.devices()) > 1  # conftest: 8 virtual CPU devices
-    monkeypatch.setenv("DAFT_PALLAS_ATTENTION", "1")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(DaftValueError, match="not partitioned over a mesh"):
-        FlaxCLIPImageEmbedder("tiny")
-
-
 def test_chip_smoke_fails_without_a_chip():
     proc = _run(["chip_smoke.py"], JAX_PLATFORMS="cpu")
     assert proc.returncode != 0

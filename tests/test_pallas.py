@@ -1,6 +1,13 @@
-"""Pallas flash-attention kernel tests (interpret mode on CPU — the
-fake-device-mesh CI pattern; real TPU compile is opt-in via
-DAFT_PALLAS_ATTENTION=1)."""
+"""The fused attention kernel (``ops/pallas_attention.py``): its arithmetic in
+interpret mode on the CPU against ``jax.nn.dot_product_attention`` on the same
+``[B, T, 3d]`` input, the rule that selects it, what it leaves in the traced
+forward, and its compile for a described v5e chip at the widths the benchmark
+runs. One file, so that the tests that describe a TPU topology stay with their
+fixture (see the on-chip-measurement guide)."""
+
+import collections
+import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -8,81 +15,279 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from daft_tpu.ops.pallas_attention import flash_attention
+from daft_tpu.models.layers import MultiHeadAttention
+from daft_tpu.ops import pallas_attention as pa
 
 
-@pytest.mark.parametrize("T", [128, 257, 300])
-def test_flash_attention_matches_reference(T):
-    rng = np.random.default_rng(0)
-    B, H, D = 2, 4, 64
-    q = jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32))
-    k = jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(B, T, H, D)).astype(np.float32))
-    ref = jax.nn.dot_product_attention(q, k, v)
-    out = flash_attention(q, k, v, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+def _reference(qkv, num_heads, head_order=None):
+    """XLA's path as ``MultiHeadAttention`` takes it; ``head_order`` permutes
+    the heads of the result (a wrong column order, for the test of the right one)."""
+    B, T, d3 = qkv.shape
+    d = d3 // 3
+    q, k, v = (t.reshape(B, T, num_heads, d // num_heads)
+               for t in jnp.split(qkv, 3, axis=-1))
+    out = jax.nn.dot_product_attention(q, k, v)
+    if head_order is not None:
+        out = out[:, :, list(head_order)]
+    return np.asarray(out.reshape(B, T, d), np.float32)
 
 
-def test_flash_attention_bf16():
-    rng = np.random.default_rng(1)
-    B, T, H, D = 1, 200, 2, 64
-    q = jnp.asarray(rng.normal(size=(B, T, H, D)), dtype=jnp.bfloat16)
-    k = jnp.asarray(rng.normal(size=(B, T, H, D)), dtype=jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=(B, T, H, D)), dtype=jnp.bfloat16)
-    ref = jax.nn.dot_product_attention(q, k, v)
-    out = flash_attention(q, k, v, interpret=True)
-    assert out.dtype == jnp.bfloat16
-    np.testing.assert_allclose(
-        np.asarray(out, dtype=np.float32), np.asarray(ref, dtype=np.float32),
-        atol=3e-2, rtol=3e-2,
-    )
+def _qkv(T, num_heads, head_dim, dtype, seed=0, B=2):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.standard_normal((B, T, 3 * num_heads * head_dim)), dtype)
 
 
-def test_env_toggle_off_tpu_keeps_kernel_off(monkeypatch):
-    """With the flag on, a backend that is not a TPU keeps the kernel off
-    (the backend gate) and the model layer computes through XLA attention."""
-    monkeypatch.setenv("DAFT_PALLAS_ATTENTION", "1")
-    from daft_tpu.models.clip import CLIPConfig, init_clip_params
+# -- arithmetic ------------------------------------------------------------------
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("num_heads,head_dim", [(4, 64), (12, 64), (2, 128)])
+@pytest.mark.parametrize("T", [128, 197, 257, 300])
+def test_fused_attention_matches_xla(T, num_heads, head_dim, dtype, tol):
+    qkv = _qkv(T, num_heads, head_dim, dtype, seed=T + num_heads)
+    out = pa.fused_attention(qkv, num_heads, interpret=True)
+    assert out.shape == (2, T, num_heads * head_dim) and out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32), _reference(qkv, num_heads),
+                               atol=tol, rtol=tol)
 
-    cfg = CLIPConfig.tiny()
-    model, params = init_clip_params(cfg)
-    px = jnp.zeros((2, cfg.image_size, cfg.image_size, 3), jnp.uint8)
-    out = model.apply(params, px, method=model.encode_image)
-    assert np.isfinite(np.asarray(out)).all()
+
+@pytest.mark.parametrize("num_heads,head_dim", [(4, 64), (4, 32), (2, 128)])
+def test_result_lands_in_the_projection_column_order(num_heads, head_dim):
+    """Head h of the result is columns [h*head_dim, (h+1)*head_dim) of [B, T, d]:
+    the reference with two heads swapped must not pass for it."""
+    qkv = _qkv(197, num_heads, head_dim, jnp.float32, seed=7)
+    out = np.asarray(pa.fused_attention(qkv, num_heads, interpret=True))
+    np.testing.assert_allclose(out, _reference(qkv, num_heads), atol=2e-5, rtol=2e-5)
+    swapped = [1, 0] + list(range(2, num_heads))
+    assert not np.allclose(out, _reference(qkv, num_heads, swapped), atol=1e-2, rtol=1e-2)
 
 
-def test_forced_kernel_failure_propagates(monkeypatch):
-    """DAFT_PALLAS_ATTENTION=1 on a TPU backend with a kernel that raises:
-    the error leaves MultiHeadAttention — XLA's result is not substituted."""
-    from daft_tpu.models.layers import MultiHeadAttention
-    from daft_tpu.ops import pallas_attention as pa
+def test_rows_and_heads_do_not_mix():
+    """Attention has no term across batch rows or heads: changing one row's one
+    head of v changes that row's head of the result and nothing else."""
+    qkv = _qkv(130, 4, 64, jnp.float32, seed=3)
+    d = 256
+    bumped = qkv.at[1, :, 2 * d + 64:2 * d + 128].add(1.0)  # v of row 1, head 1
+    a = np.asarray(pa.fused_attention(qkv, 4, interpret=True))
+    b = np.asarray(pa.fused_attention(bumped, 4, interpret=True))
+    changed = np.abs(a - b) > 1e-6
+    assert changed[1, :, 64:128].all()
+    changed[1, :, 64:128] = False
+    assert not changed.any()
 
-    mha = MultiHeadAttention(num_heads=2, dtype=jnp.float32)
-    x = jnp.ones((1, 8, 16), jnp.float32)
-    params = mha.init(jax.random.PRNGKey(0), x)  # kernel off: XLA path
 
-    def broken_kernel(q, k, v):
+def test_large_scores_do_not_overflow():
+    qkv = _qkv(197, 2, 64, jnp.float32, seed=5) * 30.0
+    out = np.asarray(pa.fused_attention(qkv, 2, interpret=True))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, _reference(qkv, 2), atol=1e-4, rtol=1e-4)
+
+
+# -- the rule that selects it ------------------------------------------------------
+def test_block_width_takes_the_whole_row_while_it_fits():
+    assert pa._block_width(257, 1024, 2) == 1024   # ViT-L/14: all 16 heads a step
+    assert pa._block_width(197, 768, 2) == 768     # ViT-B/16
+    assert pa._block_width(1024, 1024, 2) == 512   # half a row a step
+    narrower = pa._block_width(1024, 4096, 2)
+    assert 0 < narrower < 4096 and 4096 % narrower == 0 and narrower % 128 == 0
+    assert pa._step_bytes(1024, narrower, 2) <= pa.VMEM_BUDGET < pa._step_bytes(1024, 2 * narrower, 2)
+    assert pa._block_width(4096, 1024, 2) == 0     # the score tiles alone exceed it
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The backend rule answers as on a TPU, and the kernel it then selects runs
+    interpreted. ``calls`` counts the kernel's calls."""
+    calls = []
+    real = pa.fused_attention
+
+    def interpreted(qkv, num_heads):
+        calls.append(qkv.shape)
+        return real(qkv, num_heads, interpret=True)
+
+    monkeypatch.setattr(pa, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pa, "fused_attention", interpreted)
+    return calls
+
+
+def _mha(num_heads=2, d=128, T=8, **kw):
+    mha = MultiHeadAttention(num_heads=num_heads, dtype=jnp.float32, **kw)
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, T, d)), jnp.float32)
+    return mha, x, mha.init(jax.random.PRNGKey(0), x)  # init takes XLA's path
+
+
+def test_unmasked_attention_on_a_tpu_takes_the_kernel(on_tpu):
+    mha, x, params = _mha()
+    assert on_tpu == []  # init wants shapes only and traces no kernel
+    out = mha.apply(params, x)
+    assert on_tpu == [(2, 8, 384)]
+    xla = mha.clone(partitioned=True).apply(params, x)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(xla), atol=2e-5, rtol=2e-5)
+    assert len(on_tpu) == 1
+
+
+def test_a_mask_takes_xla(on_tpu):
+    mha, x, params = _mha()
+    out = mha.apply(params, x, jnp.ones((1, 1, 8, 8), bool))
+    assert on_tpu == [] and np.isfinite(np.asarray(out)).all()
+
+
+def test_cpu_backend_takes_xla(monkeypatch):
+    def never(qkv, num_heads):
+        raise AssertionError("the kernel was selected on a CPU backend")
+
+    monkeypatch.setattr(pa, "fused_attention", never)
+    assert not pa.backend_is_tpu()
+    assert not pa.fused_attention_applies((2, 257, 3072), jnp.bfloat16, 16)
+    mha, x, params = _mha()
+    assert np.isfinite(np.asarray(mha.apply(params, x))).all()
+
+
+def test_head_dim_that_does_not_fill_lane_tiles_takes_xla(on_tpu):
+    mha, x, params = _mha(num_heads=4, d=192)  # head_dim 48
+    out = mha.apply(params, x)
+    assert on_tpu == [] and np.isfinite(np.asarray(out)).all()
+    assert not pa.fused_attention_applies((2, 8, 3 * 192), jnp.float32, 4)
+    assert not pa.fused_attention_applies((2, 8, 3 * 64), jnp.float32, 1)    # d is no whole tile
+    assert not pa.fused_attention_applies((2, 8, 384), jnp.float16, 2)       # not bf16 or f32
+    assert not pa.fused_attention_applies((2, 4096, 3072), jnp.bfloat16, 16)  # beyond the budget
+    assert pa.fused_attention_applies((2, 8, 384), jnp.float32, 2)
+
+
+def test_a_module_told_it_is_partitioned_takes_xla(on_tpu):
+    mha, x, params = _mha(partitioned=True)
+    out = mha.apply(params, x)
+    assert on_tpu == [] and np.isfinite(np.asarray(out)).all()
+
+
+def test_kernel_failure_propagates(monkeypatch):
+    """A kernel that raises when the forward traces: the error leaves
+    MultiHeadAttention, and XLA's result is not substituted."""
+    mha, x, params = _mha()
+
+    def broken_kernel(qkv, num_heads):
         raise RuntimeError("mosaic refused the kernel")
 
-    monkeypatch.setenv("DAFT_PALLAS_ATTENTION", "1")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(pa, "flash_attention", broken_kernel)
-    assert pa.pallas_attention_enabled() is True
+    monkeypatch.setattr(pa, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pa, "fused_attention", broken_kernel)
     with pytest.raises(RuntimeError, match="mosaic refused"):
         mha.apply(params, x)
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        jax.jit(mha.apply)(params, x)
     # The masked path never takes the kernel.
     out = mha.apply(params, x, jnp.ones((1, 1, 8, 8), bool))
     assert np.isfinite(np.asarray(out)).all()
 
 
-def test_auto_gate_modes(monkeypatch):
-    """DAFT_PALLAS_ATTENTION: 0/absent -> off; auto on a CPU backend -> off
-    (the probe is TPU-only); 1 on CPU backend -> off (backend gate)."""
-    from daft_tpu.ops import pallas_attention as pa
+def test_a_provider_under_a_mesh_tells_the_model(on_tpu):
+    """A replica that spans several devices must not reach the bare kernel:
+    the provider sets the module's static field, and no option or variable."""
+    from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder
+    from daft_tpu.parallel.replica import replica_scope
+    from daft_tpu.profiling import newest_device_span
 
-    monkeypatch.delenv("DAFT_PALLAS_ATTENTION", raising=False)
-    assert pa.pallas_attention_enabled() is False
-    monkeypatch.setenv("DAFT_PALLAS_ATTENTION", "auto")
-    assert pa.pallas_attention_enabled() is False  # cpu backend, probe gated
-    monkeypatch.setenv("DAFT_PALLAS_ATTENTION", "0")
-    assert pa.pallas_attention_enabled() is False
+    assert len(jax.devices()) > 1  # conftest: 8 virtual CPU devices
+    px = np.zeros((8, 32, 32, 3), np.uint8)
+    meshed = FlaxCLIPImageEmbedder("tiny", batch_size=8)
+    assert meshed.mesh is not None and meshed.model.partitioned
+    meshed.embed_image(px)
+    assert newest_device_span("provider.forward").count["attn"] == "xla"
+    with replica_scope(0, jax.devices()[:1]):
+        single = FlaxCLIPImageEmbedder("tiny", batch_size=8)
+    assert single.mesh is None and not single.model.partitioned
+    assert on_tpu == []  # tiny's width is half a lane tile: XLA either way
+
+
+# -- what the forward holds, and what its span says --------------------------------
+def _vit(layers=2):
+    """The image tower at one 128-lane tile of width."""
+    from daft_tpu.models.clip import CLIPConfig, CLIPImageEncoder
+    from daft_tpu.models.layers import init_params
+
+    cfg = dataclasses.replace(CLIPConfig.tiny(), vision_width=128, vision_heads=2,
+                              vision_layers=layers, dtype=jnp.float32)
+    model = CLIPImageEncoder(cfg)
+    px = jnp.asarray(np.random.default_rng(1).integers(0, 255, (4, 32, 32, 3)), jnp.uint8)
+    return cfg, model, init_params(model, jax.random.PRNGKey(0), px), px
+
+
+def test_no_relayout_is_left_between_qkv_and_out(on_tpu):
+    """The traced image tower: under each block's ``attn``, outside the two
+    projections, the one operation is the kernel, in the ``attn_core`` scope. No
+    split, slice, reshape or transpose of the qkv tensor is left."""
+    cfg, model, params, px = _vit()
+    by_scope = collections.defaultdict(list)
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            scope = "/".join(s for s in (outer, str(eqn.source_info.name_stack)) if s)
+            if "jaxpr" in eqn.params and eqn.primitive.name != "pallas_call":
+                walk(eqn.params["jaxpr"].jaxpr, scope)  # the jitted kernel wrapper
+            else:
+                by_scope[scope].append(eqn.primitive.name)
+
+    walk(jax.make_jaxpr(model.apply)(params, px).jaxpr, "")
+    attn = {s: prims for s, prims in by_scope.items() if "/attn" in s}
+    for i in range(cfg.vision_layers):
+        block = f"CLIPImageEncoder/block_{i}/attn"
+        assert attn[block + "/attn_core"] == ["pallas_call"]
+        assert attn[block + "/qkv"] == attn[block + "/out"] == ["dot_general", "reshape", "add"]
+        assert not [s for s in attn if s.startswith(block)
+                    and s not in (block + "/attn_core", block + "/qkv", block + "/out")]
+    everything = [p for prims in attn.values() for p in prims]
+    assert everything.count("pallas_call") == cfg.vision_layers == len(on_tpu)
+    assert not {"transpose", "split", "slice", "dynamic_slice", "gather", "concatenate"} & set(everything)
+    # and the forward computes what XLA's path computes
+    fused = np.asarray(jax.jit(model.apply)(params, px))
+    xla = np.asarray(jax.jit(model.clone(partitioned=True).apply)(params, px))
+    np.testing.assert_allclose(fused, xla, atol=1e-4, rtol=1e-4)
+
+
+def test_forward_span_says_which_path_was_traced(on_tpu):
+    from daft_tpu.ai import flax_provider
+    from daft_tpu.profiling import newest_device_span
+
+    cfg, model, params, px = _vit()
+    paths = []
+    for partitioned in (False, True):
+        fwd = jax.jit(model.clone(partitioned=partitioned).apply)
+        for _ in range(2):  # the second call traces nothing and repeats the first's
+            flax_provider._chunked_forward(fwd, params, np.asarray(px), 4, cfg.embed_dim)
+            paths.append(newest_device_span("provider.forward").count["attn"])
+    assert paths == ["fused", "fused", "xla", "xla"]
+
+
+# -- compiled for the chip, without the chip ----------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,T,d,num_heads", [(512, 257, 1024, 16), (1024, 197, 768, 12)],
+                         ids=["vit_l14", "vit_b16"])
+def test_kernel_compiles_for_v5e_with_no_copy_at_its_edges(one_chip, B, T, d, num_heads):
+    """qkv projection -> kernel -> out projection + residual at the benchmark's
+    shapes, compiled by the TPU's compiler (nothing runs): the projection writes
+    the layout the kernel reads and ``out`` reads what it writes."""
+    def block(x, w_qkv, b_qkv, w_out):
+        qkv = jnp.einsum("btd,de->bte", x, w_qkv) + b_qkv
+        return x + jnp.einsum("btd,de->bte", pa.fused_attention(qkv, num_heads), w_out)
+
+    def chain(x, w_qkv, b_qkv, w_out):
+        return block(block(x, w_qkv, b_qkv, w_out), w_qkv, b_qkv, w_out)
+
+    shapes = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for s in ((B, T, d), (d, 3 * d), (3 * d,), (d, d))]
+    text = jax.jit(chain).lower(*shapes).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    ops = re.findall(r"= \S+ ([a-z\-]+)\(", entry)
+    assert ops.count("custom-call") >= 2 and entry.count("tpu_custom_call") == 2
+    # the one copy is of the entry argument x, whose device layout is tokens-outermost
+    assert ops.count("copy") <= 1 and "transpose" not in ops
+    assert f"[{B},{num_heads},{T},{T}]" not in text
