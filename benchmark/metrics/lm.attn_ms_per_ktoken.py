@@ -1,0 +1,7 @@
+"""Device milliseconds a thousand tokens processed (prefilled and decoded) in the attention layer (projections, cache write, scores: scope ``attn``), over both programs, by the scopes of their compiled text (``lib/lm_scopes.py``)."""
+
+from lib import lm_scopes
+
+
+def read(run):
+    return lm_scopes.per_ktoken_ms(run, lm_scopes.class_ns(run, "attn"))

@@ -1,0 +1,7 @@
+"""Device milliseconds a thousand tokens processed (prefilled and decoded) in the Mamba-2 mixers (projections, conv, gate and the scan: scopes ``mamba`` and ``ssd_scan``), over both programs, by the scopes of their compiled text (``lib/lm_scopes.py``)."""
+
+from lib import lm_scopes
+
+
+def read(run):
+    return lm_scopes.per_ktoken_ms(run, lm_scopes.class_ns(run, "mamba", "ssd_scan"))

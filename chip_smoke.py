@@ -6,7 +6,8 @@
 One process, no child that imports JAX. It drives the engine's main path
 (``from_pydict -> with_column(embed_image) -> UDFProject -> Flax forward ->
 collect``) at the full width of CLIP ViT-L/14 with seeded random weights,
-then the text embedder, the prompter, the relational device path and the
+then the text embedder, the prompter (the small decoder, then the tiny
+hybrid Mamba-2 / attention / expert decoder with its spans), the relational device path and the
 fused Pallas attention kernel against XLA's attention, and checks every
 result. Phases run in order and the first failure ends the run: nothing here
 turns a device, compile or Pallas failure into a result. On a pass the last
@@ -201,6 +202,46 @@ def phase_c(cfg) -> dict:
             "decode_steps": steps}
 
 
+def phase_c_hybrid(cfg) -> dict:
+    """prompt on the tiny hybrid decoder (Mamba-2, attention, a sharded expert
+    layer): chunked prefill that carries SSM state, the decode loop, answers
+    with log-probabilities, and the serving spans in the ring."""
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.functions.ai import prompt
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    began = span_clock_ns()  # the ring also holds phase C's spans
+    n = cfg["prompts"]
+    lengths = [6 + 5 * i for i in range(n - 1)] + [600]  # the last one takes two of the batcher's 512-token chunks
+    docs = [" ".join(f"w{(3 * i + j) % 40}" for j in range(m)) for i, m in enumerate(lengths)]
+    expr = prompt(col("p"), provider="flax_random", model="granite-hybrid-tiny",
+                  max_new_tokens=6, ignore_eos=True, logprobs=True, num_slots=4, max_prompt_tokens=640,
+                  num_hidden_layers=4, expert_shard=[0, 2], vocab_shard=[0, 2])
+
+    def run():
+        return daft_tpu.from_pydict({"p": docs}).with_column("a", expr) \
+            .select("a").collect().to_pydict()["a"]
+
+    answers, run_s = _timed(run)
+    assert len(answers) == n and all(
+        len(a["token_ids"]) == len(a["logprobs"]) == 6 and np.isfinite(a["logprobs"]).all()
+        and all(0 <= t < 128 for t in a["token_ids"]) for a in answers), answers
+    spans = [s for s in recent_device_spans()
+             if s.start_ns >= began and s.name.startswith(("serve.", "prompt."))]
+    names = {s.name for s in spans}
+    assert {"prompt.tokenize", "prompt.run", "serve.prefill", "serve.decode_step", "serve.fetch"} <= names, names
+    steps = [s for s in spans if s.name == "serve.decode_step"]
+    assert all(0 < s.count["active"] <= s.count["slots"] == 4 and "moe.held_assignments" in s.count
+               for s in steps), "decode-step spans lack their counters"
+    prefilled = sum(s.count["tokens"] for s in spans if s.name == "serve.prefill")
+    assert prefilled == sum(lengths), f"prefilled {prefilled} tokens"
+    assert max(s.count["chunks"] for s in spans if s.name == "serve.prefill") == 2, "no prompt ran as two chunks"
+    _release(expr)
+    return {"run_s": run_s, "rows": n, "decode_steps": len(steps), "prefill_tokens": prefilled}
+
+
 def phase_d(cfg) -> dict:
     """A q06-shaped float32 chain (filter -> project -> sum) on the device,
     against the same query on the host. The counters are the only way to see
@@ -364,6 +405,8 @@ def main(argv=None) -> int:
             done(current, phase_b(cfg))
             current = "C_prompt"
             done(current, phase_c(cfg))
+            current = "C_prompt_hybrid"
+            done(current, phase_c_hybrid(cfg))
             current = "D_device_chain"
             done(current, phase_d(cfg))
             current = "E_pallas"

@@ -37,6 +37,7 @@ from daft_tpu.ai.protocols import (
 from daft_tpu.ai.provider import Provider
 from daft_tpu.device import setup_compile_cache
 from daft_tpu.errors import DaftValueError
+from daft_tpu.models.granite_hybrid import CUT_OPTIONS
 from daft_tpu.profiling import device_span
 from daft_tpu.utils.tokenizer import HashingTokenizer
 
@@ -461,47 +462,93 @@ class FlaxCLIPClassifier(_FlaxModelBase):
 
 
 class FlaxPrompter(_FlaxModelBase):
-    def __init__(self, model_name: str, weights_path: Optional[str] = None,
-                 max_new_tokens: int = 32, temperature: float = 0.0, seed: int = 0):
-        super().__init__()
-        from daft_tpu.models.lm import DecoderLMConfig, init_lm_params
+    """``prompt`` / ``llm_generate`` over a decoder and the continuous batcher.
 
-        self.cfg = DecoderLMConfig.from_name(model_name)
-        self.model, self.params = _initialised(init_lm_params, self.cfg, seed)
+    ``model_name`` is looked up exactly among the hybrid decoders on record
+    (``models/granite_hybrid.SIZES``), which take the cut's options
+    (``num_hidden_layers``, ``expert_shard``, ``vocab_shard``: one chip's share
+    of a stated deployment); any other name is ``DecoderLMConfig.from_name``'s,
+    and the cut's options with such a name are an error. ``num_slots`` decode
+    slots, prompts cut to ``max_prompt_tokens`` hashed tokens, ``ignore_eos``
+    for a fixed answer length, ``logprobs`` for answers a comparison can hold
+    against logits."""
+
+    def __init__(self, model_name: str, weights_path: Optional[str] = None,
+                 max_new_tokens: int = 32, temperature: float = 0.0, seed: int = 0,
+                 num_slots: int = 8, max_prompt_tokens: Optional[int] = None,
+                 ignore_eos: bool = False, logprobs: bool = False, **cut):
+        super().__init__()
+        from daft_tpu.models import granite_hybrid
+
+        unknown = set(cut) - set(CUT_OPTIONS)
+        if unknown:
+            raise DaftValueError(f"prompt does not know the options {sorted(unknown)}")
+        if model_name in granite_hybrid.SIZES:
+            self.cfg = granite_hybrid.GraniteHybridConfig.from_name(model_name, **cut)
+            self.model, params = _initialised(granite_hybrid.init_granite_params, self.cfg, seed)
+            self.prompt_len = int(max_prompt_tokens or 128)
+            self.max_seq_len = self.prompt_len + max_new_tokens + 1
+        elif cut:
+            raise DaftValueError(
+                f"{sorted(cut)} cut a published model to one chip's share, and {model_name!r} is none of "
+                f"{sorted(granite_hybrid.SIZES)}")
+        else:
+            from daft_tpu.models.lm import DecoderLMConfig, init_lm_params
+
+            self.cfg = DecoderLMConfig.from_name(model_name)
+            self.model, params = _initialised(init_lm_params, self.cfg, seed)
+            self.max_seq_len = self.cfg.max_seq_len
+            self.prompt_len = min(int(max_prompt_tokens or min(self.max_seq_len // 2, 128)), self.max_seq_len - 2)
         if weights_path:
-            self.params = load_checkpoint(weights_path, self.params)
-        self.params = jax.device_put(self.params)
+            params = load_checkpoint(weights_path, params)
+        self.params = self.place_params(params)
         self.max_new_tokens = max_new_tokens
         self.temperature = temperature
-        self.prompt_len = min(self.cfg.max_seq_len // 2, 128)
-        self.tokenizer = HashingTokenizer(self.cfg.vocab_size, self.prompt_len)
-        self._batcher = None  # lazy ContinuousBatcher (persistent slots/caches)
-        import threading
-
+        self.num_slots = int(num_slots)
+        self.eos_id = None if ignore_eos else 2
+        self.logprobs = bool(logprobs)
+        self.tokenizer = HashingTokenizer(self.model.vocab_size, self.prompt_len)
+        self._batcher = None  # lazy ContinuousBatcher (persistent slots and state)
         self._batcher_lock = threading.Lock()  # batcher state is stateful
 
-    def prompt(self, prompts: Sequence[Optional[str]]) -> List[str]:
+    def prompt(self, prompts: Sequence[Optional[str]]):
         """Continuous-batching generation with prefix routing (reference:
-        the vLLM streaming sink; see daft_tpu/models/serving.py)."""
+        the vLLM streaming sink; see daft_tpu/models/serving.py). -> one
+        string of ids a row, or with ``logprobs`` ``(strings, token ids,
+        log-probabilities)``, the last two as lists of arrays."""
         from daft_tpu.models.serving import ContinuousBatcher, Request
 
-        tokens, lengths = self.tokenizer.encode_batch(prompts)
-        lengths = np.maximum(lengths, 1)
+        with device_span("prompt.tokenize", rows=len(prompts)) as sp:
+            tokens, lengths = self.tokenizer.encode_batch(prompts)
+            lengths = np.maximum(lengths, 1)
+            sp.count["tokens"] = int(lengths.sum())
         reqs = [Request(tokens=np.asarray(tokens[i][:lengths[i]], np.int32),
                         max_new_tokens=self.max_new_tokens)
                 for i in range(len(prompts))]
         with self._batcher_lock:  # slot state is shared; runs serialize
             if self._batcher is None:
                 self._batcher = ContinuousBatcher(
-                    self.model, self.params, num_slots=8,
-                    temperature=self.temperature)
-            out = self._batcher.run(reqs)
-        return [" ".join(str(t) for t in row if t != 0) for row in out]
+                    self.model, self.params, num_slots=self.num_slots, max_seq_len=self.max_seq_len,
+                    temperature=self.temperature, eos_id=self.eos_id, max_prompt_tokens=self.prompt_len)
+            with device_span("prompt.run", rows=len(reqs)) as sp:
+                out = self._batcher.run(reqs)
+                sp.count["decode_steps"] = self._batcher.decode_steps
+            logprobs = self._batcher.last_logprobs
+        if not self.logprobs:
+            return [" ".join(str(t) for t in row if t != 0) for row in out]
+        return ([" ".join(str(t) for t in row) for row in out],
+                [np.asarray(row, np.int32) for row in out],
+                [np.asarray(row, np.float32) for row in logprobs])
 
 
 # ---------------------------------------------------------------------- #
 # Descriptors                                                             #
 # ---------------------------------------------------------------------- #
+#: Options of ``prompt`` that reach ``FlaxPrompter``; any other is the engine's or is dropped.
+PROMPTER_OPTIONS = ("weights_path", "seed", "max_new_tokens", "temperature", "num_slots", "max_prompt_tokens",
+                    "ignore_eos", "logprobs") + CUT_OPTIONS
+
+
 class _FlaxDescriptor(Descriptor):
     def __init__(self, kind: str, model: str, options: Dict[str, Any]):
         self.kind = kind
@@ -568,7 +615,7 @@ class _FlaxDescriptor(Descriptor):
         if self.kind in ("image_classifier", "text_classifier"):
             return FlaxCLIPClassifier(self.model, **{k: v for k, v in opts.items() if k in ("weights_path", "seed")})
         if self.kind == "prompter":
-            return FlaxPrompter(self.model, **opts)
+            return FlaxPrompter(self.model, **{k: self.options[k] for k in PROMPTER_OPTIONS if k in self.options})
         raise DaftValueError(self.kind)
 
 

@@ -259,15 +259,39 @@ def classify_image(image: Expression, labels: Sequence[str], *,
 
 def prompt(text: Expression, *, provider: Union[str, object, None] = None,
            model: Optional[str] = None, **options) -> Expression:
-    """Generate text per row (reference: daft/functions/ai/__init__.py:430)."""
+    """Generate text per row (reference: daft/functions/ai/__init__.py:430).
+
+    With ``logprobs=True`` the column is a struct ``{text, token_ids:
+    list[int32], logprobs: list[float32]}``: the chosen tokens and each one's
+    log-probability, so that a comparison can hold the answer against logits."""
     p = load_provider(provider)
     desc = p.get_prompter(model, **options)
+    if not options.get("logprobs"):
+        def call(inst, series: Series) -> Series:
+            out = inst.prompt(series.to_pylist())
+            return Series.from_pylist(out, "response", DataType.string())
 
-    def call(inst, series: Series) -> Series:
-        out = inst.prompt(series.to_pylist())
-        return Series.from_pylist(out, "response", DataType.string())
+        return _ProtocolUdf(desc, call, DataType.string(), "prompt")(text)
 
-    return _ProtocolUdf(desc, call, DataType.string(), "prompt")(text)
+    dtype = DataType.struct({"text": DataType.string(), "token_ids": DataType.list(DataType.int32()),
+                             "logprobs": DataType.list(DataType.float32())})
+
+    def call_logprobs(inst, series: Series) -> Series:
+        import pyarrow as pa
+
+        texts, ids, logprobs = inst.prompt(series.to_pylist())
+        offsets = np.concatenate([[0], np.cumsum([len(r) for r in ids])]).astype(np.int32)
+
+        def lists(rows, np_dtype):
+            flat = np.concatenate(rows) if rows else np.zeros(0, np_dtype)
+            return pa.ListArray.from_arrays(pa.array(offsets), pa.array(flat.astype(np_dtype)))
+
+        arr = pa.StructArray.from_arrays(
+            [pa.array(texts, pa.string()), lists(ids, np.int32), lists(logprobs, np.float32)],
+            names=["text", "token_ids", "logprobs"])
+        return Series.from_arrow(arr, "response", dtype)
+
+    return _ProtocolUdf(desc, call_logprobs, dtype, "prompt")(text)
 
 
 def llm_generate(text: Expression, *, model: Optional[str] = None,

@@ -1,0 +1,538 @@
+"""Granite 4.0-H decoder (``granitemoehybrid``): Mamba-2 and attention mixers
+under one residual stream, a 72-way top-10 expert layer with a shared expert
+after every mixer, RMSNorm, no positional encoding, four multipliers, a tied
+head.
+
+    h = embedding_multiplier * E[tok]
+    h += residual_multiplier * Mixer(RMSNorm(h))          # Mamba-2 or attention, by layer_types
+    h += residual_multiplier * (MoE(v) + Shared(v)),  v = RMSNorm(h)
+    logits = RMSNorm(h) @ E.T / logits_scaling
+
+The model is plain functions over a parameter tree (no Flax module): the
+parameters are drawn tensor by tensor on the device and kept in bfloat16, and
+``jax.named_scope`` names the parts (``mamba``, ``ssd_scan``, ``attn``,
+``router``, ``experts``, ``shared_mlp``, ``head``) for the device trace.
+
+**The cut.** ``expert_shard = (rank, size)`` tells the expert layer which
+experts it holds (a contiguous ``num_local_experts / size`` of them). It routes
+over all of them with the published top-k, computes the held experts' part
+``sum_{e in I and held} g_e y_e`` with the gates as the full softmax gave them,
+and leaves the absent experts' part out; assignments to absent experts sort
+behind the held groups of one grouped product and cost no FLOPs.
+``vocab_shard`` slices the tied embedding by rows: ids and logits are over the
+slice. Nothing stands in for the other chips.
+
+**Serving protocol** (``models/serving.ContinuousBatcher``): ``init_state``,
+``prefill`` (advances some slots' state over one chunk of their prompts, true
+lengths known: padding has delta = 0 and is written neither to the conv tail
+nor to the KV rows), ``decode`` (one token for every slot) and ``copy_state``.
+Slot state is a list with one entry a layer: ``{"ssm", "conv"}`` for a Mamba
+layer, ``{"k", "v"}`` for an attention layer.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass, replace
+from typing import Any, Dict, List, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from daft_tpu.errors import DaftValueError
+
+#: Published sizes by exact model name (``config.json`` of the source). Kept as
+#: data: no substring rule.
+PUBLISHED: Dict[str, Dict[str, Any]] = {
+    # https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/config.json
+    "granite-4.0-h-small": dict(
+        vocab_size=100352, hidden_size=4096, num_hidden_layers=40,
+        layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 4,
+        num_attention_heads=32, num_key_value_heads=8,
+        attention_multiplier=0.0078125, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0, rms_norm_eps=1e-5,
+        intermediate_size=768, shared_intermediate_size=1536,
+        num_local_experts=72, num_experts_per_tok=10,
+        mamba_n_heads=128, mamba_d_head=64, mamba_d_state=128, mamba_n_groups=1,
+        mamba_d_conv=4, mamba_expand=2, mamba_chunk_size=256),
+}
+#: Not published: the same ratios at a width the CPU tests and ``chip_smoke.py``
+#: can afford (m-m-a-m, 8 experts top-3).
+TEST_SIZES: Dict[str, Dict[str, Any]] = {
+    "granite-hybrid-tiny": dict(
+        vocab_size=256, hidden_size=64, num_hidden_layers=4,
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        num_attention_heads=4, num_key_value_heads=1,
+        attention_multiplier=0.0625, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0, rms_norm_eps=1e-5,
+        intermediate_size=12, shared_intermediate_size=24,
+        num_local_experts=8, num_experts_per_tok=3,
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_n_groups=1,
+        mamba_d_conv=4, mamba_expand=2, mamba_chunk_size=8),
+}
+#: Every name ``from_name`` resolves.
+SIZES = {**PUBLISHED, **TEST_SIZES}
+#: Options of ``prompt`` that cut a published model to one chip's share.
+CUT_OPTIONS = ("num_hidden_layers", "expert_shard", "vocab_shard")
+#: The embedding is drawn in blocks of this many rows, so that a slice's rows
+#: are the whole table's whatever the split.
+EMBED_BLOCK_ROWS = 64
+#: Standard deviation of the embedding's rows. Tied and random, a larger one makes
+#: the last input token's own logit win every greedy step.
+EMBED_STD = 0.002
+
+
+@dataclass(frozen=True)
+class GraniteHybridConfig:
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    layer_types: Tuple[str, ...]
+    num_attention_heads: int
+    num_key_value_heads: int
+    attention_multiplier: float
+    embedding_multiplier: float
+    residual_multiplier: float
+    logits_scaling: float
+    rms_norm_eps: float
+    intermediate_size: int
+    shared_intermediate_size: int
+    num_local_experts: int
+    num_experts_per_tok: int
+    mamba_n_heads: int
+    mamba_d_head: int
+    mamba_d_state: int
+    mamba_n_groups: int
+    mamba_d_conv: int
+    mamba_expand: int
+    mamba_chunk_size: int
+    expert_shard: Tuple[int, int] = (0, 1)
+    vocab_shard: Tuple[int, int] = (0, 1)
+    dtype: Any = jnp.bfloat16
+
+    @staticmethod
+    def from_name(name: str, num_hidden_layers: int = None, expert_shard=(0, 1),
+                  vocab_shard=(0, 1)) -> "GraniteHybridConfig":
+        if name not in SIZES:
+            raise DaftValueError(
+                f"unknown hybrid decoder {name!r}; the published sizes on record are {sorted(PUBLISHED)}")
+        cfg = GraniteHybridConfig(**SIZES[name])
+        layers = int(num_hidden_layers or cfg.num_hidden_layers)
+        cfg = replace(cfg, num_hidden_layers=layers, layer_types=cfg.layer_types[:layers],
+                      expert_shard=tuple(int(x) for x in expert_shard),
+                      vocab_shard=tuple(int(x) for x in vocab_shard))
+        for what, (rank, size), whole in (("expert_shard", cfg.expert_shard, cfg.num_local_experts),
+                                          ("vocab_shard", cfg.vocab_shard, cfg.vocab_size // EMBED_BLOCK_ROWS)):
+            if not 0 <= rank < size or whole % size:
+                raise DaftValueError(f"{what}={[rank, size]} does not divide {whole} evenly")
+        if not 0 < layers <= len(SIZES[name]["layer_types"]):
+            raise DaftValueError(f"num_hidden_layers={layers} is outside the published {name!r}")
+        return cfg
+
+    # -- derived sizes ---------------------------------------------------- #
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def held_experts(self) -> int:
+        return self.num_local_experts // self.expert_shard[1]
+
+    @property
+    def first_expert(self) -> int:
+        return self.expert_shard[0] * self.held_experts
+
+    @property
+    def held_vocab(self) -> int:
+        return self.vocab_size // self.vocab_shard[1]
+
+
+def _rms(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _mm(x, w):
+    """bfloat16 operands, float32 accumulation."""
+    return jnp.einsum("...k,kn->...n", x, w, preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------- #
+# Parameters: drawn tensor by tensor on the device, bfloat16              #
+# ---------------------------------------------------------------------- #
+def tensor_specs(cfg: GraniteHybridConfig, kind: str) -> List[Tuple[str, tuple, str]]:
+    """One layer's tensors in the order their keys are folded: (name, shape,
+    rule). ``benchmark/reference/granite_hybrid.py`` states the same rules."""
+    d, f, fs, e = cfg.hidden_size, cfg.intermediate_size, cfg.shared_intermediate_size, cfg.num_local_experts
+    if kind == "mamba":
+        h = cfg.mamba_n_heads
+        mixer = [("norm", (d,), "norm"),
+                 ("in_proj", (d, 2 * cfg.d_inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state + h), "matrix"),
+                 ("conv_w", (cfg.mamba_d_conv, cfg.conv_dim), "matrix"), ("conv_b", (cfg.conv_dim,), "bias"),
+                 ("dt_bias", (h,), "dt_bias"), ("A_log", (h,), "A_log"), ("D", (h,), "norm"),
+                 ("gate_norm", (cfg.d_inner,), "norm"), ("out_proj", (cfg.d_inner, d), "matrix")]
+    else:
+        q, kv = cfg.num_attention_heads * cfg.head_dim, cfg.num_key_value_heads * cfg.head_dim
+        mixer = [("norm", (d,), "norm"), ("q", (d, q), "matrix"), ("k", (d, kv), "matrix"),
+                 ("v", (d, kv), "matrix"), ("o", (q, d), "matrix")]
+    return mixer + [("moe_norm", (d,), "norm"), ("router", (d, e), "matrix"),
+                    ("w_in", (d, 2 * f), "experts"), ("w_out", (f, d), "experts"),
+                    ("shared_in", (d, 2 * fs), "matrix"), ("shared_out", (fs, d), "matrix")]
+
+
+def _as_drawn(x):
+    """A draw as the generator gave it: inside a jitted program XLA would fold
+    the scale that follows into the generator's own last product, and round
+    otherwise than the same two steps taken one by one."""
+    return jax.lax.optimization_barrier(x)
+
+
+def draw(key, shape, rule: str):
+    """One tensor in float32, by rule. ``matrix``: normal, std fan_in ** -0.5
+    (each product keeps its input's scale); ``norm``: 1 + 0.1 normal; ``bias``:
+    0.1 normal; ``A_log``: log U(1, 16); ``dt_bias``: the inverse softplus of a
+    delta log-uniform in [1e-3, 1e-1] (as Mamba-2 initialises both)."""
+    if rule in ("matrix", "norm", "bias"):
+        n = _as_drawn(jax.random.normal(key, shape, jnp.float32))
+        return n * (shape[0] ** -0.5) if rule == "matrix" else 0.1 * n + (rule == "norm")
+    if rule == "A_log":
+        return jnp.log(_as_drawn(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)))
+    if rule == "dt_bias":
+        dt = jnp.exp(_as_drawn(jax.random.uniform(key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1))))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    raise ValueError(rule)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_layer(cfg: GraniteHybridConfig, key, kind: str):
+    held = cfg.first_expert + jnp.arange(cfg.held_experts)
+    out = {}
+    for j, (name, shape, rule) in enumerate(tensor_specs(cfg, kind)):
+        k = jax.random.fold_in(key, j)
+        if rule == "experts":  # an expert's weights come from its global id, whoever holds it
+            w = jax.vmap(lambda e: draw(jax.random.fold_in(k, e), shape, "matrix"))(held)
+        else:
+            w = draw(k, shape, rule)
+        out[name] = w.astype(cfg.dtype)
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _init_embedding(cfg: GraniteHybridConfig, key):
+    blocks = cfg.held_vocab // EMBED_BLOCK_ROWS
+    ids = cfg.vocab_shard[0] * blocks + jnp.arange(blocks)
+    rows = _as_drawn(jax.vmap(lambda b: jax.random.normal(
+        jax.random.fold_in(key, b), (EMBED_BLOCK_ROWS, cfg.hidden_size), jnp.float32))(ids))
+    return (rows.reshape(cfg.held_vocab, cfg.hidden_size) * EMBED_STD).astype(cfg.dtype)
+
+
+def init_granite_params(cfg: GraniteHybridConfig, seed: int = 0):
+    """-> (model, params). Key 0 of the seed draws the embedding and the final
+    norm, key i + 1 layer i; no float32 copy of the tree ever exists."""
+    root = jax.random.PRNGKey(seed)
+    k0 = jax.random.fold_in(root, 0)
+    params = {"embed": _init_embedding(cfg, jax.random.fold_in(k0, 0)),
+              "final_norm": draw(jax.random.fold_in(k0, 1), (cfg.hidden_size,), "norm").astype(cfg.dtype),
+              "layers": [_init_layer(cfg, jax.random.fold_in(root, i + 1), kind)
+                         for i, kind in enumerate(cfg.layer_types)]}
+    return GraniteHybridLM(cfg), params
+
+
+# ---------------------------------------------------------------------- #
+# Mamba-2                                                                 #
+# ---------------------------------------------------------------------- #
+def ssd_chunked(x, dt, a, b, c, s0, chunk: int):
+    """The selective state-space recurrence over ``T`` steps in chunks.
+
+    x (B, T, H, P); dt (B, T, H) float32, 0 at padding; a (H,) negative;
+    b, c (B, T, N); s0 (B, H, P, N) float32. Returns y (B, T, H, P) float32
+    and the state after the last step. ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t
+    (x) b_t``, ``y_t = S_t c_t``: inside a chunk as one masked product, between
+    chunks as a recurrence over the chunk states."""
+    B, T, H, P = x.shape
+    N = b.shape[-1]
+    L = min(chunk, T)
+    if T % L:
+        raise ValueError(f"{T} steps are no multiple of the chunk {L}")
+    nc = T // L
+    bf = x.dtype
+    xdt = (x.astype(jnp.float32) * dt[..., None]).astype(bf).reshape(B, nc, L, H, P)
+    b = b.reshape(B, nc, L, N)
+    c = c.reshape(B, nc, L, N)
+    cs = jnp.cumsum((dt * a).reshape(B, nc, L, H), axis=2)          # (B, nc, L, H), <= 0
+    cs_h = jnp.moveaxis(cs, 3, 2)                                   # (B, nc, H, L)
+    # inside the chunk: y_l = sum_{s <= l} (c_l . b_s) exp(cs_l - cs_s) dt_s x_s
+    cb = jnp.einsum("bcln,bcsn->bcls", c, b, preferred_element_type=jnp.float32)
+    diff = cs_h[..., :, None] - cs_h[..., None, :]                  # (B, nc, H, L, L)
+    lower = jnp.tril(jnp.ones((L, L), bool))
+    m = (jnp.exp(jnp.where(lower, diff, -jnp.inf)) * cb[:, :, None]).astype(bf)
+    y = jnp.einsum("bchls,bcshp->bclhp", m, xdt, preferred_element_type=jnp.float32)
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(cs[:, :, -1:, :] - cs)                         # (B, nc, L, H)
+    xdt_end = (xdt.astype(jnp.float32) * to_end[..., None]).astype(bf)
+    own = jnp.einsum("bclhp,bcln->bchpn", xdt_end, b, preferred_element_type=jnp.float32)
+    whole = jnp.exp(cs[:, :, -1, :])                                # (B, nc, H): a chunk's decay
+
+    def step(s, inp):
+        own_c, whole_c = inp
+        return whole_c[..., None, None] * s + own_c, s              # carries on; emits the state before
+
+    s_last, before = jax.lax.scan(step, s0, (jnp.moveaxis(own, 1, 0), jnp.moveaxis(whole, 1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                             # (B, nc, H, P, N)
+    c_in = (c.astype(jnp.float32)[:, :, :, None, :] * jnp.exp(cs)[..., None]).astype(bf)  # (B, nc, L, H, N)
+    y = y + jnp.einsum("bclhn,bchpn->bclhp", c_in, before.astype(bf), preferred_element_type=jnp.float32)
+    return y.reshape(B, T, H, P), s_last
+
+
+def ssd_step(x, dt, a, b, c, s):
+    """One step of the recurrence for every row: x (B, H, P), dt (B, H), b, c (B, N), s (B, H, P, N)."""
+    decay = jnp.exp(dt * a)
+    s = decay[..., None, None] * s + (dt[..., None] * x.astype(jnp.float32))[..., None] \
+        * b.astype(jnp.float32)[:, None, None, :]
+    return jnp.einsum("bhpn,bn->bhp", s, c.astype(jnp.float32)), s
+
+
+def _mamba(cfg, p, u, st, valid, lengths, single_step: bool):
+    """u (B, T, d) normed; st {"ssm" (B, H, P, N), "conv" (B, K-1, C)} of these
+    rows; valid (B, T); lengths (B,) valid steps of each row. -> (out, new st)."""
+    B, T, _ = u.shape
+    H, P, N, di = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.d_inner
+    K = cfg.mamba_d_conv
+    zxbcdt = _mm(u, p["in_proj"])
+    z, xbc, dt = jnp.split(zxbcdt, [di, di + cfg.conv_dim], axis=-1)
+    xbc = xbc.astype(cfg.dtype)
+    # causal depthwise conv over [carried tail | this chunk]
+    padded = jnp.concatenate([st["conv"], xbc], axis=1)             # (B, K-1+T, C)
+    w = p["conv_w"].astype(jnp.float32)
+    conv = sum(padded[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K)) + p["conv_b"].astype(jnp.float32)
+    xbc = jax.nn.silu(conv).astype(cfg.dtype)
+    # the tail after the last *valid* step: rows length .. length+K-2 of `padded`
+    tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, K - 1, axis=0))(padded, lengths)
+    x, b, c = jnp.split(xbc, [di, di + cfg.mamba_n_groups * N], axis=-1)
+    x = x.reshape(B, T, H, P)
+    dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
+    dt = jnp.where(valid[..., None], dt, 0.0)                       # padding: no decay, no input
+    a = -jnp.exp(p["A_log"].astype(jnp.float32))
+    with jax.named_scope("ssd_scan"):
+        if single_step:
+            y, ssm = ssd_step(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], st["ssm"])
+            y = y[:, None]
+        else:
+            y, ssm = ssd_chunked(x, dt, a, b, c, st["ssm"], cfg.mamba_chunk_size)
+    y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    y = (y.reshape(B, T, di) * jax.nn.silu(z)).astype(cfg.dtype)
+    y = _rms(y, p["gate_norm"], cfg.rms_norm_eps)
+    return _mm(y, p["out_proj"]).astype(cfg.dtype), {"ssm": ssm, "conv": tail}
+
+
+# ---------------------------------------------------------------------- #
+# Attention: grouped queries, no positions, a fixed score scale           #
+# ---------------------------------------------------------------------- #
+def _attention(cfg, p, u, rows_k, rows_v, positions, valid):
+    """u (B, T, d); rows_k, rows_v (B, S, KV, hd): these rows' cache; positions
+    (B, T) of the new tokens; valid (B, T). -> (out, new rows_k, new rows_v)."""
+    B, T, _ = u.shape
+    S = rows_k.shape[1]
+    KV, hd = cfg.num_key_value_heads, cfg.head_dim
+    R = cfg.num_attention_heads // KV
+    q = _mm(u, p["q"]).astype(cfg.dtype).reshape(B, T, KV, R, hd)
+    k = _mm(u, p["k"]).astype(cfg.dtype).reshape(B, T, KV, hd)
+    v = _mm(u, p["v"]).astype(cfg.dtype).reshape(B, T, KV, hd)
+
+    def write(rows, new):  # valid tokens only: padding leaves the rows as they were
+        def one(r, n, pos, ok):
+            old = jax.lax.dynamic_slice_in_dim(r, pos[0], T, axis=0)
+            return jax.lax.dynamic_update_slice_in_dim(r, jnp.where(ok[:, None, None], n, old), pos[0], axis=0)
+        return jax.vmap(one)(rows, new, positions, valid)
+
+    rows_k, rows_v = write(rows_k, k), write(rows_v, v)
+
+    low = jnp.finfo(jnp.float32).min
+
+    def core_step(q, rk, rv, pos):  # one row, one token: (1, KV, R, hd) over all its cache rows (S, KV, hd)
+        scores = jnp.einsum("tgrd,sgd->grts", q, rk, preferred_element_type=jnp.float32) * cfg.attention_multiplier
+        seen = jnp.arange(S)[None, :] <= pos[:, None]               # (1, S): causal over the cache
+        probs = jax.nn.softmax(jnp.where(seen[None, None], scores, low), axis=-1).astype(cfg.dtype)
+        return jnp.einsum("grts,sgd->tgrd", probs, rv, preferred_element_type=jnp.float32)
+
+    def core_chunk(q, rk, rv, pos, blocks):
+        """One row's chunk over the blocks of ``T`` cache rows that reach its last
+        position, a running softmax between them: the work follows the prefix
+        held, not the positions a slot could hold."""
+        def body(j, carry):
+            m, l, acc = carry                                       # (KV, R, T), (KV, R, T), (T, KV, R, hd)
+            kb = jax.lax.dynamic_slice_in_dim(rk, j * T, T, axis=0)
+            vb = jax.lax.dynamic_slice_in_dim(rv, j * T, T, axis=0)
+            sc = jnp.einsum("tgrd,sgd->grts", q, kb, preferred_element_type=jnp.float32) * cfg.attention_multiplier
+            seen = (j * T + jnp.arange(T))[None, :] <= pos[:, None]
+            sc = jnp.where(seen[None, None], sc, low)
+            m_new = jnp.maximum(m, sc.max(-1))
+            w = jnp.exp(sc - m_new[..., None])
+            scale = jnp.exp(m - m_new)
+            acc = acc * jnp.moveaxis(scale, 2, 0)[..., None] + jnp.einsum(
+                "grts,sgd->tgrd", w.astype(cfg.dtype), vb, preferred_element_type=jnp.float32)
+            return m_new, l * scale + w.sum(-1), acc
+
+        init = (jnp.full((KV, R, T), low), jnp.zeros((KV, R, T), jnp.float32), jnp.zeros((T, KV, R, hd), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
+        return acc / jnp.moveaxis(l, 2, 0)[..., None]
+
+    if T == 1:
+        out = jax.vmap(core_step)(q, rows_k, rows_v, positions)
+    else:  # every row of a call is at the same chunk of its prompt, or past its end
+        blocks = jnp.max(positions[:, 0]) // T + 1
+        out = jax.vmap(core_chunk, in_axes=(0, 0, 0, 0, None))(q, rows_k, rows_v, positions, blocks)
+    out = out.astype(cfg.dtype).reshape(B, T, KV * R * hd)
+    return _mm(out, p["o"]).astype(cfg.dtype), rows_k, rows_v
+
+
+# ---------------------------------------------------------------------- #
+# Experts                                                                 #
+# ---------------------------------------------------------------------- #
+def _gated_mlp(x, w_in, w_out, dtype):
+    a, b = jnp.split(_mm(x, w_in), 2, axis=-1)
+    return _mm((jax.nn.silu(a) * b).astype(dtype), w_out)
+
+
+def _moe(cfg, p, v, valid):
+    """v (n, d) normed; valid (n,). -> (held experts' part + shared expert
+    (n, d) float32, counts {assignments, held_assignments, max_expert_load})."""
+    n, d = v.shape
+    k, held_n = cfg.num_experts_per_tok, cfg.held_experts
+    with jax.named_scope("router"):
+        r = _mm(v, p["router"])                                     # (n, E) float32
+        top, idx = jax.lax.top_k(r, k)
+        gates = jax.nn.softmax(top, axis=-1)                        # over the chosen k only
+    with jax.named_scope("experts"):
+        local = idx - cfg.first_expert
+        held = (local >= 0) & (local < held_n) & valid[:, None]
+        group = jnp.where(held, local, held_n).reshape(-1)          # absent: behind every held group
+        order = jnp.argsort(group, stable=True)
+        token = order // k
+        sizes = jnp.bincount(group, length=held_n + 1)[:held_n].astype(jnp.int32)
+        x = v[token]                                                # (n k, d), sorted by held expert
+        a, b = jnp.split(jax.lax.ragged_dot(x, p["w_in"], sizes, preferred_element_type=jnp.float32), 2, axis=-1)
+        y = jax.lax.ragged_dot((jax.nn.silu(a) * b).astype(cfg.dtype), p["w_out"], sizes,
+                               preferred_element_type=cfg.dtype)
+        # Back to the tokens: one gather of n rows for each of the k choices, gated and summed as it goes
+        # (a scatter-add of rows is serial on the chip, and an (n, k, d) block pads k to a tile: 8.6 and 7.7 ms
+        # a 2,048-token layer against 5.9 this way; my chip run, PR 29). Rows behind the held groups hold
+        # whatever the product left there and are dropped by their gate of 0.
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k)).reshape(n, k)
+        g = jnp.where(held, gates, 0.0)
+        routed = jnp.zeros((n, d), jnp.float32)
+        for j in range(k):
+            gj = g[:, j, None]
+            routed = routed + jnp.where(gj > 0, y[back[:, j]].astype(jnp.float32) * gj, 0.0)
+        y = routed
+    with jax.named_scope("shared_mlp"):
+        y = y + _gated_mlp(v, p["shared_in"], p["shared_out"], cfg.dtype)
+    counts = {"assignments": jnp.sum(valid) * k, "held_assignments": jnp.sum(held),
+              "max_expert_load": jnp.max(sizes)}
+    return y, counts
+
+
+# ---------------------------------------------------------------------- #
+# The model                                                               #
+# ---------------------------------------------------------------------- #
+class GraniteHybridLM:
+    """The decoder over a parameter tree, as the serving protocol sees it."""
+
+    def __init__(self, cfg: GraniteHybridConfig):
+        self.cfg = cfg
+
+    @property
+    def vocab_size(self) -> int:
+        """Ids and logits are over the held slice."""
+        return self.cfg.held_vocab
+
+    @property
+    def prefill_multiple(self) -> int:
+        """A prefill chunk is whole chunks of the scan."""
+        return self.cfg.mamba_chunk_size
+
+    def init_state(self, slots: int, positions: int):
+        cfg = self.cfg
+        state = []
+        for kind in cfg.layer_types:
+            if kind == "mamba":
+                state.append({
+                    "ssm": jnp.zeros((slots, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state), jnp.float32),
+                    "conv": jnp.zeros((slots, cfg.mamba_d_conv - 1, cfg.conv_dim), cfg.dtype)})
+            else:
+                kv = (slots, positions, cfg.num_key_value_heads, cfg.head_dim)
+                state.append({"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype)})
+        return state
+
+    def copy_state(self, state, src, dst):
+        return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
+
+    def _forward(self, params, rows, tokens, positions, valid, lengths, fresh, single_step):
+        """The layers over ``rows`` (each layer's state of the rows in play).
+        -> (h (B, T, d), new rows, counts summed over the layers)."""
+        cfg = self.cfg
+        B, T = tokens.shape
+        h = (params["embed"][tokens].astype(jnp.float32) * cfg.embedding_multiplier).astype(cfg.dtype)
+        new_rows, totals = [], None
+        for i, (kind, p, st) in enumerate(zip(cfg.layer_types, params["layers"], rows)):
+            with jax.named_scope(f"layer_{i}"):
+                u = _rms(h, p["norm"], cfg.rms_norm_eps)
+                if kind == "mamba":
+                    with jax.named_scope("mamba"):
+                        # a prompt's first chunk starts from nothing, whatever the slot held
+                        st = jax.tree_util.tree_map(
+                            lambda a: jnp.where(fresh.reshape((B,) + (1,) * (a.ndim - 1)), 0, a), st)
+                        out, st = _mamba(cfg, p, u, st, valid, lengths, single_step)
+                else:
+                    with jax.named_scope("attn"):
+                        out, k, v = _attention(cfg, p, u, st["k"], st["v"], positions, valid)
+                        st = {"k": k, "v": v}
+                new_rows.append(st)
+                h = (h + cfg.residual_multiplier * out).astype(cfg.dtype)
+                v = _rms(h, p["moe_norm"], cfg.rms_norm_eps)
+                y, counts = _moe(cfg, p, v.reshape(B * T, -1), valid.reshape(-1))
+                totals = counts if totals is None else {
+                    "assignments": totals["assignments"] + counts["assignments"],
+                    "held_assignments": totals["held_assignments"] + counts["held_assignments"],
+                    "max_expert_load": jnp.maximum(totals["max_expert_load"], counts["max_expert_load"])}
+                h = (h + cfg.residual_multiplier * y.reshape(B, T, -1)).astype(cfg.dtype)
+        return h, new_rows, totals
+
+    def _head(self, params, h):
+        with jax.named_scope("head"):
+            h = _rms(h, params["final_norm"], self.cfg.rms_norm_eps)
+            return jnp.einsum("...d,vd->...v", h, params["embed"],
+                              preferred_element_type=jnp.float32) / self.cfg.logits_scaling
+
+    def prefill(self, params, state, tokens, slots, starts, lengths):
+        """Advance ``slots`` (B,) over one chunk: tokens (B, T) right-padded,
+        the chunk's first position ``starts`` (B,) and its valid length
+        ``lengths`` (B,; 0 leaves the slot as it was). -> (state, logits (B, V)
+        after each row's last valid token, counts)."""
+        T = tokens.shape[1]
+        steps = jnp.arange(T)[None, :]
+        valid = steps < lengths[:, None]
+        fresh = (starts == 0) & (lengths > 0)
+        rows = jax.tree_util.tree_map(lambda a: a[slots], state)
+        h, rows, counts = self._forward(params, rows, tokens, starts[:, None] + steps, valid, lengths,
+                                        fresh, single_step=False)
+        state = jax.tree_util.tree_map(lambda a, r: a.at[slots].set(r), state, rows)
+        last = jnp.take_along_axis(h, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+        return state, self._head(params, last), counts
+
+    def decode(self, params, state, tokens, positions, active):
+        """One token for every slot: tokens, positions, active (slots,). An
+        inactive slot's state is left as it was. -> (state, logits, counts)."""
+        valid = active[:, None]
+        h, state, counts = self._forward(params, state, tokens[:, None], positions[:, None], valid,
+                                         active.astype(jnp.int32), jnp.zeros_like(active), single_step=True)
+        return state, self._head(params, h[:, 0]), counts

@@ -34,6 +34,8 @@ class DecoderLMConfig:
 
     @staticmethod
     def from_name(name: str) -> "DecoderLMConfig":
+        # Substring rules, kept for the names in use (ROADMAP D4); a new model
+        # is matched exactly, from data (models/granite_hybrid.SIZES).
         n = name.lower()
         if "tiny" in n:
             return DecoderLMConfig.tiny()
@@ -98,7 +100,41 @@ class DecoderBlock(nn.Module):
 
 
 class DecoderLM(nn.Module):
+    """Also the serving protocol of ``models/serving.ContinuousBatcher``
+    (``init_state``, ``prefill``, ``decode``, ``copy_state``): its slot state is
+    the per-layer ``(k, v)`` rows."""
+
     cfg: DecoderLMConfig
+
+    @property
+    def vocab_size(self) -> int:
+        return self.cfg.vocab_size
+
+    @nn.nowrap
+    def init_state(self, slots: int, positions: int):
+        return init_caches(self.cfg, slots, positions)
+
+    @nn.nowrap
+    def copy_state(self, state, src, dst):
+        return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
+
+    @nn.nowrap
+    def prefill(self, params, state, tokens, slots, starts, lengths):
+        """Advance ``slots`` over one right-padded chunk of their prompts. Keys
+        past a prompt's end are written, lie behind every query's position and
+        are overwritten as the slot decodes."""
+        positions = starts[:, None] + jnp.arange(tokens.shape[1])[None, :]
+        rows = jax.tree_util.tree_map(lambda a: a[slots], state)
+        logits, rows = self.apply(params, tokens, rows, positions)
+        keep = (lengths > 0).reshape(-1, 1, 1, 1)  # a row with nothing to add leaves its slot alone
+        state = jax.tree_util.tree_map(lambda a, r: a.at[slots].set(jnp.where(keep, r, a[slots])), state, rows)
+        last = jnp.take_along_axis(logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+        return state, last, {}
+
+    @nn.nowrap
+    def decode(self, params, state, tokens, positions, active):
+        logits, state = self.apply(params, tokens[:, None], state, positions[:, None])
+        return state, logits[:, 0], {}
 
     @nn.compact
     def __call__(self, tokens, caches, positions):
@@ -111,7 +147,7 @@ class DecoderLM(nn.Module):
                              (1, cfg.max_seq_len, cfg.hidden))
         x = x + jnp.take_along_axis(
             jnp.broadcast_to(pos_emb, (tokens.shape[0],) + pos_emb.shape[1:]),
-            positions[:, :, None], axis=1,
+            positions[:, :, None], axis=1, mode="clip",  # padding of a last chunk may lie past the table
         ).astype(cfg.dtype)
         new_caches = []
         for i in range(cfg.layers):

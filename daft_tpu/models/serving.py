@@ -8,28 +8,46 @@ finished sequences and admitting new ones mid-decode, and optionally routes
 shared-prefix prompts to the same replica.
 
 TPU-first re-design: XLA needs static shapes, so the engine holds a FIXED
-pool of decode slots (batch dim B) and a fixed cache length; admission and
-retirement mutate slot state via jitted `dynamic_update`-style writes, and
-ONE jitted decode step advances every active slot a token per iteration.
-Mixed-length workloads win exactly where vLLM wins: a finished slot is
-refilled immediately instead of idling until the batch's longest sequence
-completes. Prefill is bucketed to limit recompiles; identical prompts share
-a single prefill via an on-device cache-row copy (prefix routing: requests
-are grouped by prompt hash before admission, the reference's
-do_prefix_routing analogue).
+pool of decode slots (batch dim B) and a fixed number of positions; admission
+and retirement mutate slot state in jitted programs, and ONE jitted decode
+step advances every active slot a token per iteration. Mixed-length workloads
+win exactly where vLLM wins: a finished slot is refilled immediately instead
+of idling until the batch's longest sequence completes.
+
+**Slot state is the model's.** The batcher never looks inside it: it asks the
+model for ``init_state(slots, positions)``, for a ``prefill`` that advances
+some slots over one chunk of their prompts with the true lengths known, for a
+``decode`` step over all slots, and for ``copy_state(state, src, dst)``.
+``models/lm.DecoderLM`` keeps key/value rows; ``models/granite_hybrid`` keeps
+SSM state and a conv tail beside them.
+
+**Prefill is chunked at one static length**, so prompts of any length share
+one executable: a prompt runs as ceil(P / chunk) calls that carry state, the
+last one right-padded. As many prompts advance in one call as bring it to
+about ``PREFILL_TOKENS`` tokens. Identical prompts admitted in the same round
+share one prefill through ``copy_state`` (prefix routing: requests are grouped
+by prompt hash before admission, the reference's do_prefix_routing analogue);
+a later round prefills again, since recurrent state, unlike key/value rows,
+has moved on with its slot.
+
+Spans (``profiling.device_span``): ``serve.prefill`` (one group's chunks:
+``slot``, ``rows``, ``tokens``, ``padded_tokens``, ``chunks``, ``row_chunks``, ``first``),
+``serve.copy_state``,
+``serve.decode_step`` (``active``, ``slots``, and the model's counts of the
+step, e.g. ``moe.held_assignments``), ``serve.fetch`` (the step's one fetch).
 """
 
 from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from daft_tpu.models.lm import DecoderLM, init_caches
+from daft_tpu.profiling import device_span
 
 
 @dataclass
@@ -44,126 +62,156 @@ class Request:
 class _Slot:
     request: Optional[Request] = None
     generated: List[int] = field(default_factory=list)
+    logprobs: List[float] = field(default_factory=list)
     remaining: int = 0
 
 
-def _bucket(n: int, buckets: Sequence[int]) -> int:
-    for b in buckets:
-        if n <= b:
-            return b
-    return buckets[-1]
-
-
 class ContinuousBatcher:
-    """Slot-based continuous batching over a DecoderLM KV cache."""
+    """Slot-based continuous batching over a model's own slot state."""
 
-    PROMPT_BUCKETS = (16, 32, 64, 128, 256)
+    #: The static prefill chunk (shorter where no prompt may be that long): the
+    #: shorter the chunk, the less of a prompt's last one is padding.
+    DEFAULT_CHUNK = 512
+    #: Tokens one prefill call aims at, as chunk x prompts: enough that a held
+    #: expert of a 72-way top-10 router sees ~280 tokens a call.
+    PREFILL_TOKENS = 2048
 
-    def __init__(self, model: DecoderLM, params, num_slots: int = 8,
+    def __init__(self, model, params, num_slots: int = 8,
                  max_seq_len: Optional[int] = None, temperature: float = 0.0,
-                 eos_id: int = 2, seed: int = 0):
+                 eos_id: Optional[int] = 2, seed: int = 0,
+                 prefill_chunk: Optional[int] = None,
+                 max_prompt_tokens: Optional[int] = None):
         self.model = model
         self.params = params
         self.cfg = model.cfg
         self.B = num_slots
-        self.S = min(max_seq_len or self.cfg.max_seq_len, self.cfg.max_seq_len)
+        limit = getattr(self.cfg, "max_seq_len", None)
+        self.S = min(max_seq_len or limit, limit or max_seq_len)
         self.temperature = temperature
-        self.eos_id = eos_id
+        self.eos_id = eos_id  # None: a sequence ends by its budget alone
         self._key = jax.random.PRNGKey(seed)
-        # Device state: per-layer caches sized for the slot pool.
-        self.caches = init_caches(self.cfg, self.B, self.S)
-        self.cur_logits = jnp.zeros((self.B, self.cfg.vocab_size), jnp.float32)
-        self.positions = jnp.zeros((self.B,), jnp.int32)
-        self.active = jnp.zeros((self.B,), bool)
+        # Room for >= 1 generated token; the caller may promise shorter prompts.
+        self.max_prompt = min(int(max_prompt_tokens or self.S - 2), self.S - 2)
+        # One static chunk, a multiple of what the model's own scan asks for
+        # (``prefill_chunk`` is for tests: a small one makes a short prompt many chunks).
+        mult = getattr(model, "prefill_multiple", 1)
+        self.chunk = -(-int(prefill_chunk or min(self.DEFAULT_CHUNK, self.max_prompt)) // mult) * mult
+        self.prefill_rows = max(1, min(self.PREFILL_TOKENS // self.chunk, self.B))
+        # Positions a slot holds: every prompt chunk lies inside, padding too.
+        self.positions_held = max(self.S, -(-self.max_prompt // self.chunk) * self.chunk)
+        self.state = model.init_state(self.B, self.positions_held)
+        self.cur_logits = jnp.zeros((self.B, model.vocab_size), jnp.float32)
+        # Host-side bookkeeping: handed to each step, never read back.
+        self.positions = np.zeros((self.B,), np.int32)
+        self.active = np.zeros((self.B,), bool)
         self.slots = [_Slot() for _ in range(self.B)]
-        self._prefill_cache: Dict[tuple, tuple] = {}
-        self._prefill_fns: Dict[int, callable] = {}
-        self._decode = jax.jit(self._decode_impl)
-        self._copy_row = jax.jit(self._copy_row_impl, donate_argnums=(0,))
+        self._prefill = None  # jitted at first use: tests/test_serving.py wraps _prefill_impl on an instance before
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2))
+        self._copy = jax.jit(self._copy_impl, donate_argnums=(0, 1))
+        self.decode_steps = 0
 
-    # -- jitted kernels ------------------------------------------------- #
-    def _prefill_impl(self, params, caches, tokens, length, slot):
-        """Run a (1, Pb) prompt; write its cache rows into `slot`."""
-        P = tokens.shape[1]
-        positions = jnp.arange(P)[None, :]
-        fresh = init_caches(self.cfg, 1, self.S)
-        logits, fresh = self.model.apply(params, tokens, fresh, positions)
-        new_caches = [
-            (ck.at[slot].set(fk[0]), cv.at[slot].set(fv[0]))
-            for (ck, cv), (fk, fv) in zip(caches, fresh)
-        ]
-        next_logits = logits[0, length - 1]
-        return new_caches, next_logits
+    # -- jitted programs ------------------------------------------------- #
+    def _prefill_impl(self, params, state, cur_logits, tokens, slots, starts, lengths, final):
+        """One chunk for ``slots``; a row whose prompt ends here (``final``)
+        leaves its next-token logits in ``cur_logits``."""
+        state, logits, _ = self.model.prefill(params, state, tokens, slots, starts, lengths)
+        kept = jnp.where(final[:, None], logits, cur_logits[slots])
+        return state, cur_logits.at[slots].set(kept)
 
-    def _copy_row_impl(self, caches, src, dst):
-        """Share a prefill: copy slot `src`'s cache rows into `dst`."""
-        return [(ck.at[dst].set(ck[src]), cv.at[dst].set(cv[src]))
-                for ck, cv in caches]
+    def _copy_impl(self, state, cur_logits, src, dst):
+        """Share a prefill: slot ``src``'s state and next-token logits into ``dst``."""
+        return self.model.copy_state(state, src, dst), cur_logits.at[dst].set(cur_logits[src])
 
-    def _decode_impl(self, params, caches, cur_logits, positions, active, key):
+    def _decode_impl(self, params, state, cur_logits, positions, active, key):
         if self.temperature <= 0.0:
             tok = jnp.argmax(cur_logits, axis=-1).astype(jnp.int32)
         else:
             tok = jax.random.categorical(
                 key, cur_logits / self.temperature, axis=-1).astype(jnp.int32)
         tok = jnp.where(active, tok, 0)
-        logits, caches = self.model.apply(params, tok[:, None], caches,
-                                          positions[:, None])
-        return caches, logits[:, 0], positions + 1, tok
+        logprob = jnp.take_along_axis(jax.nn.log_softmax(cur_logits, axis=-1), tok[:, None], axis=-1)[:, 0]
+        state, logits, counts = self.model.decode(params, state, tok, positions, active)
+        return state, logits, {"tok": tok, "logprob": logprob, "counts": counts}
+
+    def _prefill_fn(self):
+        if self._prefill is None:
+            self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1, 2))
+        return self._prefill
 
     # -- admission ------------------------------------------------------- #
-    def _prefill(self, req: Request, slot: int) -> None:
-        P = len(req.tokens)
-        Pb = min(_bucket(P, self.PROMPT_BUCKETS), self.S)
-        key = (req.prefix_key, Pb)
-        shared_src = self._prefill_cache.get(key)
-        if shared_src is not None and req.prefix_key is not None:
-            src_slot, next_logits, pos = shared_src
-            if self.slots[src_slot].request is not None and \
-                    self.slots[src_slot].request.prefix_key == req.prefix_key:
-                # Prefix hit: on-device cache-row copy, no recompute.
-                self.caches = self._copy_row(self.caches, src_slot, slot)
-                self.cur_logits = self.cur_logits.at[slot].set(next_logits)
-                self.positions = self.positions.at[slot].set(pos)
-                self._admit_host(req, slot)
-                return
-        padded = np.zeros((1, Pb), np.int32)
-        padded[0, :P] = req.tokens[:Pb]
-        if Pb not in self._prefill_fns:
-            self._prefill_fns[Pb] = jax.jit(self._prefill_impl,
-                                            donate_argnums=(1,))
-        fn = self._prefill_fns[Pb]
-        self.caches, next_logits = fn(self.params, self.caches,
-                                      jnp.asarray(padded),
-                                      jnp.int32(min(P, Pb)), jnp.int32(slot))
-        self.cur_logits = self.cur_logits.at[slot].set(next_logits)
-        self.positions = self.positions.at[slot].set(min(P, Pb))
-        if req.prefix_key is not None:
-            self._prefill_cache[key] = (slot, next_logits, min(P, Pb))
-        self._admit_host(req, slot)
+    def _admit(self, queue: List[Request], free: List[int]) -> None:
+        """Fill free slots from the queue: one prefill for each distinct
+        prompt of the round, ``prefill_rows`` prompts a call, then state
+        copies for the repeats."""
+        first: Dict[str, int] = {}  # prefix key -> the slot that prefills it, this round
+        todo, copies = [], []
+        for slot in free:
+            if not queue:
+                break
+            req = queue.pop()
+            src = first.get(req.prefix_key)
+            if src is None:
+                first[req.prefix_key] = slot
+                todo.append((req, slot))
+            else:
+                copies.append((req, src, slot))
+        # Prompts of like length share a call: it runs as many chunks as its longest needs.
+        todo.sort(key=lambda pair: len(pair[0].tokens))
+        for i in range(0, len(todo), self.prefill_rows):
+            self._prefill_group(todo[i:i + self.prefill_rows])
+        for req, src, dst in copies:
+            with device_span("serve.copy_state", src=src, slot=dst):
+                self.state, self.cur_logits = self._copy(self.state, self.cur_logits,
+                                                         jnp.int32(src), jnp.int32(dst))
+            self._admit_host(req, dst)
+
+    def _prefill_group(self, group) -> None:
+        g, T = self.prefill_rows, self.chunk
+        lens = np.zeros((g,), np.int64)
+        lens[:len(group)] = [len(req.tokens) for req, _ in group]
+        taken = [slot for _, slot in group]
+        # Rows of the call that carry no prompt name other slots (length 0: left as they are).
+        spare = [s for s in range(self.B) if s not in taken]
+        slots = np.asarray(taken + spare[:g - len(group)], np.int32)
+        chunks = max(1, -(-int(lens.max()) // T))
+        with device_span("serve.prefill", slot=int(slots[0]), rows=len(group), tokens=int(lens.sum()),
+                         padded_tokens=g * T * chunks, chunks=chunks,
+                         row_chunks=int((-(-lens // T)).sum())) as sp:
+            if self._prefill is None:
+                sp.count["first"] = 1  # this call traces and compiles (or loads) the program
+            fn = self._prefill_fn()
+            for c in range(chunks):
+                tokens = np.zeros((g, T), np.int32)
+                for i, (req, _) in enumerate(group):
+                    part = req.tokens[c * T:(c + 1) * T]
+                    tokens[i, :len(part)] = part
+                here = np.clip(lens - c * T, 0, T).astype(np.int32)
+                final = (lens > c * T) & (lens <= (c + 1) * T)
+                self.state, self.cur_logits = fn(
+                    self.params, self.state, self.cur_logits, tokens, slots,
+                    np.full((g,), c * T, np.int32), here, final)
+        for req, slot in group:
+            self._admit_host(req, slot)
 
     def _admit_host(self, req: Request, slot: int) -> None:
-        self.active = self.active.at[slot].set(True)
-        self.slots[slot] = _Slot(request=req, generated=[],
-                                 remaining=req.max_new_tokens)
+        self.active[slot] = True
+        self.positions[slot] = len(req.tokens)
+        self.slots[slot] = _Slot(request=req, remaining=req.max_new_tokens)
 
-    def _retire(self, slot: int, results: Dict[int, List[int]]) -> None:
+    def _retire(self, slot: int, results: Dict[int, _Slot]) -> None:
         s = self.slots[slot]
         if s.request is not None:
-            results[s.request.request_id] = s.generated
-        # Invalidate any prefill-cache entry pointing at this slot.
-        self._prefill_cache = {k: v for k, v in self._prefill_cache.items()
-                               if v[0] != slot}
+            results[s.request.request_id] = s
         self.slots[slot] = _Slot()
-        self.active = self.active.at[slot].set(False)
+        self.active[slot] = False
 
     # -- main loop ------------------------------------------------------- #
     def run(self, requests: Sequence[Request]) -> List[List[int]]:
-        """Generate for all requests; returns token lists in request order."""
+        """Generate for all requests; returns token lists in request order
+        (``last_logprobs`` holds each chosen token's log-probability)."""
         queue = list(requests)
-        max_prompt = self.S - 2  # room for >=1 generated token
         for i, r in enumerate(queue):
-            if len(r.tokens) > max_prompt:
+            if len(r.tokens) > self.max_prompt:
                 from daft_tpu.errors import DaftValueError
 
                 raise DaftValueError(
@@ -177,38 +225,38 @@ class ContinuousBatcher:
         # Prefix routing: adjacent identical prompts share prefills.
         queue.sort(key=lambda r: (r.prefix_key, r.request_id))
         queue.reverse()  # pop() admits in sorted order
-        results: Dict[int, List[int]] = {}
+        results: Dict[int, _Slot] = {}
         steps = 0
-        while queue or bool(np.asarray(self.active).any()):
-            # Admit into every free slot.
+        while queue or self.active.any():
             free = [i for i in range(self.B) if self.slots[i].request is None]
-            for slot in free:
-                if not queue:
-                    break
-                self._prefill(queue.pop(), slot)
+            if free and queue:
+                self._admit(queue, free)
             # One decode step for the whole pool.
-            self._key, sub = jax.random.split(self._key)
-            self.caches, self.cur_logits, self.positions, tok = self._decode(
-                self.params, self.caches, self.cur_logits, self.positions,
-                self.active, sub)
+            with device_span("serve.decode_step", active=int(self.active.sum()), slots=self.B) as sp:
+                self._key, sub = jax.random.split(self._key)
+                self.state, self.cur_logits, out = self._decode(
+                    self.params, self.state, self.cur_logits, self.positions, self.active, sub)
+                with device_span("serve.fetch"):
+                    out = jax.device_get(out)  # the step's one wait for the device
+                sp.count.update({f"moe.{k}": int(v) for k, v in out["counts"].items()})
             steps += 1
-            tok_host = np.asarray(tok)
-            pos_host = np.asarray(self.positions)
-            for slot in range(self.B):
+            self.positions += self.active
+            for slot in np.flatnonzero(self.active):
                 s = self.slots[slot]
-                if s.request is None:
-                    continue
-                t = int(tok_host[slot])
+                t = int(out["tok"][slot])
                 s.generated.append(t)
+                s.logprobs.append(float(out["logprob"][slot]))
                 s.remaining -= 1
                 if t == self.eos_id or s.remaining <= 0 \
-                        or pos_host[slot] >= self.S - 1:
-                    self._retire(slot, results)
+                        or self.positions[slot] >= self.S - 1:
+                    self._retire(int(slot), results)
         self.decode_steps = steps
-        return [results.get(i, []) for i in range(len(requests))]
+        done = [results.get(i, _Slot()) for i in range(len(requests))]
+        self.last_logprobs = [s.logprobs for s in done]
+        return [s.generated for s in done]
 
 
-def generate_continuous(model: DecoderLM, params, prompts: Sequence[np.ndarray],
+def generate_continuous(model, params, prompts: Sequence[np.ndarray],
                         max_new_tokens, num_slots: int = 8,
                         temperature: float = 0.0, seed: int = 0) -> List[List[int]]:
     """Convenience wrapper: prompts as unpadded int32 arrays; max_new_tokens

@@ -114,7 +114,7 @@ def test_chip_smoke_tiny_cpu_is_a_dry_run():
     assert rec["chip_smoke"] == "dry" and '"ok"' not in proc.stdout
     assert rec["platform"] == "cpu"
     assert set(rec["phases"]) == {"A_overlap", "A_separated", "B_embed_text",
-                                  "C_prompt", "D_device_chain", "E_pallas"}
+                                  "C_prompt", "C_prompt_hybrid", "D_device_chain", "E_pallas"}
     # The debug mode is never the default and never runs off the CPU.
     proc = _run(["chip_smoke.py", "--tiny-cpu"], JAX_PLATFORMS=None)
     assert proc.returncode != 0 and "dry" not in proc.stdout
