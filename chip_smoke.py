@@ -6,7 +6,8 @@
 One process, no child that imports JAX. It drives the engine's main path
 (``from_pydict -> with_column(embed_image) -> UDFProject -> Flax forward ->
 collect``) at the full width of CLIP ViT-L/14 with seeded random weights,
-then the text embedder, the prompter (the small decoder, then the tiny
+then the same UDF over encoded JPEGs with its host stage running ahead of
+the chip, the text embedder, the prompter (the small decoder, then the tiny
 hybrid Mamba-2 / attention / expert decoder with its spans), the relational device path and the
 fused Pallas attention kernel against XLA's attention, and checks every
 result. Phases run in order and the first failure ends the run: nothing here
@@ -99,7 +100,8 @@ def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]
     record, the embeddings and the expression (for its engine instance)."""
     from daft_tpu import col
     from daft_tpu.functions.ai import embed_image
-    from daft_tpu.profiling import newest_device_span
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
 
     rows, batch = len(imgs), cfg["image_batch"]
     df = _embed_image_df(cfg, imgs)
@@ -113,14 +115,19 @@ def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]
         out = df.with_column("emb", expr).select("emb").collect()
         return np.asarray(out.to_pydict()["emb"], dtype=np.float32)
 
+    began = span_clock_ns()
     emb, run_s = _timed(run)
     _check_embeddings(emb, rows, cfg["embed_dim"])
     inst = _engine_instance(expr)
     assert inst.staging_mode == mode
-    forward = newest_device_span("provider.forward").count
-    assert forward["mode"] == mode
-    assert forward["chunks"] == -(-rows // batch) >= 4
-    return {"setup_s": setup_s, "run_s": run_s, "rows": rows}, emb, expr
+    # One forward a morsel: of one device batch staged ahead on a TPU, of up
+    # to sixteen that the call stages itself on the CPU (--tiny-cpu).
+    forwards = [s.count for s in recent_device_spans()
+                if s.name == "provider.forward" and s.start_ns >= began]
+    assert {f["mode"] for f in forwards} == {mode}
+    assert sum(f["chunks"] for f in forwards) == -(-rows // batch) >= 4
+    return {"setup_s": setup_s, "run_s": run_s, "rows": rows,
+            "forwards": len(forwards)}, emb, expr
 
 
 def check_placement(inst, cfg, tiny: bool) -> None:
@@ -144,6 +151,64 @@ def check_placement(inst, cfg, tiny: bool) -> None:
     staged = inst.stage_batch(np.zeros((batch, px, px, 3), np.uint8))
     assert staged.sharding.device_set == set(devices)
     assert staged.addressable_shards[0].data.shape[0] == batch // len(devices)
+
+
+def phase_a_jpeg(cfg, imgs: np.ndarray, tiny: bool) -> dict:
+    """embed_image over two morsels of encoded JPEGs: the host stage of the
+    second (decode, resize, pad, transfer) runs while the first is on the
+    chip, so its ``provider.stage`` has ended before the first morsel's
+    ``provider.fetch`` has. On the CPU the operator keeps the serial loop;
+    --tiny-cpu tells the descriptor otherwise, rehearses the same control
+    flow and asserts no order of two threads on one CPU."""
+    import io
+
+    from PIL import Image
+
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.ai.flax_provider import _FlaxDescriptor
+    from daft_tpu.functions.ai import embed_image
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    batch = cfg["image_batch"]
+    jpegs = []
+    for img in imgs[:2 * batch]:
+        buf = io.BytesIO()
+        Image.fromarray(img).save(buf, format="JPEG", quality=90)
+        jpegs.append(buf.getvalue())
+    df = daft_tpu.from_pydict({"jpg": jpegs})
+    expr = embed_image(col("jpg"), provider="flax_random",
+                       model=cfg["image_model"], batch_size=batch)
+    beside_host = _FlaxDescriptor.runs_beside_host
+    if tiny:
+        _FlaxDescriptor.runs_beside_host = lambda self: True
+    try:
+        _, setup_s = _timed(lambda: df.limit(batch).with_column("emb", expr)
+                            .select("emb").collect())
+        began = span_clock_ns()
+        out, run_s = _timed(lambda: df.with_column("emb", expr).select("emb").collect())
+    finally:
+        _FlaxDescriptor.runs_beside_host = beside_host
+    emb = np.asarray(out.to_pydict()["emb"], dtype=np.float32)
+    _check_embeddings(emb, 2 * batch, cfg["embed_dim"])
+    spans = [s for s in recent_device_spans() if s.start_ns >= began]
+
+    def named(name):
+        return sorted((s for s in spans if s.name == name), key=lambda s: s.start_ns)
+
+    stages, fetches, forwards = named("provider.stage"), named("provider.fetch"), named("provider.forward")
+    assert len(stages) == len(fetches) == len(named("udf.host_stage")) == 2, \
+        f"{len(stages)} stages, {len(fetches)} fetches: not two morsels of one batch"
+    assert all(f.count.get("staged") == 1 for f in forwards), "a forward staged its own input"
+    assert {s.thread for s in stages}.isdisjoint({s.thread for s in fetches})
+    ahead_ms = (fetches[0].end_ns - stages[1].end_ns) / 1e6
+    assert tiny or ahead_ms > 0, \
+        f"the second morsel was staged {-ahead_ms:.1f} ms after the first one's fetch ended"
+    ready = [s.count["ready"] for s in named("udf.wait") if "rows" in s.count]
+    _release(expr)
+    return {"setup_s": setup_s, "run_s": run_s, "rows": 2 * batch,
+            "second_stage_before_first_fetch_end_ms": round(ahead_ms, 2), "ready": ready}
 
 
 def phase_b(cfg) -> dict:
@@ -401,6 +466,8 @@ def main(argv=None) -> int:
             done(current, rec)
             _release(expr)
 
+            current = "A_jpeg_host_stage_ahead"
+            done(current, phase_a_jpeg(cfg, imgs, tiny))
             current = "B_embed_text"
             done(current, phase_b(cfg))
             current = "C_prompt"

@@ -127,7 +127,40 @@ def resolve_staging_mode(requested: Optional[str] = None) -> str:
 _FORWARDS_RUN = weakref.WeakKeyDictionary()
 
 
-def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
+class StagedChunks(list):
+    """``[(rows, device batch), ...]``: a batch that ``stage_chunks`` has already
+    chunked, padded and put on the device, in the order it will run."""
+
+
+def stage_chunks(arr: np.ndarray, max_batch: int, stage=None, pad_mult: int = 1) -> StagedChunks:
+    """The host half of ``_chunked_forward``, for a caller that runs it ahead
+    of the forward (the UDF operator's host stage): every chunk of ``arr``
+    padded to its bucket (span ``provider.pad``) and handed to ``stage``
+    (span ``provider.stage``, around the call), in order."""
+    return StagedChunks((len(chunk), _staged(chunk, b, stage or jax.device_put))
+                        for chunk, b in _chunks(arr, max_batch, pad_mult))
+
+
+def _chunks(arr: np.ndarray, max_batch: int, pad_mult: int) -> list:
+    """-> [(chunk of at most ``max_batch`` rows, the rows it is padded to)]"""
+    out = []
+    for start in range(0, arr.shape[0], max_batch):
+        chunk = arr[start:start + max_batch]
+        b = _bucket(len(chunk))
+        if b % pad_mult:  # dp-sharded batches must divide the dp axis
+            b = ((b + pad_mult - 1) // pad_mult) * pad_mult
+        out.append((chunk, b))
+    return out
+
+
+def _staged(chunk: np.ndarray, b: int, stage):
+    with device_span("provider.pad", rows=len(chunk), padded_rows=b):
+        padded = _pad_batch(chunk, b)
+    with device_span("provider.stage", bytes=padded.nbytes):
+        return stage(padded)
+
+
+def _chunked_forward(fwd, params, arr, max_batch: int, out_dim: int,
                      stage=None, pad_mult: int = 1, mode: str = "separated") -> np.ndarray:
     """Chunk to max_batch and run the forwards under the given staging policy.
 
@@ -139,11 +172,18 @@ def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
     * ``overlap``: depth-1 pipeline — dispatch forward for chunk i, stage
       chunk i+1 while it computes, then fetch chunk i.
 
+    ``arr`` may be ``StagedChunks`` instead of a host array: the chunks are on
+    the device already (``stage_chunks``, run while an earlier forward
+    computed), nothing is padded or staged here, ``mode`` chooses nothing, and
+    the span counts ``staged`` = 1.
+
     The call is the span ``provider.forward`` (``attn`` says which attention
     path the forward took); each chunk's pad, stage, dispatch and fetch are
-    spans of their own below it (profiling.py).
+    spans of their own below it (profiling.py), pad and stage below whoever
+    ran ``stage_chunks`` where that was not this call.
     """
-    n = arr.shape[0]
+    prestaged = isinstance(arr, StagedChunks)
+    n = sum(cn for cn, _ in arr) if prestaged else arr.shape[0]
     if n == 0:
         return np.zeros((0, out_dim), dtype=np.float32)
     with device_span("provider.forward", rows=n, mode=mode) as sp:
@@ -152,24 +192,15 @@ def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
             sp.count["first"] = 1
         if stage is None:
             stage = jax.device_put
-        chunks = []
-        for start in range(0, n, max_batch):
-            chunk = arr[start:start + max_batch]
-            b = _bucket(min(len(chunk), max_batch))
-            if b % pad_mult:  # dp-sharded batches must divide the dp axis
-                b = ((b + pad_mult - 1) // pad_mult) * pad_mult
-            chunks.append((len(chunk), chunk, b))
-        sp.count["chunks"] = len(chunks)
+        if prestaged:
+            sp.count["staged"] = 1
+        else:
+            chunks = _chunks(arr, max_batch, pad_mult)
+        sp.count["chunks"] = len(arr) if prestaged else len(chunks)
         # n_devices: the devices the parameters occupy — what a per-chip rate
         # divides by, whatever else the host can see.
         leaf = jax.tree_util.tree_leaves(params)[0]
         sp.count["n_devices"] = len(leaf.sharding.device_set)
-
-        def staged(chunk, b):
-            with device_span("provider.pad", rows=len(chunk), padded_rows=b):
-                padded = _pad_batch(chunk, b)
-            with device_span("provider.stage", bytes=padded.nbytes):
-                return stage(padded)
 
         def fetched(f, cn):
             with device_span("provider.fetch") as fetch:
@@ -178,24 +209,29 @@ def _chunked_forward(fwd, params, arr: np.ndarray, max_batch: int, out_dim: int,
             return out[:cn]
 
         outs = []
-        if mode == "overlap":
-            nxt = staged(chunks[0][1], chunks[0][2])
-            for i, (cn, _, _) in enumerate(chunks):
+        if prestaged:
+            for cn, on_device in arr:
+                with device_span("provider.dispatch"):
+                    f = fwd(params, on_device)
+                outs.append(fetched(f, cn))
+        elif mode == "overlap":
+            nxt = _staged(*chunks[0], stage)
+            for i, (chunk, _) in enumerate(chunks):
                 cur, nxt = nxt, None
                 with device_span("provider.dispatch"):
                     f = fwd(params, cur)  # async dispatch
                 if i + 1 < len(chunks):  # stage i+1 while chunk i computes
-                    nxt = staged(chunks[i + 1][1], chunks[i + 1][2])
-                outs.append(fetched(f, cn))
+                    nxt = _staged(*chunks[i + 1], stage)
+                outs.append(fetched(f, len(chunk)))
         else:
-            on_device = [staged(c, b) for _, c, b in chunks]
+            on_device = [_staged(c, b, stage) for c, b in chunks]
             for s in on_device:
                 s.block_until_ready()
-            for i, (cn, _, _) in enumerate(chunks):
+            for i, (chunk, _) in enumerate(chunks):
                 with device_span("provider.dispatch"):
                     f = fwd(params, on_device[i])
                 on_device[i] = None  # free the HBM reference once consumed
-                outs.append(fetched(f, cn))
+                outs.append(fetched(f, len(chunk)))
         # The model notes the attention path on this span while a forward
         # traces (layers.MultiHeadAttention); a call that traces nothing
         # repeats what the newest trace chose.
@@ -306,15 +342,26 @@ class FlaxCLIPImageEmbedder(_FlaxModelBase):
     def dimensions(self) -> int:
         return self.cfg.embed_dim
 
-    def embed_image(self, images: np.ndarray) -> np.ndarray:
-        """images: (B, H, W, 3) uint8 (or flat (B, H*W*3)). Returns (B, D) f32.
+    def _nhwc(self, images: np.ndarray) -> np.ndarray:
+        if images.ndim == 2:
+            return images.reshape(images.shape[0], self.cfg.image_size, self.cfg.image_size, 3)
+        return images
+
+    def stage_images(self, images: np.ndarray) -> StagedChunks:
+        """The transfer of ``embed_image``'s input, for a caller that issues it
+        while an earlier batch is on the chip: images as ``embed_image`` takes
+        them, chunked to ``max_batch``, padded and put on the device(s)."""
+        return stage_chunks(self._nhwc(images), self.max_batch, self.stage_batch, self.batch_multiple())
+
+    def embed_image(self, images) -> np.ndarray:
+        """images: (B, H, W, 3) uint8 (or flat (B, H*W*3)), or what
+        ``stage_images`` made of them. Returns (B, D) f32.
 
         Chunks to ``max_batch`` and runs forwards under this instance's
         staging policy (``self.staging_mode``) — see ``_chunked_forward``.
         """
-        n = images.shape[0]
-        if images.ndim == 2:
-            images = images.reshape(n, self.cfg.image_size, self.cfg.image_size, 3)
+        if not isinstance(images, StagedChunks):
+            images = self._nhwc(images)
         return _chunked_forward(self._fwd, self.params, images, self.max_batch,
                                 self.cfg.embed_dim, stage=self.stage_batch,
                                 pad_mult=self.batch_multiple(),
@@ -443,7 +490,10 @@ class FlaxCLIPClassifier(_FlaxModelBase):
             )
         return self._label_cache[key]
 
-    def classify_image(self, images: np.ndarray, labels: Sequence[str]) -> List[str]:
+    def stage_images(self, images: np.ndarray) -> StagedChunks:
+        return self.image_embedder.stage_images(images)
+
+    def classify_image(self, images, labels: Sequence[str]) -> List[str]:
         img = self.image_embedder.embed_image(images)
         lab = self._label_embs(labels)
         sims = img @ lab.T
@@ -574,6 +624,10 @@ class _FlaxDescriptor(Descriptor):
             tpus=self.options.get("tpus", 1.0),
             chips_per_replica=self.options.get("chips_per_replica"),
         )
+
+    def runs_beside_host(self) -> bool:
+        # On the CPU backend the forward takes the cores a host stage would.
+        return jax.default_backend() != "cpu"
 
     def get_dimensions(self) -> Optional[int]:
         from daft_tpu.models.clip import CLIPConfig

@@ -79,6 +79,13 @@ class Descriptor:
         """Embedding dimensionality, when known statically."""
         return None
 
+    def runs_beside_host(self) -> bool:
+        """True where instances compute on a processor other than the host's
+        CPU, so that the host's work for the next batch can run while this one
+        computes (``Udf.host_stage``). Asked when a query runs, never while it
+        is planned."""
+        return True
+
     def instantiate(self):
         raise NotImplementedError
 

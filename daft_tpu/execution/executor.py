@@ -42,9 +42,11 @@ so the thread pool gives real parallelism on multi-core hosts.
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import queue
 import threading
+import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, Iterator, List, Optional
 
@@ -184,6 +186,10 @@ class Executor:
                 max_workers=self.compute_threads, thread_name_prefix="daft-compute")
         return self._compute_pool
 
+    #: How long run()'s teardown retries the close of an operator iterator
+    #: that a feeder thread is still executing.
+    TEARDOWN_RETRY_S = 2.0
+
     def run(self, plan: pp.PhysicalPlan) -> Iterator[MicroPartition]:
         # Plans are DAGs: subquery decorrelation references the same subtree
         # object from multiple parents (e.g. the row-id EXISTS technique).
@@ -228,12 +234,29 @@ class Executor:
             # audit is what made this window visible.
             with self._state_lock:
                 live, self._live_iters = list(self._live_iters), []
-            for g in reversed(live):
+
+            def closed(g) -> bool:
                 try:
                     g.close()
+                except ValueError:
+                    # "already executing": the stage above is pulling it on
+                    # its feeder thread, which that stage's close (later in
+                    # this pass) releases.
+                    return False
                 # daftlint: disable=DTL002 -- teardown close of an already-unwinding iterator; an error here must not mask the query's own outcome
                 except Exception:  # noqa: BLE001 — best-effort teardown
                     pass
+                return True
+
+            busy = [g for g in reversed(live) if not closed(g)]
+            # An operator that holds threads or device memory (the UDF
+            # operator's host stage) lets go in its finally, so give the
+            # feeders a moment to come out of their pull; what is still
+            # executing after that is closed when its feeder drops it.
+            give_up = time.monotonic() + self.TEARDOWN_RETRY_S
+            while busy and time.monotonic() < give_up:
+                time.sleep(0.005)
+                busy = [g for g in busy if not closed(g)]
             self._shared_cache = {}
             if self._compute_pool is not None:
                 self._compute_pool.shutdown(wait=False, cancel_futures=True)
@@ -942,15 +965,19 @@ class Executor:
         # as one giant batch (bounds host memory + enables replica
         # concurrency). A UDF with a declared device batch_size gets morsels
         # of 16 device-batches — enough chunks for async transfer/compute
-        # overlap inside the impl without unbounded host buffers. Host UDFs
-        # with no device batch shape instead follow the latency-constrained
-        # feedback loop (execution/dynamic_batching.py).
+        # overlap inside the impl without unbounded host buffers — or, where
+        # it declares a host stage (Udf.host_stage), of one: the overlap is
+        # then between morsels, and what runs ahead is held a morsel at a
+        # time. Host UDFs with no device batch shape instead follow the
+        # latency-constrained feedback loop (execution/dynamic_batching.py).
         from daft_tpu.execution.pipeline import split_morsels
 
         udf_bs = getattr(udf, "batch_size", None)
+        host_stage = getattr(udf, "host_stage", None) \
+            if udf_bs and concurrency == 1 and slots is None else None
         batch_state = None
         if udf_bs:
-            morsel_rows = min(udf_bs * 16, self.cfg.default_morsel_size)
+            morsel_rows = min(udf_bs * (1 if host_stage else 16), self.cfg.default_morsel_size)
             child_iter = split_morsels(self._run(node.children[0]), morsel_rows)
         elif getattr(self.cfg, "udf_dynamic_batching", False) and slots is None:
             from daft_tpu.execution.dynamic_batching import (
@@ -978,12 +1005,17 @@ class Executor:
                 return out
         from daft_tpu.profiling import device_span
 
+        frame = self._stage_frame(node)
+
         def pulled(it):
             # Each pull of the child as a span; it is closed before the
             # morsel is handed on (a generator never yields inside a span).
+            # With a host stage the pull is on its feeder's thread, where the
+            # span still names this operator.
             it = iter(it)
+            named = contextlib.nullcontext if frame is None or host_stage is None else frame.attributing
             while True:
-                with device_span("udf.pull") as sp:
+                with named(), device_span("udf.pull") as sp:
                     mp = next(it, None)
                     if mp is not None:
                         sp.count["rows"] = len(mp)
@@ -991,15 +1023,23 @@ class Executor:
                     return
                 yield mp
 
-        def call_mp(mp):
+        def call_mp(mp, prepared=None):
             # The root that one morsel's device-path spans name as their cause.
             with device_span("udf.call", rows=len(mp)):
-                return eval_mp(mp)
+                return eval_mp(mp) if host_stage is None else device_stage(mp, prepared)
 
         child_iter = pulled(child_iter)
         if concurrency == 1:
-            for mp in child_iter:
-                yield call_mp(mp)
+            # One loop: a UDF with no host stage is handed each morsel as it is
+            # pulled, on this thread; one with a host stage is handed morsels
+            # whose host stage has run ahead (_host_stage_ahead).
+            if host_stage is None:
+                ahead = ((mp, None) for mp in child_iter)
+            else:
+                device_stage, ahead = self._host_stage_ahead(node, udf, child_iter)
+            with contextlib.closing(ahead):
+                for mp, prepared in ahead:
+                    yield call_mp(mp, prepared)
             return
         # Ordered stage over morsels (actor-pool analogue). UDFs get their
         # OWN pool: replica-slot acquisition can block a worker, which
@@ -1012,6 +1052,96 @@ class Executor:
                              workers=concurrency, name="UDFProject",
                              owns_pool=True, timer=self._stage_frame(node),
                              ledger=self._stage_ledger("UDFProject"))
+
+    #: Host-stage workers of one UDF operator: enough to decode two or three
+    #: times faster than one thread and so hand the pace to the chip, few
+    #: enough that what is prepared ahead (twice this many morsels) stays small.
+    HOST_STAGE_WORKERS = 4
+    #: Morsels whose input may lie on the device ahead of the one that runs.
+    STAGED_AHEAD = 2
+
+    def _host_stage_ahead(self, node: pp.UDFProject, udf, child_iter):
+        """-> (device stage, iterator of ``(morsel, prepared)``) for a UDF that
+        declares a host stage (``Udf.host_stage``).
+
+        ``udf.host_stage`` runs over the pulled morsels as an ordered
+        ``run_stage`` on a pool of this operator's own (span ``udf.host_stage``,
+        on the worker); ``udf.transfer`` runs on the one thread of a
+        ``Prefetch`` over its results, so in morsel order and at most
+        ``STAGED_AHEAD`` morsels ahead of the one the device stage holds; the
+        iterator hands them to the operator's thread, which waits for each
+        under the span ``udf.wait`` (``ready`` = 1 where it was prepared before
+        it was asked for). A failure in either reaches the consumer in its
+        morsel's place, as ``Udf.evaluate`` would have raised it; closing the
+        iterator releases both threads' work and drops what was staged.
+
+        The device stage evaluates the operator's expressions with the UDF's
+        call replaced by its result, ``udf.evaluate`` over the arguments the
+        host stage computed and ``prepared``: nothing is evaluated twice."""
+        from daft_tpu.execution.pipeline import Prefetch, run_stage
+        from daft_tpu.expressions.expr import ColumnRef, UdfCall
+        from daft_tpu.profiling import device_span
+
+        call = next(n for n in node.udf_expr.walk() if isinstance(n, UdfCall))
+        result = "__udf_result__"
+        exprs = node.passthrough + [node.udf_expr.transform(
+            lambda n: ColumnRef(result) if isinstance(n, UdfCall) and n.udf is udf else None)]
+        frame = self._stage_frame(node)
+        timed = (lambda fn, x: fn(x)) if frame is None else frame.run_timed
+
+        def failing_as_the_call(fn, *args):
+            try:
+                return fn(*args)
+            except Exception as e:  # noqa: BLE001 -- as Udf.evaluate wraps a failing fn
+                raise DaftExecutionError(f"UDF {udf.name!r} failed in its host stage: {e}") from e
+
+        host_stage, to_device = udf.host_stage, udf.transfer
+
+        def host(mp):
+            with device_span("udf.host_stage", rows=len(mp)):
+                rb = mp.combined()
+                args = [evaluate(a, rb) for a in call.args]
+                return rb, args, failing_as_the_call(host_stage, *args)
+
+        def transfer(item):
+            rb, args, batch = item
+            return rb, args, failing_as_the_call(to_device, batch)
+
+        def device_stage(item):
+            rb, args, prepared = item
+            res = udf.evaluate(args, dict(call.kwargs, prepared=prepared)).rename(result)
+            schema = Schema(list(rb.schema) + [Field(result, res.dtype)])
+            out = RecordBatch(schema, rb.columns() + [res], len(rb)).eval_expression_list(exprs)
+            return MicroPartition(out.schema, [out])
+
+        workers = max(1, min(self.HOST_STAGE_WORKERS, int(udf.cpus or self.compute_threads)))
+
+        def ahead():
+            pool = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="daft-udf-host")
+            hosted = run_stage(child_iter, host, pool=pool, workers=workers, name="UDFHostStage",
+                               owns_pool=True, timer=frame)
+            staged = Prefetch((timed(transfer, item) for item in hosted),
+                              capacity=self.STAGED_AHEAD - 1, name="udf-transfer")
+            it = iter(staged)
+            try:
+                while True:
+                    with device_span("udf.wait") as sp:
+                        sp.count["ready"] = int(staged.ready())
+                        item = next(it, None)
+                        if item is not None:
+                            sp.count["rows"] = len(item[0])
+                    if item is None:
+                        return
+                    yield item[0], item
+            finally:
+                # The transfer in flight ends, then the host stages in flight:
+                # no thread of this operator outlives it, and no staged batch.
+                staged.close(wait_s=2.0)
+                with contextlib.suppress(ValueError):  # its thread is still in a pull that outlasted the wait
+                    hosted.close()
+                pool.shutdown(wait=True, cancel_futures=True)
+
+        return (lambda rb, item: timed(device_stage, item)), ahead()
 
     # -- streaming sinks --------------------------------------------------
     def _run_Limit(self, node: pp.Limit) -> Iterator[MicroPartition]:

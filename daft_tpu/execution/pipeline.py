@@ -483,6 +483,11 @@ class Prefetch:
     occurred. Callers MUST :meth:`close` (or exhaust) the prefetch — an
     error between construction and consumption would otherwise leave the
     puller thread spinning against a full queue.
+
+    Whatever ``it`` computes runs on the one puller thread, in order, and at
+    most ``capacity + 1`` items lie ahead of the one the consumer holds (the
+    queue's and the one the puller waits to put): the UDF operator's ordered,
+    bounded input transfer is a Prefetch over a generator that stages.
     """
 
     def __init__(self, it: Iterator, capacity: int = 4,
@@ -515,8 +520,24 @@ class Prefetch:
             name=f"daft-{name}")
         self._thread.start()
 
-    def close(self) -> None:
+    def close(self, wait_s: float = 0.0) -> None:
+        """Release the puller. With ``wait_s`` also wait that long for it to
+        end and drop what it had queued, so that nothing prefetched outlives
+        the consumer (a staged device batch holds HBM). The puller ends after
+        the item it is computing, or when its source next yields."""
         self._stop.set()
+        if wait_s > 0 and threading.current_thread() is not self._thread:
+            self._thread.join(wait_s)
+            while True:
+                try:
+                    self._q.get_nowait()
+                except queue.Empty:
+                    return
+
+    def ready(self) -> bool:
+        """True when the consumer's next item (or the stream's end) is already
+        in the queue: asking for it will not wait."""
+        return not self._q.empty()
 
     def __iter__(self):
         try:

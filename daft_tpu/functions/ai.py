@@ -30,21 +30,31 @@ class _ProtocolUdf(Udf):
     The instance (model params in HBM) is created once per worker process on
     first batch — the actor-pool replica pattern (reference:
     daft/ai/_expressions.py + @daft.cls wrapping in functions/ai).
+
+    ``host`` and ``transfer`` (both ``(inst, x)``) are the UDF's host stage
+    (``Udf.host_stage``): ``call`` then also takes what ``transfer`` returned
+    and starts from it.
     """
 
-    def __init__(self, descriptor, call, return_dtype: DataType, name: str):
+    def __init__(self, descriptor, call, return_dtype: DataType, name: str,
+                 host=None, transfer=None):
         self._descriptor = descriptor
         self._call = call
+        self._host = host
+        self._transfer = transfer
         self._instances = {}
         self._instance_lock = threading.Lock()
         udf_opts = descriptor.get_udf_options()
 
-        def fn(*series):
+        def fn(*series, prepared=None):
             # Device-batch chunking lives inside the protocol impls (they
             # chunk to their device batch and async-dispatch all chunks so
-            # transfers overlap compute); here we just hand over the morsel.
+            # transfers overlap compute); here we just hand over the morsel,
+            # or what the host stage made of it.
             inst = self._get_instance()
-            return self._call(inst, *series)
+            if prepared is None:
+                return self._call(inst, *series)
+            return self._call(inst, *series, prepared)
 
         fn.__name__ = name
         super().__init__(
@@ -55,6 +65,15 @@ class _ProtocolUdf(Udf):
             batch_size=udf_opts.batch_size, use_process=udf_opts.use_process,
             chips_per_replica=udf_opts.chips_per_replica,
         )
+
+    @property
+    def host_stage(self):
+        if self._host is None or not self._descriptor.runs_beside_host():
+            return None
+        return lambda *series: self._host(self._get_instance(), *series)
+
+    def transfer(self, batch):
+        return self._transfer(self._get_instance(), batch)
 
     def _get_instance(self):
         # One model instance PER REPLICA SLOT: with chips_per_replica the
@@ -191,6 +210,20 @@ def _host_resize_batch(vals: np.ndarray, size: int) -> np.ndarray:
     return out
 
 
+def _image_batch(inst, series: Series) -> np.ndarray:
+    """Host stage of the image UDFs: the column as the dense uint8 batch the
+    model takes (decode, resize, copy)."""
+    cfg = getattr(getattr(inst, "image_embedder", inst), "cfg", None)
+    return _images_to_numpy(series, cfg.image_size if cfg is not None else 224)
+
+
+def _stage_images(inst, batch: np.ndarray):
+    """Its transfer: chunked, padded and put on the device where the instance
+    can take a batch in that form (``stage_images``); else the host batch."""
+    stage = getattr(inst, "stage_images", None)
+    return batch if stage is None else stage(batch)
+
+
 def embed_text(text: Expression, *, provider: Union[str, object, None] = None,
                model: Optional[str] = None, **options) -> Expression:
     """Embed a string column (reference: daft/functions/ai/__init__.py:72)."""
@@ -218,13 +251,11 @@ def embed_image(image: Expression, *, provider: Union[str, object, None] = None,
     dims = desc.get_dimensions() or 768
     dtype = DataType.embedding(DataType.float32(), dims)
 
-    def call(inst, series: Series) -> Series:
-        size = getattr(inst, "cfg", None).image_size if hasattr(inst, "cfg") else 224
-        batch = _images_to_numpy(series, size)
-        embs = inst.embed_image(batch)
+    def call(inst, series: Series, batch=None) -> Series:
+        embs = inst.embed_image(_image_batch(inst, series) if batch is None else batch)
         return Series.from_numpy(embs, "embedding", dtype)
 
-    return _ProtocolUdf(desc, call, dtype, "embed_image")(image)
+    return _ProtocolUdf(desc, call, dtype, "embed_image", host=_image_batch, transfer=_stage_images)(image)
 
 
 def classify_text(text: Expression, labels: Sequence[str], *,
@@ -248,13 +279,12 @@ def classify_image(image: Expression, labels: Sequence[str], *,
     desc = p.get_image_classifier(model, **options)
     labels = list(labels)
 
-    def call(inst, series: Series) -> Series:
-        size = inst.image_embedder.cfg.image_size if hasattr(inst, "image_embedder") else 224
-        batch = _images_to_numpy(series, size)
-        out = inst.classify_image(batch, labels)
+    def call(inst, series: Series, batch=None) -> Series:
+        out = inst.classify_image(_image_batch(inst, series) if batch is None else batch, labels)
         return Series.from_pylist(out, "label", DataType.string())
 
-    return _ProtocolUdf(desc, call, DataType.string(), "classify_image")(image)
+    return _ProtocolUdf(desc, call, DataType.string(), "classify_image",
+                        host=_image_batch, transfer=_stage_images)(image)
 
 
 def prompt(text: Expression, *, provider: Union[str, object, None] = None,

@@ -490,6 +490,19 @@ class _OpFrame:
             if st and st[-1] is self:
                 st.pop()
 
+    @contextlib.contextmanager
+    def attributing(self):
+        """Ride this thread's attribution stack without being timed: for a
+        stage's feeder thread, whose pull of the child is the child's time but
+        whose own device span (``udf.pull``) names this operator."""
+        st = _stack()
+        st.append(self)
+        try:
+            yield
+        finally:
+            if st and st[-1] is self:
+                st.pop()
+
     def add_worker_output(self, rows: int, mp) -> None:
         """Output accounting from a stage WORKER thread (fused-chain member
         operators record their per-node output inside the composed morsel

@@ -28,6 +28,17 @@ from daft_tpu.udf.udaf import Udaf, udaf  # noqa: F401  (public surface)
 class Udf:
     """A callable UDF descriptor; calling it builds a UdfCall expression."""
 
+    #: A batch UDF with a declared ``batch_size`` may split off what its call
+    #: does before it needs the chip, and the UDF operator then runs that for
+    #: later morsels while this one's ``fn`` runs (``_run_UDFProject``):
+    #: ``host_stage(*series) -> batch`` on any worker thread, several morsels
+    #: at once; ``transfer(batch) -> prepared`` on one thread in morsel order,
+    #: at most two ahead (what it puts on the device holds HBM); then
+    #: ``fn(*series, prepared=prepared)`` on the operator's thread. None: ``fn``
+    #: does all of it when called, as every UDF without a host stage does.
+    host_stage: Optional[Callable] = None
+    transfer: Optional[Callable] = None
+
     def __init__(self, fn: Callable, return_dtype: DataType, batch: bool = False,
                  name: Optional[str] = None, max_concurrency: Optional[int] = None,
                  cpus: Optional[float] = None, gpus: Optional[float] = None,
