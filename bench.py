@@ -79,8 +79,8 @@ def _bench_engine(num_images: int, batch_size: int) -> dict:
         elapsed = time.perf_counter() - start
 
     assert total == num_images, f"expected {num_images} rows, got {total}"
-    # Publish the last forward's counters (rows, chunks, staging mode,
-    # devices), so results are attributable.
+    # Publish the last forward's counters (rows, chunks, devices), so
+    # results are attributable.
     stats = newest_device_span("provider.forward").count
     sys.stderr.write(f"last forward: {stats}, engine wall {elapsed:.2f}s\n")
     from daft_tpu.perf_report import resolved_compute_threads

@@ -95,9 +95,9 @@ def _embed_image_df(cfg, imgs: np.ndarray):
     return daft_tpu.from_pydict({"img": series})
 
 
-def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]:
-    """embed_image through the engine under one staging mode. Returns the
-    record, the embeddings and the expression (for its engine instance)."""
+def phase_a(cfg, imgs: np.ndarray) -> Tuple[dict, np.ndarray, object]:
+    """embed_image through the engine. Returns the record, the embeddings and
+    the expression (for its engine instance)."""
     from daft_tpu import col
     from daft_tpu.functions.ai import embed_image
     from daft_tpu.profiling import recent_device_spans
@@ -106,8 +106,7 @@ def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]
     rows, batch = len(imgs), cfg["image_batch"]
     df = _embed_image_df(cfg, imgs)
     expr = embed_image(col("img"), provider="flax_random",
-                       model=cfg["image_model"], batch_size=batch,
-                       staging_mode=mode)
+                       model=cfg["image_model"], batch_size=batch)
     _, setup_s = _timed(lambda: df.limit(batch).with_column("emb", expr)
                         .select("emb").collect())
 
@@ -118,13 +117,10 @@ def phase_a(cfg, imgs: np.ndarray, mode: str) -> Tuple[dict, np.ndarray, object]
     began = span_clock_ns()
     emb, run_s = _timed(run)
     _check_embeddings(emb, rows, cfg["embed_dim"])
-    inst = _engine_instance(expr)
-    assert inst.staging_mode == mode
     # One forward a morsel: of one device batch staged ahead on a TPU, of up
     # to sixteen that the call stages itself on the CPU (--tiny-cpu).
     forwards = [s.count for s in recent_device_spans()
                 if s.name == "provider.forward" and s.start_ns >= began]
-    assert {f["mode"] for f in forwards} == {mode}
     assert sum(f["chunks"] for f in forwards) == -(-rows // batch) >= 4
     return {"setup_s": setup_s, "run_s": run_s, "rows": rows,
             "forwards": len(forwards)}, emb, expr
@@ -448,22 +444,15 @@ def main(argv=None) -> int:
     try:
         # The result cache would answer a repeated query without the device.
         with daft_tpu.execution_config_ctx(result_cache_enabled=False):
-            current = "A_overlap"
-            rec, emb_overlap, expr = phase_a(cfg, imgs, "overlap")
+            current = "A_embed_image"
+            rec, emb, expr = phase_a(cfg, imgs)
             inst = _engine_instance(expr)
             check_placement(inst, cfg, tiny)
             np.testing.assert_allclose(
-                emb_overlap, inst.embed_image(imgs), atol=1e-3,
+                emb, inst.embed_image(imgs), atol=1e-3,
                 err_msg="engine result != direct embed_image call")
             done(current, rec)
             del inst
-            _release(expr)
-
-            current = "A_separated"
-            rec, emb_separated, expr = phase_a(cfg, imgs, "separated")
-            cos = _min_cosine(emb_overlap, emb_separated)
-            assert cos > 0.999, f"staging modes disagree: min cosine {cos}"
-            done(current, rec)
             _release(expr)
 
             current = "A_jpeg_host_stage_ahead"

@@ -8,8 +8,13 @@ encoder and a decoder LM, all served as jitted XLA computations with
   worker process; the models cast activations and matmul operands to bf16,
 * **batch-shape bucketing** — inputs pad to power-of-two buckets so jax.jit
   recompiles O(log batch) times, never per morsel (SURVEY.md §7 hard part (f)),
-* **uint8 device staging** — images ship to HBM as uint8 NHWC and are
-  normalised on device (4× less PCIe/DMA traffic than host-side f32),
+* **uint8 device staging** — images ship to HBM as uint8 NHWC (a quarter of
+  the bytes of f32) and are normalised on device,
+* **one staging policy** — ``_chunked_forward`` dispatches the forward of one
+  device batch, has the next batch on the device while that one computes, and
+  only then fetches: the next batch is either staged already by a caller that
+  ran ahead (``stage_chunks``: the UDF operator's transfer thread) or padded
+  and staged by the call itself at that point,
 * **zero-egress weights** — random init by default; ``weights_path`` loads a
   local checkpoint when present.
 """
@@ -99,26 +104,8 @@ def _load_clip(model_name: str, weights_path: str):
     return load_params(weights_path, CLIPConfig.from_name(model_name))
 
 
-#: Host-to-device staging policies of ``_chunked_forward``. Both stay
-#: selectable (``staging_mode=`` / ``DAFT_STAGING_MODE``) until a benchmark
-#: cell deletes one with a number.
-STAGING_MODES = ("overlap", "separated")
-DEFAULT_STAGING_MODE = "overlap"
 #: Default device batch of the image embedder.
 DEFAULT_BATCH = 128
-
-
-def resolve_staging_mode(requested: Optional[str] = None) -> str:
-    """``DAFT_STAGING_MODE``, else ``requested``, else the default; anything
-    but the two explicit modes is an error."""
-    from daft_tpu.config import daft_env
-
-    mode = daft_env("DAFT_STAGING_MODE") or requested or DEFAULT_STAGING_MODE
-    if mode not in STAGING_MODES:
-        raise DaftValueError(
-            f"staging_mode must be one of {STAGING_MODES}, got {mode!r}")
-    return mode
-
 
 #: Jitted forwards that ``_chunked_forward`` has run: an instance's first call
 #: traces, loads or compiles its executable and runs it, and is set-up. The
@@ -135,47 +122,40 @@ class StagedChunks(list):
 def stage_chunks(arr: np.ndarray, max_batch: int, stage=None, pad_mult: int = 1) -> StagedChunks:
     """The host half of ``_chunked_forward``, for a caller that runs it ahead
     of the forward (the UDF operator's host stage): every chunk of ``arr``
-    padded to its bucket (span ``provider.pad``) and handed to ``stage``
-    (span ``provider.stage``, around the call), in order."""
-    return StagedChunks((len(chunk), _staged(chunk, b, stage or jax.device_put))
-                        for chunk, b in _chunks(arr, max_batch, pad_mult))
+    padded and staged as ``_staged_chunks`` does it, all of them now."""
+    return StagedChunks(_staged_chunks(arr, max_batch, stage, pad_mult))
 
 
-def _chunks(arr: np.ndarray, max_batch: int, pad_mult: int) -> list:
-    """-> [(chunk of at most ``max_batch`` rows, the rows it is padded to)]"""
-    out = []
+def _staged_chunks(arr: np.ndarray, max_batch: int, stage, pad_mult: int):
+    """Yields ``(rows, device batch)`` for each chunk of at most ``max_batch``
+    rows of ``arr``, in order: padded to its bucket (span ``provider.pad``) and
+    handed to ``stage`` (``jax.device_put`` if None; span ``provider.stage``
+    around the call) when it is asked for, not before."""
+    stage = stage or jax.device_put
     for start in range(0, arr.shape[0], max_batch):
         chunk = arr[start:start + max_batch]
         b = _bucket(len(chunk))
         if b % pad_mult:  # dp-sharded batches must divide the dp axis
             b = ((b + pad_mult - 1) // pad_mult) * pad_mult
-        out.append((chunk, b))
-    return out
-
-
-def _staged(chunk: np.ndarray, b: int, stage):
-    with device_span("provider.pad", rows=len(chunk), padded_rows=b):
-        padded = _pad_batch(chunk, b)
-    with device_span("provider.stage", bytes=padded.nbytes):
-        return stage(padded)
+        with device_span("provider.pad", rows=len(chunk), padded_rows=b):
+            padded = _pad_batch(chunk, b)
+        with device_span("provider.stage", bytes=padded.nbytes):
+            on_device = stage(padded)
+        yield len(chunk), on_device
 
 
 def _chunked_forward(fwd, params, arr, max_batch: int, out_dim: int,
-                     stage=None, pad_mult: int = 1, mode: str = "separated") -> np.ndarray:
-    """Chunk to max_batch and run the forwards under the given staging policy.
+                     stage=None, pad_mult: int = 1) -> np.ndarray:
+    """Chunk to max_batch and run the forwards as a depth-1 pipeline: dispatch
+    the forward of chunk i, have chunk i+1 on the device while it computes,
+    then fetch chunk i. Never more than one forward is queued ahead of the
+    fetch.
 
-    Neither mode queues more than one forward ahead of the fetch.
-
-    * ``separated``: stage ALL chunks, block, then run forward+fetch per
-      chunk (transfers never interleave a running compute; host window
-      bounded by the engine's UDF morsel size).
-    * ``overlap``: depth-1 pipeline — dispatch forward for chunk i, stage
-      chunk i+1 while it computes, then fetch chunk i.
-
-    ``arr`` may be ``StagedChunks`` instead of a host array: the chunks are on
-    the device already (``stage_chunks``, run while an earlier forward
-    computed), nothing is padded or staged here, ``mode`` chooses nothing, and
-    the span counts ``staged`` = 1.
+    ``arr`` is a host array, whose chunks the call pads and stages itself
+    (``_staged_chunks``), each when the pipeline comes to it, or
+    ``StagedChunks``: the chunks are on the device already (``stage_chunks``,
+    run while an earlier forward computed), nothing is padded or staged here,
+    and the span counts ``staged`` = 1.
 
     The call is the span ``provider.forward`` (``attn`` says which attention
     path the forward took); each chunk's pad, stage, dispatch and fetch are
@@ -186,52 +166,32 @@ def _chunked_forward(fwd, params, arr, max_batch: int, out_dim: int,
     n = sum(cn for cn, _ in arr) if prestaged else arr.shape[0]
     if n == 0:
         return np.zeros((0, out_dim), dtype=np.float32)
-    with device_span("provider.forward", rows=n, mode=mode) as sp:
+    with device_span("provider.forward", rows=n) as sp:
         if fwd not in _FORWARDS_RUN:
             _FORWARDS_RUN[fwd] = None
             sp.count["first"] = 1
-        if stage is None:
-            stage = jax.device_put
         if prestaged:
             sp.count["staged"] = 1
+            staged = iter(arr)
         else:
-            chunks = _chunks(arr, max_batch, pad_mult)
-        sp.count["chunks"] = len(arr) if prestaged else len(chunks)
+            staged = _staged_chunks(arr, max_batch, stage, pad_mult)
         # n_devices: the devices the parameters occupy — what a per-chip rate
         # divides by, whatever else the host can see.
         leaf = jax.tree_util.tree_leaves(params)[0]
         sp.count["n_devices"] = len(leaf.sharding.device_set)
 
-        def fetched(f, cn):
+        outs = []
+        nxt = next(staged)  # n > 0: there is a first chunk
+        while nxt is not None:
+            cn, on_device = nxt
+            with device_span("provider.dispatch"):
+                f = fwd(params, on_device)  # async dispatch
+            nxt = next(staged, None)  # chunk i+1 is staged while chunk i computes
             with device_span("provider.fetch") as fetch:
                 out = np.asarray(f)  # forces + fetches the chunk
                 fetch.count["bytes"] = out.nbytes
-            return out[:cn]
-
-        outs = []
-        if prestaged:
-            for cn, on_device in arr:
-                with device_span("provider.dispatch"):
-                    f = fwd(params, on_device)
-                outs.append(fetched(f, cn))
-        elif mode == "overlap":
-            nxt = _staged(*chunks[0], stage)
-            for i, (chunk, _) in enumerate(chunks):
-                cur, nxt = nxt, None
-                with device_span("provider.dispatch"):
-                    f = fwd(params, cur)  # async dispatch
-                if i + 1 < len(chunks):  # stage i+1 while chunk i computes
-                    nxt = _staged(*chunks[i + 1], stage)
-                outs.append(fetched(f, len(chunk)))
-        else:
-            on_device = [_staged(c, b, stage) for c, b in chunks]
-            for s in on_device:
-                s.block_until_ready()
-            for i, (chunk, _) in enumerate(chunks):
-                with device_span("provider.dispatch"):
-                    f = fwd(params, on_device[i])
-                on_device[i] = None  # free the HBM reference once consumed
-                outs.append(fetched(f, len(chunk)))
+            outs.append(out[:cn])
+        sp.count["chunks"] = len(outs)
         # The model notes the attention path on this span while a forward
         # traces (layers.MultiHeadAttention); a call that traces nothing
         # repeats what the newest trace chose.
@@ -246,11 +206,10 @@ class _FlaxModelBase:
     ``chips_per_replica`` each instance owns an ICI mesh slice of the
     devices its process holds)."""
 
-    def __init__(self, staging_mode: Optional[str] = None):
+    def __init__(self):
         self._lock = threading.Lock()
         self.mesh = None
         self._param_specs = None
-        self.staging_mode = resolve_staging_mode(staging_mode)
 
     def setup_mesh(self, mesh_axes: Optional[Dict[str, int]] = None):
         """Build this replica's mesh over its device slot.
@@ -311,9 +270,8 @@ class _FlaxModelBase:
 class FlaxCLIPImageEmbedder(_FlaxModelBase):
     def __init__(self, model_name: str, weights_path: Optional[str] = None,
                  seed: int = 0, batch_size: Optional[int] = None,
-                 mesh_axes: Optional[Dict[str, int]] = None,
-                 staging_mode: Optional[str] = None):
-        super().__init__(staging_mode)
+                 mesh_axes: Optional[Dict[str, int]] = None):
+        super().__init__()
         from daft_tpu.models.clip import CLIPConfig, init_clip_params, load_params
 
         self.max_batch = int(batch_size or DEFAULT_BATCH)
@@ -357,15 +315,14 @@ class FlaxCLIPImageEmbedder(_FlaxModelBase):
         """images: (B, H, W, 3) uint8 (or flat (B, H*W*3)), or what
         ``stage_images`` made of them. Returns (B, D) f32.
 
-        Chunks to ``max_batch`` and runs forwards under this instance's
-        staging policy (``self.staging_mode``) — see ``_chunked_forward``.
+        Chunks to ``max_batch`` and runs the forwards as ``_chunked_forward``
+        does.
         """
         if not isinstance(images, StagedChunks):
             images = self._nhwc(images)
         return _chunked_forward(self._fwd, self.params, images, self.max_batch,
                                 self.cfg.embed_dim, stage=self.stage_batch,
-                                pad_mult=self.batch_multiple(),
-                                mode=self.staging_mode)
+                                pad_mult=self.batch_multiple())
 
 
 class FlaxCLIPTextEmbedder(_FlaxModelBase):
@@ -415,7 +372,7 @@ class FlaxCLIPTextEmbedder(_FlaxModelBase):
     def embed_text(self, texts: Sequence[Optional[str]]) -> np.ndarray:
         tokens, _ = self.tokenizer.encode_batch(texts)
         return _chunked_forward(self._fwd, self.params, tokens, self.max_batch,
-                                self.cfg.embed_dim, mode=self.staging_mode)
+                                self.cfg.embed_dim)
 
 
 class FlaxMiniLMTextEmbedder(_FlaxModelBase):
@@ -469,7 +426,7 @@ class FlaxMiniLMTextEmbedder(_FlaxModelBase):
     def embed_text(self, texts: Sequence[Optional[str]]) -> np.ndarray:
         tokens, _ = self.tokenizer.encode_batch(texts)
         return _chunked_forward(self._fwd, self.params, tokens, self.max_batch,
-                                self.cfg.embed_dim, mode=self.staging_mode)
+                                self.cfg.embed_dim)
 
 
 class FlaxCLIPClassifier(_FlaxModelBase):
@@ -660,7 +617,6 @@ class _FlaxDescriptor(Descriptor):
             kw = {k: v for k, v in opts.items() if k in ("weights_path", "seed")}
             kw["batch_size"] = self.options.get("batch_size")
             kw["mesh_axes"] = self.options.get("mesh_axes")
-            kw["staging_mode"] = self.options.get("staging_mode")
             return FlaxCLIPImageEmbedder(self.model, **kw)
         if self.kind == "text_embedder":
             if "clip" in self.model.lower() or "vit" in self.model.lower():

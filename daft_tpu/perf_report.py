@@ -3,7 +3,7 @@
 The metrics plane answers "how much", the profiler answers "where did the
 time go" — this module makes both DURABLE and COMPARABLE across commits, so
 every perf claim ("q21 got 12% faster") is mechanically checkable instead of
-anecdotal. Three pieces:
+anecdotal. Two pieces:
 
 * **Trajectory store** — :func:`capture_query` runs one query under the
   profiler bracketed by a metrics-snapshot pair and distills a structured
@@ -23,9 +23,6 @@ anecdotal. Three pieces:
   the machines' speed difference, and each query is judged against that
   median — a box that is uniformly 2x slower flags nothing, a single query
   that slipped against its peers flags loudly.
-* **Gap attribution** — :func:`gap_breakdown` explains an A/B wall gap
-  (engine vs standalone) operator by operator, for the engine-overhead
-  watchdog (``tests/benchmarks/test_engine_overhead.py``).
 
 Schema stability: entries carry ``schema_version``; :func:`validate_entry`
 is the contract both the writer (scripts/perf_observatory.py) and the CI
@@ -472,34 +469,3 @@ def diff_latest(trajectory: List[dict]) -> Optional[RegressionReport]:
     if len(trajectory) < 2:
         return None
     return diff_entries(trajectory[-2], trajectory[-1])
-
-
-# --------------------------------------------------------------------- #
-# Engine-overhead gap attribution                                       #
-# --------------------------------------------------------------------- #
-def gap_breakdown(profile, standalone_s: float, engine_s: float) -> str:
-    """Explain an engine-vs-standalone wall gap operator by operator: the
-    profiled engine run's per-plan-node self times, each as seconds and as
-    a share of the gap — so a failing watchdog verdict names the layer
-    (morsel re-batching, fetch ordering, dispatch) instead of a bare ratio."""
-    gap = engine_s - standalone_s
-    lines = [f"engine {engine_s:.3f}s vs standalone {standalone_s:.3f}s "
-             f"(x{engine_s / standalone_s:.3f}, gap {gap:+.3f}s)"]
-    if profile is None:
-        lines.append("  (no profile attached)")
-        return "\n".join(lines)
-    table = profile.operator_table(by="plan_node")
-    accounted = 0.0
-    for r in table:
-        self_s = r["self_wall_ns"] / 1e9
-        accounted += self_s
-        share = (self_s / gap * 100.0) if gap > 1e-9 else 0.0
-        lines.append(
-            f"  {r.get('plan_node', r['operator']):<24} self "
-            f"{self_s:8.3f}s  cpu {r['self_cpu_ns'] / 1e9:7.3f}s  "
-            f"rows {r['rows']:>9}  morsels {r['morsels']:>5}"
-            + (f"  ({share:5.1f}% of gap)" if gap > 1e-9 else ""))
-    residual = engine_s - accounted
-    lines.append(f"  {'<unattributed (plan/dispatch)>':<24} self "
-                 f"{residual:8.3f}s")
-    return "\n".join(lines)

@@ -163,27 +163,31 @@ def test_weights_path_orbax_dir(tmp_path):
     np.testing.assert_allclose(np.asarray(e1), np.asarray(e2), atol=1e-5)
 
 
-def test_staging_modes_agree():
-    """Both staging policies produce identical embeddings; the forward's
-    span records which mode ran."""
-    from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder, resolve_staging_mode
-    from daft_tpu.profiling import newest_device_span
+@pytest.mark.parametrize("rows, chunks", [(10, 3), (4, 1), (0, 0)])
+def test_staged_ahead_and_staged_in_the_call_agree(rows, chunks):
+    """The same images as a host array (the call pads and stages its own
+    chunks) and as ``stage_images`` made of them (staged before the call)
+    give the same embeddings; the forward's span counts what ran."""
+    from daft_tpu.ai.flax_provider import FlaxCLIPImageEmbedder
+    from daft_tpu.profiling import device_span, newest_device_span
 
-    imgs = np.random.default_rng(1).integers(0, 255, (10, 32, 32, 3), dtype=np.uint8)
+    imgs = np.random.default_rng(1).integers(0, 255, (rows, 32, 32, 3), dtype=np.uint8)
+    emb = FlaxCLIPImageEmbedder("tiny", batch_size=4)  # 10 rows: 4, 4 and a ragged 2
     outs = {}
-    for mode in ("overlap", "separated"):
-        emb = FlaxCLIPImageEmbedder("tiny", batch_size=4, staging_mode=mode)
-        outs[mode] = emb.embed_image(imgs)
-        assert emb.staging_mode == mode
-        forward = newest_device_span("provider.forward").count
-        assert forward["mode"] == mode
-        assert forward["rows"] == 10
-        assert forward["chunks"] == 3
-    np.testing.assert_allclose(outs["overlap"], outs["separated"], rtol=1e-5)
-    assert resolve_staging_mode(None) == "overlap"
-    for bad in ("auto", "bogus"):
-        with pytest.raises(Exception):
-            resolve_staging_mode(bad)
+    for form in ("host", "staged"):
+        with device_span("test.mark") as mark:
+            pass
+        outs[form] = emb.embed_image(imgs if form == "host" else emb.stage_images(imgs))
+        assert outs[form].shape == (rows, emb.dimensions)
+        forward = newest_device_span("provider.forward")
+        if rows == 0:  # nothing to run: no forward, no span
+            assert forward is None or forward.span_id < mark.span_id
+            continue
+        assert forward.span_id > mark.span_id
+        assert (forward.count["rows"], forward.count["chunks"]) == (rows, chunks)
+        assert forward.count.get("staged") == (1 if form == "staged" else None)
+        assert "mode" not in forward.count
+    np.testing.assert_allclose(outs["host"], outs["staged"], rtol=1e-5)
 
 
 def test_building_ai_expressions_initialises_no_backend():
@@ -215,16 +219,15 @@ print("planned-without-backend")
 
 
 def test_image_embedder_defaults_are_constants():
-    """The default staging mode and device batch are constants, and the
-    UDF's morsel batch is derived from the options alone."""
+    """The default device batch is a constant, the UDF's morsel batch is
+    derived from the options alone, and how a batch is staged is no option."""
     from daft_tpu.ai import flax_provider as fp
 
     emb = fp.FlaxCLIPImageEmbedder("tiny")
-    assert (emb.staging_mode, emb.max_batch) == ("overlap", 128)
+    assert emb.max_batch == 128 and not [k for k in dir(emb) if "staging" in k]
     prov = fp.FlaxProvider(random_init=True)
     assert prov.get_image_embedder("tiny").get_udf_options().batch_size == 256
-    desc = prov.get_image_embedder("tiny", batch_size=512,
-                                   staging_mode="separated")
+    desc = prov.get_image_embedder("tiny", batch_size=512)
     assert desc.get_udf_options().batch_size == 512
     inst = desc.instantiate()
-    assert (inst.staging_mode, inst.max_batch) == ("separated", 512)
+    assert inst.max_batch == 512 and not [k for k in dir(inst) if "staging" in k]

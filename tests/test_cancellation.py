@@ -678,7 +678,14 @@ def test_breaker_probe_failure_reopens_with_backoff():
     assert second_delay > first_delay
 
 
-def test_breaker_jitter_is_seed_deterministic():
+def test_breaker_jitter_is_seed_deterministic(monkeypatch):
+    import types
+
+    from daft_tpu.io import circuit
+
+    now = 1000.0  # the breaker's clock stands still, so _probe_at - now is the seeded delay itself
+    monkeypatch.setattr(circuit, "time", types.SimpleNamespace(monotonic=lambda: now))
+
     def delays(seed):
         seed_circuit_jitter(seed)
         b = CircuitBreaker(f"seed{seed}://h", failure_threshold=1,
@@ -687,11 +694,14 @@ def test_breaker_jitter_is_seed_deterministic():
         out = []
         for _ in range(4):
             b.record_failure()
-            out.append(round(b._probe_at - time.monotonic(), 3))
+            out.append(b._probe_at - now)
             b._state = "half_open"  # re-trip without waiting
         return out
 
-    assert delays(11) == delays(11)
+    first = delays(11)
+    assert first == delays(11)  # to the bit: nothing but the seed decides them
+    assert all(0.5 * 2 ** i <= d <= 2 ** i for i, d in enumerate(first))  # base * 2^n * [0.5, 1]
+    assert delays(12) != first
 
 
 def test_breaker_registry_shared_and_reset():

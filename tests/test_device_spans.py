@@ -47,18 +47,28 @@ def _mark() -> int:
     return sp.span_id
 
 
-def _run_query(mode: str, profile: bool = False, seed: int = 1):
+def _run_query(form: str = "host", profile: bool = False, seed: int = 1):
+    """One ``embed_image`` query over ROWS JPEGs. ``form`` is how a batch reaches
+    ``_chunked_forward``: ``host``, a morsel of MORSEL rows as a host array that
+    the call chunks, pads and stages itself (the CPU backend's loop), or
+    ``staged``, one device batch a morsel that the operator's host stage has put
+    on the device already (the loop beside an accelerator: the descriptor is
+    told that it runs beside the host, as ``tests/test_udf_host_stage.py`` does)."""
+    from daft_tpu.ai.flax_provider import _FlaxDescriptor
+
     df = daft_tpu.from_pydict({"id": list(range(ROWS)), "jpg": _jpegs(ROWS)})
-    expr = embed_image(col("jpg"), provider="flax_random", model="tiny", batch_size=BATCH,
-                       seed=seed, staging_mode=mode)
-    mark = _mark()
-    with daft_tpu.execution_config_ctx(default_morsel_size=MORSEL, result_cache_enabled=False):
-        out = df.with_column("emb", expr).select("id", "emb").collect(profile=profile)
+    expr = embed_image(col("jpg"), provider="flax_random", model="tiny", batch_size=BATCH, seed=seed)
+    with pytest.MonkeyPatch.context() as patch:
+        if form == "staged":
+            patch.setattr(_FlaxDescriptor, "runs_beside_host", lambda self: True)
+        mark = _mark()
+        with daft_tpu.execution_config_ctx(default_morsel_size=MORSEL, result_cache_enabled=False):
+            out = df.with_column("emb", expr).select("id", "emb").collect(profile=profile)
     assert sum(len(p) for p in out.iter_partitions()) == ROWS  # the collected result, no second query
     return _since(mark), out
 
 
-@pytest.fixture(scope="module", params=["overlap", "separated"])
+@pytest.fixture(scope="module", params=["host", "staged"])
 def query_spans(request):
     spans, _ = _run_query(request.param)
     return request.param, spans
@@ -68,43 +78,60 @@ def _named(spans, name):
     return [s for s in spans if s.name == name]
 
 
+def _morsels(form: str) -> int:
+    return ROWS // (MORSEL if form == "host" else BATCH)  # staged: one device batch a morsel
+
+
 # -- the span tree of one query ----------------------------------------------------
 def test_span_tree_names_and_parentage(query_spans):
-    _, spans = query_spans
+    form, spans = query_spans
     by_id = {s.span_id: s for s in spans}
     calls = _named(spans, "udf.call")
-    assert len(calls) == ROWS // MORSEL  # one udf.call per morsel
+    assert len(calls) == _morsels(form)  # one udf.call per morsel
     assert all(s.parent == 0 for s in calls + _named(spans, "udf.pull"))
-    for name in ("image.preprocess", "provider.forward"):
+    # whoever prepares a morsel is the root of its preprocessing, pad and stage
+    preparer = "udf.call" if form == "host" else "udf.host_stage"
+    for name, parent in (("image.preprocess", preparer), ("provider.forward", "udf.call")):
         mine = _named(spans, name)
         assert len(mine) == len(calls)
-        assert all(by_id[s.parent].name == "udf.call" for s in mine)
-    for name in CHUNK_SPANS:
+        assert all(by_id[s.parent].name == parent for s in mine)
+    for name in ("provider.dispatch", "provider.fetch"):
         assert all(by_id[s.parent].name == "provider.forward" for s in _named(spans, name))
+    for name in ("provider.pad", "provider.stage"):
+        if form == "host":
+            assert all(by_id[s.parent].name == "provider.forward" for s in _named(spans, name))
+        else:  # the operator's transfer thread ran them, ahead of the call
+            assert all(s.parent == 0 for s in _named(spans, name))
     # a child lies within its parent, on one thread, and the ring is in closing order
     for s in spans:
         if s.parent:
             p = by_id[s.parent]
             assert p.start_ns <= s.start_ns <= s.end_ns <= p.end_ns and p.thread == s.thread
-    assert [s.end_ns for s in spans] == sorted(s.end_ns for s in spans)
+    for thread in {s.thread for s in spans}:  # one thread in the host form
+        ends = [s.end_ns for s in spans if s.thread == thread]
+        assert ends == sorted(ends)
     assert len({s.query_id for s in spans}) == 1 and spans[0].query_id  # one query, one id
 
 
-def test_one_pad_stage_dispatch_fetch_per_chunk_in_both_modes(query_spans):
-    mode, spans = query_spans
+def test_one_pad_stage_dispatch_fetch_per_chunk_whoever_staged_it(query_spans):
+    form, spans = query_spans
     forwards = _named(spans, "provider.forward")
     chunks = sum(f.count["chunks"] for f in forwards)
-    assert chunks == (ROWS // MORSEL) * -(-MORSEL // BATCH)
+    assert chunks == _morsels(form) * (-(-MORSEL // BATCH) if form == "host" else 1)
     for name in CHUNK_SPANS:
         assert len(_named(spans, name)) == chunks
-    assert {f.count["mode"] for f in forwards} == {mode}
+    assert not any("mode" in f.count for f in forwards)
     first = forwards[0]
     order = [s.name for s in spans if s.parent == first.span_id]  # in closing order
-    if mode == "separated":  # every chunk staged before the first dispatch
-        assert order[:6] == ["provider.pad", "provider.stage"] * 3
-    else:  # chunk 1 is staged while chunk 0 computes, then chunk 0 is fetched
+    if form == "host":  # chunk 1 is staged while chunk 0 computes, then chunk 0 is fetched
         assert order[:6] == ["provider.pad", "provider.stage", "provider.dispatch",
                              "provider.pad", "provider.stage", "provider.fetch"]
+        assert not any("staged" in f.count for f in forwards)
+    else:  # the chunk was on the device before the call: nothing is padded or staged below it
+        assert order == ["provider.dispatch", "provider.fetch"]
+        assert all(f.count["staged"] == 1 for f in forwards)
+        staged_by = {s.thread for s in _named(spans, "provider.pad") + _named(spans, "provider.stage")}
+        assert len(staged_by) == 1 and staged_by.isdisjoint(f.thread for f in forwards)
 
 
 def test_pull_spans_count_the_rows_handed_on(query_spans):
@@ -118,12 +145,13 @@ def test_pull_spans_count_the_rows_handed_on(query_spans):
 def test_rows_sum_to_the_rows_delivered_and_padding_is_counted(query_spans):
     import jax
 
-    _, spans = query_spans
+    form, spans = query_spans
     for name in ("udf.call", "image.preprocess", "provider.forward", "provider.pad"):
         assert sum(s.count["rows"] for s in _named(spans, name)) == ROWS, name
     pads = _named(spans, "provider.pad")
-    # 10 rows in chunks of 4: 4, 4 and a ragged 2, each padded to the bucket (8, or the mesh's multiple)
-    assert sorted(p.count["rows"] for p in pads) == [2, 2, 4, 4, 4, 4]
+    # host: 10 rows in chunks of 4: 4, 4 and a ragged 2; staged: a morsel is one chunk of 4;
+    # each padded to the bucket (8, or the mesh's multiple)
+    assert sorted(p.count["rows"] for p in pads) == ([2, 2, 4, 4, 4, 4] if form == "host" else [4] * 5)
     assert all(p.count["padded_rows"] >= 8 and p.count["padded_rows"] % 8 == 0 for p in pads)
     for f in _named(spans, "provider.forward"):
         assert f.count["n_devices"] in (1, len(jax.devices()))
@@ -267,7 +295,7 @@ def test_span_clock_offset_places_the_ring_on_the_wall_clock():
 
 # -- under a profiled query ---------------------------------------------------------
 def test_under_collect_profile_the_spans_hang_below_the_udf_operator():
-    spans, out = _run_query("overlap", profile=True, seed=3)
+    spans, out = _run_query(profile=True, seed=3)
     trace = out.query_profile.to_chrome_trace()
     events = [e for e in trace["traceEvents"] if e["ph"] == "X"]
     by_name = {}
@@ -294,7 +322,7 @@ def test_under_collect_profile_the_spans_hang_below_the_udf_operator():
 
 
 def test_unprofiled_query_emits_nothing_but_fills_the_ring():
-    spans, out = _run_query("overlap", profile=False, seed=4)
+    spans, out = _run_query(profile=False, seed=4)
     assert out.query_profile is None and _named(spans, "provider.dispatch")
 
 
