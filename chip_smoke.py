@@ -299,8 +299,13 @@ def phase_c_hybrid(cfg) -> dict:
     prefilled = sum(s.count["tokens"] for s in spans if s.name == "serve.prefill")
     assert prefilled == sum(lengths), f"prefilled {prefilled} tokens"
     assert max(s.count["chunks"] for s in spans if s.name == "serve.prefill") == 2, "no prompt ran as two chunks"
+    # the path the grouped products of each program took when it traced: the tiny decoder's widths fill no
+    # lane tile, so XLA's on any backend (the kernel itself is run by tests/test_pallas_grouped_matmul.py)
+    moe = {s.name: s.count.get("moe") for s in spans if s.name in ("serve.prefill", "serve.decode_step")}
+    assert set(moe.values()) <= {"grouped", "xla"} and len(moe) == 2, moe
     _release(expr)
-    return {"run_s": run_s, "rows": n, "decode_steps": len(steps), "prefill_tokens": prefilled}
+    return {"run_s": run_s, "rows": n, "decode_steps": len(steps), "prefill_tokens": prefilled,
+            "moe": moe}
 
 
 def phase_d(cfg) -> dict:

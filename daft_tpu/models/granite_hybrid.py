@@ -17,8 +17,10 @@ parameters are drawn tensor by tensor on the device and kept in bfloat16, and
 experts it holds (a contiguous ``num_local_experts / size`` of them). It routes
 over all of them with the published top-k, computes the held experts' part
 ``sum_{e in I and held} g_e y_e`` with the gates as the full softmax gave them,
-and leaves the absent experts' part out; assignments to absent experts sort
-behind the held groups of one grouped product and cost no FLOPs.
+and leaves the absent experts' part out; assignments to absent experts and to
+padded tokens sort behind the held groups of the two grouped products, which on
+a TPU are one kernel that visits the held rows alone
+(``ops/pallas_grouped_matmul.py``; ``_grouped_mlp`` has the rule).
 ``vocab_shard`` slices the tied embedding by rows: ids and logits are over the
 slice. Nothing stands in for the other chips.
 
@@ -40,6 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from daft_tpu.errors import DaftValueError
+from daft_tpu.ops import pallas_grouped_matmul as gmm
+from daft_tpu.profiling import open_device_span
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as
 #: data: no substring rule.
@@ -403,6 +407,28 @@ def _gated_mlp(x, w_in, w_out, dtype):
     return _mm((jax.nn.silu(a) * b).astype(dtype), w_out)
 
 
+def _grouped_mlp(x, w_in, w_out, sizes, dtype):
+    """The held experts' gated MLPs over x (rows, d) sorted by expert: group e's
+    rows times ``w_in[e]``, ``silu(a) * b`` in float32, one cast to ``dtype``,
+    times ``w_out[e]``. On a TPU at widths that fill lane tiles both products are
+    ``ops/pallas_grouped_matmul`` (it visits only the rows ``sizes`` covers, and
+    its first product applies the gate before it writes); otherwise XLA's
+    ``ragged_dot`` over every row. Rows behind the groups come back undefined.
+    The choice is made here, when the program traces, and noted on the batcher's
+    open span as ``moe``."""
+    f = w_out.shape[1]
+    grouped = (gmm.grouped_matmul_applies(x.shape, w_in.shape, x.dtype, gated=True)
+               and gmm.grouped_matmul_applies((x.shape[0], f), w_out.shape, dtype))
+    for name in ("serve.prefill", "serve.decode_step"):
+        span = open_device_span(name)
+        if span is not None:
+            span.count["moe"] = "grouped" if grouped else "xla"
+    if grouped:  # a kernel that fails to trace or lower fails the program
+        return gmm.grouped_matmul(gmm.grouped_matmul(x, w_in, sizes, gated=True).astype(dtype), w_out, sizes)
+    a, b = jnp.split(jax.lax.ragged_dot(x, w_in, sizes, preferred_element_type=jnp.float32), 2, axis=-1)
+    return jax.lax.ragged_dot((jax.nn.silu(a) * b).astype(dtype), w_out, sizes, preferred_element_type=dtype)
+
+
 def _moe(cfg, p, v, valid):
     """v (n, d) normed; valid (n,). -> (held experts' part + shared expert
     (n, d) float32, counts {assignments, held_assignments, max_expert_load})."""
@@ -420,9 +446,7 @@ def _moe(cfg, p, v, valid):
         token = order // k
         sizes = jnp.bincount(group, length=held_n + 1)[:held_n].astype(jnp.int32)
         x = v[token]                                                # (n k, d), sorted by held expert
-        a, b = jnp.split(jax.lax.ragged_dot(x, p["w_in"], sizes, preferred_element_type=jnp.float32), 2, axis=-1)
-        y = jax.lax.ragged_dot((jax.nn.silu(a) * b).astype(cfg.dtype), p["w_out"], sizes,
-                               preferred_element_type=cfg.dtype)
+        y = _grouped_mlp(x, p["w_in"], p["w_out"], sizes, cfg.dtype)
         # Back to the tokens: one gather of n rows for each of the k choices, gated and summed as it goes
         # (a scatter-add of rows is serial on the chip, and an (n, k, d) block pads k to a tile: 8.6 and 7.7 ms
         # a 2,048-token layer against 5.9 this way; my chip run, PR 29). Rows behind the held groups hold

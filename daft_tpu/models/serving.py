@@ -35,6 +35,9 @@ Spans (``profiling.device_span``): ``serve.prefill`` (one group's chunks:
 ``serve.copy_state``,
 ``serve.decode_step`` (``active``, ``slots``, and the model's counts of the
 step, e.g. ``moe.held_assignments``), ``serve.fetch`` (the step's one fetch).
+``serve.prefill`` and ``serve.decode_step`` also carry what the model noted on
+them while its program traced (``moe`` = ``grouped`` | ``xla``: the path of the
+hybrid decoder's grouped products).
 """
 
 from __future__ import annotations
@@ -109,6 +112,7 @@ class ContinuousBatcher:
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._copy = jax.jit(self._copy_impl, donate_argnums=(0, 1))
         self.decode_steps = 0
+        self._noted: Dict[str, dict] = {}  # span name -> what the model noted while that program traced
 
     # -- jitted programs ------------------------------------------------- #
     def _prefill_impl(self, params, state, cur_logits, tokens, slots, starts, lengths, final):
@@ -132,6 +136,14 @@ class ContinuousBatcher:
         logprob = jnp.take_along_axis(jax.nn.log_softmax(cur_logits, axis=-1), tok[:, None], axis=-1)[:, 0]
         state, logits, counts = self.model.decode(params, state, tok, positions, active)
         return state, logits, {"tok": tok, "logprob": logprob, "counts": counts}
+
+    def _repeat_noted(self, sp) -> None:
+        """A model notes its choice of path on the open span while its program
+        traces (``granite_hybrid._grouped_mlp``: ``moe``); a call that traces
+        nothing repeats what the trace chose."""
+        noted = self._noted.setdefault(sp.name, {})
+        noted.update({k: v for k, v in sp.count.items() if isinstance(v, str)})
+        sp.count.update(noted)
 
     def _prefill_fn(self):
         if self._prefill is None:
@@ -190,6 +202,7 @@ class ContinuousBatcher:
                 self.state, self.cur_logits = fn(
                     self.params, self.state, self.cur_logits, tokens, slots,
                     np.full((g,), c * T, np.int32), here, final)
+            self._repeat_noted(sp)
         for req, slot in group:
             self._admit_host(req, slot)
 
@@ -239,6 +252,7 @@ class ContinuousBatcher:
                 with device_span("serve.fetch"):
                     out = jax.device_get(out)  # the step's one wait for the device
                 sp.count.update({f"moe.{k}": int(v) for k, v in out["counts"].items()})
+                self._repeat_noted(sp)
             steps += 1
             self.positions += self.active
             for slot in np.flatnonzero(self.active):

@@ -247,6 +247,26 @@ def test_identical_prompts_share_one_prefill_through_copy_state():
     assert alone.run([Request(tokens=base.copy(), max_new_tokens=6)])[0] == out[3]
 
 
+def test_serving_spans_say_which_grouped_product_each_program_traced():
+    """``_grouped_mlp`` notes its path on the batcher's open span while the program
+    traces; the batcher repeats it on the spans of calls that trace nothing. At the
+    tiny widths, and on the CPU at any, that is XLA's ``ragged_dot``."""
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    model, params = program(0)
+    began = span_clock_ns()
+    b = ContinuousBatcher(model, params, num_slots=2, max_seq_len=64, eos_id=None, prefill_chunk=8)
+    b.run([Request(tokens=t, max_new_tokens=3) for t in prompts(5, [5, 19, 7])])
+    spans = [s for s in recent_device_spans() if s.start_ns >= began]
+    prefills = [s for s in spans if s.name == "serve.prefill"]
+    steps = [s for s in spans if s.name == "serve.decode_step"]
+    assert len(prefills) >= 2 and len(steps) >= 3 and prefills[0].count["first"] == 1
+    assert [s.count["moe"] for s in prefills + steps] == ["xla"] * (len(prefills) + len(steps))
+    assert b._noted == {"serve.prefill": {"moe": "xla"}, "serve.decode_step": {"moe": "xla"}}
+    assert all(s.count["moe.assignments"] >= s.count["moe.held_assignments"] for s in steps)  # the counts stay
+
+
 # (f) prompt(..., logprobs=True) through a dataframe
 def test_prompt_with_logprobs_through_a_dataframe(ref):
     import daft_tpu

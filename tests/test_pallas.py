@@ -2,8 +2,11 @@
 interpret mode on the CPU against ``jax.nn.dot_product_attention`` on the same
 ``[B, T, 3d]`` input, the rule that selects it, what it leaves in the traced
 forward, and its compile for a described v5e chip at the widths the benchmark
-runs. One file, so that the tests that describe a TPU topology stay with their
-fixture (see the on-chip-measurement guide)."""
+runs; and the same compile of the grouped-matmul kernel
+(``ops/pallas_grouped_matmul.py``, whose other tests are in
+``tests/test_pallas_grouped_matmul.py``). One file, so that the tests that
+describe a TPU topology stay with their fixture (see the on-chip-measurement
+guide)."""
 
 import collections
 import dataclasses
@@ -291,3 +294,24 @@ def test_kernel_compiles_for_v5e_with_no_copy_at_its_edges(one_chip, B, T, d, nu
     # the one copy is of the entry argument x, whose device layout is tokens-outermost
     assert ops.count("copy") <= 1 and "transpose" not in ops
     assert f"[{B},{num_heads},{T},{T}]" not in text
+
+
+@pytest.mark.parametrize("m,k,n,gated", [(20480, 4096, 1536, True), (20480, 768, 4096, False),
+                                         (320, 4096, 1536, True), (320, 768, 4096, False)],
+                         ids=["prefill_w_in", "prefill_w_out", "decode_w_in", "decode_w_out"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, gated):
+    """The routed experts' two products of granite-4.0-h-small's prefill call
+    (2,048 tokens x top 10) and decode step (32 slots x 10) over the 36 held
+    experts, compiled by the TPU's compiler (nothing runs): one custom call each,
+    and the first product's result is ``(m, 768)`` bfloat16, with no float32
+    ``(m, 1536)`` beside it."""
+    from daft_tpu.ops import pallas_grouped_matmul as gmm
+
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+              for s, d in (((m, k), jnp.bfloat16), ((36, k, n), jnp.bfloat16), ((36,), jnp.int32))]
+    compiled = jax.jit(lambda x, w, sizes: gmm.grouped_matmul(x, w, sizes, gated=gated)).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "ragged-dot" not in text
+    assert f"bf16[{m},{n // 2 if gated else n}]" in text and f"f32[{m}," not in text
+    tm, tn = gmm._tiles(m, k, n // 2 if gated else n, 36, 2, gated)
+    assert tn > 0 and gmm._step_bytes(tm, k, tn, 2, gated) <= gmm.VMEM_BUDGET
