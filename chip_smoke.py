@@ -31,7 +31,6 @@ run unless ``JAX_PLATFORMS=cpu``; its last line is the record with
 from __future__ import annotations
 
 import argparse
-import gc
 import json
 import os
 import sys
@@ -69,8 +68,7 @@ def _engine_instance(expr):
 def _release(expr) -> None:
     """Drop the engine's provider instances, and with them their device
     memory: a cached plan may keep the UDF itself alive."""
-    expr._expr.udf._instances.clear()
-    gc.collect()
+    assert expr._expr.udf.release(), "a call of the UDF was still in flight"
 
 
 def _check_embeddings(emb: np.ndarray, rows: int, dim: int) -> None:
@@ -308,6 +306,49 @@ def phase_c_hybrid(cfg) -> dict:
             "moe": moe}
 
 
+def phase_c_longcat(cfg) -> dict:
+    """prompt on the tiny LongCat-Flash decoder (latent attention with a rotary
+    part, double layers, identity experts, a sharded expert layer): chunked
+    prefill over the latent cache (expanded), the decode loop (absorbed), and
+    what the spans say of both."""
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.functions.ai import prompt
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    began = span_clock_ns()
+    n = cfg["prompts"]
+    lengths = [6 + 5 * i for i in range(n - 1)] + [1100]  # the last one takes three of the batcher's 512-token chunks
+    docs = [" ".join(f"w{(3 * i + j) % 40}" for j in range(m)) for i, m in enumerate(lengths)]
+    expr = prompt(col("p"), provider="flax_random", model="longcat-flash-tiny",
+                  max_new_tokens=6, ignore_eos=True, logprobs=True, num_slots=4, max_prompt_tokens=1200,
+                  num_layers=2, expert_shard=[0, 2], vocab_shard=[0, 2])
+
+    def run():
+        return daft_tpu.from_pydict({"p": docs}).with_column("a", expr) \
+            .select("a").collect().to_pydict()["a"]
+
+    answers, run_s = _timed(run)
+    assert len(answers) == n and all(
+        len(a["token_ids"]) == len(a["logprobs"]) == 6 and np.isfinite(a["logprobs"]).all()
+        and all(0 <= t < 128 for t in a["token_ids"]) for a in answers), answers
+    spans = [s for s in recent_device_spans()
+             if s.start_ns >= began and s.name.startswith(("serve.", "prompt."))]
+    steps = [s for s in spans if s.name == "serve.decode_step"]
+    assert steps and all("moe.zero_assignments" in s.count and s.count["moe.assignments"] == s.count["active"] * 3 * 2
+                         for s in steps), "decode-step spans lack their counters"
+    prefills = [s for s in spans if s.name == "serve.prefill"]
+    assert sum(s.count["tokens"] for s in prefills) == sum(lengths) and max(s.count["chunks"] for s in prefills) == 3
+    paths = {s.name: (s.count.get("mla"), s.count.get("moe")) for s in prefills + steps}
+    assert paths["serve.prefill"][0] == "expanded" and paths["serve.decode_step"][0] == "absorbed", paths
+    held = [s.count for s in spans if s.name == "prompt.run"][-1]
+    assert held["state_bytes"] == held["slots"] * held["positions"] * 4 * 16 * 2, held  # four latent caches of 16 values
+    _release(expr)
+    return {"run_s": run_s, "rows": n, "decode_steps": len(steps), "paths": paths,
+            "cache_bytes_per_token": held["state_bytes"] // (held["slots"] * held["positions"])}
+
+
 def phase_d(cfg) -> dict:
     """A q06-shaped float32 chain (filter -> project -> sum) on the device,
     against the same query on the host. The counters are the only way to see
@@ -468,6 +509,8 @@ def main(argv=None) -> int:
             done(current, phase_c(cfg))
             current = "C_prompt_hybrid"
             done(current, phase_c_hybrid(cfg))
+            current = "C_prompt_longcat"
+            done(current, phase_c_longcat(cfg))
             current = "D_device_chain"
             done(current, phase_d(cfg))
             current = "E_pallas"

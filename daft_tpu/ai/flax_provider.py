@@ -42,7 +42,8 @@ from daft_tpu.ai.protocols import (
 from daft_tpu.ai.provider import Provider
 from daft_tpu.device import setup_compile_cache
 from daft_tpu.errors import DaftValueError
-from daft_tpu.models.granite_hybrid import CUT_OPTIONS
+from daft_tpu.models import decoders
+from daft_tpu.models import granite_hybrid, longcat_flash  # noqa: F401  (each enters its names in decoders.DECODERS)
 from daft_tpu.profiling import device_span
 from daft_tpu.utils.tokenizer import HashingTokenizer
 
@@ -468,16 +469,25 @@ class FlaxCLIPClassifier(_FlaxModelBase):
         return [labels[i] for i in idx]
 
 
+#: Options of ``prompt`` that cut a published decoder to one chip's share: every decoder's on record.
+CUT_OPTIONS = decoders.cut_options()
+#: Options of ``prompt`` that reach ``FlaxPrompter``; any other is the engine's or is dropped.
+PROMPTER_OPTIONS = ("weights_path", "seed", "max_new_tokens", "temperature", "num_slots", "max_prompt_tokens",
+                    "ignore_eos", "logprobs") + CUT_OPTIONS
+
+
 class FlaxPrompter(_FlaxModelBase):
     """``prompt`` / ``llm_generate`` over a decoder and the continuous batcher.
 
-    ``model_name`` is looked up exactly among the hybrid decoders on record
-    (``models/granite_hybrid.SIZES``), which take the cut's options
-    (``num_hidden_layers``, ``expert_shard``, ``vocab_shard``: one chip's share
-    of a stated deployment); any other name is ``DecoderLMConfig.from_name``'s,
-    and the cut's options with such a name are an error. ``num_slots`` decode
-    slots, prompts cut to ``max_prompt_tokens`` hashed tokens, ``ignore_eos``
-    for a fixed answer length, ``logprobs`` for answers a comparison can hold
+    ``model_name`` is looked up exactly in the record of published decoders
+    (``models/decoders.DECODERS``, which ``models/granite_hybrid`` and
+    ``models/longcat_flash`` enter); such a decoder takes its own cut's options
+    (e.g. ``num_hidden_layers`` or ``num_layers``, ``expert_shard``,
+    ``vocab_shard``: one chip's share of a stated deployment). Any other name is
+    ``DecoderLMConfig.from_name``'s, and a cut's options with such a name are an
+    error that lists the names on record. ``num_slots`` decode slots, prompts cut
+    to ``max_prompt_tokens`` hashed tokens, ``ignore_eos`` for a fixed answer
+    length, ``logprobs`` for answers a comparison can hold
     against logits."""
 
     def __init__(self, model_name: str, weights_path: Optional[str] = None,
@@ -485,20 +495,22 @@ class FlaxPrompter(_FlaxModelBase):
                  num_slots: int = 8, max_prompt_tokens: Optional[int] = None,
                  ignore_eos: bool = False, logprobs: bool = False, **cut):
         super().__init__()
-        from daft_tpu.models import granite_hybrid
-
         unknown = set(cut) - set(CUT_OPTIONS)
         if unknown:
             raise DaftValueError(f"prompt does not know the options {sorted(unknown)}")
-        if model_name in granite_hybrid.SIZES:
-            self.cfg = granite_hybrid.GraniteHybridConfig.from_name(model_name, **cut)
-            self.model, params = _initialised(granite_hybrid.init_granite_params, self.cfg, seed)
+        decoder = decoders.DECODERS.get(model_name)
+        if decoder is not None:
+            foreign = set(cut) - set(decoder.cut_options)
+            if foreign:
+                raise DaftValueError(f"{model_name!r} is cut by {list(decoder.cut_options)}, not by {sorted(foreign)}")
+            self.cfg = decoder.from_name(model_name, **cut)
+            self.model, params = _initialised(decoder.init, self.cfg, seed)
             self.prompt_len = int(max_prompt_tokens or 128)
             self.max_seq_len = self.prompt_len + max_new_tokens + 1
         elif cut:
             raise DaftValueError(
                 f"{sorted(cut)} cut a published model to one chip's share, and {model_name!r} is none of "
-                f"{sorted(granite_hybrid.SIZES)}")
+                f"{sorted(decoders.DECODERS)}")
         else:
             from daft_tpu.models.lm import DecoderLMConfig, init_lm_params
 
@@ -537,9 +549,11 @@ class FlaxPrompter(_FlaxModelBase):
                 self._batcher = ContinuousBatcher(
                     self.model, self.params, num_slots=self.num_slots, max_seq_len=self.max_seq_len,
                     temperature=self.temperature, eos_id=self.eos_id, max_prompt_tokens=self.prompt_len)
-            with device_span("prompt.run", rows=len(reqs)) as sp:
-                out = self._batcher.run(reqs)
-                sp.count["decode_steps"] = self._batcher.decode_steps
+            b = self._batcher
+            with device_span("prompt.run", rows=len(reqs), slots=b.B, positions=b.positions_held,
+                             state_bytes=_tree_bytes(b.state)) as sp:
+                out = b.run(reqs)
+                sp.count["decode_steps"] = b.decode_steps
             logprobs = self._batcher.last_logprobs
         if not self.logprobs:
             return [" ".join(str(t) for t in row if t != 0) for row in out]
@@ -551,11 +565,6 @@ class FlaxPrompter(_FlaxModelBase):
 # ---------------------------------------------------------------------- #
 # Descriptors                                                             #
 # ---------------------------------------------------------------------- #
-#: Options of ``prompt`` that reach ``FlaxPrompter``; any other is the engine's or is dropped.
-PROMPTER_OPTIONS = ("weights_path", "seed", "max_new_tokens", "temperature", "num_slots", "max_prompt_tokens",
-                    "ignore_eos", "logprobs") + CUT_OPTIONS
-
-
 class _FlaxDescriptor(Descriptor):
     def __init__(self, kind: str, model: str, options: Dict[str, Any]):
         self.kind = kind

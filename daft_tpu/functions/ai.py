@@ -9,6 +9,8 @@ TPU chips and the models are jitted Flax forwards (daft_tpu/ai/flax_provider).
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import threading
 import time
 from typing import Any, List, Optional, Sequence, Union
@@ -17,7 +19,7 @@ import numpy as np
 
 from daft_tpu.ai.provider import load_provider
 from daft_tpu.datatype import DataType, TypeId
-from daft_tpu.errors import DaftTypeError
+from daft_tpu.errors import DaftExecutionError, DaftTypeError
 from daft_tpu.expressions.expression import Expression
 from daft_tpu.profiling import device_span
 from daft_tpu.series import Series
@@ -34,6 +36,12 @@ class _ProtocolUdf(Udf):
     ``host`` and ``transfer`` (both ``(inst, x)``) are the UDF's host stage
     (``Udf.host_stage``): ``call`` then also takes what ``transfer`` returned
     and starts from it.
+
+    ``release()`` gives the instances' HBM back. A stream that its consumer
+    abandoned (a ``limit``, a closed ``iter_partitions()``) leaves its last
+    morsel running on the next stage's feeder thread (``pipeline.run_stage``
+    does not wait for it), and that call holds the instance until it returns:
+    ``release`` waits for the calls in flight, then drops the instances.
     """
 
     def __init__(self, descriptor, call, return_dtype: DataType, name: str,
@@ -44,17 +52,19 @@ class _ProtocolUdf(Udf):
         self._transfer = transfer
         self._instances = {}
         self._instance_lock = threading.Lock()
+        self._idle = threading.Condition()  # guards the two below
+        self._calls = 0          # calls of ``fn`` in flight
+        self._released = False
         udf_opts = descriptor.get_udf_options()
 
         def fn(*series, prepared=None):
             # Device-batch chunking lives inside the protocol impls (they
             # chunk to their device batch and async-dispatch all chunks so
             # transfers overlap compute); here we just hand over the morsel,
-            # or what the host stage made of it.
-            inst = self._get_instance()
-            if prepared is None:
-                return self._call(inst, *series)
-            return self._call(inst, *series, prepared)
+            # or what the host stage made of it. The instance is a local of
+            # ``_run`` alone: gone before the call counts as ended.
+            with self._in_flight():
+                return self._run(series, prepared)
 
         fn.__name__ = name
         super().__init__(
@@ -75,6 +85,40 @@ class _ProtocolUdf(Udf):
     def transfer(self, batch):
         return self._transfer(self._get_instance(), batch)
 
+    def _run(self, series, prepared):
+        inst = self._get_instance()
+        if prepared is None:
+            return self._call(inst, *series)
+        return self._call(inst, *series, prepared)
+
+    @contextlib.contextmanager
+    def _in_flight(self):
+        with self._idle:
+            self._calls += 1
+        try:
+            yield
+        finally:
+            with self._idle:
+                self._calls -= 1
+                self._idle.notify_all()
+
+    def release(self, wait_s: Optional[float] = None) -> bool:
+        """Drop the instances this process holds (parameters and serving state
+        in HBM) once the calls in flight have ended, waiting up to ``wait_s``
+        for them (None: until they end). From then on the UDF makes no
+        instance: a call that comes later (the abandoned stream's feeder may
+        start one more) is an error, not a second set of parameters. -> whether
+        the instances were dropped."""
+        with self._idle:
+            self._released = True
+            if not self._idle.wait_for(lambda: self._calls == 0, wait_s):
+                return False
+            self._instances.clear()
+        # An instance is not freed by its last reference alone: a batcher's
+        # jitted programs are bound methods that refer back to it.
+        gc.collect()
+        return True
+
     def _get_instance(self):
         # One model instance PER REPLICA SLOT: with chips_per_replica the
         # executor runs each morsel inside a replica_scope, and the instance
@@ -84,6 +128,8 @@ class _ProtocolUdf(Udf):
         rid = replica_id()
         inst = self._instances.get(rid)
         if inst is None:
+            if self._released:
+                raise DaftExecutionError(f"the UDF {self.name!r} was released: it makes no further instance")
             with self._instance_lock:
                 inst = self._instances.get(rid)
                 if inst is None:
@@ -95,7 +141,10 @@ class _ProtocolUdf(Udf):
         # each worker process re-instantiates (params must live in ITS HBM).
         state = self.__dict__.copy()
         state["_instances"] = {}
+        state["_calls"] = 0
+        state["_released"] = False
         state.pop("_instance_lock", None)
+        state.pop("_idle", None)
         return state
 
     def __setstate__(self, state):
@@ -104,6 +153,7 @@ class _ProtocolUdf(Udf):
         self.__dict__.update(state)
         self._instances = {}
         self._instance_lock = threading.Lock()
+        self._idle = threading.Condition()
 
 
 class _RowClock:

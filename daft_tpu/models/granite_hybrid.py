@@ -20,7 +20,9 @@ over all of them with the published top-k, computes the held experts' part
 and leaves the absent experts' part out; assignments to absent experts and to
 padded tokens sort behind the held groups of the two grouped products, which on
 a TPU are one kernel that visits the held rows alone
-(``ops/pallas_grouped_matmul.py``; ``_grouped_mlp`` has the rule).
+(``ops/pallas_grouped_matmul.py``; ``models/decoders.grouped_mlp`` has the rule, and
+the dispatch after the router is ``decoders.held_experts_part``, shared with
+``models/longcat_flash.py``).
 ``vocab_shard`` slices the tied embedding by rows: ids and logits are over the
 slice. Nothing stands in for the other chips.
 
@@ -42,8 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from daft_tpu.errors import DaftValueError
-from daft_tpu.ops import pallas_grouped_matmul as gmm
-from daft_tpu.profiling import open_device_span
+from daft_tpu.models import decoders
+from daft_tpu.models.decoders import draw, gated_mlp as _gated_mlp, mm as _mm, rms as _rms
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as
 #: data: no substring rule.
@@ -125,10 +127,8 @@ class GraniteHybridConfig:
         cfg = replace(cfg, num_hidden_layers=layers, layer_types=cfg.layer_types[:layers],
                       expert_shard=tuple(int(x) for x in expert_shard),
                       vocab_shard=tuple(int(x) for x in vocab_shard))
-        for what, (rank, size), whole in (("expert_shard", cfg.expert_shard, cfg.num_local_experts),
-                                          ("vocab_shard", cfg.vocab_shard, cfg.vocab_size // EMBED_BLOCK_ROWS)):
-            if not 0 <= rank < size or whole % size:
-                raise DaftValueError(f"{what}={[rank, size]} does not divide {whole} evenly")
+        decoders.check_shards((("expert_shard", cfg.expert_shard, cfg.num_local_experts),
+                               ("vocab_shard", cfg.vocab_shard, cfg.vocab_size // EMBED_BLOCK_ROWS)))
         if not 0 < layers <= len(SIZES[name]["layer_types"]):
             raise DaftValueError(f"num_hidden_layers={layers} is outside the published {name!r}")
         return cfg
@@ -159,17 +159,6 @@ class GraniteHybridConfig:
         return self.vocab_size // self.vocab_shard[1]
 
 
-def _rms(x, w, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _mm(x, w):
-    """bfloat16 operands, float32 accumulation."""
-    return jnp.einsum("...k,kn->...n", x, w, preferred_element_type=jnp.float32)
-
-
 # ---------------------------------------------------------------------- #
 # Parameters: drawn tensor by tensor on the device, bfloat16              #
 # ---------------------------------------------------------------------- #
@@ -193,29 +182,6 @@ def tensor_specs(cfg: GraniteHybridConfig, kind: str) -> List[Tuple[str, tuple, 
                     ("shared_in", (d, 2 * fs), "matrix"), ("shared_out", (fs, d), "matrix")]
 
 
-def _as_drawn(x):
-    """A draw as the generator gave it: inside a jitted program XLA would fold
-    the scale that follows into the generator's own last product, and round
-    otherwise than the same two steps taken one by one."""
-    return jax.lax.optimization_barrier(x)
-
-
-def draw(key, shape, rule: str):
-    """One tensor in float32, by rule. ``matrix``: normal, std fan_in ** -0.5
-    (each product keeps its input's scale); ``norm``: 1 + 0.1 normal; ``bias``:
-    0.1 normal; ``A_log``: log U(1, 16); ``dt_bias``: the inverse softplus of a
-    delta log-uniform in [1e-3, 1e-1] (as Mamba-2 initialises both)."""
-    if rule in ("matrix", "norm", "bias"):
-        n = _as_drawn(jax.random.normal(key, shape, jnp.float32))
-        return n * (shape[0] ** -0.5) if rule == "matrix" else 0.1 * n + (rule == "norm")
-    if rule == "A_log":
-        return jnp.log(_as_drawn(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)))
-    if rule == "dt_bias":
-        dt = jnp.exp(_as_drawn(jax.random.uniform(key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1))))
-        return dt + jnp.log(-jnp.expm1(-dt))
-    raise ValueError(rule)
-
-
 @functools.partial(jax.jit, static_argnums=(0, 2))
 def _init_layer(cfg: GraniteHybridConfig, key, kind: str):
     held = cfg.first_expert + jnp.arange(cfg.held_experts)
@@ -233,10 +199,8 @@ def _init_layer(cfg: GraniteHybridConfig, key, kind: str):
 @functools.partial(jax.jit, static_argnums=(0,))
 def _init_embedding(cfg: GraniteHybridConfig, key):
     blocks = cfg.held_vocab // EMBED_BLOCK_ROWS
-    ids = cfg.vocab_shard[0] * blocks + jnp.arange(blocks)
-    rows = _as_drawn(jax.vmap(lambda b: jax.random.normal(
-        jax.random.fold_in(key, b), (EMBED_BLOCK_ROWS, cfg.hidden_size), jnp.float32))(ids))
-    return (rows.reshape(cfg.held_vocab, cfg.hidden_size) * EMBED_STD).astype(cfg.dtype)
+    rows = decoders.draw_row_blocks(key, cfg.vocab_shard[0] * blocks, blocks, EMBED_BLOCK_ROWS, cfg.hidden_size)
+    return (rows * EMBED_STD).astype(cfg.dtype)
 
 
 def init_granite_params(cfg: GraniteHybridConfig, seed: int = 0):
@@ -402,62 +366,17 @@ def _attention(cfg, p, u, rows_k, rows_v, positions, valid):
 # ---------------------------------------------------------------------- #
 # Experts                                                                 #
 # ---------------------------------------------------------------------- #
-def _gated_mlp(x, w_in, w_out, dtype):
-    a, b = jnp.split(_mm(x, w_in), 2, axis=-1)
-    return _mm((jax.nn.silu(a) * b).astype(dtype), w_out)
-
-
-def _grouped_mlp(x, w_in, w_out, sizes, dtype):
-    """The held experts' gated MLPs over x (rows, d) sorted by expert: group e's
-    rows times ``w_in[e]``, ``silu(a) * b`` in float32, one cast to ``dtype``,
-    times ``w_out[e]``. On a TPU at widths that fill lane tiles both products are
-    ``ops/pallas_grouped_matmul`` (it visits only the rows ``sizes`` covers, and
-    its first product applies the gate before it writes); otherwise XLA's
-    ``ragged_dot`` over every row. Rows behind the groups come back undefined.
-    The choice is made here, when the program traces, and noted on the batcher's
-    open span as ``moe``."""
-    f = w_out.shape[1]
-    grouped = (gmm.grouped_matmul_applies(x.shape, w_in.shape, x.dtype, gated=True)
-               and gmm.grouped_matmul_applies((x.shape[0], f), w_out.shape, dtype))
-    for name in ("serve.prefill", "serve.decode_step"):
-        span = open_device_span(name)
-        if span is not None:
-            span.count["moe"] = "grouped" if grouped else "xla"
-    if grouped:  # a kernel that fails to trace or lower fails the program
-        return gmm.grouped_matmul(gmm.grouped_matmul(x, w_in, sizes, gated=True).astype(dtype), w_out, sizes)
-    a, b = jnp.split(jax.lax.ragged_dot(x, w_in, sizes, preferred_element_type=jnp.float32), 2, axis=-1)
-    return jax.lax.ragged_dot((jax.nn.silu(a) * b).astype(dtype), w_out, sizes, preferred_element_type=dtype)
-
-
 def _moe(cfg, p, v, valid):
     """v (n, d) normed; valid (n,). -> (held experts' part + shared expert
     (n, d) float32, counts {assignments, held_assignments, max_expert_load})."""
-    n, d = v.shape
-    k, held_n = cfg.num_experts_per_tok, cfg.held_experts
+    k = cfg.num_experts_per_tok
     with jax.named_scope("router"):
         r = _mm(v, p["router"])                                     # (n, E) float32
         top, idx = jax.lax.top_k(r, k)
         gates = jax.nn.softmax(top, axis=-1)                        # over the chosen k only
     with jax.named_scope("experts"):
-        local = idx - cfg.first_expert
-        held = (local >= 0) & (local < held_n) & valid[:, None]
-        group = jnp.where(held, local, held_n).reshape(-1)          # absent: behind every held group
-        order = jnp.argsort(group, stable=True)
-        token = order // k
-        sizes = jnp.bincount(group, length=held_n + 1)[:held_n].astype(jnp.int32)
-        x = v[token]                                                # (n k, d), sorted by held expert
-        y = _grouped_mlp(x, p["w_in"], p["w_out"], sizes, cfg.dtype)
-        # Back to the tokens: one gather of n rows for each of the k choices, gated and summed as it goes
-        # (a scatter-add of rows is serial on the chip, and an (n, k, d) block pads k to a tile: 8.6 and 7.7 ms
-        # a 2,048-token layer against 5.9 this way; my chip run, PR 29). Rows behind the held groups hold
-        # whatever the product left there and are dropped by their gate of 0.
-        back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k)).reshape(n, k)
-        g = jnp.where(held, gates, 0.0)
-        routed = jnp.zeros((n, d), jnp.float32)
-        for j in range(k):
-            gj = g[:, j, None]
-            routed = routed + jnp.where(gj > 0, y[back[:, j]].astype(jnp.float32) * gj, 0.0)
-        y = routed
+        y, held, sizes = decoders.held_experts_part(v, idx, gates, valid, cfg.first_expert,
+                                                    p["w_in"], p["w_out"], cfg.dtype)
     with jax.named_scope("shared_mlp"):
         y = y + _gated_mlp(v, p["shared_in"], p["shared_out"], cfg.dtype)
     counts = {"assignments": jnp.sum(valid) * k, "held_assignments": jnp.sum(held),
@@ -498,7 +417,7 @@ class GraniteHybridLM:
         return state
 
     def copy_state(self, state, src, dst):
-        return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
+        return decoders.copy_slot(state, src, dst)
 
     def _forward(self, params, rows, tokens, positions, valid, lengths, fresh, single_step):
         """The layers over ``rows`` (each layer's state of the rows in play).
@@ -560,3 +479,7 @@ class GraniteHybridLM:
         h, state, counts = self._forward(params, state, tokens[:, None], positions[:, None], valid,
                                          active.astype(jnp.int32), jnp.zeros_like(active), single_step=True)
         return state, self._head(params, h[:, 0]), counts
+
+
+decoders.register(SIZES, from_name=GraniteHybridConfig.from_name, init=init_granite_params,
+                  model=GraniteHybridLM, cut_options=CUT_OPTIONS)

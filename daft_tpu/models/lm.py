@@ -35,7 +35,7 @@ class DecoderLMConfig:
     @staticmethod
     def from_name(name: str) -> "DecoderLMConfig":
         # Substring rules, kept for the names in use (ROADMAP D4); a new model
-        # is matched exactly, from data (models/granite_hybrid.SIZES).
+        # is matched exactly, from data (models/decoders.DECODERS).
         n = name.lower()
         if "tiny" in n:
             return DecoderLMConfig.tiny()

@@ -19,7 +19,10 @@ model for ``init_state(slots, positions)``, for a ``prefill`` that advances
 some slots over one chunk of their prompts with the true lengths known, for a
 ``decode`` step over all slots, and for ``copy_state(state, src, dst)``.
 ``models/lm.DecoderLM`` keeps key/value rows; ``models/granite_hybrid`` keeps
-SSM state and a conv tail beside them.
+SSM state and a conv tail beside them; ``models/longcat_flash`` keeps one
+latent row a token an attention (576 values where 64 heads' keys and values
+would be 20,480), writes only a prefill's chunk into it and reads only the
+blocks a chunk attends.
 
 **Prefill is chunked at one static length**, so prompts of any length share
 one executable: a prompt runs as ceil(P / chunk) calls that carry state, the
@@ -31,13 +34,15 @@ a later round prefills again, since recurrent state, unlike key/value rows,
 has moved on with its slot.
 
 Spans (``profiling.device_span``): ``serve.prefill`` (one group's chunks:
-``slot``, ``rows``, ``tokens``, ``padded_tokens``, ``chunks``, ``row_chunks``, ``first``),
+``slot``, ``rows``, ``tokens``, ``padded_tokens``, ``chunks``, ``row_chunks``, ``first``;
+``pairs``: the causal (query, key) pairs of the group's prompts, an attention's least work),
 ``serve.copy_state``,
 ``serve.decode_step`` (``active``, ``slots``, and the model's counts of the
 step, e.g. ``moe.held_assignments``), ``serve.fetch`` (the step's one fetch).
 ``serve.prefill`` and ``serve.decode_step`` also carry what the model noted on
 them while its program traced (``moe`` = ``grouped`` | ``xla``: the path of the
-hybrid decoder's grouped products).
+routed experts' grouped products, ``models/decoders.grouped_mlp``; ``mla`` =
+``expanded`` | ``absorbed``: the latent attention's, ``models/longcat_flash``).
 """
 
 from __future__ import annotations
@@ -75,8 +80,12 @@ class ContinuousBatcher:
     #: The static prefill chunk (shorter where no prompt may be that long): the
     #: shorter the chunk, the less of a prompt's last one is padding.
     DEFAULT_CHUNK = 512
-    #: Tokens one prefill call aims at, as chunk x prompts: enough that a held
-    #: expert of a 72-way top-10 router sees ~280 tokens a call.
+    #: Tokens one prefill call aims at, as chunk x prompts. What a held expert
+    #: sees of them is the model's and the cut's: ~280 rows a call under
+    #: granite-4.0-h-small's 72-way top-10 router with half the experts held
+    #: (as in its deployment), ~32 under LongCat-Flash-Chat's 768-way top-12 with
+    #: 16 of 512 held (its deployment's expert would see ~1,024: every chip's
+    #: tokens reach it there, and here only this chip's).
     PREFILL_TOKENS = 2048
 
     def __init__(self, model, params, num_slots: int = 8,
@@ -139,8 +148,8 @@ class ContinuousBatcher:
 
     def _repeat_noted(self, sp) -> None:
         """A model notes its choice of path on the open span while its program
-        traces (``granite_hybrid._grouped_mlp``: ``moe``); a call that traces
-        nothing repeats what the trace chose."""
+        traces (``decoders.grouped_mlp``: ``moe``; ``longcat_flash``: ``mla``); a
+        call that traces nothing repeats what the trace chose."""
         noted = self._noted.setdefault(sp.name, {})
         noted.update({k: v for k, v in sp.count.items() if isinstance(v, str)})
         sp.count.update(noted)
@@ -188,7 +197,7 @@ class ContinuousBatcher:
         chunks = max(1, -(-int(lens.max()) // T))
         with device_span("serve.prefill", slot=int(slots[0]), rows=len(group), tokens=int(lens.sum()),
                          padded_tokens=g * T * chunks, chunks=chunks,
-                         row_chunks=int((-(-lens // T)).sum())) as sp:
+                         row_chunks=int((-(-lens // T)).sum()), pairs=int((lens * (lens + 1) // 2).sum())) as sp:
             if self._prefill is None:
                 sp.count["first"] = 1  # this call traces and compiles (or loads) the program
             fn = self._prefill_fn()

@@ -231,3 +231,74 @@ def test_image_embedder_defaults_are_constants():
     assert desc.get_udf_options().batch_size == 512
     inst = desc.instantiate()
     assert inst.max_batch == 512 and not [k for k in dir(inst) if "staging" in k]
+
+
+# -- release: the instances go once the calls in flight have ended ------------------------------
+class _HeldDescriptor:
+    """A protocol whose call waits until it is let go, so that a test holds one in flight."""
+
+    def __init__(self):
+        from daft_tpu.ai.protocols import UDFOptions
+
+        import threading
+
+        self.options, self.made = UDFOptions(batch_size=4), 0
+        self.entered, self.go = threading.Event(), threading.Event()
+
+    def get_udf_options(self):
+        return self.options
+
+    def runs_beside_host(self):
+        return False
+
+    def instantiate(self):
+        self.made += 1
+        return object()
+
+
+def _held_udf():
+    from daft_tpu.functions.ai import _ProtocolUdf
+
+    d = _HeldDescriptor()
+
+    def call(inst, s):
+        d.entered.set()
+        assert d.go.wait(30)
+        return s
+
+    return d, _ProtocolUdf(d, call, DataType.int64(), "held")
+
+
+def test_release_waits_for_the_call_in_flight_and_then_drops_the_instances():
+    import threading
+
+    from daft_tpu.errors import DaftExecutionError
+    from daft_tpu.series import Series
+
+    d, udf = _held_udf()
+    s = Series.from_pylist([1, 2, 3], "x")
+    worker = threading.Thread(target=udf.fn, args=(s,))
+    worker.start()
+    assert d.entered.wait(30)
+    assert udf.release(wait_s=0.05) is False and len(udf._instances) == 1  # still running: nothing is dropped under it
+    d.go.set()
+    assert udf.release() is True and not udf._instances
+    worker.join(30)
+    with pytest.raises(DaftExecutionError, match="released"):  # no second set of parameters for a late call
+        udf.fn(s)
+    assert d.made == 1
+
+
+def test_what_ships_to_another_process_holds_no_instance_and_no_call():
+    from daft_tpu.functions.ai import _ProtocolUdf
+
+    d, udf = _held_udf()
+    d.go.set()
+    udf.fn(daft_tpu.Series.from_pylist([1], "x"))
+    assert udf.release() is True
+    state = udf.__getstate__()
+    assert state["_instances"] == {} and (state["_calls"], state["_released"]) == (0, False)
+    assert "_idle" not in state and "_instance_lock" not in state
+    again = _ProtocolUdf.__new__(_ProtocolUdf)
+    again.__setstate__(state)
+    assert again._get_instance() is not None and again.release() is True

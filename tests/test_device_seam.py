@@ -114,7 +114,7 @@ def test_chip_smoke_tiny_cpu_is_a_dry_run():
     assert rec["chip_smoke"] == "dry" and '"ok"' not in proc.stdout
     assert rec["platform"] == "cpu"
     assert set(rec["phases"]) == {"A_embed_image", "A_jpeg_host_stage_ahead", "B_embed_text", "C_prompt",
-                                  "C_prompt_hybrid", "D_device_chain", "E_pallas"}
+                                  "C_prompt_hybrid", "C_prompt_longcat", "D_device_chain", "E_pallas"}
     assert len(rec["phases"]["A_jpeg_host_stage_ahead"]["ready"]) == 2  # two morsels through the host stage
     # The debug mode is never the default and never runs off the CPU.
     proc = _run(["chip_smoke.py", "--tiny-cpu"], JAX_PLATFORMS=None)

@@ -296,22 +296,26 @@ def test_kernel_compiles_for_v5e_with_no_copy_at_its_edges(one_chip, B, T, d, nu
     assert f"[{B},{num_heads},{T},{T}]" not in text
 
 
-@pytest.mark.parametrize("m,k,n,gated", [(20480, 4096, 1536, True), (20480, 768, 4096, False),
-                                         (320, 4096, 1536, True), (320, 768, 4096, False)],
-                         ids=["prefill_w_in", "prefill_w_out", "decode_w_in", "decode_w_out"])
-def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, gated):
+@pytest.mark.parametrize("m,k,n,gated,groups", [
+    (20480, 4096, 1536, True, 36), (20480, 768, 4096, False, 36), (320, 4096, 1536, True, 36), (320, 768, 4096, False, 36),
+    (24576, 6144, 4096, True, 16), (24576, 2048, 6144, False, 16), (192, 6144, 4096, True, 16), (192, 2048, 6144, False, 16)],
+    ids=["prefill_w_in", "prefill_w_out", "decode_w_in", "decode_w_out",
+         "longcat_prefill_w_in", "longcat_prefill_w_out", "longcat_decode_w_in", "longcat_decode_w_out"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, gated, groups):
     """The routed experts' two products of granite-4.0-h-small's prefill call
     (2,048 tokens x top 10) and decode step (32 slots x 10) over the 36 held
-    experts, compiled by the TPU's compiler (nothing runs): one custom call each,
-    and the first product's result is ``(m, 768)`` bfloat16, with no float32
-    ``(m, 1536)`` beside it."""
+    experts, and of LongCat-Flash-Chat's (2,048 x top 12, 16 slots x 12; 16 held
+    experts whose ``w_in`` is 50 MB each, so a column block is narrower than one
+    expert's), compiled by the TPU's compiler (nothing runs): one custom call
+    each, and the first product's result is ``(m, n / 2)`` bfloat16, with no
+    float32 ``(m, n)`` beside it."""
     from daft_tpu.ops import pallas_grouped_matmul as gmm
 
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-              for s, d in (((m, k), jnp.bfloat16), ((36, k, n), jnp.bfloat16), ((36,), jnp.int32))]
+              for s, d in (((m, k), jnp.bfloat16), ((groups, k, n), jnp.bfloat16), ((groups,), jnp.int32))]
     compiled = jax.jit(lambda x, w, sizes: gmm.grouped_matmul(x, w, sizes, gated=gated)).lower(*shapes).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1 and "ragged-dot" not in text
     assert f"bf16[{m},{n // 2 if gated else n}]" in text and f"f32[{m}," not in text
-    tm, tn = gmm._tiles(m, k, n // 2 if gated else n, 36, 2, gated)
+    tm, tn = gmm._tiles(m, k, n // 2 if gated else n, groups, 2, gated)
     assert tn > 0 and gmm._step_bytes(tm, k, tn, 2, gated) <= gmm.VMEM_BUDGET
