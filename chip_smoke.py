@@ -45,12 +45,14 @@ FULL = dict(image_model="ViT-L/14", image_px=224, image_rows=1024,
             text_model="all-MiniLM-L6-v2", text_rows=1536, text_warm=512,
             text_dim=384, lm_model="default-lm", prompts=16,
             chain_rows=200_000,
-            attn_shapes=((8, 257, 16, 64), (8, 197, 12, 64), (8, 256, 4, 128)))
+            attn_shapes=((8, 257, 16, 64), (8, 197, 12, 64), (8, 256, 4, 128)),
+            mla_shape=(4, 512, 64, 2049))
 TINY = dict(image_model="tiny", image_px=32, image_rows=40, image_batch=8,
             embed_dim=32,
             text_model="tiny", text_rows=40, text_warm=8, text_dim=64,
             lm_model="tiny-lm", prompts=16, chain_rows=20_000,
-            attn_shapes=((2, 257, 4, 64), (2, 197, 2, 64), (2, 130, 1, 128)))
+            attn_shapes=((2, 257, 4, 64), (2, 197, 2, 64), (2, 130, 1, 128)),
+            mla_shape=(4, 128, 2, 517))
 
 
 def _timed(fn: Callable):
@@ -306,11 +308,50 @@ def phase_c_hybrid(cfg) -> dict:
             "moe": moe}
 
 
-def phase_c_longcat(cfg) -> dict:
+def mla_kernel_check(cfg, tiny: bool) -> dict:
+    """The prefill attention kernel alone at LongCat-Flash-Chat's head widths
+    (128 + 64 | 128 over a latent of 512; ``mla_shape``: rows, chunk, heads,
+    positions a slot), four blocks deep, one row without a query and one that
+    ends inside its chunk, against ``mla_core_expanded`` over the same cache.
+    On a TPU the rule must select it; under --tiny-cpu it runs interpreted."""
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from daft_tpu.models import longcat_flash as lc
+    from daft_tpu.ops import pallas_mla_attention as pm
+
+    B, T, H, S = cfg["mla_shape"]
+    lat, nope, rope, dv = 512, 128, 64, 128
+    sizes = SimpleNamespace(kv_lora_rank=lat, qk_nope_head_dim=nope, qk_rope_head_dim=rope, qk_head_dim=nope + rope,
+                            v_head_dim=dv, dtype=jnp.bfloat16)
+    assert tiny or pm.mla_prefill_applies((B, T, H, nope + rope), jnp.bfloat16, lat, nope, rope, dv), \
+        "the prefill kernel does not apply at the published widths"
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(2.0 * rng.standard_normal((B, T, H, nope + rope)), jnp.bfloat16)
+    kv = jnp.asarray(rng.standard_normal((B + 1, lat + rope, S)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((lat, H, nope + dv)) * lat ** -0.5, jnp.bfloat16)
+    slots = jnp.asarray([3, 0, 4, 1], jnp.int32)
+    starts, lengths = jnp.asarray([3 * T, 3 * T, T, 3 * T], jnp.int32), jnp.asarray([T, 0, T - 28, T], jnp.int32)
+
+    expanded = jax.jit(lambda q, kv, w: lc.mla_expanded_over_slots(sizes, w, q, kv, slots, starts))
+    ref, xla_s = _timed(lambda: np.asarray(expanded(q, kv, w), np.float32))
+    out, fused_s = _timed(lambda: np.asarray(pm.mla_prefill_attention(
+        q, kv, w, slots, starts, lengths, nope=nope, interpret=tiny), np.float32))
+    held = np.asarray(lengths) > 0
+    assert not out[~held].any(), "a row without a query did not come back as zeros"
+    np.testing.assert_allclose(out[held], ref[held], atol=3e-2, rtol=3e-2, err_msg=f"mla_prefill_attention {(B, T, H, S)}")
+    return {"shape": [B, T, H, S], "max_abs_diff_vs_expanded": round(float(np.abs(out - ref)[held].max()), 6),
+            "first_call_s": {"fused": fused_s, "expanded": xla_s}}
+
+
+def phase_c_longcat(cfg, tiny: bool) -> dict:
     """prompt on the tiny LongCat-Flash decoder (latent attention with a rotary
     part, double layers, identity experts, a sharded expert layer): chunked
-    prefill over the latent cache (expanded), the decode loop (absorbed), and
-    what the spans say of both."""
+    prefill over the latent cache (expanded: its widths fill no lane tile), the
+    decode loop (absorbed), and what the spans say of both; then the prefill
+    kernel alone at the published head widths (``mla_kernel_check``)."""
     import daft_tpu
     from daft_tpu import col
     from daft_tpu.functions.ai import prompt
@@ -346,7 +387,8 @@ def phase_c_longcat(cfg) -> dict:
     assert held["state_bytes"] == held["slots"] * held["positions"] * 4 * 16 * 2, held  # four latent caches of 16 values
     _release(expr)
     return {"run_s": run_s, "rows": n, "decode_steps": len(steps), "paths": paths,
-            "cache_bytes_per_token": held["state_bytes"] // (held["slots"] * held["positions"])}
+            "cache_bytes_per_token": held["state_bytes"] // (held["slots"] * held["positions"]),
+            "mla_kernel": mla_kernel_check(cfg, tiny)}
 
 
 def phase_d(cfg) -> dict:
@@ -510,7 +552,7 @@ def main(argv=None) -> int:
             current = "C_prompt_hybrid"
             done(current, phase_c_hybrid(cfg))
             current = "C_prompt_longcat"
-            done(current, phase_c_longcat(cfg))
+            done(current, phase_c_longcat(cfg, tiny))
             current = "D_device_chain"
             done(current, phase_d(cfg))
             current = "E_pallas"

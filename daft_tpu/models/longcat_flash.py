@@ -32,8 +32,17 @@ paths**: prefill *expands* a block of cache rows at a time (``c W_kvb`` ->
 per-head keys and values, a running softmax between blocks; work follows the
 prefix held); decode *absorbs* ``W_kvb`` into the query and the output
 (``q_nope W_uk^T`` against ``c``, ``probs @ c`` then ``W_uv``) and never
-expands a key. Which one a program traced is noted on the batcher's open span
-as ``mla`` = ``expanded`` | ``absorbed``. A prefill writes only its chunk into
+expands a key. The prefill's has two forms of one arithmetic. On a TPU, at
+widths that fill lane tiles (the published ones), it is one Pallas kernel
+(``ops/pallas_mla_attention.py``): a block's scores, exponentials and expanded
+keys and values stay in VMEM, and only the (row, block) pairs in which the row
+holds a query are walked: a row whose prompt has ended (``lengths == 0``)
+costs nothing and reads zeros. Elsewhere (the CPU backend, the tiny test
+widths) it is ``mla_core_expanded``: XLA's loop over the blocks the call's
+deepest row attends, every row alike. ``mla_prefill_applies`` decides when the
+program traces, from the backend, the dtype and the shapes; there is no switch.
+Which one a program traced is noted on the batcher's open span as ``mla`` =
+``fused`` | ``expanded`` | ``absorbed``. A prefill writes only its chunk into
 the slots' rows and reads only the blocks that reach its last position; a
 decode step writes one lane tile of positions a slot, in place.
 
@@ -72,6 +81,7 @@ import numpy as np
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
 from daft_tpu.models.decoders import draw, gated_mlp, mm, rms
+from daft_tpu.ops import pallas_mla_attention
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
 PUBLISHED: Dict[str, Dict[str, Any]] = {
@@ -320,11 +330,26 @@ def mla_core_expanded(cfg, w_kvb, q, block_of, blocks, positions):
     return acc / jnp.moveaxis(l, 1, 2)[..., None]
 
 
-def _mla_prefill(cfg, p, s, x, kv, slots, starts, positions, valid):
+def mla_expanded_over_slots(cfg, w_kvb, q, kv, slots, starts):
+    """``mla_core_expanded`` for the rows ``slots`` of ``kv`` (slots, cache_row, S),
+    each at the chunk that begins at ``starts``: every row over the blocks of T
+    positions that the call's deepest row attends (every row of a call is at the
+    same chunk of its prompt, or past its end)."""
+    B, T = q.shape[:2]
+
+    def block_of(j):
+        return jnp.concatenate([jax.lax.dynamic_slice(kv, (slots[b], 0, j * T), (1, kv.shape[1], T)) for b in range(B)])
+
+    return mla_core_expanded(cfg, w_kvb, q, block_of, jnp.max(starts) // T + 1, starts[:, None] + jnp.arange(T)[None, :])
+
+
+def _mla_prefill(cfg, p, s, x, kv, slots, starts, lengths):
     """One chunk for the rows ``slots`` of ``kv`` (slots, cache_row, S): write
-    the chunk's valid rows at ``starts``, then attend over the blocks held.
-    -> (out (B, T, d) float32, kv)."""
+    the chunk's valid rows (``lengths`` of each, from ``starts``), then attend
+    over the blocks held. -> (out (B, T, d) float32, kv)."""
     B, T, _ = x.shape
+    positions = starts[:, None] + jnp.arange(T)[None, :]
+    valid = jnp.arange(T)[None, :] < lengths[:, None]
     with jax.named_scope("mla_proj"):
         q, rows = mla_project(cfg, p, s, x, positions)
     with jax.named_scope("mla_core"):
@@ -334,13 +359,14 @@ def _mla_prefill(cfg, p, s, x, kv, slots, starts, positions, valid):
             old = jax.lax.dynamic_slice(kv, at, (1, cfg.cache_row, T))
             kv = jax.lax.dynamic_update_slice(kv, jnp.where(valid[b][None, None, :], cols[b][None], old), at)
 
-        def block_of(j):
-            return jnp.concatenate([jax.lax.dynamic_slice(kv, (slots[b], 0, j * T), (1, cfg.cache_row, T))
-                                    for b in range(B)])
-
-        # every row of a call is at the same chunk of its prompt, or past its end
-        out = mla_core_expanded(cfg, _kv_b(cfg, p, s), q, block_of, jnp.max(starts) // T + 1, positions)
-        decoders.note_on_serving_span("mla", "expanded")
+        w_kvb = _kv_b(cfg, p, s)
+        if pallas_mla_attention.mla_prefill_applies(q.shape, q.dtype, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                                                    cfg.qk_rope_head_dim, cfg.v_head_dim):
+            out = pallas_mla_attention.mla_prefill_attention(q, kv, w_kvb, slots, starts, lengths, nope=cfg.qk_nope_head_dim)
+            decoders.note_on_serving_span("mla", "fused")
+        else:
+            out = mla_expanded_over_slots(cfg, w_kvb, q, kv, slots, starts)
+            decoders.note_on_serving_span("mla", "expanded")
     with jax.named_scope("mla_proj"):
         return mm(out.astype(cfg.dtype).reshape(B, T, -1), p["o" + s]), kv
 
@@ -462,12 +488,9 @@ class LongcatFlashLM:
         after each row's last valid token, counts)."""
         cfg = self.cfg
         T = tokens.shape[1]
-        steps = jnp.arange(T)[None, :]
-        valid = steps < lengths[:, None]
-        positions = starts[:, None] + steps
         x, state, counts = self._forward(
-            params, state, tokens, valid,
-            lambda p, s, x, kv: _mla_prefill(cfg, p, s, x, kv, slots, starts, positions, valid))
+            params, state, tokens, jnp.arange(T)[None, :] < lengths[:, None],
+            lambda p, s, x, kv: _mla_prefill(cfg, p, s, x, kv, slots, starts, lengths))
         last = jnp.take_along_axis(x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
         return state, self._head(params, last), counts
 

@@ -35,14 +35,16 @@ has moved on with its slot.
 
 Spans (``profiling.device_span``): ``serve.prefill`` (one group's chunks:
 ``slot``, ``rows``, ``tokens``, ``padded_tokens``, ``chunks``, ``row_chunks``, ``first``;
-``pairs``: the causal (query, key) pairs of the group's prompts, an attention's least work),
+``pairs``: the causal (query, key) pairs of the group's prompts, an attention's least work;
+``block_rows``: the (row, block of ``chunk`` positions) pairs its calls attend in which the row holds a query,
+of ``padded_block_rows`` = rows a call x chunks x (chunks + 1) / 2 that calls of static shape span),
 ``serve.copy_state``,
 ``serve.decode_step`` (``active``, ``slots``, and the model's counts of the
 step, e.g. ``moe.held_assignments``), ``serve.fetch`` (the step's one fetch).
 ``serve.prefill`` and ``serve.decode_step`` also carry what the model noted on
 them while its program traced (``moe`` = ``grouped`` | ``xla``: the path of the
 routed experts' grouped products, ``models/decoders.grouped_mlp``; ``mla`` =
-``expanded`` | ``absorbed``: the latent attention's, ``models/longcat_flash``).
+``fused`` | ``expanded`` | ``absorbed``: the latent attention's, ``models/longcat_flash``).
 """
 
 from __future__ import annotations
@@ -195,9 +197,12 @@ class ContinuousBatcher:
         spare = [s for s in range(self.B) if s not in taken]
         slots = np.asarray(taken + spare[:g - len(group)], np.int32)
         chunks = max(1, -(-int(lens.max()) // T))
+        row_chunks = -(-lens // T)
         with device_span("serve.prefill", slot=int(slots[0]), rows=len(group), tokens=int(lens.sum()),
                          padded_tokens=g * T * chunks, chunks=chunks,
-                         row_chunks=int((-(-lens // T)).sum()), pairs=int((lens * (lens + 1) // 2).sum())) as sp:
+                         row_chunks=int(row_chunks.sum()), pairs=int((lens * (lens + 1) // 2).sum()),
+                         block_rows=int((row_chunks * (row_chunks + 1) // 2).sum()),
+                         padded_block_rows=g * chunks * (chunks + 1) // 2) as sp:
             if self._prefill is None:
                 sp.count["first"] = 1  # this call traces and compiles (or loads) the program
             fn = self._prefill_fn()

@@ -116,6 +116,8 @@ def test_chip_smoke_tiny_cpu_is_a_dry_run():
     assert set(rec["phases"]) == {"A_embed_image", "A_jpeg_host_stage_ahead", "B_embed_text", "C_prompt",
                                   "C_prompt_hybrid", "C_prompt_longcat", "D_device_chain", "E_pallas"}
     assert len(rec["phases"]["A_jpeg_host_stage_ahead"]["ready"]) == 2  # two morsels through the host stage
+    kernel = rec["phases"]["C_prompt_longcat"]["mla_kernel"]  # interpreted here; the tiny model itself stays expanded
+    assert kernel["shape"][2:] == [2, 517] and 0 < kernel["max_abs_diff_vs_expanded"] < 3e-2
     # The debug mode is never the default and never runs off the CPU.
     proc = _run(["chip_smoke.py", "--tiny-cpu"], JAX_PLATFORMS=None)
     assert proc.returncode != 0 and "dry" not in proc.stdout

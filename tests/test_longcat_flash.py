@@ -16,6 +16,7 @@ operands in float8_e4m3, put in the program's place) has to break it.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import sys
 
@@ -36,6 +37,7 @@ from lib import manifest  # noqa: E402
 from daft_tpu.errors import DaftValueError  # noqa: E402
 from daft_tpu.models import decoders, granite_hybrid as gh, longcat_flash as lc  # noqa: E402
 from daft_tpu.models.serving import ContinuousBatcher, Request  # noqa: E402
+from daft_tpu.ops import pallas_attention, pallas_mla_attention  # noqa: E402
 
 TINY = "longcat-flash-tiny"
 #: |program log-probability - reference's| of a chosen token, and the regret of the greedy choice. Readings over
@@ -61,9 +63,23 @@ def ref_config(rank: int = 0, size: int = 2) -> dict:
                 options={"expert_shard": [rank, size], "vocab_shard": [rank, size]})
 
 
-def program(seed: int, rank: int = 0, size: int = 2):
+#: The tiny decoder with an attention one lane tile wide: the narrowest the prefill kernel serves.
+LANE_TILE = dict(num_attention_heads=2, kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=16, v_head_dim=128)
+
+
+def program(seed: int, rank: int = 0, size: int = 2, **sizes):
     cfg = lc.LongcatFlashConfig.from_name(TINY, expert_shard=(rank, size), vocab_shard=(rank, size))
-    return lc.init_longcat_params(cfg, seed)
+    return lc.init_longcat_params(dataclasses.replace(cfg, **sizes), seed)
+
+
+@pytest.fixture
+def fused_prefill(monkeypatch):
+    """The backend rule answers as on a TPU and the prefill kernel it then
+    selects runs interpreted (the experts stay too narrow for theirs)."""
+    real = pallas_mla_attention.mla_prefill_attention
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pallas_mla_attention, "mla_prefill_attention",
+                        lambda *a, nope: real(*a, nope=nope, interpret=True))
 
 
 def prompts(seed: int, lengths):
@@ -128,6 +144,69 @@ def test_chunked_prefill_then_decode_agrees_with_the_references_logits(ref, seed
     assert float(np.max(np.abs(low[n - 1:] - want[n - 1:]))) > LOGIT_GAP_MAX
 
 
+def test_chunked_prefill_through_the_kernel_then_decode_agrees_with_the_references_logits(ref, fused_prefill):
+    """At an attention one lane tile wide the prefill takes the kernel: a prompt of
+    293 tokens as three chunks of 128 beside one of 100 that ends in the first
+    (its row goes on with length 0: no block of it is visited, its slot keeps its
+    rows), then three decode steps on the first through the absorbed path, against
+    the reference's forward over each whole sequence."""
+    seed, T = 0, 128
+    model, params = program(seed, **LANE_TILE)
+    rcfg = dict(ref_config(), **LANE_TILE)
+    rng = np.random.default_rng(seed)
+    long_toks, short_toks = rng.integers(2, 128, 296).astype(np.int32), rng.integers(2, 128, 100).astype(np.int32)
+    lens = np.asarray([293, 100])
+    state = model.init_state(3, 3 * T)
+    slots = jnp.asarray([2, 0], jnp.int32)
+    prefill = jax.jit(model.prefill)
+    got = {}
+    for c in range(3):
+        part = np.zeros((2, T), np.int32)
+        here = np.clip(lens - c * T, 0, T)
+        part[0, :here[0]] = long_toks[c * T:c * T + here[0]]
+        part[1, :here[1]] = short_toks[c * T:c * T + here[1]]
+        if c == 1:
+            short_rows = [np.asarray(s["kv"][0]) for s in state]
+        state, logits, _ = prefill(params, state, part, slots, jnp.full((2,), c * T, jnp.int32), jnp.asarray(here, jnp.int32))
+        if c == 0:
+            got["short"] = np.asarray(logits[1])
+    assert all(np.array_equal(np.asarray(s["kv"][0]), b) for s, b in zip(state, short_rows))
+    want = ref.forward(rcfg, seed, long_toks)
+    got["long"] = [np.asarray(logits[0])]
+    decode = jax.jit(model.decode)
+    for i in range(293, 296):
+        state, logits, _ = decode(params, state, jnp.full((3,), long_toks[i], jnp.int32), jnp.full((3,), i, jnp.int32),
+                                  jnp.asarray([False, False, True]))
+        got["long"].append(np.asarray(logits[2]))
+    gaps = [float(np.max(np.abs(g - want[292 + j]))) for j, g in enumerate(got["long"])]
+    gaps.append(float(np.max(np.abs(got["short"] - ref.forward(rcfg, seed, short_toks)[-1]))))
+    assert max(gaps) <= LOGIT_GAP_MAX, gaps  # read 0.010 to 0.023
+    assert float(np.std(want)) > 0.5
+    low = ref.forward(rcfg, seed, long_toks, precision="fp8")
+    assert float(np.max(np.abs(low[292:] - want[292:]))) > LOGIT_GAP_MAX
+
+
+def test_the_batcher_at_lane_tile_widths_prefills_through_the_kernel_and_counts_its_visits(monkeypatch, fused_prefill):
+    """Three prompts of unlike length in one group of four rows: the span says
+    which attention the prefill program traced and how many (row, block) pairs
+    held a query, of those a call of static shape spans."""
+    from daft_tpu.profiling import newest_device_span
+
+    model, params = program(1, **LANE_TILE)
+    b = ContinuousBatcher(model, params, num_slots=4, max_seq_len=400, eos_id=None, prefill_chunk=128)
+    out = b.run([Request(tokens=t, max_new_tokens=2) for t in prompts(1, [300, 100, 140])])
+    assert all(len(o) == 2 for o in out)
+    assert b._noted == {"serve.prefill": {"mla": "fused", "moe": "xla"}, "serve.decode_step": {"mla": "absorbed", "moe": "xla"}}
+    count = newest_device_span("serve.prefill").count
+    assert (count["mla"], count["chunks"], count["row_chunks"]) == ("fused", 3, 3 + 1 + 2)
+    assert (count["block_rows"], count["padded_block_rows"]) == (6 + 1 + 3, 4 * 6)
+    # the same prompts on XLA's path choose the same tokens
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: False)
+    x = ContinuousBatcher(model, params, num_slots=4, max_seq_len=400, eos_id=None, prefill_chunk=128)
+    assert x.run([Request(tokens=t, max_new_tokens=2) for t in prompts(1, [300, 100, 140])]) == out
+    assert x._noted["serve.prefill"]["mla"] == "expanded"
+
+
 def _served(seed, lengths=(5, 17, 33, 40, 9, 20), new=8, **kw):
     model, params = program(seed)
     b = ContinuousBatcher(model, params, num_slots=4, max_seq_len=64, eos_id=None,
@@ -178,6 +257,12 @@ def test_the_absorbed_and_the_expanded_attention_agree(seed):
     assert absorbed.shape == expanded.shape == (B, T, cfg.num_attention_heads, cfg.v_head_dim)
     scale = float(jnp.max(jnp.abs(expanded)))
     assert float(jnp.max(jnp.abs(absorbed - expanded))) <= 3e-2 * scale  # bfloat16 products in two orders; read 1.1e-2
+    # the third path: the prefill kernel (interpreted; a chip takes it only at lane-tile widths) over the same cache
+    fused = pallas_mla_attention.mla_prefill_attention(q, cache, w, jnp.arange(B, dtype=jnp.int32), positions[:, 0],
+                                                       jnp.full((B,), T, jnp.int32), nope=cfg.qk_nope_head_dim, interpret=True)
+    assert fused.shape == expanded.shape and fused.dtype == jnp.bfloat16
+    assert float(jnp.max(jnp.abs(fused - expanded))) <= 1e-2 * scale  # the same products in the same order, rounded once more
+    assert float(jnp.max(jnp.abs(fused - absorbed))) <= 3e-2 * scale
     # neither looks past a query's position: rows behind it may hold anything
     junk = cache.at[:, :, 20:].set(99.0).at[1, :, 14:].set(-99.0)
     again = lc.mla_core_expanded(cfg, w, q[:, :4], lambda j: jax.lax.dynamic_slice_in_dim(junk, j * T, T, axis=2),

@@ -4,7 +4,8 @@ interpret mode on the CPU against ``jax.nn.dot_product_attention`` on the same
 forward, and its compile for a described v5e chip at the widths the benchmark
 runs; and the same compile of the grouped-matmul kernel
 (``ops/pallas_grouped_matmul.py``, whose other tests are in
-``tests/test_pallas_grouped_matmul.py``). One file, so that the tests that
+``tests/test_pallas_grouped_matmul.py``) and of LongCat-Flash's prefill
+attention (``ops/pallas_mla_attention.py``, ``tests/test_pallas_mla_attention.py``). One file, so that the tests that
 describe a TPU topology stay with their fixture (see the on-chip-measurement
 guide)."""
 
@@ -319,3 +320,38 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, gated, groups):
     assert f"bf16[{m},{n // 2 if gated else n}]" in text and f"f32[{m}," not in text
     tm, tn = gmm._tiles(m, k, n // 2 if gated else n, groups, 2, gated)
     assert tn > 0 and gmm._step_bytes(tm, k, tn, 2, gated) <= gmm.VMEM_BUDGET
+
+
+def test_mla_prefill_attention_compiles_for_v5e_with_no_expansion_and_no_copy_of_the_cache(one_chip):
+    """One attention of LongCat-Flash-Chat's prefill call at the cell's shapes
+    (4 rows of 512 queries, 64 heads of 128 + 64 | 128 over a latent of 512, 16
+    slots of 16,449 positions) between its neighbours: the chunk's rows written
+    into the cache, the kernel, the output projection's reshape. Compiled by the
+    TPU's compiler (nothing runs): one custom call; no float32 score tensor and
+    no expanded keys and values in HBM (the one ``bf16[4,512,64,256]`` is q, each
+    head padded to two lane tiles, and no product writes that shape); the cache
+    reaches the kernel as the update left it, with no copy of it."""
+    from daft_tpu.ops import pallas_mla_attention as pm
+
+    B, T, H, lat, nope, rope, dv = 4, 512, 64, 512, 128, 64, 128
+    cache = (16, lat + rope, 16449)
+
+    def attention(q, kv, w_kvb, rows, slots, starts, lengths):
+        for b in range(B):  # as ``longcat_flash._mla_prefill`` writes the chunk
+            kv = jax.lax.dynamic_update_slice(kv, rows[b][None], (slots[b], 0, starts[b]))
+        out = pm.mla_prefill_attention(q, kv, w_kvb, slots, starts, lengths, nope=nope)
+        return out.reshape(B, T, H * dv), kv
+
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, T, H, nope + rope), jnp.bfloat16), (cache, jnp.bfloat16), ((lat, H, nope + dv), jnp.bfloat16),
+        ((B, lat + rope, T), jnp.bfloat16), ((B,), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))]
+    text = jax.jit(attention, donate_argnums=(1,)).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "f32[4,64,512,512]" not in text and "f32[4,512,64,256]" not in text
+    assert not re.search(r"bf16\[4,512,64,256\]\S* (convolution|dot|fusion)\(.*kind=kOutput", text)
+    assert "convolution" not in text and not re.search(r"= \S+ dot\(", text)
+    shape = "bf16[%d,%d,%d]" % cache
+    assert not re.search(r"= %s\S* (copy|transpose)\(" % re.escape(shape), text)
+    assert f"bf16[{B},{T},{H * dv}]" in text
+    heads = pm._heads_a_step(T, lat, nope, rope, dv, H, 2)
+    assert heads > 0 and pm._step_bytes(T, lat, nope, rope, dv, heads, 2) <= pm.VMEM_BUDGET

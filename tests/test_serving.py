@@ -114,6 +114,34 @@ def test_prefix_routing_shares_prefills(lm):
     assert calls["n"] == 1, f"expected one shared prefill, got {calls['n']}"
 
 
+@pytest.mark.parametrize("lengths,slots,block_rows,padded", [
+    ([4, 9, 21, 30], 4, 1 + 6 + 21 + 36, 4 * 36),    # one group of four rows of 1, 3, 6 and 8 chunks: 64 of 144
+    ([3, 4, 5], 4, 1 + 1 + 3, 4 * 3),                # three prompts in four rows: the spare row attends nothing
+    ([16, 16], 2, 10 + 10, 2 * 10),                  # like lengths: every visit holds a query
+], ids=["unlike_lengths", "a_spare_row", "like_lengths"])
+def test_prefill_span_counts_the_block_rows_that_hold_a_query(lm, lengths, slots, block_rows, padded):
+    """``serve.prefill`` of one group: ``block_rows`` sums, over the group's calls
+    and the rows that still hold a query, the blocks of ``chunk`` positions
+    attended (a row of c chunks: c (c + 1) / 2); ``padded_block_rows`` is what the
+    calls span at static shape: rows a call x chunks (chunks + 1) / 2 of the longest."""
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    model, params = lm
+    rng = np.random.default_rng(4)
+    began = span_clock_ns()
+    b = ContinuousBatcher(model, params, num_slots=slots, prefill_chunk=4)
+    b.run([Request(tokens=rng.integers(3, model.cfg.vocab_size, n).astype(np.int32), max_new_tokens=1) for n in lengths])
+    spans = [s for s in recent_device_spans() if s.name == "serve.prefill" and s.start_ns >= began]
+    assert len(spans) == 1
+    count = spans[0].count
+    chunks = [-(-n // 4) for n in lengths]
+    assert count["block_rows"] == sum(c * (c + 1) // 2 for c in chunks)
+    assert count["padded_block_rows"] == b.prefill_rows * max(chunks) * (max(chunks) + 1) // 2
+    assert (count["block_rows"], count["padded_block_rows"], count["row_chunks"]) == (block_rows, padded, sum(chunks))
+    assert count["block_rows"] <= count["padded_block_rows"]
+
+
 def test_llm_generate_through_engine():
     """llm_generate end-to-end over the continuous-batching prompter."""
     import daft_tpu.functions as F
