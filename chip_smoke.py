@@ -263,6 +263,12 @@ def phase_c(cfg) -> dict:
             "decode_steps": steps}
 
 
+def _prompt_docs(n: int, last: int):
+    """n documents of unlike length in words (one hashed token each), the last of ``last`` words."""
+    lengths = [6 + 5 * i for i in range(n - 1)] + [last]
+    return lengths, [" ".join(f"w{(3 * i + j) % 40}" for j in range(m)) for i, m in enumerate(lengths)]
+
+
 def phase_c_hybrid(cfg) -> dict:
     """prompt on the tiny hybrid decoder (Mamba-2, attention, a sharded expert
     layer): chunked prefill that carries SSM state, the decode loop, answers
@@ -275,8 +281,7 @@ def phase_c_hybrid(cfg) -> dict:
 
     began = span_clock_ns()  # the ring also holds phase C's spans
     n = cfg["prompts"]
-    lengths = [6 + 5 * i for i in range(n - 1)] + [600]  # the last one takes two of the batcher's 512-token chunks
-    docs = [" ".join(f"w{(3 * i + j) % 40}" for j in range(m)) for i, m in enumerate(lengths)]
+    lengths, docs = _prompt_docs(n, 600)  # the last one takes two of the batcher's 512-token chunks
     expr = prompt(col("p"), provider="flax_random", model="granite-hybrid-tiny",
                   max_new_tokens=6, ignore_eos=True, logprobs=True, num_slots=4, max_prompt_tokens=640,
                   num_hidden_layers=4, expert_shard=[0, 2], vocab_shard=[0, 2])
@@ -306,6 +311,63 @@ def phase_c_hybrid(cfg) -> dict:
     _release(expr)
     return {"run_s": run_s, "rows": n, "decode_steps": len(steps), "prefill_tokens": prefilled,
             "moe": moe}
+
+
+def phase_c_olmo(cfg, tiny: bool) -> dict:
+    """prompt on the tiny Olmo-Hybrid decoder (gated delta-rule linear attention in
+    three layers of four, full attention with QK-norm in the fourth): chunked
+    prefill that carries the delta rule's state, the decode loop, answers with
+    log-probabilities, ``delta`` noted on both serving spans and both kinds of
+    slot state counted on ``prompt.run``; then, on the chip, the two kernels that
+    carry a prefill's traffic with the key/value rows, at the published head
+    sizes, against XLA's slices."""
+    import jax
+    import jax.numpy as jnp
+
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.functions.ai import prompt
+    from daft_tpu.ops import pallas_cache_blocks as pcb
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    began = span_clock_ns()
+    n = cfg["prompts"]
+    lengths, docs = _prompt_docs(n, 600)  # the last one takes two of the batcher's 512-token chunks
+    expr = prompt(col("p"), provider="flax_random", model="olmo-hybrid-tiny", max_new_tokens=6, ignore_eos=True,
+                  logprobs=True, num_slots=4, max_prompt_tokens=640, num_hidden_layers=4)
+
+    def run():
+        return daft_tpu.from_pydict({"p": docs}).with_column("a", expr).select("a").collect().to_pydict()["a"]
+
+    answers, run_s = _timed(run)
+    assert len(answers) == n and all(
+        len(a["token_ids"]) == len(a["logprobs"]) == 6 and np.isfinite(a["logprobs"]).all()
+        and all(0 <= t < 256 for t in a["token_ids"]) for a in answers), answers
+    spans = [s for s in recent_device_spans() if s.start_ns >= began and s.name.startswith(("serve.", "prompt."))]
+    prefilled = sum(s.count["tokens"] for s in spans if s.name == "serve.prefill")
+    assert prefilled == sum(lengths), f"prefilled {prefilled} tokens"
+    delta = {s.name: s.count.get("delta") for s in spans if s.name in ("serve.prefill", "serve.decode_step")}
+    assert delta == {"serve.prefill": "chunked", "serve.decode_step": "recurrent"}, delta
+    held = [s.count for s in spans if s.name == "prompt.run"][-1]
+    assert held["kv_bytes"] > 0 and held["recurrent_bytes"] > 0 and held["kv_bytes"] + held["recurrent_bytes"] == held["state_bytes"], held
+    _release(expr)
+    out = {"run_s": run_s, "rows": n, "prefill_tokens": prefilled, "delta": delta,
+           "kv_bytes": held["kv_bytes"], "recurrent_bytes": held["recurrent_bytes"]}
+    if not tiny:  # 3 slots x 30 heads x 1,031 positions x 128, a chunk of 512: a full row, an empty one, one ending inside
+        ks = jax.random.split(jax.random.PRNGKey(0), 2)
+        cache = jax.random.normal(ks[0], (3, 30, 1031, 128)).astype(jnp.bfloat16)
+        new = jax.random.normal(ks[1], (3, 512, 30, 128)).astype(jnp.bfloat16)
+        slots, starts, lens = jnp.asarray([2, 0, 1], jnp.int32), jnp.full((3,), 512, jnp.int32), jnp.asarray([512, 0, 77], jnp.int32)
+        assert pcb.kernels_apply(cache.shape, 512)
+        want = pcb.write_blocks_xla(cache, new, slots, starts, lens)
+        got = jax.jit(pcb.write_blocks)(cache, new, slots, starts, lens)
+        assert bool(jnp.all(got == want)), "the write kernel and XLA's slices differ"
+        block = jax.jit(lambda c: pcb.read_blocks(c, slots, jnp.int32(512), 512))(got)
+        sliced = pcb.read_blocks_xla(got, slots, 512, 512)
+        assert block.dtype == sliced.dtype == jnp.bfloat16 and bool(jnp.all(block == sliced))
+        out["cache_kernels"] = "equal to XLA's slices"
+    return out
 
 
 def mla_kernel_check(cfg, tiny: bool) -> dict:
@@ -360,8 +422,7 @@ def phase_c_longcat(cfg, tiny: bool) -> dict:
 
     began = span_clock_ns()
     n = cfg["prompts"]
-    lengths = [6 + 5 * i for i in range(n - 1)] + [1100]  # the last one takes three of the batcher's 512-token chunks
-    docs = [" ".join(f"w{(3 * i + j) % 40}" for j in range(m)) for i, m in enumerate(lengths)]
+    lengths, docs = _prompt_docs(n, 1100)  # the last one takes three of the batcher's 512-token chunks
     expr = prompt(col("p"), provider="flax_random", model="longcat-flash-tiny",
                   max_new_tokens=6, ignore_eos=True, logprobs=True, num_slots=4, max_prompt_tokens=1200,
                   num_layers=2, expert_shard=[0, 2], vocab_shard=[0, 2])
@@ -553,6 +614,8 @@ def main(argv=None) -> int:
             done(current, phase_c_hybrid(cfg))
             current = "C_prompt_longcat"
             done(current, phase_c_longcat(cfg, tiny))
+            current = "C_prompt_olmo"
+            done(current, phase_c_olmo(cfg, tiny))
             current = "D_device_chain"
             done(current, phase_d(cfg))
             current = "E_pallas"

@@ -43,7 +43,7 @@ from daft_tpu.ai.provider import Provider
 from daft_tpu.device import setup_compile_cache
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
-from daft_tpu.models import granite_hybrid, longcat_flash  # noqa: F401  (each enters its names in decoders.DECODERS)
+from daft_tpu.models import granite_hybrid, longcat_flash, olmo_hybrid  # noqa: F401  (each enters its names in decoders.DECODERS)
 from daft_tpu.profiling import device_span
 from daft_tpu.utils.tokenizer import HashingTokenizer
 
@@ -480,8 +480,8 @@ class FlaxPrompter(_FlaxModelBase):
     """``prompt`` / ``llm_generate`` over a decoder and the continuous batcher.
 
     ``model_name`` is looked up exactly in the record of published decoders
-    (``models/decoders.DECODERS``, which ``models/granite_hybrid`` and
-    ``models/longcat_flash`` enter); such a decoder takes its own cut's options
+    (``models/decoders.DECODERS``, which ``models/granite_hybrid``,
+    ``models/longcat_flash`` and ``models/olmo_hybrid`` enter); such a decoder takes its own cut's options
     (e.g. ``num_hidden_layers`` or ``num_layers``, ``expert_shard``,
     ``vocab_shard``: one chip's share of a stated deployment). Any other name is
     ``DecoderLMConfig.from_name``'s, and a cut's options with such a name are an
@@ -550,8 +550,9 @@ class FlaxPrompter(_FlaxModelBase):
                     self.model, self.params, num_slots=self.num_slots, max_seq_len=self.max_seq_len,
                     temperature=self.temperature, eos_id=self.eos_id, max_prompt_tokens=self.prompt_len)
             b = self._batcher
+            kinds = decoders.state_bytes_by_kind(b.state)
             with device_span("prompt.run", rows=len(reqs), slots=b.B, positions=b.positions_held,
-                             state_bytes=_tree_bytes(b.state)) as sp:
+                             state_bytes=sum(kinds.values()), **kinds) as sp:
                 out = b.run(reqs)
                 sp.count["decode_steps"] = b.decode_steps
             logprobs = self._batcher.last_logprobs

@@ -1,12 +1,28 @@
 """What the published decoders behind ``prompt`` share: the record of them by
-exact name, the rules their random parameters are drawn by, and the expert
-layer's dispatch after the router.
+exact name, the rules their random parameters are drawn by, the attention core
+over a cache of per-head keys and values, the causal conv with a carried tail,
+and the expert layer's dispatch after the router.
 
 **The record.** A decoder module enters its names here (``register``) with how
 a name becomes a configuration, how parameters are drawn, the model class the
 serving protocol talks to, and the options of ``prompt`` that cut it to one
 chip's share. ``ai/flax_provider.FlaxPrompter`` and the benchmark's
 ``entries/prompt_decoder.py`` look a name up and know no module by name.
+
+**The attention core** (``attention_core``, ``attention_chunk``): scores,
+softmax and weighted values of grouped queries over a slot's cache rows, given
+the queries, a score scale and where the rows are: one token over every row
+held (decode), or a chunk of queries over the blocks of cache rows that reach
+its last position with a running softmax between them (prefill: the work
+follows the prefix held, not the positions a slot could hold). Projections,
+norms, positions and the cache's layout are each model's own:
+``granite_hybrid`` (8 key/value heads x 4 queries, rows gathered a call) and
+``olmo_hybrid`` (30 x 1, QK-norm, blocks read in place from the slots' rows)
+both call it, so a kernel written for it serves, and is held by, both.
+
+**The conv** (``conv_with_tail``): the causal depthwise conv of a recurrent
+mixer over [carried tail | this chunk] with its SiLU, and the tail after each row's last
+valid step (``granite_hybrid``'s Mamba-2 input, ``olmo_hybrid``'s q, k and v).
 
 **The dispatch** (``held_experts_part``): each model routes in its own way
 (which scores, which weights, which experts cost nothing); what follows is the
@@ -134,6 +150,97 @@ def note_on_serving_span(key: str, value: str) -> None:
 def copy_slot(state, src, dst):
     """Slot ``src``'s state into slot ``dst``, whatever the leaves hold: every leaf's first axis is the slot."""
     return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
+
+
+#: The names under which a decoder's ``init_state`` holds rows a token (key/value rows, latent rows: they grow with
+#: the longest document a slot may hold). A leaf under any other name is recurrent state, the same whatever the length.
+ROW_LEAVES = ("k", "v", "kv")
+
+
+def state_bytes_by_kind(state) -> Dict[str, int]:
+    """A batcher's slot state in two kinds, by the names the model gave its leaves: ``kv_bytes`` (``ROW_LEAVES``;
+    a leaf under no name, as the toy decoder's (k, v) pairs, is rows too) and ``recurrent_bytes`` (the rest)."""
+    kinds = {"kv_bytes": 0, "recurrent_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        names = [p.key for p in path if isinstance(p, jax.tree_util.DictKey)]
+        kinds["kv_bytes" if not names or names[-1] in ROW_LEAVES else "recurrent_bytes"] += leaf.nbytes
+    return kinds
+
+
+# ---------------------------------------------------------------------- #
+# Attention over a cache of per-head keys and values                      #
+# ---------------------------------------------------------------------- #
+_LOW = jnp.finfo(jnp.float32).min
+
+
+def attention_step(q, rk, rv, pos, scale: float, dtype):
+    """One row, one token: q (1, KV, R, hd) over all its cache rows rk, rv (S, KV, hd), causal by ``pos`` (1,)."""
+    scores = jnp.einsum("tgrd,sgd->grts", q, rk, preferred_element_type=jnp.float32) * scale
+    seen = jnp.arange(rk.shape[0])[None, :] <= pos[:, None]         # (1, S): causal over the cache
+    probs = jax.nn.softmax(jnp.where(seen[None, None], scores, _LOW), axis=-1).astype(dtype)
+    return jnp.einsum("grts,sgd->tgrd", probs, rv, preferred_element_type=jnp.float32)
+
+
+def attention_chunk(q, block_of, positions, blocks, scale: float, dtype):
+    """A chunk of T queries a row, q (B, T, KV, R, hd) at ``positions`` (B, T),
+    over the ``blocks`` blocks of T cache rows that reach the deepest row's last
+    position (``block_of(j)`` -> that block's keys and values of every row, (B,
+    T, KV, hd) each), a running softmax between them: the work follows the
+    prefix held, not the positions a slot could hold. -> (B, T, KV, R, hd) float32."""
+    B, T, KV, R, hd = q.shape
+
+    def body(j, carry):
+        m, l, acc = carry                                           # (B, KV, R, T), (B, KV, R, T), (B, T, KV, R, hd)
+        kb, vb = block_of(j)
+        sc = jnp.einsum("btgrd,bsgd->bgrts", q, kb, preferred_element_type=jnp.float32) * scale
+        seen = (j * T + jnp.arange(T))[None, None, :] <= positions[:, :, None]      # (B, T, S)
+        sc = jnp.where(seen[:, None, None], sc, _LOW)
+        m_new = jnp.maximum(m, sc.max(-1))
+        w = jnp.exp(sc - m_new[..., None])
+        rescale = jnp.exp(m - m_new)
+        acc = acc * jnp.moveaxis(rescale, 3, 1)[..., None] + jnp.einsum(
+            "bgrts,bsgd->btgrd", w.astype(dtype), vb, preferred_element_type=jnp.float32)
+        return m_new, l * rescale + w.sum(-1), acc
+
+    init = (jnp.full((B, KV, R, T), _LOW), jnp.zeros((B, KV, R, T), jnp.float32),
+            jnp.zeros((B, T, KV, R, hd), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
+    return acc / jnp.moveaxis(l, 3, 1)[..., None]
+
+
+def attention_core(q, rows_k, rows_v, positions, scale: float, dtype):
+    """q (B, T, KV, R, hd) over these rows' cache rows_k, rows_v (B, S, KV, hd),
+    the new tokens' rows already written; positions (B, T). One token a row
+    attends every row held; a chunk attends block by block (every row of a call
+    is at the same chunk of its prompt, or past its end). -> (B, T, KV, R, hd) float32."""
+    T = q.shape[1]
+    if T == 1:
+        return jax.vmap(lambda q, rk, rv, pos: attention_step(q, rk, rv, pos, scale, dtype))(
+            q, rows_k, rows_v, positions)
+    rows = lambda j: (jax.lax.dynamic_slice_in_dim(rows_k, j * T, T, axis=1),  # noqa: E731
+                      jax.lax.dynamic_slice_in_dim(rows_v, j * T, T, axis=1))
+    return attention_chunk(q, rows, positions, jnp.max(positions[:, 0]) // T + 1, scale, dtype)
+
+
+# ---------------------------------------------------------------------- #
+# The causal conv of a recurrent mixer                                    #
+# ---------------------------------------------------------------------- #
+def conv_with_tail(tail, x, w, bias, lengths, dtype):
+    """Causal depthwise conv over [carried tail | this chunk], then SiLU: tail
+    (B, K - 1, C) the last inputs before the chunk, x (B, T, C), w (K, C), bias
+    (C,) or None, lengths (B,) valid steps of each row. -> (silu(conv) (B, T, C)
+    as ``dtype``, the tail after each row's last *valid* step: rows length ..
+    length + K - 2 of the padded input, so a row without a valid step keeps its
+    tail)."""
+    K, T = w.shape[0], x.shape[1]
+    padded = jnp.concatenate([tail, x], axis=1)                     # (B, K-1+T, C)
+    w = w.astype(jnp.float32)
+    conv = sum(padded[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K))
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
+    out = jax.nn.silu(conv).astype(dtype)
+    tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, K - 1, axis=0))(padded, lengths)
+    return out, tail
 
 
 # ---------------------------------------------------------------------- #

@@ -274,17 +274,10 @@ def _mamba(cfg, p, u, st, valid, lengths, single_step: bool):
     rows; valid (B, T); lengths (B,) valid steps of each row. -> (out, new st)."""
     B, T, _ = u.shape
     H, P, N, di = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.d_inner
-    K = cfg.mamba_d_conv
     zxbcdt = _mm(u, p["in_proj"])
     z, xbc, dt = jnp.split(zxbcdt, [di, di + cfg.conv_dim], axis=-1)
     xbc = xbc.astype(cfg.dtype)
-    # causal depthwise conv over [carried tail | this chunk]
-    padded = jnp.concatenate([st["conv"], xbc], axis=1)             # (B, K-1+T, C)
-    w = p["conv_w"].astype(jnp.float32)
-    conv = sum(padded[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K)) + p["conv_b"].astype(jnp.float32)
-    xbc = jax.nn.silu(conv).astype(cfg.dtype)
-    # the tail after the last *valid* step: rows length .. length+K-2 of `padded`
-    tail = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(row, n, K - 1, axis=0))(padded, lengths)
+    xbc, tail = decoders.conv_with_tail(st["conv"], xbc, p["conv_w"], p["conv_b"], lengths, cfg.dtype)
     x, b, c = jnp.split(xbc, [di, di + cfg.mamba_n_groups * N], axis=-1)
     x = x.reshape(B, T, H, P)
     dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
@@ -307,9 +300,10 @@ def _mamba(cfg, p, u, st, valid, lengths, single_step: bool):
 # ---------------------------------------------------------------------- #
 def _attention(cfg, p, u, rows_k, rows_v, positions, valid):
     """u (B, T, d); rows_k, rows_v (B, S, KV, hd): these rows' cache; positions
-    (B, T) of the new tokens; valid (B, T). -> (out, new rows_k, new rows_v)."""
+    (B, T) of the new tokens; valid (B, T). -> (out, new rows_k, new rows_v).
+    The core (scores, softmax, weighted values over the cache) is
+    ``decoders.attention_core``, shared with ``models/olmo_hybrid.py``."""
     B, T, _ = u.shape
-    S = rows_k.shape[1]
     KV, hd = cfg.num_key_value_heads, cfg.head_dim
     R = cfg.num_attention_heads // KV
     q = _mm(u, p["q"]).astype(cfg.dtype).reshape(B, T, KV, R, hd)
@@ -324,41 +318,7 @@ def _attention(cfg, p, u, rows_k, rows_v, positions, valid):
 
     rows_k, rows_v = write(rows_k, k), write(rows_v, v)
 
-    low = jnp.finfo(jnp.float32).min
-
-    def core_step(q, rk, rv, pos):  # one row, one token: (1, KV, R, hd) over all its cache rows (S, KV, hd)
-        scores = jnp.einsum("tgrd,sgd->grts", q, rk, preferred_element_type=jnp.float32) * cfg.attention_multiplier
-        seen = jnp.arange(S)[None, :] <= pos[:, None]               # (1, S): causal over the cache
-        probs = jax.nn.softmax(jnp.where(seen[None, None], scores, low), axis=-1).astype(cfg.dtype)
-        return jnp.einsum("grts,sgd->tgrd", probs, rv, preferred_element_type=jnp.float32)
-
-    def core_chunk(q, rk, rv, pos, blocks):
-        """One row's chunk over the blocks of ``T`` cache rows that reach its last
-        position, a running softmax between them: the work follows the prefix
-        held, not the positions a slot could hold."""
-        def body(j, carry):
-            m, l, acc = carry                                       # (KV, R, T), (KV, R, T), (T, KV, R, hd)
-            kb = jax.lax.dynamic_slice_in_dim(rk, j * T, T, axis=0)
-            vb = jax.lax.dynamic_slice_in_dim(rv, j * T, T, axis=0)
-            sc = jnp.einsum("tgrd,sgd->grts", q, kb, preferred_element_type=jnp.float32) * cfg.attention_multiplier
-            seen = (j * T + jnp.arange(T))[None, :] <= pos[:, None]
-            sc = jnp.where(seen[None, None], sc, low)
-            m_new = jnp.maximum(m, sc.max(-1))
-            w = jnp.exp(sc - m_new[..., None])
-            scale = jnp.exp(m - m_new)
-            acc = acc * jnp.moveaxis(scale, 2, 0)[..., None] + jnp.einsum(
-                "grts,sgd->tgrd", w.astype(cfg.dtype), vb, preferred_element_type=jnp.float32)
-            return m_new, l * scale + w.sum(-1), acc
-
-        init = (jnp.full((KV, R, T), low), jnp.zeros((KV, R, T), jnp.float32), jnp.zeros((T, KV, R, hd), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
-        return acc / jnp.moveaxis(l, 2, 0)[..., None]
-
-    if T == 1:
-        out = jax.vmap(core_step)(q, rows_k, rows_v, positions)
-    else:  # every row of a call is at the same chunk of its prompt, or past its end
-        blocks = jnp.max(positions[:, 0]) // T + 1
-        out = jax.vmap(core_chunk, in_axes=(0, 0, 0, 0, None))(q, rows_k, rows_v, positions, blocks)
+    out = decoders.attention_core(q, rows_k, rows_v, positions, cfg.attention_multiplier, cfg.dtype)
     out = out.astype(cfg.dtype).reshape(B, T, KV * R * hd)
     return _mm(out, p["o"]).astype(cfg.dtype), rows_k, rows_v
 

@@ -22,14 +22,16 @@ some slots over one chunk of their prompts with the true lengths known, for a
 SSM state and a conv tail beside them; ``models/longcat_flash`` keeps one
 latent row a token an attention (576 values where 64 heads' keys and values
 would be 20,480), writes only a prefill's chunk into it and reads only the
-blocks a chunk attends.
+blocks a chunk attends; ``models/olmo_hybrid`` keeps a delta rule's state and a
+conv tail in three layers of four and 30 heads' key/value rows in the fourth.
 
 **Prefill is chunked at one static length**, so prompts of any length share
 one executable: a prompt runs as ceil(P / chunk) calls that carry state, the
 last one right-padded. As many prompts advance in one call as bring it to
 about ``PREFILL_TOKENS`` tokens. Identical prompts admitted in the same round
-share one prefill through ``copy_state`` (prefix routing: requests are grouped
-by prompt hash before admission, the reference's do_prefix_routing analogue);
+share one prefill through ``copy_state`` (prefix routing: requests are ordered
+by length and prompt hash before admission, so identical prompts are adjacent,
+the reference's do_prefix_routing analogue, and a round's prompts are of like length);
 a later round prefills again, since recurrent state, unlike key/value rows,
 has moved on with its slot.
 
@@ -44,7 +46,8 @@ step, e.g. ``moe.held_assignments``), ``serve.fetch`` (the step's one fetch).
 ``serve.prefill`` and ``serve.decode_step`` also carry what the model noted on
 them while its program traced (``moe`` = ``grouped`` | ``xla``: the path of the
 routed experts' grouped products, ``models/decoders.grouped_mlp``; ``mla`` =
-``fused`` | ``expanded`` | ``absorbed``: the latent attention's, ``models/longcat_flash``).
+``fused`` | ``expanded`` | ``absorbed``: the latent attention's, ``models/longcat_flash``;
+``delta`` = ``chunked`` | ``recurrent``: the gated delta rule's, ``models/olmo_hybrid``).
 """
 
 from __future__ import annotations
@@ -150,7 +153,7 @@ class ContinuousBatcher:
 
     def _repeat_noted(self, sp) -> None:
         """A model notes its choice of path on the open span while its program
-        traces (``decoders.grouped_mlp``: ``moe``; ``longcat_flash``: ``mla``); a
+        traces (``decoders.grouped_mlp``: ``moe``; ``longcat_flash``: ``mla``; ``olmo_hybrid``: ``delta``); a
         call that traces nothing repeats what the trace chose."""
         noted = self._noted.setdefault(sp.name, {})
         noted.update({k: v for k, v in sp.count.items() if isinstance(v, str)})
@@ -249,8 +252,11 @@ class ContinuousBatcher:
                 r.prefix_key = hashlib.blake2b(
                     np.ascontiguousarray(r.tokens).tobytes(),
                     digest_size=8).hexdigest()
-        # Prefix routing: adjacent identical prompts share prefills.
-        queue.sort(key=lambda r: (r.prefix_key, r.request_id))
+        # Prefix routing: identical prompts (one length, one key) are adjacent and share a prefill. Distinct prompts
+        # are admitted in order of length: a call runs as many chunks as its longest row, so which prompts share a
+        # round, and with it a call's work, must not hang on how their hashes fall (16 documents of 1-15 thousand
+        # tokens on 8 slots: 55 calls of 4 x 512 a partition in this order, 55 to 69 by hash, PERF.md section 6).
+        queue.sort(key=lambda r: (len(r.tokens), r.prefix_key, r.request_id))
         queue.reverse()  # pop() admits in sorted order
         results: Dict[int, _Slot] = {}
         steps = 0

@@ -114,7 +114,8 @@ def test_chip_smoke_tiny_cpu_is_a_dry_run():
     assert rec["chip_smoke"] == "dry" and '"ok"' not in proc.stdout
     assert rec["platform"] == "cpu"
     assert set(rec["phases"]) == {"A_embed_image", "A_jpeg_host_stage_ahead", "B_embed_text", "C_prompt",
-                                  "C_prompt_hybrid", "C_prompt_longcat", "D_device_chain", "E_pallas"}
+                                  "C_prompt_hybrid", "C_prompt_longcat", "C_prompt_olmo", "D_device_chain", "E_pallas"}
+    assert rec["phases"]["C_prompt_olmo"]["delta"] == {"serve.prefill": "chunked", "serve.decode_step": "recurrent"}
     assert len(rec["phases"]["A_jpeg_host_stage_ahead"]["ready"]) == 2  # two morsels through the host stage
     kernel = rec["phases"]["C_prompt_longcat"]["mla_kernel"]  # interpreted here; the tiny model itself stays expanded
     assert kernel["shape"][2:] == [2, 517] and 0 < kernel["max_abs_diff_vs_expanded"] < 3e-2

@@ -322,3 +322,33 @@ def test_an_unknown_name_with_the_cuts_options_is_an_error():
                                            expert_shard=(0, 2), vocab_shard=(0, 2))
     assert (cfg.hidden_size, cfg.held_experts, cfg.held_vocab, cfg.num_experts_per_tok) == (4096, 36, 50176, 10)
     assert cfg.layer_types == ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+# -- the attention core this decoder shares with ``models/olmo_hybrid.py`` (``decoders.attention_core``) -----
+@pytest.mark.parametrize("kv_heads,queries", [(8, 4), (30, 1)])
+def test_the_shared_attention_core_against_a_plain_softmax(kv_heads, queries):
+    """Granite's 8 key/value heads x 4 queries and Olmo-Hybrid's 30 x 1: a chunk over the blocks held (the second
+    chunk of each row: two blocks, a running softmax between them) and one token over every row held, each against
+    a plain causal softmax in float32 over the same bfloat16 inputs; 2e-2 of outputs that spread ~0.3 is what the
+    probabilities' rounding to bfloat16 before the weighted values costs (readings up to 6e-3)."""
+    from daft_tpu.models import decoders
+
+    B, T, S, hd, scale = 2, 16, 48, 32, 32 ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(kv_heads), 3)
+    q = jax.random.normal(ks[0], (B, T, kv_heads, queries, hd)).astype(jnp.bfloat16)
+    rows_k = jax.random.normal(ks[1], (B, S, kv_heads, hd)).astype(jnp.bfloat16)
+    rows_v = jax.random.normal(ks[2], (B, S, kv_heads, hd)).astype(jnp.bfloat16)
+
+    def plain(q, positions):
+        sc = jnp.einsum("btgrd,bsgd->bgrts", q.astype(jnp.float32), rows_k.astype(jnp.float32)) * scale
+        seen = jnp.arange(S)[None, None, :] <= positions[:, :, None]
+        probs = jax.nn.softmax(jnp.where(seen[:, None, None], sc, -jnp.inf), axis=-1)
+        return jnp.einsum("bgrts,bsgd->btgrd", probs, rows_v.astype(jnp.float32))
+
+    positions = T + jnp.arange(T)[None, :] + jnp.zeros((B, 1), jnp.int32)
+    got = decoders.attention_core(q, rows_k, rows_v, positions, scale, jnp.bfloat16)
+    assert got.shape == (B, T, kv_heads, queries, hd) and got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - plain(q, positions)))) < 2e-2
+    one = jnp.asarray([[40], [7]], jnp.int32)  # one token a row, rows at unlike depths
+    got = decoders.attention_core(q[:, :1], rows_k, rows_v, one, scale, jnp.bfloat16)
+    assert float(jnp.max(jnp.abs(got - plain(q[:, :1], one)))) < 2e-2

@@ -510,7 +510,7 @@ def test_names_and_cuts_are_looked_up_in_one_record():
     from daft_tpu.ai import flax_provider
     from daft_tpu.ai.flax_provider import FlaxPrompter
 
-    assert {"LongCat-Flash-Chat", TINY, "granite-4.0-h-small", "granite-hybrid-tiny"} == set(decoders.DECODERS)
+    assert {"LongCat-Flash-Chat", TINY, "granite-4.0-h-small", "granite-hybrid-tiny"} < set(decoders.DECODERS)
     assert set(flax_provider.CUT_OPTIONS) == {"num_layers", "num_hidden_layers", "expert_shard", "vocab_shard"}
     assert set(flax_provider.CUT_OPTIONS) < set(flax_provider.PROMPTER_OPTIONS)
     with pytest.raises(DaftValueError, match="LongCat-Flash-Chat.*granite-4.0-h-small"):

@@ -142,6 +142,28 @@ def test_prefill_span_counts_the_block_rows_that_hold_a_query(lm, lengths, slots
     assert count["block_rows"] <= count["padded_block_rows"]
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_rounds_are_admitted_in_order_of_length_whatever_the_hashes(lm, seed):
+    """Eight prompts of unlike length on four slots, in an order and with tokens (so hashes) from the seed: the
+    first round is the four shortest, the second the four longest, and a call's chunks follow its round's longest
+    prompt; two identical prompts still meet in one round and share one prefill."""
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    model, params = lm
+    rng = np.random.default_rng(seed)
+    lengths = [3, 5, 6, 8, 17, 18, 23, 23]
+    twin = rng.integers(3, model.cfg.vocab_size, 23).astype(np.int32)
+    prompts = [rng.integers(3, model.cfg.vocab_size, n).astype(np.int32) for n in lengths[:6]] + [twin, twin.copy()]
+    began = span_clock_ns()
+    b = ContinuousBatcher(model, params, num_slots=4, prefill_chunk=4)
+    b.run([Request(tokens=prompts[i], max_new_tokens=2) for i in rng.permutation(8)])
+    spans = [s.count for s in recent_device_spans() if s.name == "serve.prefill" and s.start_ns >= began]
+    assert [(c["rows"], c["tokens"], c["chunks"]) for c in spans] == [(4, 3 + 5 + 6 + 8, 2), (3, 17 + 18 + 23, 6)]
+    copies = [s for s in recent_device_spans() if s.name == "serve.copy_state" and s.start_ns >= began]
+    assert len(copies) == 1
+
+
 def test_llm_generate_through_engine():
     """llm_generate end-to-end over the continuous-batching prompter."""
     import daft_tpu.functions as F
