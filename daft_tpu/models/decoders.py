@@ -211,8 +211,8 @@ def attention_chunk(q, block_of, positions, blocks, scale: float, dtype):
 def attention_core(q, rows_k, rows_v, positions, scale: float, dtype):
     """q (B, T, KV, R, hd) over these rows' cache rows_k, rows_v (B, S, KV, hd),
     the new tokens' rows already written; positions (B, T). One token a row
-    attends every row held; a chunk attends block by block (every row of a call
-    is at the same chunk of its prompt, or past its end). -> (B, T, KV, R, hd) float32."""
+    attends every row held; a chunk attends block by block (each row of a call at
+    its own depth: blocks beyond a row's own weigh 0). -> (B, T, KV, R, hd) float32."""
     T = q.shape[1]
     if T == 1:
         return jax.vmap(lambda q, rk, rv, pos: attention_step(q, rk, rv, pos, scale, dtype))(

@@ -333,8 +333,8 @@ def mla_core_expanded(cfg, w_kvb, q, block_of, blocks, positions):
 def mla_expanded_over_slots(cfg, w_kvb, q, kv, slots, starts):
     """``mla_core_expanded`` for the rows ``slots`` of ``kv`` (slots, cache_row, S),
     each at the chunk that begins at ``starts``: every row over the blocks of T
-    positions that the call's deepest row attends (every row of a call is at the
-    same chunk of its prompt, or past its end)."""
+    positions that the call's deepest row attends (each row of a call at its own
+    depth: the blocks beyond a row's own positions are masked and weigh 0)."""
     B, T = q.shape[:2]
 
     def block_of(j):

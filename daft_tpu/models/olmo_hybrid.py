@@ -387,7 +387,7 @@ def _attn_prefill(cfg, p, u, st, slots, starts, lengths):
         q, k, v = _attn_project(cfg, p, u)
     with jax.named_scope("attn_core"):
         # The chunk's valid rows go into the slots' rows in place; the core then walks the blocks that the call's
-        # deepest row attends (every row of a call is at the same chunk of its prompt, or past its end), each block
+        # deepest row attends (rows of a call stand at unlike depths: blocks beyond a row's own weigh 0), each block
         # read from the slots' rows where they lie (``ops/pallas_cache_blocks.py`` has why both are kernels on a TPU).
         cache_k = pallas_cache_blocks.write_blocks(st["k"], k, slots, starts, lengths)
         cache_v = pallas_cache_blocks.write_blocks(st["v"], v, slots, starts, lengths)

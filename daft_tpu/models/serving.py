@@ -28,18 +28,26 @@ conv tail in three layers of four and 30 heads' key/value rows in the fourth.
 **Prefill is chunked at one static length**, so prompts of any length share
 one executable: a prompt runs as ceil(P / chunk) calls that carry state, the
 last one right-padded. As many prompts advance in one call as bring it to
-about ``PREFILL_TOKENS`` tokens. Identical prompts admitted in the same round
-share one prefill through ``copy_state`` (prefix routing: requests are ordered
-by length and prompt hash before admission, so identical prompts are adjacent,
-the reference's do_prefix_routing analogue, and a round's prompts are of like length);
-a later round prefills again, since recurrent state, unlike key/value rows,
-has moved on with its slot.
+about ``PREFILL_TOKENS`` tokens, **each at its own depth**: a call takes the
+next chunk of whichever prompts of the admission round still have one, those
+with the most chunks left first (``prefill_schedule``), so a round runs
+max(its longest prompt's chunks, ceil(its prompts' chunks / prompts a call))
+calls, the fewest any schedule can, whatever the spread of its lengths; a
+model's ``prefill`` takes the chunk's first position row by row. Identical
+prompts admitted in the same round share one prefill through ``copy_state``
+(prefix routing: requests are ordered by length and prompt hash before
+admission, so identical prompts are adjacent, the reference's
+do_prefix_routing analogue, and which prompts share a round does not hang on
+their hashes); a later round prefills again, since recurrent state, unlike
+key/value rows, has moved on with its slot.
 
-Spans (``profiling.device_span``): ``serve.prefill`` (one group's chunks:
-``slot``, ``rows``, ``tokens``, ``padded_tokens``, ``chunks``, ``row_chunks``, ``first``;
-``pairs``: the causal (query, key) pairs of the group's prompts, an attention's least work;
+Spans (``profiling.device_span``): ``serve.prefill`` (one admission round's calls:
+``slot``, ``rows``: the prompts prefilled, ``tokens``, ``chunks``: the calls, ``padded_tokens`` = calls x prompts
+a call x ``chunk``, ``row_chunks``, ``first``; ``mixed_calls``: the calls whose prompts stood at unlike depths;
+``pairs``: the causal (query, key) pairs of the round's prompts, an attention's least work;
 ``block_rows``: the (row, block of ``chunk`` positions) pairs its calls attend in which the row holds a query,
-of ``padded_block_rows`` = rows a call x chunks x (chunks + 1) / 2 that calls of static shape span),
+of ``padded_block_rows`` = sum over calls of rows a call x (the deepest row's block + 1) that calls of static
+shape span),
 ``serve.copy_state``,
 ``serve.decode_step`` (``active``, ``slots``, and the model's counts of the
 step, e.g. ``moe.held_assignments``), ``serve.fetch`` (the step's one fetch).
@@ -54,7 +62,7 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +85,24 @@ class _Slot:
     generated: List[int] = field(default_factory=list)
     logprobs: List[float] = field(default_factory=list)
     remaining: int = 0
+
+
+def prefill_schedule(chunks: Sequence[int], rows: int) -> List[List[Tuple[int, int]]]:
+    """The prefill calls of one admission round whose prompt ``i`` runs
+    ``chunks[i]`` chunks: each call is up to ``rows`` pairs (prompt, the chunk
+    of it that the call runs), the prompts with the most chunks left first, ties
+    to the earlier prompt. A prompt gives a call at most one chunk (chunk c + 1
+    reads what chunk c wrote), so no schedule has fewer calls than
+    max(max(chunks), ceil(sum(chunks) / rows)), and this one has as many."""
+    done = [0] * len(chunks)
+    calls = []
+    while True:
+        call = sorted((i for i, n in enumerate(chunks) if done[i] < n), key=lambda i: (done[i] - chunks[i], i))[:rows]
+        if not call:
+            return calls
+        calls.append([(i, done[i]) for i in call])
+        for i in call:
+            done[i] += 1
 
 
 class ContinuousBatcher:
@@ -167,8 +193,8 @@ class ContinuousBatcher:
     # -- admission ------------------------------------------------------- #
     def _admit(self, queue: List[Request], free: List[int]) -> None:
         """Fill free slots from the queue: one prefill for each distinct
-        prompt of the round, ``prefill_rows`` prompts a call, then state
-        copies for the repeats."""
+        prompt of the round, all of them through the same packed calls, then
+        state copies for the repeats."""
         first: Dict[str, int] = {}  # prefix key -> the slot that prefills it, this round
         todo, copies = [], []
         for slot in free:
@@ -181,46 +207,46 @@ class ContinuousBatcher:
                 todo.append((req, slot))
             else:
                 copies.append((req, src, slot))
-        # Prompts of like length share a call: it runs as many chunks as its longest needs.
-        todo.sort(key=lambda pair: len(pair[0].tokens))
-        for i in range(0, len(todo), self.prefill_rows):
-            self._prefill_group(todo[i:i + self.prefill_rows])
+        self._prefill_round(todo)
         for req, src, dst in copies:
             with device_span("serve.copy_state", src=src, slot=dst):
                 self.state, self.cur_logits = self._copy(self.state, self.cur_logits,
                                                          jnp.int32(src), jnp.int32(dst))
             self._admit_host(req, dst)
 
-    def _prefill_group(self, group) -> None:
+    def _prefill_round(self, todo) -> None:
+        """Prefill the round's prompts (``todo``: (request, slot), in order of
+        slot) in the calls ``prefill_schedule`` gives: every row of a call at
+        its own chunk of its own prompt."""
         g, T = self.prefill_rows, self.chunk
-        lens = np.zeros((g,), np.int64)
-        lens[:len(group)] = [len(req.tokens) for req, _ in group]
-        taken = [slot for _, slot in group]
-        # Rows of the call that carry no prompt name other slots (length 0: left as they are).
-        spare = [s for s in range(self.B) if s not in taken]
-        slots = np.asarray(taken + spare[:g - len(group)], np.int32)
-        chunks = max(1, -(-int(lens.max()) // T))
+        lens = np.asarray([len(req.tokens) for req, _ in todo], np.int64)
         row_chunks = -(-lens // T)
-        with device_span("serve.prefill", slot=int(slots[0]), rows=len(group), tokens=int(lens.sum()),
-                         padded_tokens=g * T * chunks, chunks=chunks,
+        calls = prefill_schedule(row_chunks.tolist(), g)
+        with device_span("serve.prefill", slot=todo[0][1], rows=len(todo), tokens=int(lens.sum()),
+                         padded_tokens=g * T * len(calls), chunks=len(calls),
+                         mixed_calls=sum(len({c for _, c in call}) > 1 for call in calls),
                          row_chunks=int(row_chunks.sum()), pairs=int((lens * (lens + 1) // 2).sum()),
                          block_rows=int((row_chunks * (row_chunks + 1) // 2).sum()),
-                         padded_block_rows=g * chunks * (chunks + 1) // 2) as sp:
+                         padded_block_rows=g * sum(max(c for _, c in call) + 1 for call in calls)) as sp:
             if self._prefill is None:
                 sp.count["first"] = 1  # this call traces and compiles (or loads) the program
             fn = self._prefill_fn()
-            for c in range(chunks):
+            for call in calls:
+                taken = [todo[i][1] for i, _ in call]
+                # Rows of the call that carry no prompt name other slots, at length 0 (left as they are) and from
+                # position 0 (an attention's block loop runs as deep as the call's deepest row). Such a call holds
+                # every prompt of the round that still has a chunk, so none of those slots is in the middle of one.
+                spare = [s for s in range(self.B) if s not in taken][:g - len(call)]
                 tokens = np.zeros((g, T), np.int32)
-                for i, (req, _) in enumerate(group):
-                    part = req.tokens[c * T:(c + 1) * T]
-                    tokens[i, :len(part)] = part
-                here = np.clip(lens - c * T, 0, T).astype(np.int32)
-                final = (lens > c * T) & (lens <= (c + 1) * T)
-                self.state, self.cur_logits = fn(
-                    self.params, self.state, self.cur_logits, tokens, slots,
-                    np.full((g,), c * T, np.int32), here, final)
+                starts, here, final = np.zeros((g,), np.int32), np.zeros((g,), np.int32), np.zeros((g,), bool)
+                for row, (i, c) in enumerate(call):
+                    part = todo[i][0].tokens[c * T:(c + 1) * T]
+                    tokens[row, :len(part)] = part
+                    starts[row], here[row], final[row] = c * T, len(part), c == row_chunks[i] - 1
+                self.state, self.cur_logits = fn(self.params, self.state, self.cur_logits, tokens,
+                                                 np.asarray(taken + spare, np.int32), starts, here, final)
             self._repeat_noted(sp)
-        for req, slot in group:
+        for req, slot in todo:
             self._admit_host(req, slot)
 
     def _admit_host(self, req: Request, slot: int) -> None:
@@ -253,9 +279,9 @@ class ContinuousBatcher:
                     np.ascontiguousarray(r.tokens).tobytes(),
                     digest_size=8).hexdigest()
         # Prefix routing: identical prompts (one length, one key) are adjacent and share a prefill. Distinct prompts
-        # are admitted in order of length: a call runs as many chunks as its longest row, so which prompts share a
-        # round, and with it a call's work, must not hang on how their hashes fall (16 documents of 1-15 thousand
-        # tokens on 8 slots: 55 calls of 4 x 512 a partition in this order, 55 to 69 by hash, PERF.md section 6).
+        # are admitted in order of length: a round runs at least as many calls as its longest prompt has chunks, so
+        # which prompts share a round, and with it a partition's calls, must not hang on how their hashes fall (16
+        # documents of 1-15 thousand tokens on 8 slots: 11 + 31 calls of 4 x 512 in this order, PERF.md section 6).
         queue.sort(key=lambda r: (len(r.tokens), r.prefix_key, r.request_id))
         queue.reverse()  # pop() admits in sorted order
         results: Dict[int, _Slot] = {}
