@@ -49,8 +49,12 @@ a call x ``chunk``, ``row_chunks``, ``first``; ``mixed_calls``: the calls whose 
 of ``padded_block_rows`` = sum over calls of rows a call x (the deepest row's block + 1) that calls of static
 shape span),
 ``serve.copy_state``,
-``serve.decode_step`` (``active``, ``slots``, and the model's counts of the
-step, e.g. ``moe.held_assignments``), ``serve.fetch`` (the step's one fetch).
+``serve.decode_step`` (one turn of the decode loop, from before the key split to after the step's bookkeeping:
+``active``, ``slots``, the model's counts of the step, e.g. ``moe.held_assignments``, and ``first`` on the step
+that traces and compiles or loads the program) with two children, ``serve.dispatch`` (the call of the decode program,
+from entry to return) and ``serve.fetch`` (the step's one fetch: ``arrays``, the leaves it brings). Between the
+first and the last step of a run every instant of the loop lies in one of ``serve.decode_step``, ``serve.prefill``
+and ``serve.copy_state``; what of a step lies in neither child is its own, before the dispatch or after the fetch.
 ``serve.prefill`` and ``serve.decode_step`` also carry what the model noted on
 them while its program traced (``moe`` = ``grouped`` | ``xla``: the path of the
 routed experts' grouped products, ``models/decoders.grouped_mlp``; ``mla`` =
@@ -152,6 +156,7 @@ class ContinuousBatcher:
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._copy = jax.jit(self._copy_impl, donate_argnums=(0, 1))
         self.decode_steps = 0
+        self._fetched_arrays = 0  # leaves a step's fetch brings, a constant of the program: counted on the first step
         self._noted: Dict[str, dict] = {}  # span name -> what the model noted while that program traced
 
     # -- jitted programs ------------------------------------------------- #
@@ -290,26 +295,35 @@ class ContinuousBatcher:
             free = [i for i in range(self.B) if self.slots[i].request is None]
             if free and queue:
                 self._admit(queue, free)
-            # One decode step for the whole pool.
+            # One decode step for the whole pool: the span is one turn of the loop, and whatever of it lies in
+            # neither child is the step's own, told by where it lies (before the dispatch: the key split; after
+            # the fetch: the bookkeeping).
             with device_span("serve.decode_step", active=int(self.active.sum()), slots=self.B) as sp:
+                first = not self._fetched_arrays
+                if first:
+                    sp.count["first"] = 1  # this step traces and compiles (or loads) the program
                 self._key, sub = jax.random.split(self._key)
-                self.state, self.cur_logits, out = self._decode(
-                    self.params, self.state, self.cur_logits, self.positions, self.active, sub)
-                with device_span("serve.fetch"):
+                with device_span("serve.dispatch"):
+                    self.state, self.cur_logits, out = self._decode(
+                        self.params, self.state, self.cur_logits, self.positions, self.active, sub)
+                with device_span("serve.fetch") as fetch:
                     out = jax.device_get(out)  # the step's one wait for the device
+                    if first:
+                        self._fetched_arrays = len(jax.tree_util.tree_leaves(out))
+                    fetch.count["arrays"] = self._fetched_arrays
                 sp.count.update({f"moe.{k}": int(v) for k, v in out["counts"].items()})
                 self._repeat_noted(sp)
-            steps += 1
-            self.positions += self.active
-            for slot in np.flatnonzero(self.active):
-                s = self.slots[slot]
-                t = int(out["tok"][slot])
-                s.generated.append(t)
-                s.logprobs.append(float(out["logprob"][slot]))
-                s.remaining -= 1
-                if t == self.eos_id or s.remaining <= 0 \
-                        or self.positions[slot] >= self.S - 1:
-                    self._retire(int(slot), results)
+                steps += 1
+                self.positions += self.active
+                for slot in np.flatnonzero(self.active):
+                    s = self.slots[slot]
+                    t = int(out["tok"][slot])
+                    s.generated.append(t)
+                    s.logprobs.append(float(out["logprob"][slot]))
+                    s.remaining -= 1
+                    if t == self.eos_id or s.remaining <= 0 \
+                            or self.positions[slot] >= self.S - 1:
+                        self._retire(int(slot), results)
         self.decode_steps = steps
         done = [results.get(i, _Slot()) for i in range(len(requests))]
         self.last_logprobs = [s.logprobs for s in done]

@@ -36,6 +36,11 @@ step-timeline profiler demonstrated a dataflow engine needs:
   the device path, on whether or not a query is profiled) and, under an
   ambient :class:`TaskProfiler`, also in the query's trace as a child of
   the operator span.
+* **Compile log** — every program JAX traces, lowers, compiles or loads
+  from its persistent cache is logged beside the ring
+  (:func:`recent_compiles`) with the device span open on that thread, and
+  its seconds are counters of that span: why a first row took a minute,
+  and which span compiled in steady state.
 
 Spans are ALWAYS opened through context managers (daftlint DTL009): an
 un-ended span silently drops from export and leaks the thread-local parent
@@ -813,16 +818,20 @@ class device_span:
         return {k: getattr(self, k) for k in self.__slots__[:-1]}
 
 
+def _copied(ring: deque) -> list:
+    while True:
+        try:
+            return list(ring)
+        except RuntimeError:  # another thread appended during the copy
+            continue
+
+
 def recent_device_spans() -> List[device_span]:
     """A copy of the ring: the newest ``DEVICE_SPAN_RING`` finished device-path
     spans of this process, oldest first (by the time they closed, so a parent
     follows its children). Read it after a slow or stalled batch, or after a
     measured window, without having asked for a profile beforehand."""
-    while True:
-        try:
-            return list(_device_ring)
-        except RuntimeError:  # another thread appended during the copy
-            continue
+    return _copied(_device_ring)
 
 
 def newest_device_span(name: str) -> Optional[device_span]:
@@ -837,6 +846,80 @@ def open_device_span(name: str) -> Optional[device_span]:
     of path while its forward traces is a counter of ``provider.forward``)."""
     open_spans = getattr(_tls, "device_spans", ())
     return next((sp for sp in reversed(open_spans) if sp.name == name), None)
+
+
+# --------------------------------------------------------------------- #
+# Compile log (which program was traced, lowered, compiled or loaded)   #
+# --------------------------------------------------------------------- #
+#: The newest entries of the compile log: three a program compiled and one a function traced outside any program
+#: (every ``jnp`` call under ``eval_shape``): some 2,400 by a decoder's first steady row, most of them such traces.
+COMPILE_LOG = 16384
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+#: ``jax.monitoring``'s duration events -> (the log's kind, the seconds' and the count's counter on the open span).
+_COMPILE_EVENTS = {
+    _TRACE_EVENT: ("trace", "trace_s", None),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lower", "lower_s", None),
+    "/jax/core/compile/backend_compile_duration": ("compile", "compile_s", "compiles"),
+    "/jax/compilation_cache/cache_retrieval_time_sec": ("cache_load", "cache_load_s", "cache_loads"),
+}
+_compile_log: "deque[Tuple[int, str, float, str]]" = deque(maxlen=COMPILE_LOG)
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
+    """One stage of bringing a program to the device, as JAX reports it on the
+    thread that asked for the program: logged, and added to the counters of the
+    innermost device span open on that thread (``serve.dispatch`` for the decode
+    program's first call, ``provider.forward`` for an embedder's)."""
+    found = _COMPILE_EVENTS.get(event)
+    if found is None:
+        return
+    kind, seconds_key, count_key = found
+    if kind == "trace":
+        _tls.tracing = depth = max(getattr(_tls, "tracing", 0) - 1, 0)
+        if depth:
+            return  # a jitted function traced inside another's trace, whose seconds hold this one's
+    elif kind == "cache_load":
+        _tls.loaded_from_cache = True
+    elif kind == "compile" and getattr(_tls, "loaded_from_cache", False):
+        _tls.loaded_from_cache = False
+        return  # JAX times the backend around its cache too: this is the load just logged, not a compile
+    seconds = float(seconds)
+    open_spans = getattr(_tls, "device_spans", None)
+    sp = open_spans[-1] if open_spans else None
+    _compile_log.append((span_clock_ns(), kind, seconds, sp.name if sp is not None else ""))
+    if sp is not None:
+        sp.count[seconds_key] = sp.count.get(seconds_key, 0.0) + seconds
+        if count_key:
+            sp.count[count_key] = sp.count.get(count_key, 0) + 1
+
+
+def _on_jax_scalar(event: str, _value, **_kw) -> None:
+    """JAX raises a stage's event as a scalar when the stage begins: traces nest
+    (every ``jnp`` function is jitted), and only the outermost is logged."""
+    if event == _TRACE_EVENT:
+        _tls.tracing = getattr(_tls, "tracing", 0) + 1
+
+
+def recent_compiles() -> List[Tuple[int, str, float, str]]:
+    """The compile log, oldest first: ``(span_clock_ns() when the stage ended,
+    kind, seconds, name of the innermost device span open on that thread or
+    "")`` for every program this process traced (``trace``), lowered
+    (``lower``), compiled (``compile``) or loaded from the persistent compile
+    cache (``cache_load``). Read it after a first row that took a minute, or
+    after a steady state that should have compiled nothing; the same seconds
+    are on the spans (``trace_s``, ``lower_s``, ``compile_s``, ``cache_load_s``,
+    ``compiles``, ``cache_loads``), so ``collect(profile=...)`` shows them."""
+    return _copied(_compile_log)
+
+
+if not globals().get("_listening"):  # a reload keeps the module's dictionary, and JAX the listener
+    import jax.monitoring
+
+    # Looked up by name at each event, so that a reloaded module's functions and log are the ones written to.
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, seconds, **kw: _on_jax_duration(event, seconds, **kw))
+    jax.monitoring.register_scalar_listener(lambda event, value, **kw: _on_jax_scalar(event, value, **kw))
+    _listening = True
 
 
 def span_clock_offset_ns() -> int:

@@ -363,3 +363,155 @@ def test_named_scopes_change_op_name_only(monkeypatch):
     # every module of the block is named by Flax without our help
     for scope in ("block_1/ln1/", "block_1/attn/qkv/", "block_1/attn/out/", "block_1/mlp/fc1/", "ln_post/"):
         assert f"/vision/{scope}" in without
+
+
+# -- the decode loop's spans (PR 37) ------------------------------------------------
+SERVING = ("serve.decode_step", "serve.prefill", "serve.copy_state")
+
+
+@pytest.fixture(scope="module")
+def two_waves():
+    """``FlaxPrompter.prompt`` over six prompts (two of them alike) on two slots, twice: the spans of each call."""
+    from daft_tpu.ai.flax_provider import FlaxPrompter
+
+    inst = FlaxPrompter("tiny", max_new_tokens=3, num_slots=2, ignore_eos=True)
+    docs = ["a b", "e f g h i j", "k l m", "a b", "m n o p q", "r s t u v w x"]  # the two alike are admitted together
+    calls = []
+    for _ in range(2):
+        mark = _mark()
+        inst.prompt(docs)
+        calls.append(_since(mark))
+    return calls
+
+
+def _under(spans, parent):
+    return sorted((s for s in spans if s.parent == parent.span_id), key=lambda s: s.start_ns)
+
+
+def test_from_the_first_decode_step_to_the_last_the_loop_lies_in_one_serving_span(two_waves):
+    for spans in two_waves:
+        (run,) = _named(spans, "prompt.run")
+        top = _under(spans, run)
+        steps = _named(top, "serve.decode_step")
+        assert len(steps) == run.count["decode_steps"] >= 6 and len(_named(top, "serve.prefill")) >= 3
+        assert _named(top, "serve.copy_state")  # the two prompts alike shared a prefill
+        # nothing else hangs below ``prompt.run``, no two of them overlap, and all are on its thread
+        assert {s.name for s in top} == set(SERVING) and {s.thread for s in top} == {run.thread}
+        assert all(a.end_ns <= b.start_ns for a, b in zip(top, top[1:]))
+        assert run.start_ns <= top[0].start_ns and top[-1].end_ns <= run.end_ns and top[-1].name == "serve.decode_step"
+
+
+def test_each_decode_step_has_one_dispatch_and_one_fetch_in_that_order(two_waves):
+    for spans in two_waves:
+        for step in _named(spans, "serve.decode_step"):
+            dispatch, fetch = _under(spans, step)
+            assert (dispatch.name, fetch.name) == ("serve.dispatch", "serve.fetch")
+            assert step.start_ns <= dispatch.start_ns <= dispatch.end_ns <= fetch.start_ns <= fetch.end_ns <= step.end_ns
+            assert fetch.count == {"arrays": 2}  # tok and logprob: counted on the first step, a constant after it
+            assert step.count["slots"] == 2 and 1 <= step.count["active"] <= 2
+
+
+def test_first_is_on_the_batchers_first_decode_step_alone(two_waves):
+    firsts = [[s.count.get("first", 0) for s in _named(spans, "serve.decode_step")] for spans in two_waves]
+    assert firsts[0][0] == 1 and not any(firsts[0][1:]) and not any(firsts[1])
+    # that step traced and compiled the decode program, under its dispatch: the compile log's counters say so
+    dispatch = _named(two_waves[0], "serve.dispatch")
+    assert dispatch[0].count["compiles"] >= 1 and dispatch[0].count["compile_s"] > 0 and dispatch[0].count["trace_s"] > 0
+    assert not any("compile_s" in s.count for s in dispatch[1:] + _named(two_waves[1], "serve.dispatch"))
+
+
+def test_the_steps_bookkeeping_and_key_split_run_inside_the_step(monkeypatch):
+    """One span is one turn of the loop: the key split before the dispatch and the retiring of finished slots after
+    the fetch happen under ``serve.decode_step``, admission's part on the host under no serving span."""
+    import jax
+
+    from daft_tpu.models.lm import DecoderLMConfig, init_lm_params
+    from daft_tpu.models.serving import ContinuousBatcher, Request
+
+    seen = []
+
+    def noting(what, fn):
+        def wrapped(*a, **kw):
+            seen.append((what, [s.name for s in (profiling.open_device_span(n) for n in SERVING + ("serve.dispatch", "serve.fetch")) if s]))
+            return fn(*a, **kw)
+        return wrapped
+
+    monkeypatch.setattr(jax.random, "split", noting("split", jax.random.split))
+    monkeypatch.setattr(ContinuousBatcher, "_retire", noting("retire", ContinuousBatcher._retire))
+    monkeypatch.setattr(ContinuousBatcher, "_admit_host", noting("admit", ContinuousBatcher._admit_host))
+    model, params = init_lm_params(DecoderLMConfig.tiny(), seed=0)
+    b = ContinuousBatcher(model, params, num_slots=2, eos_id=None)
+    b.run([Request(tokens=np.arange(3, 3 + n, dtype=np.int32), max_new_tokens=2) for n in (4, 6, 5)])
+    by = {what: [open_ for w, open_ in seen if w == what] for what in ("split", "retire", "admit")}
+    assert len(by["split"]) == b.decode_steps == 4 and len(by["retire"]) == 3 and len(by["admit"]) == 3
+    assert by["split"] == [["serve.decode_step"]] * 4 and by["retire"] == [["serve.decode_step"]] * 3
+    assert by["admit"] == [[]] * 3
+
+
+# -- the compile log (PR 37) --------------------------------------------------------
+def _logged_since(t_ns: int):
+    """By time and not by place: the log is the process's, bounded, and other tests of this worker fill it."""
+    return [row for row in profiling.recent_compiles() if row[0] >= t_ns]
+
+
+def test_a_new_shape_is_logged_with_the_open_spans_name_and_counted_on_it():
+    import jax
+    import jax.numpy as jnp
+
+    fn = jax.jit(lambda a: (a * 3 + 1).sum())
+    with device_span("test.outer") as outer:
+        with device_span("test.compiles") as sp:
+            fn(jnp.ones((37,)))
+    mine = _logged_since(outer.start_ns)
+    assert {kind for _, kind, _, _ in mine} == {"trace", "lower", "compile"}
+    assert {name for _, _, _, name in mine} == {"test.compiles"}  # the innermost open span, not its parent
+    assert all(sp.start_ns <= t <= sp.end_ns and seconds > 0 for t, _, seconds, _ in mine)
+    for kind, key in (("trace", "trace_s"), ("lower", "lower_s"), ("compile", "compile_s")):
+        assert sp.count[key] == pytest.approx(sum(s for _, k, s, _ in mine if k == kind))
+    assert sp.count["compiles"] == sum(k == "compile" for _, k, _, _ in mine) >= 1
+    assert "cache_loads" not in sp.count and not outer.count
+    # the same shape again compiles nothing and logs nothing; a new shape does, outside any span under no name
+    with device_span("test.again") as again:
+        fn(jnp.ones((37,)))
+    assert not _logged_since(again.start_ns) and not again.count
+    fn(jnp.ones((41,)))
+    assert {name for _, _, _, name in _logged_since(again.end_ns)} == {""}
+
+
+def test_a_load_from_the_compile_cache_is_logged_as_a_load_and_not_as_a_compile():
+    """JAX times the backend around its persistent cache too: a hit raises the retrieval's event and then the
+    backend's, and the log keeps the first alone. (Processes held to the CPU keep no cache, so the events are raised
+    by hand, through ``jax.monitoring`` as JAX raises them.)"""
+    import jax.monitoring as monitoring
+
+    with device_span("test.loads") as sp:
+        monitoring.record_event_duration_secs("/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        monitoring.record_event_duration_secs("/jax/core/compile/backend_compile_duration", 0.26, fun_name="f")
+        monitoring.record_event_duration_secs("/jax/core/compile/backend_compile_duration", 1.5, fun_name="g")
+        monitoring.record_event_duration_secs("/jax/compilation_cache/compile_time_saved_sec", 9.0)  # not a stage
+    assert [(kind, seconds, name) for _, kind, seconds, name in _logged_since(sp.start_ns)] == [
+        ("cache_load", 0.25, "test.loads"), ("compile", 1.5, "test.loads")]
+    assert sp.count == {"cache_load_s": 0.25, "cache_loads": 1, "compile_s": 1.5, "compiles": 1}
+    assert len(profiling.recent_compiles()) <= profiling.COMPILE_LOG
+
+
+def test_the_listener_registers_once_however_often_profiling_is_imported_or_reloaded():
+    """In a process of its own: a reload here would empty the ring under the other tests."""
+    import os
+    import subprocess
+
+    script = """
+import importlib
+import jax.monitoring as monitoring
+from daft_tpu import profiling
+import daft_tpu.profiling
+for _ in range(3):
+    profiling = importlib.reload(profiling)
+with profiling.device_span("once") as sp:
+    monitoring.record_event_duration_secs("/jax/core/compile/jaxpr_trace_duration", 0.5, fun_name="f")
+print(len(profiling.recent_compiles()), sp.count)
+"""
+    p = subprocess.run([sys.executable, "-c", script], capture_output=True, text=True, timeout=120,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.strip().splitlines()[-1] == "1 {'trace_s': 0.5}"
