@@ -318,16 +318,11 @@ def phase_c_olmo(cfg, tiny: bool) -> dict:
     three layers of four, full attention with QK-norm in the fourth): chunked
     prefill that carries the delta rule's state, the decode loop, answers with
     log-probabilities, ``delta`` noted on both serving spans and both kinds of
-    slot state counted on ``prompt.run``; then, on the chip, the two kernels that
-    carry a prefill's traffic with the key/value rows, at the published head
-    sizes, against XLA's slices."""
-    import jax
-    import jax.numpy as jnp
-
+    slot state counted on ``prompt.run``; then, on the chip, the kernels over the
+    key/value rows (``cache_kernels_check``)."""
     import daft_tpu
     from daft_tpu import col
     from daft_tpu.functions.ai import prompt
-    from daft_tpu.ops import pallas_cache_blocks as pcb
     from daft_tpu.profiling import recent_device_spans
     from daft_tpu.tracing import span_clock_ns
 
@@ -354,20 +349,59 @@ def phase_c_olmo(cfg, tiny: bool) -> dict:
     _release(expr)
     out = {"run_s": run_s, "rows": n, "prefill_tokens": prefilled, "delta": delta,
            "kv_bytes": held["kv_bytes"], "recurrent_bytes": held["recurrent_bytes"]}
-    if not tiny:  # 3 slots x 30 heads x 1,031 positions x 128, a chunk of 512: a full row, an empty one, one ending inside
-        ks = jax.random.split(jax.random.PRNGKey(0), 2)
-        cache = jax.random.normal(ks[0], (3, 30, 1031, 128)).astype(jnp.bfloat16)
-        new = jax.random.normal(ks[1], (3, 512, 30, 128)).astype(jnp.bfloat16)
-        slots, starts, lens = jnp.asarray([2, 0, 1], jnp.int32), jnp.full((3,), 512, jnp.int32), jnp.asarray([512, 0, 77], jnp.int32)
-        assert pcb.kernels_apply(cache.shape, 512)
-        want = pcb.write_blocks_xla(cache, new, slots, starts, lens)
-        got = jax.jit(pcb.write_blocks)(cache, new, slots, starts, lens)
-        assert bool(jnp.all(got == want)), "the write kernel and XLA's slices differ"
-        block = jax.jit(lambda c: pcb.read_blocks(c, slots, jnp.int32(512), 512))(got)
-        sliced = pcb.read_blocks_xla(got, slots, 512, 512)
-        assert block.dtype == sliced.dtype == jnp.bfloat16 and bool(jnp.all(block == sliced))
-        out["cache_kernels"] = "equal to XLA's slices"
+    if not tiny:
+        out["cache_kernels"] = cache_kernels_check()
     return out
+
+
+def cache_kernels_check() -> dict:
+    """On the chip: the kernel that writes a chunk's key/value rows against XLA's
+    slices and the attention kernel against ``decoders.attention_chunk`` /
+    ``attention_core`` at the published head sizes (3 slots x 30 heads x 1,040
+    positions x 128, a chunk of 512: a full row, an empty one, one ending inside;
+    a decode step with a slot in the last, partial block and an idle one); then
+    the tiny decoder with an attention one lane tile wide through the batcher:
+    both serving spans must read ``attn`` = ``fused``."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from daft_tpu.models import decoders, olmo_hybrid as oh
+    from daft_tpu.models.serving import ContinuousBatcher, Request
+    from daft_tpu.ops import pallas_cache_attention as pca, pallas_cache_blocks as pcb
+    from daft_tpu.profiling import newest_device_span
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    cache = jax.random.normal(ks[0], (3, 30, 1040, 128)).astype(jnp.bfloat16)
+    new = jax.random.normal(ks[1], (3, 512, 30, 128)).astype(jnp.bfloat16)
+    slots, starts, lens = jnp.asarray([2, 0, 1], jnp.int32), jnp.full((3,), 512, jnp.int32), jnp.asarray([512, 0, 77], jnp.int32)
+    assert pcb.kernels_apply(cache.shape, 512)
+    want = pcb.write_blocks_xla(cache, new, slots, starts, lens)
+    got = jax.jit(pcb.write_blocks)(cache, new, slots, starts, lens)
+    assert bool(jnp.all(got == want)), "the write kernel and XLA's slices differ"
+    scale, gaps = 128 ** -0.5, {}
+    q = jax.random.normal(ks[2], (3, 512, 30, 1, 128)).astype(jnp.bfloat16)
+    assert pca.cache_attention_applies(q.shape, cache.shape, jnp.bfloat16) and pca.cache_attention_applies((3, 1, 30, 1, 128), cache.shape, jnp.bfloat16)
+    wide = lambda x: x.astype(jnp.float32)  # noqa: E731  (the reference's rows: the same values, and a product any backend has)
+    block = lambda j: (wide(pcb.read_blocks_xla(got, slots, j * 512, 512)), wide(pcb.read_blocks_xla(want, slots, j * 512, 512)))  # noqa: E731
+    ref = jax.jit(lambda q: decoders.attention_chunk(q, block, starts[:, None] + jnp.arange(512)[None, :], 2, scale, jnp.bfloat16))(q)
+    fused = pca.cache_attention(q, got, want, slots, starts, lens, scale=scale)
+    held = np.asarray(lens) > 0
+    assert not np.asarray(fused, np.float32)[~held].any(), "a row without a query did not come back as zeros"
+    gaps["chunk"] = float(np.abs(np.asarray(fused, np.float32) - np.asarray(ref, np.float32))[held].max())
+    q1, positions, active = q[:, :1], jnp.asarray([1030, 511, 7], jnp.int32), jnp.asarray([True, True, False])
+    ref = jax.jit(lambda q: decoders.attention_core(q, wide(jnp.swapaxes(got, 1, 2)), wide(jnp.swapaxes(want, 1, 2)), positions[:, None], scale, jnp.bfloat16))(q1)
+    fused = pca.cache_attention(q1, got, want, jnp.arange(3), positions, active.astype(jnp.int32), scale=scale)
+    gaps["step"] = float(np.abs(np.asarray(fused, np.float32) - np.asarray(ref, np.float32))[:2].max())
+    assert not np.asarray(fused, np.float32)[2].any() and max(gaps.values()) <= 3e-2, gaps
+    cfg = dataclasses.replace(oh.OlmoHybridConfig.from_name("olmo-hybrid-tiny"), num_attention_heads=2, num_key_value_heads=2, head_dim=128)
+    model, params = oh.init_olmo_params(cfg, 0)
+    b = ContinuousBatcher(model, params, num_slots=4, max_seq_len=1100, eos_id=None)
+    answers = b.run([Request(tokens=np.arange(2, 2 + n).astype(np.int32) % 256, max_new_tokens=4) for n in (600, 90, 1030)])
+    attn = {name: newest_device_span(name).count.get("attn") for name in ("serve.prefill", "serve.decode_step")}
+    assert attn == {"serve.prefill": "fused", "serve.decode_step": "fused"} and all(len(a) == 4 for a in answers), (attn, answers)
+    return {"write": "equal to XLA's slices", "max_abs_diff_vs_xla": {k: round(v, 6) for k, v in gaps.items()}, "attn": attn}
 
 
 def mla_kernel_check(cfg, tiny: bool) -> dict:

@@ -15,10 +15,10 @@ the queries, a score scale and where the rows are: one token over every row
 held (decode), or a chunk of queries over the blocks of cache rows that reach
 its last position with a running softmax between them (prefill: the work
 follows the prefix held, not the positions a slot could hold). Projections,
-norms, positions and the cache's layout are each model's own:
-``granite_hybrid`` (8 key/value heads x 4 queries, rows gathered a call) and
-``olmo_hybrid`` (30 x 1, QK-norm, blocks read in place from the slots' rows)
-both call it, so a kernel written for it serves, and is held by, both.
+norms, positions and the cache's layout are each model's own: ``granite_hybrid``
+(8 key/value heads x 4 queries, rows gathered a call) runs it on every backend,
+``olmo_hybrid`` (30 x 1, QK-norm) on the CPU and at the tiny sizes: on a TPU it
+takes ``ops/pallas_cache_attention.py``, whose arithmetic is this one's.
 
 **The conv** (``conv_with_tail``): the causal depthwise conv of a recurrent
 mixer over [carried tail | this chunk] with its SiLU, and the tail after each row's last

@@ -56,11 +56,17 @@ layer (row-major with no axis padded to a tile: 30 heads next to the head size
 would be laid out as 32),
 15,360 B a token a layer at the published sizes: a quarter of the depth holds
 97% of the cache. A prefill writes only its chunk into the slots' rows, in
-place, and reads only the blocks that reach its last position
-(``decoders.attention_chunk``: the core is shared with
-``models/granite_hybrid.py``, as is the conv with its carried tail;
-``ops/pallas_cache_blocks.py`` writes the chunk and hands the core each block
-from where the rows lie); a slot admitted anew starts from zero recurrent state.
+place (``ops/pallas_cache_blocks.write_blocks``), and attends, for each row of
+the call, only the blocks up to that row's own chunk; a decode step writes each
+slot's new row and attends the blocks up to each slot's own position. On a TPU at
+widths that fill lane tiles both are ``ops/pallas_cache_attention.py``, one
+kernel over the rows where they lie (scores in VMEM, no block beyond a row's
+depth fetched); on the CPU and at the tiny sizes ``decoders.attention_chunk``
+over XLA's slices (every row of a call as deep as the deepest) and
+``decoders.attention_core`` over every position a slot could hold, the core
+``models/granite_hybrid.py`` runs, as it does the conv with its carried tail.
+Which one a program traced is noted on the batcher's open span as ``attn`` =
+``fused`` | ``xla``. A slot admitted anew starts from zero recurrent state.
 
 Plain functions over a parameter tree, drawn tensor by tensor on the device in
 bfloat16; ``jax.named_scope`` names the parts (``lin_proj``, ``delta_rule``,
@@ -80,7 +86,7 @@ import jax.numpy as jnp
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
 from daft_tpu.models.decoders import draw, gated_mlp, mm, rms
-from daft_tpu.ops import pallas_attention, pallas_cache_blocks
+from daft_tpu.ops import pallas_attention, pallas_cache_attention, pallas_cache_blocks
 
 LINEAR, FULL = "linear_attention", "full_attention"
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
@@ -382,24 +388,29 @@ def _attn_prefill(cfg, p, u, st, slots, starts, lengths):
     hd)}: write the chunk's valid rows in place (``lengths`` of each, from
     ``starts``), then attend over the blocks held. -> (out (B, T, d) float32, st)."""
     T, hd = u.shape[1], cfg.head_dim
-    positions = starts[:, None] + jnp.arange(T)[None, :]
     with jax.named_scope("attn_proj"):
         q, k, v = _attn_project(cfg, p, u)
     with jax.named_scope("attn_core"):
-        # The chunk's valid rows go into the slots' rows in place; the core then walks the blocks that the call's
-        # deepest row attends (rows of a call stand at unlike depths: blocks beyond a row's own weigh 0), each block
-        # read from the slots' rows where they lie (``ops/pallas_cache_blocks.py`` has why both are kernels on a TPU).
+        # The chunk's valid rows go into the slots' rows in place (``ops/pallas_cache_blocks.py`` has why that is a
+        # kernel on a TPU); the core then attends them where they lie.
         cache_k = pallas_cache_blocks.write_blocks(st["k"], k, slots, starts, lengths)
         cache_v = pallas_cache_blocks.write_blocks(st["v"], v, slots, starts, lengths)
-        def block(cache, j):
-            rows = pallas_cache_blocks.read_blocks(cache, slots, j * T, T)
-            # XLA's CPU backend has no bfloat16 product for this block and does not widen it itself: it is widened
-            # here, which gives the same numbers (a bfloat16 value is its float32 value; the products accumulate
-            # in float32 either way).
-            return rows if pallas_attention.backend_is_tpu() else rows.astype(jnp.float32)
+        fused = pallas_cache_attention.cache_attention_applies(q.shape, cache_k.shape, cfg.dtype)
+        decoders.note_on_serving_span("attn", "fused" if fused else "xla")
+        if fused:  # every row over the blocks up to its own chunk; a kernel that fails to trace or lower fails the program
+            out = pallas_cache_attention.cache_attention(q, cache_k, cache_v, slots, starts, lengths, scale=hd ** -0.5)
+        else:
+            # XLA's loop walks the blocks that the call's deepest row attends, for every row (rows of a call stand at
+            # unlike depths: blocks beyond a row's own weigh 0). Its CPU backend has no bfloat16 product for a block
+            # and does not widen it itself: it is widened here, which gives the same numbers (a bfloat16 value is
+            # its float32 value; the products accumulate in float32 either way).
+            def block(cache, j):
+                rows = pallas_cache_blocks.read_blocks_xla(cache, slots, j * T, T)
+                return rows if pallas_attention.backend_is_tpu() else rows.astype(jnp.float32)
 
-        out = decoders.attention_chunk(q, lambda j: (block(cache_k, j), block(cache_v, j)), positions,
-                                       jnp.max(starts) // T + 1, hd ** -0.5, cfg.dtype)
+            positions = starts[:, None] + jnp.arange(T)[None, :]
+            out = decoders.attention_chunk(q, lambda j: (block(cache_k, j), block(cache_v, j)), positions,
+                                           jnp.max(starts) // T + 1, hd ** -0.5, cfg.dtype)
     with jax.named_scope("attn_proj"):
         return _attn_out(cfg, p, out), {"k": cache_k, "v": cache_v}
 
@@ -411,9 +422,10 @@ def _attn_decode(cfg, p, u, st, positions, active):
     with jax.named_scope("attn_proj"):
         q, k, v = _attn_project(cfg, p, u)
     with jax.named_scope("attn_core"):
-        # Through a view (slots, positions, heads, head size), the shape ``granite_hybrid`` keeps: the swap moves
-        # nothing, and XLA then writes the position and reads the rows where they lie (compiled for a described
-        # v5e, PR 35: no temporary of the cache's size; on the rows as kept it lays all six caches out anew, twice a step).
+        # The new rows go in through a view (slots, positions, heads, head size), the shape ``granite_hybrid`` keeps:
+        # the swap moves nothing, and XLA then writes the position where the rows lie (compiled for a described v5e,
+        # PR 35: no temporary of the cache's size; on the rows as kept it lays all six caches out anew, twice a step).
+        # The kernel takes the rows as the state keeps them, XLA's core the view.
         def write(cache, new):  # slot by slot, in place; an inactive slot keeps what it held
             for b in range(B):
                 at = (b, positions[b], 0, 0)
@@ -421,9 +433,15 @@ def _attn_decode(cfg, p, u, st, positions, active):
                 cache = jax.lax.dynamic_update_slice(cache, jnp.where(active[b], new[b][None], old), at)
             return cache
 
-        cache_k, cache_v = write(jnp.swapaxes(st["k"], 1, 2), k), write(jnp.swapaxes(st["v"], 1, 2), v)
-        out = decoders.attention_core(q, cache_k, cache_v, positions[:, None], hd ** -0.5, cfg.dtype)
-        cache_k, cache_v = jnp.swapaxes(cache_k, 1, 2), jnp.swapaxes(cache_v, 1, 2)
+        view_k, view_v = write(jnp.swapaxes(st["k"], 1, 2), k), write(jnp.swapaxes(st["v"], 1, 2), v)
+        cache_k, cache_v = jnp.swapaxes(view_k, 1, 2), jnp.swapaxes(view_v, 1, 2)
+        fused = pallas_cache_attention.cache_attention_applies(q.shape, cache_k.shape, cfg.dtype)
+        decoders.note_on_serving_span("attn", "fused" if fused else "xla")
+        if fused:  # every active slot over the blocks up to its own position, the rows as the state keeps them
+            out = pallas_cache_attention.cache_attention(q, cache_k, cache_v, jnp.arange(B), positions,
+                                                         active.astype(jnp.int32), scale=hd ** -0.5)
+        else:  # every slot over every position it could hold
+            out = decoders.attention_core(q, view_k, view_v, positions[:, None], hd ** -0.5, cfg.dtype)
     with jax.named_scope("attn_proj"):
         return _attn_out(cfg, p, out), {"k": cache_k, "v": cache_v}
 

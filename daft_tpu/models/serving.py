@@ -184,7 +184,7 @@ class ContinuousBatcher:
 
     def _repeat_noted(self, sp) -> None:
         """A model notes its choice of path on the open span while its program
-        traces (``decoders.grouped_mlp``: ``moe``; ``longcat_flash``: ``mla``; ``olmo_hybrid``: ``delta``); a
+        traces (``decoders.grouped_mlp``: ``moe``; ``longcat_flash``: ``mla``; ``olmo_hybrid``: ``delta``, ``attn``); a
         call that traces nothing repeats what the trace chose."""
         noted = self._noted.setdefault(sp.name, {})
         noted.update({k: v for k, v in sp.count.items() if isinstance(v, str)})

@@ -1,33 +1,29 @@
-"""A prefill's traffic with a cache of per-head key/value rows, ``cache (slots,
-heads, positions, head size)``, as two small Pallas TPU kernels: ``write_blocks``
-puts a chunk's valid rows into the slots' rows in place, ``read_blocks`` hands
-an attention's loop one block of ``T`` positions of every row of the call. Row
-``b`` of the call is slot ``slots[b]``.
+"""A prefill's write into a cache of per-head key/value rows, ``cache (slots,
+heads, positions, head size)``, as one small Pallas TPU kernel: ``write_blocks``
+puts a chunk's valid rows into the slots' rows in place. Row ``b`` of the call
+is slot ``slots[b]``. Beside it, XLA's slices for the same write and for the
+read of one block of every row of a call, which the CPU backend and the tiny
+test sizes take (``models/olmo_hybrid._attn_prefill``'s loop over
+``decoders.attention_chunk``).
 
-As XLA both are a few dynamic slices, and at long caches that is what does not
-work. XLA chooses the layout of whatever an update or a loop's products touch
-by what they like best, so it lays the *whole* cache out anew, once a call:
-before the attention's loop over blocks, whichever axis the rows keep minor
-(positions, heads or head size; a block of all rows or a row at a time; with or
-without a gather of the call's rows first), and again around the chunk's
-``dynamic_update_slice``, which it prefers to do with positions minor (compiled
-for a described v5e at 30 heads x 128, PR 35: at 8 or 9 slots x 16,464 rows two
-copies of 1 GB a call, 2.2 to 2.5 GB of temporaries where the kernels leave
-0.49; four to six where the device itself kept the rows slots-minor, which
-``models/olmo_hybrid.ROW_TILE`` now prevents). A custom call's operands keep
-the layout they arrive in: here the cache is an operand of these two calls alone, in the layout the device
-keeps it in (heads, positions, head size: row-major, no padding), the write
-aliases its input, and the loop's products are free to lay out the 16 MB block,
-not the 1 GB cache. The read is the block's bytes once more through HBM, a few
-percent of what a block's scores move.
+As XLA the write is a few dynamic slices, and at long caches that is what does
+not work. XLA chooses the layout of whatever an update or a loop's products
+touch by what they like best, so it lays the *whole* cache out anew, once a
+call: around the chunk's ``dynamic_update_slice``, which it prefers to do with
+positions minor, and before a loop over blocks, whichever axis the rows keep
+minor (compiled for a described v5e at 30 heads x 128, PR 35: at 8 or 9 slots x
+16,464 rows two copies of 1 GB a call, 2.2 to 2.5 GB of temporaries where the
+kernels leave 0.49; four to six where the device itself kept the rows
+slots-minor, which ``models/olmo_hybrid.ROW_TILE`` now prevents). A custom
+call's operands keep the layout they arrive in: on a TPU the cache is an operand
+of this kernel and of ``ops/pallas_cache_attention.py``'s alone, in the layout
+the device keeps it in (heads, positions, head size: row-major, no padding), and
+the write aliases its input.
 
-Both take the kernel on a TPU where the shapes let them (``kernels_apply``: a
-head size in whole lane tiles, blocks in whole steps of ``STEP_POSITIONS``) and
-XLA's slices elsewhere (the CPU backend, the tiny test sizes), in the cache's
-dtype either way; the choice is made when the program traces. Tests run the
-kernels in interpret mode on the CPU. ``read_blocks`` is scaffolding: an
-attention kernel for ``decoders.attention_chunk`` that reads blocks where the
-rows lie retires it (``ROADMAP.md`` M1).
+The write takes the kernel on a TPU where the shapes let it (``kernels_apply``:
+a head size in whole lane tiles, blocks in whole steps of ``STEP_POSITIONS``)
+and XLA's slices elsewhere, in the cache's dtype either way; the choice is made
+when the program traces. Tests run the kernel in interpret mode on the CPU.
 """
 
 from __future__ import annotations
@@ -50,6 +46,7 @@ def kernels_apply(cache_shape, T: int) -> bool:
 
 # -- XLA's slices: the CPU backend and the tiny sizes ---------------------------------------------
 def read_blocks_xla(cache, slots, first, T: int):
+    """``cache[slots[b], :, first : first + T]`` for every row b of the call, as (B, T, heads, head size)."""
     KV, hd = cache.shape[1], cache.shape[3]
     rows = [jax.lax.dynamic_slice(cache, (slots[b], 0, first, 0), (1, KV, T, hd)) for b in range(slots.shape[0])]
     return jnp.moveaxis(jnp.concatenate(rows), 1, 2)
@@ -66,29 +63,7 @@ def write_blocks_xla(cache, new, slots, starts, lengths):
     return cache
 
 
-# -- the kernels ------------------------------------------------------------------------------------
-def read_blocks_kernel(cache, slots, first, T: int, interpret: bool = False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, KV, hd, step = slots.shape[0], cache.shape[1], cache.shape[3], STEP_POSITIONS
-
-    def copy(slots_ref, first_ref, src, dst):
-        dst[...] = src[...]
-
-    out = pl.pallas_call(
-        copy,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B, T // step),
-            in_specs=[pl.BlockSpec((1, KV, step, hd), lambda b, t, slots, first: (slots[b], 0, first[0] // step + t, 0))],
-            out_specs=pl.BlockSpec((1, KV, step, hd), lambda b, t, slots, first: (b, 0, t, 0))),
-        out_shape=jax.ShapeDtypeStruct((B, KV, T, hd), cache.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(slots.astype(jnp.int32), jnp.reshape(first, (1,)).astype(jnp.int32), cache)
-    return jnp.moveaxis(out, 1, 2)
-
-
+# -- the kernel -------------------------------------------------------------------------------------
 def write_blocks_kernel(cache, new, slots, starts, lengths, interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -117,18 +92,10 @@ def write_blocks_kernel(cache, new, slots, starts, lengths, interpret: bool = Fa
 
 
 # -- what a model calls -----------------------------------------------------------------------------
-def read_blocks(cache, slots, first, T: int):
-    """``cache[slots[b], :, first : first + T]`` for every row b of the call, as
-    (B, T, heads, head size); ``first`` is a multiple of ``T``."""
-    if kernels_apply(cache.shape, T):  # a kernel that fails to trace or lower fails the program
-        return read_blocks_kernel(cache, slots, first, T)
-    return read_blocks_xla(cache, slots, first, T)
-
-
 def write_blocks(cache, new, slots, starts, lengths):
     """The chunk ``new (B, T, heads, head size)`` into the slots' rows from
     ``starts`` (B,; multiples of ``T``), valid tokens only (``lengths`` of each
     row): padding, and a row that carries no prompt, leave the slot as it was."""
-    if kernels_apply(cache.shape, new.shape[1]):
+    if kernels_apply(cache.shape, new.shape[1]):  # a kernel that fails to trace or lower fails the program
         return write_blocks_kernel(cache, new, slots, starts, lengths)
     return write_blocks_xla(cache, new, slots, starts, lengths)

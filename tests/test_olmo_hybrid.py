@@ -374,12 +374,11 @@ def test_names_and_cuts_are_looked_up_in_one_record():
         FlaxPrompter(TINY, expert_shard=(0, 2))  # another decoder's cut
 
 
-# -- the two kernels that carry a prefill's traffic with the key/value rows ----------------------
+# -- the kernels over the key/value rows: the chunk's write, and the attention where the rows lie ---
 def test_the_cache_kernels_move_what_xlas_slices_move():
-    """``ops/pallas_cache_blocks.py`` at the narrowest sizes its kernels serve (a head size of one lane tile, blocks
+    """``ops/pallas_cache_blocks.py`` at the narrowest sizes its kernel serves (a head size of one lane tile, blocks
     of one step), interpreted: the chunk's valid rows land where XLA's slices put them and nowhere else (a row of
-    length 0 and the tail of a row that ends inside the chunk keep what the slot held), and a block comes back as
-    the slices hand it over, for rows at unlike slots."""
+    length 0 and the tail of a row that ends inside the chunk keep what the slot held), for rows at unlike slots."""
     from daft_tpu.ops import pallas_cache_blocks as pcb
 
     slots_n, KV, S, hd, B, T = 5, 3, 3 * 128 + 7, 128, 3, 128
@@ -390,29 +389,27 @@ def test_the_cache_kernels_move_what_xlas_slices_move():
     lengths = jnp.asarray([128, 0, 37], jnp.int32)
     want = pcb.write_blocks_xla(cache, new, slots, starts, lengths)
     got = pcb.write_blocks_kernel(cache, new, slots, starts, lengths, interpret=True)
+    assert got.dtype == want.dtype == jnp.bfloat16                                              # one dtype, either path
     assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
     assert np.array_equal(np.asarray(got[0], np.float32), np.asarray(cache[0], np.float32))      # length 0: as it was
     assert not np.array_equal(np.asarray(got[4, :, 128:256], np.float32), np.asarray(cache[4, :, 128:256], np.float32))
     assert np.array_equal(np.asarray(got[2, :, 128 + 37:], np.float32), np.asarray(cache[2, :, 128 + 37:], np.float32))
-    for first in (0, 128, 256):
-        block = pcb.read_blocks_kernel(got, slots, jnp.int32(first), T, interpret=True)
-        sliced = pcb.read_blocks_xla(got, slots, first, T)
-        assert block.shape == (B, T, KV, hd) and block.dtype == sliced.dtype == jnp.bfloat16    # one dtype, either path
-        assert np.array_equal(np.asarray(block, np.float32), np.asarray(sliced, np.float32))
     # which path a program takes is decided from the backend and the shapes: none of the tiny decoder's, here
     assert not pcb.kernels_apply(cache.shape, T) and not pcb.kernels_apply((4, 4, 64, 16), 16)
 
 
 @pytest.mark.parametrize("slots", [8, 9, 16])
-def test_the_cache_kernels_lower_for_a_described_v5e(slots):
+def test_the_cache_kernels_lower_for_a_described_v5e(slots, monkeypatch):
     """The kernels over the rows the model holds at the cell's sizes (30 heads x 16,449 positions x 128, blocks of
-    512), lowered and compiled for a described v5e without the chip: the write aliases the cache and neither kernel
-    is handed a copy of it. A slot count of whole sublane tiles (8, the cell's; 16) is the case that copied: the
-    device keeps rows that end inside a tile slots-minor there, which is why ``init_state`` holds whole tiles."""
+    512), lowered and compiled for a described v5e without the chip: the write aliases the cache, the attention
+    kernel of a chunk and of a decode step is handed no copy of it, and the whole attention of a prefill call and of
+    a decode step (the new rows' write, the kernel) holds no temporary of a cache's size with both caches aliased.
+    A slot count of whole sublane tiles (8, the cell's; 16) is the case that copied: the device keeps rows that end
+    inside a tile slots-minor there, which is why ``init_state`` holds whole tiles."""
     topologies = pytest.importorskip("jax.experimental.topologies")
     from jax.sharding import SingleDeviceSharding
 
-    from daft_tpu.ops import pallas_cache_blocks as pcb
+    from daft_tpu.ops import pallas_attention, pallas_cache_attention as pca, pallas_cache_blocks as pcb
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -420,13 +417,122 @@ def test_the_cache_kernels_lower_for_a_described_v5e(slots):
         pytest.skip(f"no described v5e here: {e}")
     one = SingleDeviceSharding(topo.devices[0])
     of = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)  # noqa: E731
-    model = oh.OlmoHybridLM(oh.OlmoHybridConfig.from_name("Olmo-Hybrid-7B", num_hidden_layers=4))
-    held = jax.eval_shape(lambda: model.init_state(slots, 16449))[3]["k"]
+    cfg = oh.OlmoHybridConfig.from_name("Olmo-Hybrid-7B", num_hidden_layers=4)
+    held = jax.eval_shape(lambda: oh.OlmoHybridLM(cfg).init_state(slots, 16449))[3]["k"]
     assert held.shape == (slots, 30, 16464, 128) and held.dtype == jnp.bfloat16
+    rows = slots * 30 * 16464 * 128 * 2
     cache, new = of(held.shape, held.dtype), of((4, 512, 30, 128), jnp.bfloat16)
     ints = of((4,), jnp.int32)
     write = jax.jit(pcb.write_blocks_kernel, donate_argnums=(0,)).lower(cache, new, ints, ints, ints).compile()
     mem = write.memory_analysis()
-    assert mem.temp_size_in_bytes < 64 << 20 and mem.alias_size_in_bytes >= slots * 30 * 16464 * 128 * 2
-    read = jax.jit(lambda c, s, f: pcb.read_blocks_kernel(c, s, f, 512)).lower(cache, ints, of((), jnp.int32)).compile()
-    assert read.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert mem.temp_size_in_bytes < 64 << 20 and mem.alias_size_in_bytes >= rows
+    attend = lambda q, k, v, s, a, n: pca.cache_attention(q, k, v, s, a, n, scale=128 ** -0.5)  # noqa: E731
+    for q, n in ((of((4, 512, 30, 1, 128), jnp.bfloat16), ints), (of((slots, 1, 30, 1, 128), jnp.bfloat16), of((slots,), jnp.int32))):
+        assert jax.jit(attend).lower(q, cache, cache, n, n, n).compile().memory_analysis().temp_size_in_bytes < 64 << 20
+    # the model's own two attentions, as the chip traces them
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: True)
+    layer = jax.eval_shape(lambda: oh._init_layer(cfg, jax.random.PRNGKey(0), oh.FULL))
+    p = jax.tree_util.tree_map(lambda x: of(x.shape, x.dtype), layer)
+    st = {"k": cache, "v": cache}
+    prefill = jax.jit(lambda p, u, st, s, a, n: oh._attn_prefill(cfg, p, u, st, s, a, n), donate_argnums=(2,))
+    decode = jax.jit(lambda p, u, st, pos, act: oh._attn_decode(cfg, p, u, st, pos, act), donate_argnums=(2,))
+    # (a prefill call's projections are 94 MB of float32 for its 2,048 tokens: its bound is an eighth of one cache)
+    for compiled, most in ((prefill.lower(p, of((4, 512, 3840), jnp.bfloat16), st, ints, ints, ints).compile(), rows // 8),
+                           (decode.lower(p, of((slots, 1, 3840), jnp.bfloat16), st, of((slots,), jnp.int32),
+                                         of((slots,), jnp.bool_)).compile(), 64 << 20)):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < most and mem.alias_size_in_bytes >= 2 * rows, (mem.temp_size_in_bytes, mem.alias_size_in_bytes)
+
+
+# -- the model with the attention kernel (interpreted) and on XLA's path -------------------------
+LANE_TILE = dict(num_attention_heads=2, num_key_value_heads=2, head_dim=128)   # the narrowest attention the kernel serves
+
+
+@pytest.fixture
+def fused_attention(monkeypatch):
+    """The backend rule answers as on a TPU, and the kernels it then selects run
+    interpreted. ``calls`` keeps the shape of q at each call traced."""
+    from daft_tpu.ops import pallas_attention, pallas_cache_attention as pca, pallas_cache_blocks as pcb
+
+    calls = []
+    attend, write = pca.cache_attention, pcb.write_blocks_kernel
+
+    def interpreted(q, *rest, scale):
+        calls.append(q.shape)
+        return attend(q, *rest, scale=scale, interpret=True)
+
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pca, "cache_attention", interpreted)
+    monkeypatch.setattr(pcb, "write_blocks_kernel", lambda *a: write(*a, interpret=True))
+    return calls
+
+
+def _prefill_then_decode(model, params, lengths, T, steps):
+    """Rows of ``lengths`` tokens at slots 3, 2, 1 of four as calls of one chunk, then ``steps`` decode steps in
+    which the slot of the first row is idle; -> (the logits after each call and step, the key/value rows)."""
+    rng = np.random.default_rng(1)
+    rows = len(lengths)
+    chunks = -(-max(lengths) // T)
+    tokens = rng.integers(2, 256, (rows, chunks * T)).astype(np.int32)
+    state = model.init_state(rows + 1, 600)
+    slots = jnp.arange(rows, 0, -1, dtype=jnp.int32)
+    prefill, decode = jax.jit(model.prefill), jax.jit(model.decode)
+    logits = []
+    for c in range(chunks):
+        here = np.clip(np.asarray(lengths) - c * T, 0, T).astype(np.int32)
+        state, out, _ = prefill(params, state, tokens[:, c * T:(c + 1) * T], slots, jnp.full((rows,), c * T, jnp.int32), here)
+        logits.append(np.asarray(out)[here > 0])
+    positions = np.zeros((rows + 1,), np.int32)
+    positions[np.asarray(slots)] = lengths
+    active = np.asarray([False] + [True] * rows)
+    active[slots[0]] = False
+    for _ in range(steps):
+        state, out, _ = decode(params, state, jnp.full((rows + 1,), 7, jnp.int32), jnp.asarray(positions), jnp.asarray(active))
+        logits.append(np.asarray(out)[active])
+        positions += active
+    return logits, [np.asarray(st[n], np.float32) for st in state if "k" in st for n in ("k", "v")]
+
+
+def test_prefill_and_decode_with_the_kernel_equal_xlas_path(monkeypatch, fused_attention):
+    """Three rows of unlike lengths (one ends in the first chunk) through three
+    calls of one chunk and four decode steps with an idle slot and an empty one:
+    every logit and every slot's key/value rows agree across the two paths."""
+    from daft_tpu.ops import pallas_attention, pallas_cache_attention as pca
+
+    T = 128
+    model, params = oh.init_olmo_params(dataclasses.replace(oh.OlmoHybridConfig.from_name(TINY), **LANE_TILE), 0)
+    lengths = [3 * T, 60, T + 31]
+    fused, fused_rows = _prefill_then_decode(model, params, lengths, T, steps=4)
+    assert fused_attention == [(3, T, 2, 1, 128), (4, 1, 2, 1, 128)]                    # one trace a program
+    assert not pca.cache_attention_applies((3, 8, 4, 1, 16), (4, 4, 64, 16), jnp.bfloat16)   # the tiny decoder's own widths
+    fused_attention.clear()
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: False)
+    xla, xla_rows = _prefill_then_decode(model, params, lengths, T, steps=4)
+    assert fused_attention == [] and len(fused) == len(xla) == 3 + 4
+    gap = max(float(np.max(np.abs(a - b))) for a, b in zip(fused, xla))
+    assert gap <= 3e-2, gap  # bfloat16 rounding of the attention's result under logits that spread ~1
+    assert min(float(np.std(x)) for x in xla) > 0.3
+    for a, b in zip(fused_rows, xla_rows):
+        assert np.max(np.abs(a - b)) <= 0.07  # one bfloat16 step of rows that spread ~1 through the layers before
+
+
+def test_serving_spans_say_which_attention_each_program_traced(monkeypatch, fused_attention):
+    from daft_tpu.ops import pallas_attention
+
+    model, params = oh.init_olmo_params(dataclasses.replace(oh.OlmoHybridConfig.from_name(TINY), **LANE_TILE), 1)
+    reqs = lambda: [Request(tokens=np.arange(2, 2 + n).astype(np.int32), max_new_tokens=3) for n in (300, 100, 140)]  # noqa: E731
+    b = ContinuousBatcher(model, params, num_slots=4, max_seq_len=600, eos_id=None, prefill_chunk=128)
+    out = b.run(reqs())
+    assert b._noted == {"serve.prefill": {"attn": "fused", "delta": "chunked"},
+                        "serve.decode_step": {"attn": "fused", "delta": "recurrent"}}
+    from daft_tpu.profiling import newest_device_span
+
+    count = newest_device_span("serve.prefill").count
+    assert (count["attn"], count["block_rows"], count["padded_block_rows"]) == ("fused", 6 + 1 + 3, 4 * 6)
+    assert newest_device_span("serve.decode_step").count["attn"] == "fused"
+    # the same prompts on XLA's path choose the same tokens, and the spans say so
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: False)
+    x = ContinuousBatcher(model, params, num_slots=4, max_seq_len=600, eos_id=None, prefill_chunk=128)
+    assert x.run(reqs()) == out
+    assert x._noted["serve.prefill"]["attn"] == x._noted["serve.decode_step"]["attn"] == "xla"
+    assert newest_device_span("serve.decode_step").count["attn"] == "xla"
