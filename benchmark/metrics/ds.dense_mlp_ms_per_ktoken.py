@@ -1,0 +1,7 @@
+"""Device milliseconds a thousand tokens processed (prefilled and decoded) in the dense layer's MLP and the shared experts (scope ``dense_mlp``), over both programs, by the scopes of their compiled text (``lib/decoder_scopes.py``, the classes the configuration names under ``scopes``)."""
+
+from lib import decoder_scopes
+
+
+def read(run):
+    return decoder_scopes.per_ktoken(run, 'dense_mlp')

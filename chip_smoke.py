@@ -486,6 +486,89 @@ def phase_c_longcat(cfg, tiny: bool) -> dict:
             "mla_kernel": mla_kernel_check(cfg, tiny)}
 
 
+def phase_c_deepseek(cfg, tiny: bool) -> dict:
+    """prompt on the tiny DeepSeek-V3.2 decoder (latent attention whose keys an
+    indexer selects, YaRN, a grouped sigmoid router beside a shared expert):
+    chunked prefill over the latent cache and the indexer's keys with the
+    selection biting from position 32 (XLA's loops: its widths fill no lane tile),
+    the decode loop (the masked absorbed form), and what the spans say of both;
+    then the selection path's two kernels at the published widths, the index
+    scores and the prefill attention with the selection as an input, against
+    XLA's loops (under --tiny-cpu interpreted, at a narrow shape)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.functions.ai import prompt
+    from daft_tpu.models import deepseek_v32 as ds, latent_attention as la
+    from daft_tpu.ops import pallas_dsa_index as pi, pallas_mla_attention as pm
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    began = span_clock_ns()
+    n = cfg["prompts"]
+    lengths, docs = _prompt_docs(n, 1100)
+    expr = prompt(col("p"), provider="flax_random", model="deepseek-v32-tiny",
+                  max_new_tokens=6, ignore_eos=True, logprobs=True, num_slots=4, max_prompt_tokens=1200,
+                  num_layers=3, expert_shard=[0, 2], vocab_shard=[0, 2])
+    answers, run_s = _timed(lambda: daft_tpu.from_pydict({"p": docs}).with_column("a", expr)
+                            .select("a").collect().to_pydict()["a"])
+    assert len(answers) == n and all(
+        len(a["token_ids"]) == len(a["logprobs"]) == 6 and np.isfinite(a["logprobs"]).all()
+        and all(0 <= t < 128 for t in a["token_ids"]) for a in answers), answers
+    spans = [s for s in recent_device_spans() if s.start_ns >= began and s.name.startswith(("serve.", "prompt."))]
+    steps = [s for s in spans if s.name == "serve.decode_step"]
+    prefills = [s for s in spans if s.name == "serve.prefill"]
+    assert steps and all(s.count["moe.assignments"] == s.count["active"] * 3 * 2 for s in steps)
+    assert sum(s.count["selected_pairs"] for s in prefills) == ds.selected_pairs(lengths, 32) < sum(s.count["pairs"] for s in prefills)
+    paths = {s.name: (s.count.get("dsa"), s.count.get("mla")) for s in prefills + steps}
+    assert paths == {"serve.prefill": ("masked", "expanded"), "serve.decode_step": ("masked", "absorbed")}, paths
+    held = [s.count for s in spans if s.name == "prompt.run"][-1]
+    _release(expr)
+
+    # the two kernels of the selection path against XLA's loops over the same caches
+    B, T, H, S = cfg["mla_shape"]
+    # 16 index heads at the least: with fewer a key's index score is an exact 0 often enough to tie at a threshold
+    Hi, Di, lat, nope, rope, dv = (16, 128, 128, 128, 16, 128) if tiny else (64, 128, 512, 128, 64, 128)
+    sizes = dataclasses.replace(ds.DeepseekV32Config.from_name("deepseek-v32-tiny"), kv_lora_rank=lat, qk_nope_head_dim=nope,
+                                qk_rope_head_dim=rope, v_head_dim=dv)
+    assert tiny or (pi.index_scores_applies((B, T, Hi, Di), jnp.bfloat16)
+                    and pm.mla_prefill_applies((B, T, H, nope + rope), jnp.bfloat16, lat, nope, rope, dv)), \
+        "the selection path's kernels do not apply at the published widths"
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.standard_normal((B, T, H, nope + rope)), jnp.bfloat16)
+    kv = jnp.asarray(rng.standard_normal((B + 1, lat + rope, S)), jnp.bfloat16)
+    w_kvb = jnp.asarray(rng.standard_normal((lat, H, nope + dv)) * lat ** -0.5, jnp.bfloat16)
+    qi = jnp.asarray(rng.standard_normal((B, T, Hi, Di)), jnp.bfloat16)
+    wi = jnp.asarray(rng.standard_normal((B, T, Hi)) * (Hi * Di) ** -0.5, jnp.float32)
+    ik = jnp.asarray(rng.standard_normal((B + 1, Di, S)), jnp.bfloat16)
+    slots = jnp.asarray([3, 0, 4, 1], jnp.int32)
+    starts, lengths_ = jnp.asarray([3 * T, 3 * T, T, 3 * T], jnp.int32), jnp.asarray([T, 0, T - 28, T], jnp.int32)
+    positions = starts[:, None] + jnp.arange(T)[None, :]
+    keep_k = min(2048, 2 * T)
+    want_index = np.asarray(jax.jit(lambda: ds.index_scores_expanded(qi, wi, ik, slots, starts))())
+    index, index_s = _timed(lambda: pi.index_scores(qi, wi, ik, slots, starts, lengths_, interpret=tiny))
+    for b, blocks in ((0, 4), (2, 2), (3, 4)):
+        np.testing.assert_allclose(np.asarray(index)[b, :, :blocks * T], want_index[b, :, :blocks * T], atol=2e-2, rtol=2e-2)
+    threshold = ds.kth_threshold(index, positions, keep_k, reach=jnp.max(starts) + T)
+    kept = np.asarray((index >= threshold[..., None]) & (jnp.arange(index.shape[-1]) <= positions[..., None]))
+    held_rows = np.asarray(lengths_) > 0
+    assert (kept.sum(-1)[held_rows] == np.minimum(np.asarray(positions)[held_rows] + 1, keep_k)).all(), "a query kept another count than its top-k"
+    masked = np.asarray(jax.jit(lambda: la.expanded_over_slots(sizes, w_kvb, q, kv, slots, starts, 0.1, index >= threshold[..., None]))())
+    out, fused_s = _timed(lambda: np.asarray(pm.mla_prefill_attention(
+        q, kv, w_kvb, slots, starts, lengths_, nope=nope, interpret=tiny, scale=0.1, index=index, threshold=threshold,
+        max_heads=ds.KERNEL_HEADS), np.float32))
+    np.testing.assert_allclose(out[held_rows], masked[held_rows], atol=3e-2, rtol=3e-2)
+    return {"run_s": run_s, "rows": n, "decode_steps": len(steps), "paths": paths,
+            "selected_pair_share": round(sum(s.count["selected_pairs"] for s in prefills) / sum(s.count["pairs"] for s in prefills), 4),
+            "row_bytes_per_token": held["kv_bytes"] // (held["slots"] * held["positions"]),
+            "kernels": {"shape": [B, T, H, S], "first_call_s": {"index": index_s, "attention": fused_s},
+                        "max_abs_diff_vs_masked_loop": round(float(np.abs(out - masked)[held_rows].max()), 6)}}
+
+
 def phase_d(cfg) -> dict:
     """A q06-shaped float32 chain (filter -> project -> sum) on the device,
     against the same query on the host. The counters are the only way to see
@@ -648,6 +731,8 @@ def main(argv=None) -> int:
             done(current, phase_c_hybrid(cfg))
             current = "C_prompt_longcat"
             done(current, phase_c_longcat(cfg, tiny))
+            current = "C_prompt_deepseek"
+            done(current, phase_c_deepseek(cfg, tiny))
             current = "C_prompt_olmo"
             done(current, phase_c_olmo(cfg, tiny))
             current = "D_device_chain"

@@ -43,7 +43,7 @@ from daft_tpu.ai.provider import Provider
 from daft_tpu.device import setup_compile_cache
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
-from daft_tpu.models import granite_hybrid, longcat_flash, olmo_hybrid  # noqa: F401  (each enters its names in decoders.DECODERS)
+from daft_tpu.models import deepseek_v32, granite_hybrid, longcat_flash, olmo_hybrid  # noqa: F401  (each enters its names in decoders.DECODERS)
 from daft_tpu.profiling import device_span
 from daft_tpu.utils.tokenizer import HashingTokenizer
 
@@ -481,7 +481,7 @@ class FlaxPrompter(_FlaxModelBase):
 
     ``model_name`` is looked up exactly in the record of published decoders
     (``models/decoders.DECODERS``, which ``models/granite_hybrid``,
-    ``models/longcat_flash`` and ``models/olmo_hybrid`` enter); such a decoder takes its own cut's options
+    ``models/longcat_flash``, ``models/olmo_hybrid`` and ``models/deepseek_v32`` enter); such a decoder takes its own cut's options
     (e.g. ``num_hidden_layers`` or ``num_layers``, ``expert_shard``,
     ``vocab_shard``: one chip's share of a stated deployment). Any other name is
     ``DecoderLMConfig.from_name``'s, and a cut's options with such a name are an

@@ -1,7 +1,9 @@
-"""What the published decoders behind ``prompt`` share: the record of them by
+"""What the four published decoders behind ``prompt`` share (``granite_hybrid``,
+``longcat_flash``, ``olmo_hybrid``, ``deepseek_v32``): the record of them by
 exact name, the rules their random parameters are drawn by, the attention core
 over a cache of per-head keys and values, the causal conv with a carried tail,
-and the expert layer's dispatch after the router.
+and the expert layer's dispatch after the router. The latent attention that
+``longcat_flash`` and ``deepseek_v32`` share is ``models/latent_attention.py``.
 
 **The record.** A decoder module enters its names here (``register``) with how
 a name becomes a configuration, how parameters are drawn, the model class the
@@ -26,7 +28,7 @@ valid step (``granite_hybrid``'s Mamba-2 input, ``olmo_hybrid``'s q, k and v).
 
 **The dispatch** (``held_experts_part``): each model routes in its own way
 (which scores, which weights, which experts cost nothing); what follows is the
-same work: mark the assignments that reach an expert this chip holds, sort them
+same work (three callers: ``granite_hybrid``, ``longcat_flash``, ``deepseek_v32``): mark the assignments that reach an expert this chip holds, sort them
 by held expert (absent experts and padded tokens behind every held group), the
 two grouped products of the gated MLPs, and one gated gather back for each of
 the top-k choices. On a TPU at widths that fill lane tiles the two products are
@@ -147,14 +149,21 @@ def note_on_serving_span(key: str, value: str) -> None:
             span.count[key] = value
 
 
+def add_counts(a, b):
+    """A model's counts of its expert layers so far and one layer's more: sums, but the fullest expert's load, a maximum."""
+    if a is None:
+        return b
+    return {k: jnp.maximum(a[k], b[k]) if k == "max_expert_load" else a[k] + b[k] for k in a}
+
+
 def copy_slot(state, src, dst):
     """Slot ``src``'s state into slot ``dst``, whatever the leaves hold: every leaf's first axis is the slot."""
     return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
 
 
-#: The names under which a decoder's ``init_state`` holds rows a token (key/value rows, latent rows: they grow with
-#: the longest document a slot may hold). A leaf under any other name is recurrent state, the same whatever the length.
-ROW_LEAVES = ("k", "v", "kv")
+#: The names under which a decoder's ``init_state`` holds rows a token (key/value rows, latent rows, the keys of
+#: ``deepseek_v32``'s indexer: they grow with the longest document a slot may hold). A leaf under any other name is recurrent state, the same whatever the length.
+ROW_LEAVES = ("k", "v", "kv", "ik")
 
 
 def state_bytes_by_kind(state) -> Dict[str, int]:

@@ -22,29 +22,15 @@ operands and accumulate in float32):
           w_e = routed_scaling_factor * s_e  (the bias moves the choice, not the weight; no renormalisation)
           MoE(u) = sum_{e chosen, routed} w_e SwiGLU_e(u)  +  (sum_{e chosen, zero} w_e) u
 
-**The cache row** of a token is ``[c | RoPE(k_r)]`` (``kv_lora_rank +
-qk_rope_head_dim`` values, bfloat16): the latent cache. Slot state is one
-``{"kv": (slots, row, positions)}`` an attention, two a layer: positions are the
-minor axis, which is how both programs' products read the cache, so that
-neither lays it out anew (with rows minor XLA copied every attention's whole
-cache once a call: my chip run, PR 33). **Two attention
-paths**: prefill *expands* a block of cache rows at a time (``c W_kvb`` ->
-per-head keys and values, a running softmax between blocks; work follows the
-prefix held); decode *absorbs* ``W_kvb`` into the query and the output
-(``q_nope W_uk^T`` against ``c``, ``probs @ c`` then ``W_uv``) and never
-expands a key. The prefill's has two forms of one arithmetic. On a TPU, at
-widths that fill lane tiles (the published ones), it is one Pallas kernel
-(``ops/pallas_mla_attention.py``): a block's scores, exponentials and expanded
-keys and values stay in VMEM, and only the (row, block) pairs in which the row
-holds a query are walked: a row whose prompt has ended (``lengths == 0``)
-costs nothing and reads zeros. Elsewhere (the CPU backend, the tiny test
-widths) it is ``mla_core_expanded``: XLA's loop over the blocks the call's
-deepest row attends, every row alike. ``mla_prefill_applies`` decides when the
-program traces, from the backend, the dtype and the shapes; there is no switch.
-Which one a program traced is noted on the batcher's open span as ``mla`` =
-``fused`` | ``expanded`` | ``absorbed``. A prefill writes only its chunk into
-the slots' rows and reads only the blocks that reach its last position; a
-decode step writes one lane tile of positions a slot, in place.
+**The latent attention** (the cache row ``[c | RoPE(k_r)]``, slot state one
+``{"kv": (slots, row, positions)}`` an attention, two a layer, positions minor;
+prefill *expanded*, on a TPU at the published widths one Pallas kernel,
+``ops/pallas_mla_attention.py``, elsewhere XLA's loop over blocks; decode
+*absorbed*; noted on the batcher's open span as ``mla`` = ``fused`` |
+``expanded`` | ``absorbed``) is ``models/latent_attention.py``, shared with
+``models/deepseek_v32``: this model calls it with both LoRA scales, plain rotary
+frequencies (theta ``rope_theta``), the softmax scale ``(nope + rope) ** -0.5``
+and no selection.
 
 **The cut.** ``expert_shard = (rank, size)``: the routed experts held (a
 contiguous ``n_routed_experts / size``). The router ranks all routed and zero
@@ -76,12 +62,9 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
 from daft_tpu.errors import DaftValueError
-from daft_tpu.models import decoders
+from daft_tpu.models import decoders, latent_attention
 from daft_tpu.models.decoders import draw, gated_mlp, mm, rms
-from daft_tpu.ops import pallas_mla_attention
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
 PUBLISHED: Dict[str, Dict[str, Any]] = {
@@ -244,103 +227,26 @@ def init_longcat_params(cfg: LongcatFlashConfig, seed: int = 0):
 
 
 # ---------------------------------------------------------------------- #
-# Latent attention                                                        #
+# Latent attention: ``models/latent_attention.py``, with this model's scales #
 # ---------------------------------------------------------------------- #
+mla_core_absorbed = latent_attention.core_absorbed
+mla_core_expanded = latent_attention.core_expanded
+mla_expanded_over_slots = latent_attention.expanded_over_slots
+
+
 def rope(x, positions, theta: float):
     """Rotary positions over the last axis, pairs interleaved: (x[2i], x[2i+1])
     turns by ``positions * theta ** (-2i / n)``. x (B, T, ..., n), positions
     (B, T). float32 in and out."""
-    n = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
-    ang = positions.astype(jnp.float32)[..., None] * inv                   # (B, T, n / 2)
-    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + ang.shape[-1:])
-    pairs = x.reshape(x.shape[:-1] + (n // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
+    return latent_attention.rope(x, positions, latent_attention.frequencies(theta, x.shape[-1]))
 
 
 def mla_project(cfg, p, s: str, x, positions):
     """x (B, T, d) normed -> (q (B, T, H, nope + rope) with its rotary part turned,
-    the tokens' cache rows (B, T, cache_row)), both bfloat16."""
-    B, T, _ = x.shape
-    eps = cfg.rms_norm_eps
-    cq = (rms(mm(x, p["q_a" + s]), p["q_a_norm" + s], eps) * cfg.q_scale).astype(cfg.dtype)
-    q = mm(cq, p["q_b" + s]).reshape(B, T, cfg.num_attention_heads, cfg.qk_head_dim)
-    q = jnp.concatenate([q[..., :cfg.qk_nope_head_dim], rope(q[..., cfg.qk_nope_head_dim:], positions, cfg.rope_theta)], -1)
-    ckr = mm(x, p["kv_a" + s])
-    c = rms(ckr[..., :cfg.kv_lora_rank], p["kv_a_norm" + s], eps) * cfg.kv_scale
-    rows = jnp.concatenate([c, rope(ckr[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)], -1)
-    return q.astype(cfg.dtype), rows.astype(cfg.dtype)
-
-
-def _kv_b(cfg, p, s: str):
-    """``W_kvb`` as (latent, heads, nope + v)."""
-    return p["kv_b" + s].reshape(cfg.kv_lora_rank, cfg.num_attention_heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
-
-
-_LOW = float(np.finfo(np.float32).min)
-#: Positions a decode step's write takes in and puts back around the one it sets: one lane tile.
-_WRITE_POSITIONS = 128
-
-
-def mla_core_absorbed(cfg, w_kvb, q, cache, positions):
-    """``W_kvb`` absorbed into the query and the output: q (B, T, H, nope + rope)
-    against every position of ``cache`` (B, cache_row, S), causal by ``positions``
-    (B, T); no key or value is expanded. -> (B, T, H, v) float32."""
-    nope, lat = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    q_lat = jnp.einsum("bthd,chd->bthc", q[..., :nope], w_kvb[..., :nope], preferred_element_type=jnp.float32)
-    q_abs = jnp.concatenate([q_lat.astype(cfg.dtype), q[..., nope:]], -1)          # (B, T, H, cache_row)
-    scores = jnp.einsum("bhtc,bcs->bhts", jnp.swapaxes(q_abs, 1, 2), cache, preferred_element_type=jnp.float32) * cfg.qk_head_dim ** -0.5
-    seen = jnp.arange(cache.shape[2])[None, None, :] <= positions[:, :, None]       # (B, T, S)
-    probs = jax.nn.softmax(jnp.where(seen[:, None], scores, _LOW), axis=-1).astype(cfg.dtype)
-    # over whole rows (the rotary part's columns are dropped after): no copy of the cache's latent columns
-    o_lat = jnp.einsum("bhts,bcs->bhtc", probs, cache, preferred_element_type=jnp.float32)[..., :lat]
-    return jnp.einsum("bhtc,chd->bthd", o_lat.astype(cfg.dtype), w_kvb[..., nope:], preferred_element_type=jnp.float32)
-
-
-def mla_core_expanded(cfg, w_kvb, q, block_of, blocks, positions):
-    """One chunk of T queries a row over the ``blocks`` blocks of cache rows that
-    reach its last position: each block's rows (``block_of(j)`` -> (B,
-    cache_row, S), positions ``j S ..``) are expanded to per-head keys and values, a
-    running softmax between blocks. q (B, T, H, nope + rope), positions (B, T).
-    -> (B, T, H, v) float32."""
-    B, T, H, _ = q.shape
-    nope, lat, dv = cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.v_head_dim
-
-    def body(j, carry):
-        m, l, acc = carry                                               # (B, H, T), (B, H, T), (B, T, H, v)
-        rows = block_of(j)
-        S = rows.shape[2]
-        kv = jnp.einsum("bcs,chd->bshd", rows[:, :lat], w_kvb, preferred_element_type=jnp.float32).astype(cfg.dtype)
-        k_r = jnp.broadcast_to(jnp.swapaxes(rows[:, lat:], 1, 2)[:, :, None, :], (B, S, H, cfg.qk_rope_head_dim))
-        k = jnp.concatenate([kv[..., :nope], k_r], -1)
-        sc = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32) * cfg.qk_head_dim ** -0.5
-        seen = (j * S + jnp.arange(S))[None, None, :] <= positions[:, :, None]      # (B, T, S)
-        sc = jnp.where(seen[:, None], sc, _LOW)
-        m_new = jnp.maximum(m, sc.max(-1))
-        w = jnp.exp(sc - m_new[..., None])
-        scale = jnp.exp(m - m_new)
-        acc = acc * jnp.moveaxis(scale, 1, 2)[..., None] + jnp.einsum(
-            "bhts,bshd->bthd", w.astype(cfg.dtype), kv[..., nope:], preferred_element_type=jnp.float32)
-        return m_new, l * scale + w.sum(-1), acc
-
-    init = (jnp.full((B, H, T), _LOW), jnp.zeros((B, H, T), jnp.float32), jnp.zeros((B, T, H, dv), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
-    return acc / jnp.moveaxis(l, 1, 2)[..., None]
-
-
-def mla_expanded_over_slots(cfg, w_kvb, q, kv, slots, starts):
-    """``mla_core_expanded`` for the rows ``slots`` of ``kv`` (slots, cache_row, S),
-    each at the chunk that begins at ``starts``: every row over the blocks of T
-    positions that the call's deepest row attends (each row of a call at its own
-    depth: the blocks beyond a row's own positions are masked and weigh 0)."""
-    B, T = q.shape[:2]
-
-    def block_of(j):
-        return jnp.concatenate([jax.lax.dynamic_slice(kv, (slots[b], 0, j * T), (1, kv.shape[1], T)) for b in range(B)])
-
-    return mla_core_expanded(cfg, w_kvb, q, block_of, jnp.max(starts) // T + 1, starts[:, None] + jnp.arange(T)[None, :])
+    the tokens' cache rows (B, T, cache_row)), both bfloat16: the shared
+    projection with both LoRA scales and plain rotary frequencies."""
+    inv = latent_attention.frequencies(cfg.rope_theta, cfg.qk_rope_head_dim)
+    return latent_attention.project(cfg, p, s, x, positions, inv, cfg.q_scale, cfg.kv_scale)[:2]
 
 
 def _mla_prefill(cfg, p, s, x, kv, slots, starts, lengths):
@@ -353,20 +259,8 @@ def _mla_prefill(cfg, p, s, x, kv, slots, starts, lengths):
     with jax.named_scope("mla_proj"):
         q, rows = mla_project(cfg, p, s, x, positions)
     with jax.named_scope("mla_core"):
-        cols = jnp.swapaxes(rows, 1, 2)                                 # (B, cache_row, T): positions are minor
-        for b in range(B):  # valid tokens only: padding, and a row that carries no prompt, leave the slot as it was
-            at = (slots[b], 0, starts[b])
-            old = jax.lax.dynamic_slice(kv, at, (1, cfg.cache_row, T))
-            kv = jax.lax.dynamic_update_slice(kv, jnp.where(valid[b][None, None, :], cols[b][None], old), at)
-
-        w_kvb = _kv_b(cfg, p, s)
-        if pallas_mla_attention.mla_prefill_applies(q.shape, q.dtype, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-                                                    cfg.qk_rope_head_dim, cfg.v_head_dim):
-            out = pallas_mla_attention.mla_prefill_attention(q, kv, w_kvb, slots, starts, lengths, nope=cfg.qk_nope_head_dim)
-            decoders.note_on_serving_span("mla", "fused")
-        else:
-            out = mla_expanded_over_slots(cfg, w_kvb, q, kv, slots, starts)
-            decoders.note_on_serving_span("mla", "expanded")
+        kv = latent_attention.write_chunk(kv, rows, slots, starts, valid)
+        out = latent_attention.attend_chunk(cfg, latent_attention.kv_b(cfg, p, s), q, kv, slots, starts, lengths)
     with jax.named_scope("mla_proj"):
         return mm(out.astype(cfg.dtype).reshape(B, T, -1), p["o" + s]), kv
 
@@ -377,17 +271,8 @@ def _mla_decode(cfg, p, s, x, kv, positions, active):
     with jax.named_scope("mla_proj"):
         q, rows = mla_project(cfg, p, s, x, positions[:, None])
     with jax.named_scope("mla_core"):
-        # Slot by slot, in place, a lane tile of positions at a time: a window one position wide (as a gather,
-        # a scatter or a slice) makes XLA lay the whole cache out rows-minor for it, a copy of every row an
-        # attention a step (8 ms a step at 16 x 16,449 positions). An inactive slot keeps what it held.
-        W = min(_WRITE_POSITIONS, kv.shape[2])
-        for b in range(B):
-            first = jnp.minimum(positions[b] // W * W, kv.shape[2] - W)
-            old = jax.lax.dynamic_slice(kv, (b, 0, first), (1, cfg.cache_row, W))
-            here = (jnp.arange(W) == positions[b] - first) & active[b]
-            kv = jax.lax.dynamic_update_slice(kv, jnp.where(here, rows[b, 0][None, :, None], old), (b, 0, first))
-        out = mla_core_absorbed(cfg, _kv_b(cfg, p, s), q, kv, positions[:, None])
-        decoders.note_on_serving_span("mla", "absorbed")
+        kv = latent_attention.write_token(kv, rows[:, 0], positions, active)
+        out = latent_attention.attend_token(cfg, latent_attention.kv_b(cfg, p, s), q, kv, positions)
     with jax.named_scope("mla_proj"):
         return mm(out.astype(cfg.dtype).reshape(B, 1, -1), p["o" + s]), kv
 
@@ -421,12 +306,6 @@ def _moe(cfg, p, u, valid):
               "held_assignments": jnp.sum(held), "max_expert_load": jnp.max(sizes),
               "experts_reached": jnp.sum(sizes > 0)}
     return y, counts
-
-
-def _add_counts(a, b):
-    if a is None:
-        return b
-    return {k: jnp.maximum(a[k], b[k]) if k == "max_expert_load" else a[k] + b[k] for k in a}
 
 
 # ---------------------------------------------------------------------- #
@@ -465,7 +344,7 @@ class LongcatFlashLM:
                 x = x + out
                 u = norm(x, p["ffn_norm0"])
                 m, counts = _moe(cfg, p, u.reshape(B * T, -1), valid.reshape(-1))
-                totals = _add_counts(totals, counts)
+                totals = decoders.add_counts(totals, counts)
                 with jax.named_scope("dense_mlp"):
                     x = x + gated_mlp(u.astype(cfg.dtype), p["ffn_in0"], p["ffn_out0"], cfg.dtype)
                 out, kv1 = attend(p, "1", norm(x, p["attn_norm1"]).astype(cfg.dtype), state[2 * i + 1]["kv"])

@@ -23,7 +23,9 @@ SSM state and a conv tail beside them; ``models/longcat_flash`` keeps one
 latent row a token an attention (576 values where 64 heads' keys and values
 would be 20,480), writes only a prefill's chunk into it and reads only the
 blocks a chunk attends; ``models/olmo_hybrid`` keeps a delta rule's state and a
-conv tail in three layers of four and 30 heads' key/value rows in the fourth.
+conv tail in three layers of four and 30 heads' key/value rows in the fourth;
+``models/deepseek_v32`` keeps two row leaves a layer, the latent row and the key
+of the indexer that selects which rows a query attends (128 values beside 576).
 
 **Prefill is chunked at one static length**, so prompts of any length share
 one executable: a prompt runs as ceil(P / chunk) calls that carry state, the
@@ -44,7 +46,10 @@ key/value rows, has moved on with its slot.
 Spans (``profiling.device_span``): ``serve.prefill`` (one admission round's calls:
 ``slot``, ``rows``: the prompts prefilled, ``tokens``, ``chunks``: the calls, ``padded_tokens`` = calls x prompts
 a call x ``chunk``, ``row_chunks``, ``first``; ``mixed_calls``: the calls whose prompts stood at unlike depths;
-``pairs``: the causal (query, key) pairs of the round's prompts, an attention's least work;
+``pairs``: the causal (query, key) pairs of the round's prompts, an attention's least work; beside it whatever
+the model counts of a round from its prompts' lengths (``prefill_counts``, if it has one: ``models/deepseek_v32``
+gives ``index_pairs``, the pairs its indexer scores, = ``pairs``, and ``selected_pairs``, the pairs its selection
+keeps: sum over prompt positions of min(position + 1, ``index_topk``));
 ``block_rows``: the (row, block of ``chunk`` positions) pairs its calls attend in which the row holds a query,
 of ``padded_block_rows`` = sum over calls of rows a call x (the deepest row's block + 1) that calls of static
 shape span),
@@ -59,7 +64,9 @@ and ``serve.copy_state``; what of a step lies in neither child is its own, befor
 them while its program traced (``moe`` = ``grouped`` | ``xla``: the path of the
 routed experts' grouped products, ``models/decoders.grouped_mlp``; ``mla`` =
 ``fused`` | ``expanded`` | ``absorbed``: the latent attention's, ``models/longcat_flash``;
-``delta`` = ``chunked`` | ``recurrent``: the gated delta rule's, ``models/olmo_hybrid``).
+``delta`` = ``chunked`` | ``recurrent``: the gated delta rule's, ``models/olmo_hybrid``;
+``dsa`` = ``masked`` (| ``gathered``, no program yet): how ``models/deepseek_v32``'s attention applies its
+selection: every block a row holds expanded and scored with the keys not kept masked out, or the kept rows gathered).
 """
 
 from __future__ import annotations
@@ -184,7 +191,8 @@ class ContinuousBatcher:
 
     def _repeat_noted(self, sp) -> None:
         """A model notes its choice of path on the open span while its program
-        traces (``decoders.grouped_mlp``: ``moe``; ``longcat_flash``: ``mla``; ``olmo_hybrid``: ``delta``, ``attn``); a
+        traces (``decoders.grouped_mlp``: ``moe``; ``latent_attention``: ``mla``; ``olmo_hybrid``: ``delta``, ``attn``;
+        ``deepseek_v32``: ``dsa``); a
         call that traces nothing repeats what the trace chose."""
         noted = self._noted.setdefault(sp.name, {})
         noted.update({k: v for k, v in sp.count.items() if isinstance(v, str)})
@@ -235,6 +243,8 @@ class ContinuousBatcher:
                          padded_block_rows=g * sum(max(c for _, c in call) + 1 for call in calls)) as sp:
             if self._prefill is None:
                 sp.count["first"] = 1  # this call traces and compiles (or loads) the program
+            # what the model counts of a round from its prompts' lengths (``deepseek_v32``: the pairs its selection keeps)
+            sp.count.update(getattr(self.model, "prefill_counts", lambda lens: {})(lens))
             fn = self._prefill_fn()
             for call in calls:
                 taken = [todo[i][1] for i, _ in call]

@@ -1,12 +1,13 @@
-"""LongCat-Flash's prefill attention as one Pallas TPU kernel: one chunk of ``T``
-queries a row against the blocks of latent cache rows its slot holds so far.
+"""The latent attention's prefill as one Pallas TPU kernel (``models/latent_attention``:
+LongCat-Flash's, and DeepSeek-V3.2's with a selection as a further input): one chunk of
+``T`` queries a row against the blocks of latent cache rows its slot holds so far.
 
 ``q (B, T, H, nope + rope)`` (the rotary part turned), the cache ``kv (slots,
 lat + rope, S)`` with positions minor (a token's row is ``[c | RoPE(k_r)]``),
 ``w_kvb (lat, H, nope + v)``; row ``b`` of the call is slot ``slots[b]``, its
 query ``t`` stands at position ``starts[b] + t``, and ``lengths[b] == 0`` says
-that the row carries no query. What XLA's path (``models/longcat_flash.
-mla_core_expanded``) streams through HBM stays in VMEM here:
+that the row carries no query. What XLA's path (``models/latent_attention.
+core_expanded``) streams through HBM stays in VMEM here:
 
 - **The scores.** A grid step holds one row's queries of ``heads`` heads and one
   block of ``T`` cache positions; per head it computes the ``(T, T)`` float32
@@ -26,7 +27,7 @@ mla_core_expanded``) streams through HBM stays in VMEM here:
   fetched, expanded or multiplied. A row without a query gets one visit that
   only writes zeros: the layers after the attention still run over that row.
 
-The arithmetic is ``mla_core_expanded``'s: operands enter the MXU in the dtype
+The arithmetic is ``core_expanded``'s: operands enter the MXU in the dtype
 they arrive in (the expanded keys and values cast to it, the probabilities cast
 to it for the second product), scores, max, exp, sum and the accumulator are
 float32, the scale multiplies the float32 scores, the causal mask is by
@@ -67,23 +68,27 @@ MAX_HEADS = 2
 _LOW = float(np.finfo(np.float32).min)
 
 
-def _step_bytes(T: int, lat: int, nope: int, rope: int, dv: int, heads: int, itemsize: int) -> int:
+def _step_bytes(T: int, lat: int, nope: int, rope: int, dv: int, heads: int, itemsize: int, selects: bool = False) -> int:
     """VMEM of one grid step over ``heads`` heads: the q, weight, cache and result
-    blocks, double-buffered; the running max, sum (a lane tile wide each) and
-    accumulator of every head and the keys' tile; and the float32 temporaries of
-    two heads in flight (the expanded block, scores, exponentials and their cast)."""
+    blocks (with a selection the block's float32 index scores and the thresholds,
+    a lane tile wide), double-buffered; the running max, sum (a lane tile wide
+    each) and accumulator of every head and the keys' tile; and the float32
+    temporaries of two heads in flight (the expanded block, scores, exponentials
+    and their cast)."""
     qk = pallas_attention._round_up(nope + rope, _LANES)
     blocks = 2 * itemsize * (T * heads * qk + heads * (nope + dv) * lat + (lat + rope) * T + T * heads * dv)
+    blocks += 2 * 4 * (T * T + T * _LANES) if selects else 0
     scratch = heads * T * (2 * _LANES + dv) * 4 + qk * T * itemsize
     flight = 2 * ((nope + dv) * T * 4 + T * T * (4 + 4 + itemsize))
     return blocks + scratch + flight
 
 
-def _heads_a_step(T: int, lat: int, nope: int, rope: int, dv: int, H: int, itemsize: int) -> int:
-    """The most heads (a divisor of ``H``, at most ``MAX_HEADS``) whose step fits
+def _heads_a_step(T: int, lat: int, nope: int, rope: int, dv: int, H: int, itemsize: int,
+                  max_heads: int = MAX_HEADS, selects: bool = False) -> int:
+    """The most heads (a divisor of ``H``, at most ``max_heads``) whose step fits
     the budget; 0 when one head's does not."""
-    for heads in range(min(H, MAX_HEADS), 0, -1):
-        if H % heads == 0 and _step_bytes(T, lat, nope, rope, dv, heads, itemsize) <= VMEM_BUDGET:
+    for heads in range(min(H, max_heads), 0, -1):
+        if H % heads == 0 and _step_bytes(T, lat, nope, rope, dv, heads, itemsize, selects) <= VMEM_BUDGET:
             return heads
     return 0
 
@@ -92,7 +97,7 @@ def mla_prefill_applies(q_shape, dtype, lat: int, nope: int, rope: int, dv: int)
     """Whether ``mla_prefill_attention`` serves this chunk: a TPU backend,
     bfloat16, the latent, both head widths and the chunk in whole 128-lane tiles
     (the rotary width in whole sublane tiles), and one head's step inside
-    ``VMEM_BUDGET``. Otherwise the caller takes ``mla_core_expanded``."""
+    ``VMEM_BUDGET``. Otherwise the caller takes ``latent_attention.core_expanded``."""
     _, T, H, _ = q_shape
     return (pallas_attention.backend_is_tpu()
             and jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16)
@@ -116,13 +121,20 @@ def row_visits(starts: jax.Array, lengths: jax.Array, T: int, max_blocks: int):
     return counts, row, block, ends[-1]
 
 
-def _kernel(slots, starts, counts, row, block, q_ref, w_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref, k_ref, *,
-            lat: int, nope: int, rope: int, dv: int, heads: int, scale: float):
+def _kernel(slots, starts, counts, row, block, q_ref, w_ref, kv_ref, *rest,
+            lat: int, nope: int, rope: int, dv: int, heads: int, scale: float, selects: bool):
     """One visit: blocks are q ``(1, T, heads * qk)``, the weights ``(heads, nope
-    + v, lat)``, the cache rows ``(1, lat + rope, T)`` and the result ``(1, T,
-    heads * v)``; scratch is the running max and sum ``(heads, T, 128)``, the
-    accumulator ``(heads, T, v)`` and the keys' tile ``(qk, T)``."""
+    + v, lat)``, the cache rows ``(1, lat + rope, T)``, with a selection the
+    block's index scores ``(1, T, T)`` and the queries' thresholds ``(1, T, 1)``,
+    and the result ``(1, T, heads * v)``; scratch is the running max and sum
+    ``(heads, T, 128)``, the accumulator ``(heads, T, v)`` and the keys' tile
+    ``(qk, T)``."""
     from jax.experimental import pallas as pl
+
+    if selects:
+        idx_ref, thr_ref, o_ref, m_ref, l_ref, acc_ref, k_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref, k_ref = rest
 
     T = q_ref.shape[1]
     qk = k_ref.shape[0]
@@ -146,6 +158,8 @@ def _kernel(slots, starts, counts, row, block, q_ref, w_ref, kv_ref, o_ref, m_re
             k_ref[nope + rope:, :] = jnp.zeros((qk - nope - rope, T), dtype)
         key = j * T + jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
         seen = key <= start + jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+        if selects:  # a key enters a query's softmax where its index score reaches the query's threshold
+            seen = seen & (idx_ref[0] >= thr_ref[0])
         for h in range(heads):
             kv_t = jnp.dot(w_ref[h], c, preferred_element_type=jnp.float32)     # (nope + v, T)
             k_ref[:nope, :] = kv_t[:nope].astype(dtype)
@@ -172,21 +186,33 @@ def _kernel(slots, starts, counts, row, block, q_ref, w_ref, kv_ref, o_ref, m_re
 
 
 # Jitted so that the attentions of a model share one trace and one lowering of the kernel (as ``fused_attention``).
-@functools.partial(jax.jit, static_argnames=("nope", "interpret"))
+@functools.partial(jax.jit, static_argnames=("nope", "interpret", "scale", "max_heads"))
 def mla_prefill_attention(q: jax.Array, kv: jax.Array, w_kvb: jax.Array, slots: jax.Array, starts: jax.Array,
-                          lengths: jax.Array, nope: int, interpret: bool = False) -> jax.Array:
+                          lengths: jax.Array, nope: int, interpret: bool = False, scale: float = None,
+                          index: jax.Array = None, threshold: jax.Array = None,
+                          max_heads: int = MAX_HEADS) -> jax.Array:
     """``q (B, T, H, nope + rope)``, ``kv (slots, lat + rope, S)``, ``w_kvb (lat, H,
     nope + v)``, ``slots``, ``starts`` (multiples of ``T``) and ``lengths`` ``(B,)``
     integers. Returns ``(B, T, H, v)`` in ``q``'s dtype: query ``t`` of row ``b``
     over positions ``<= starts[b] + t`` of slot ``slots[b]``, zeros for a row
-    with ``lengths[b] == 0``. The caller has asked ``mla_prefill_applies``."""
+    with ``lengths[b] == 0``. The caller has asked ``mla_prefill_applies``.
+
+    ``scale`` multiplies the scores (``None``: ``(nope + rope) ** -0.5``).
+    A selection (``models/deepseek_v32``) is ``index (B, T, blocks * T)`` float32,
+    query ``t``'s index score of each position of its slot, and ``threshold (B,
+    T)``: position ``s`` enters the softmax where it is causal and ``index[b, t,
+    s] >= threshold[b, t]``. The walk is the same (the masked form: a block of
+    which no query keeps a key is still expanded); what the visits of one block
+    read more is its ``(T, T)`` scores, once a head group, which is why such a
+    caller takes more heads a step (``max_heads``). Without ``index`` the call
+    lowers to the kernel it was before a selection existed."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, qk_dim = q.shape
     lat, _, kv_dim = w_kvb.shape
     rope, dv = qk_dim - nope, kv_dim - nope
-    heads = _heads_a_step(T, lat, nope, rope, dv, H, q.dtype.itemsize)
+    heads = _heads_a_step(T, lat, nope, rope, dv, H, q.dtype.itemsize, max_heads, index is not None)
     if heads == 0:
         raise ValueError(f"mla_prefill_attention: one head's step over T={T}, latent {lat} exceeds "
                          f"the VMEM budget of {VMEM_BUDGET} bytes")
@@ -195,15 +221,23 @@ def mla_prefill_attention(q: jax.Array, kv: jax.Array, w_kvb: jax.Array, slots: 
     counts, row, block, visits = row_visits(starts, lengths, T, max_blocks)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, qk - qk_dim))).reshape(B, T, H * qk)
     w_t = jnp.transpose(w_kvb, (1, 2, 0))                                   # (H, nope + v, lat)
+    selects = index is not None
+    selection, selection_specs = (), []
+    if selects:
+        selection = (index, threshold.reshape(B, T, 1).astype(index.dtype))
+        selection_specs = [pl.BlockSpec((1, T, T), lambda g, v, slots, starts, counts, row, block: (row[v], 0, block[v])),
+                           pl.BlockSpec((1, T, 1), lambda g, v, slots, starts, counts, row, block: (row[v], 0, 0))]
     out = pl.pallas_call(
-        functools.partial(_kernel, lat=lat, nope=nope, rope=rope, dv=dv, heads=heads, scale=qk_dim ** -0.5),
+        functools.partial(_kernel, lat=lat, nope=nope, rope=rope, dv=dv, heads=heads,
+                          scale=qk_dim ** -0.5 if scale is None else scale, selects=selects),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(H // heads, visits),
             in_specs=[pl.BlockSpec((1, T, heads * qk), lambda g, v, slots, starts, counts, row, block: (row[v], 0, g)),
                       pl.BlockSpec((heads, nope + dv, lat), lambda g, v, *_: (g, 0, 0)),
                       pl.BlockSpec((1, lat + rope, T),
-                                   lambda g, v, slots, starts, counts, row, block: (slots[row[v]], 0, block[v]))],
+                                   lambda g, v, slots, starts, counts, row, block: (slots[row[v]], 0, block[v]))]
+            + selection_specs,
             out_specs=pl.BlockSpec((1, T, heads * dv), lambda g, v, slots, starts, counts, row, block: (row[v], 0, g)),
             scratch_shapes=[pltpu.VMEM((heads, T, _LANES), jnp.float32), pltpu.VMEM((heads, T, _LANES), jnp.float32),
                             pltpu.VMEM((heads, T, dv), jnp.float32), pltpu.VMEM((qk, T), q.dtype)]),
@@ -212,5 +246,5 @@ def mla_prefill_attention(q: jax.Array, kv: jax.Array, w_kvb: jax.Array, slots: 
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
-    )(slots.astype(jnp.int32), starts.astype(jnp.int32), counts, row, block, q, w_t, kv)
+    )(slots.astype(jnp.int32), starts.astype(jnp.int32), counts, row, block, q, w_t, kv, *selection)
     return out.reshape(B, T, H, dv)

@@ -114,8 +114,12 @@ def test_chip_smoke_tiny_cpu_is_a_dry_run():
     assert rec["chip_smoke"] == "dry" and '"ok"' not in proc.stdout
     assert rec["platform"] == "cpu"
     assert set(rec["phases"]) == {"A_embed_image", "A_jpeg_host_stage_ahead", "B_embed_text", "C_prompt",
-                                  "C_prompt_hybrid", "C_prompt_longcat", "C_prompt_olmo", "D_device_chain", "E_pallas"}
+                                  "C_prompt_hybrid", "C_prompt_longcat", "C_prompt_deepseek", "C_prompt_olmo", "D_device_chain",
+                                  "E_pallas"}
     assert rec["phases"]["C_prompt_olmo"]["delta"] == {"serve.prefill": "chunked", "serve.decode_step": "recurrent"}
+    selection = rec["phases"]["C_prompt_deepseek"]  # the tiny decoder's selection bites; its two kernels ran interpreted
+    assert selection["paths"] == {"serve.prefill": ["masked", "expanded"], "serve.decode_step": ["masked", "absorbed"]}
+    assert 0 < selection["selected_pair_share"] < 1 and 0 < selection["kernels"]["max_abs_diff_vs_masked_loop"] < 3e-2
     assert len(rec["phases"]["A_jpeg_host_stage_ahead"]["ready"]) == 2  # two morsels through the host stage
     kernel = rec["phases"]["C_prompt_longcat"]["mla_kernel"]  # interpreted here; the tiny model itself stays expanded
     assert kernel["shape"][2:] == [2, 517] and 0 < kernel["max_abs_diff_vs_expanded"] < 3e-2

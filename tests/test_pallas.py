@@ -355,3 +355,38 @@ def test_mla_prefill_attention_compiles_for_v5e_with_no_expansion_and_no_copy_of
     assert f"bf16[{B},{T},{H * dv}]" in text
     heads = pm._heads_a_step(T, lat, nope, rope, dv, H, 2)
     assert heads > 0 and pm._step_bytes(T, lat, nope, rope, dv, heads, 2) <= pm.VMEM_BUDGET
+
+
+def test_deepseeks_index_kernel_and_selected_attention_compile_for_v5e(one_chip):
+    """One layer's selection path of DeepSeek-V3.2-Exp's prefill call at the cell's shapes (4 rows of 512 queries,
+    64 index heads of 128, 128 heads of 128 + 64 | 128 over a latent of 512, 8 slots of 32,896 positions): the index
+    kernel, the threshold search and the prefill kernel with the selection as an input, compiled by the TPU's
+    compiler (nothing runs): two custom calls; no (heads, 512, 512) score tensor of either in HBM; neither cache
+    copied; the search reads the (4, 512, 33,280) scores and writes no tensor of that size but its ordered copy."""
+    from daft_tpu.models import deepseek_v32 as ds
+    from daft_tpu.ops import pallas_dsa_index as pi
+    from daft_tpu.ops import pallas_mla_attention as pm
+
+    B, T, H, Hi, Di, lat, nope, rope, dv, slots_n, P = 4, 512, 128, 64, 128, 512, 128, 64, 128, 8, 32896
+    cfg = ds.DeepseekV32Config.from_name("DeepSeek-V3.2-Exp", num_layers=5, expert_shard=(0, 16), vocab_shard=(0, 8))
+
+    def select_and_attend(q, kv, w_kvb, qi, w, ik, slots, starts, lengths):
+        index = pi.index_scores(qi, w, ik, slots, starts, lengths)
+        threshold = ds.kth_threshold(index, starts[:, None] + jnp.arange(T)[None, :], cfg.index_topk, reach=jnp.max(starts) + T)
+        return pm.mla_prefill_attention(q, kv, w_kvb, slots, starts, lengths, nope=nope, scale=cfg.softmax_scale,
+                                        index=index, threshold=threshold, max_heads=ds.KERNEL_HEADS)
+
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, T, H, nope + rope), jnp.bfloat16), ((slots_n, lat + rope, P), jnp.bfloat16), ((lat, H, nope + dv), jnp.bfloat16),
+        ((B, T, Hi, Di), jnp.bfloat16), ((B, T, Hi), jnp.float32), ((slots_n, Di, P), jnp.bfloat16),
+        ((B,), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))]
+    text = jax.jit(select_and_attend).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert f"f32[{B},{Hi},{T},{T}]" not in text and f"f32[{B},{H},{T},{T}]" not in text
+    for cache in ((slots_n, lat + rope, P), (slots_n, Di, P)):
+        assert not re.search(r"= %s\S* (copy|transpose)\(" % re.escape("bf16[%d,%d,%d]" % cache), text)
+    wide = -(-P // T) * T
+    assert f"f32[{B},{T},{wide}]" in text and f"u32[{B},{T},{wide}]" in text
+    heads = pm._heads_a_step(T, lat, nope, rope, dv, H, 2, ds.KERNEL_HEADS, selects=True)
+    assert heads == ds.KERNEL_HEADS and pm._step_bytes(T, lat, nope, rope, dv, heads, 2, selects=True) <= pm.VMEM_BUDGET
+    assert pi._step_bytes(T, Hi, Di, 2) <= pi.VMEM_BUDGET
