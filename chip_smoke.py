@@ -319,7 +319,8 @@ def phase_c_olmo(cfg, tiny: bool) -> dict:
     prefill that carries the delta rule's state, the decode loop, answers with
     log-probabilities, ``delta`` noted on both serving spans and both kinds of
     slot state counted on ``prompt.run``; then, on the chip, the kernels over the
-    key/value rows (``cache_kernels_check``)."""
+    key/value rows (``cache_kernels_check``) and the delta rule's kernel
+    (``delta_rule_check``)."""
     import daft_tpu
     from daft_tpu import col
     from daft_tpu.functions.ai import prompt
@@ -351,7 +352,55 @@ def phase_c_olmo(cfg, tiny: bool) -> dict:
            "kv_bytes": held["kv_bytes"], "recurrent_bytes": held["recurrent_bytes"]}
     if not tiny:
         out["cache_kernels"] = cache_kernels_check()
+    out["delta_rule"] = delta_rule_check(tiny)
     return out
+
+
+def delta_rule_check(tiny: bool) -> dict:
+    """The delta rule's kernel (``ops/pallas_delta_rule.py``) against the XLA form
+    it stands beside (``olmo_hybrid.gated_delta_chunked``) at the published head
+    sizes (96 | 192, chunks of 64; on the chip three rows of 512 steps x 30 heads
+    from a carried state: a full row, one that ends inside a chunk, one that is
+    all padding and must return its state to the bit; under --tiny-cpu two heads
+    and 128 steps, interpreted): the largest |difference| of o and of the state.
+    Then, on the chip, the tiny decoder with chunks of 64 steps (the published
+    model's, and the chunk the kernel serves) through the batcher: the prefill
+    span must read ``delta`` = ``fused``, the decode span ``recurrent``."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from daft_tpu.models import olmo_hybrid as oh
+    from daft_tpu.models.serving import ContinuousBatcher, Request
+    from daft_tpu.ops import pallas_delta_rule as pdr
+    from daft_tpu.profiling import newest_device_span
+
+    B, T, H, dk, dv, C = (2, 128, 2, 96, 192, 64) if tiny else (3, 512, 30, 96, 192, 64)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    q = (oh._l2_normalised(jax.random.normal(ks[0], (B, T, H, dk))) * dk ** -0.5).astype(jnp.bfloat16)
+    k = oh._l2_normalised(jax.random.normal(ks[1], (B, T, H, dk))).astype(jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, T, H, dv)).astype(jnp.bfloat16)
+    lengths = jnp.asarray([T, T - 37, 0][:B] if not tiny else [T, 0], jnp.int32)
+    keep = (jnp.arange(T)[None, :] < lengths[:, None])[..., None]
+    g = jnp.where(keep, -jnp.exp(-2.0 + 2.0 * jax.random.normal(ks[3], (B, T, H))), 0.0)
+    beta = jnp.where(keep, 2 * jax.nn.sigmoid(3 * jax.random.normal(ks[4], (B, T, H))), 0.0)
+    s0 = jax.random.normal(ks[5], (B, H, dv, dk))
+    assert tiny or pdr.delta_rule_applies(q.shape, v.shape, jnp.bfloat16, C), "the kernel does not apply at the published widths"
+    want_o, want_s = jax.jit(oh.gated_delta_chunked, static_argnums=6)(q, k, v, g, beta, s0, C)
+    got_o, got_s = pdr.gated_delta_fused(q, k, v, g, beta, s0, chunk=C, interpret=tiny)
+    assert bool(jnp.all(got_s[-1] == s0[-1])), "a row that is all padding did not return its state as it came"
+    gaps = {"o": float(jnp.max(jnp.abs(got_o - want_o))), "state": float(jnp.max(jnp.abs(got_s - want_s)))}
+    assert max(gaps.values()) <= 1e-4 and float(jnp.std(want_o)) > 0.05, gaps  # both forms are float32 after their inputs
+    out = {"shape": [B, T, H, dk, dv, C], "max_abs_diff_vs_chunked": gaps}
+    if tiny:
+        return out
+    model, params = oh.init_olmo_params(dataclasses.replace(oh.OlmoHybridConfig.from_name("olmo-hybrid-tiny"), linear_chunk_size=64), 0)
+    b = ContinuousBatcher(model, params, num_slots=4, max_seq_len=700, eos_id=None)
+    answers = b.run([Request(tokens=np.arange(2, 2 + n).astype(np.int32) % 256, max_new_tokens=4) for n in (600, 90, 300)])
+    delta = {name: newest_device_span(name).count.get("delta") for name in ("serve.prefill", "serve.decode_step")}
+    assert delta == {"serve.prefill": "fused", "serve.decode_step": "recurrent"} and all(len(a) == 4 for a in answers), (delta, answers)
+    return dict(out, delta=delta)
 
 
 def cache_kernels_check() -> dict:

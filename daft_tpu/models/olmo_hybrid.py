@@ -23,7 +23,7 @@ here; products take bfloat16 operands and accumulate in float32):
     full_attention: q, k = RMSNorm(W_q h), RMSNorm(W_k h) over the whole projection (QK-norm), v = W_v h,
         heads of ``head_dim``, scale head_dim ** -0.5, causal softmax, W_o; no rotary positions (``rope_theta`` null)
 
-**The delta rule in chunks** (``gated_delta_chunked``). The write ``beta (v -
+**The delta rule in chunks** (``gated_delta_chunked``, ``ops/pallas_delta_rule.py``). The write ``beta (v -
 alpha S k)`` reads the state, so inside a chunk of C steps the writes
 ``u_i = beta_i (v_i - alpha_i S_{i-1} k_i)`` solve a unit-lower-triangular system.
 With ``gamma_i`` the decay accumulated from the chunk's start and
@@ -35,14 +35,27 @@ With ``gamma_i`` the decay accumulated from the chunk's start and
     O = (Q * gamma) S_0^T + tril(Q K^T * Gamma) U
     S_C = gamma_C S_0 + U^T (K * gamma_C / gamma)
 
-Everything that does not read ``S_0`` is computed for all chunks of a call at
-once; a ``lax.scan`` over the chunks carries the state through four small
-products each. q, k and v arrive in bfloat16 and ``K K^T`` and ``Q K^T`` take
-them so; the decays, the solve, the state and every product that reads the
-solve's result or the state are float32. Padding has ``g = 0`` and ``beta = 0``: it neither decays
-nor writes. Decode is one step of the recurrence a slot (``gated_delta_step``).
-Which form a program traced is noted on the batcher's open span as ``delta`` =
-``chunked`` | ``recurrent``.
+Two forms of this algebra, which share no code on purpose. On a TPU, at bfloat16
+inputs, whole chunks of 64 steps and an even head group inside the VMEM budget
+(``ops/pallas_delta_rule.delta_rule_applies``, asked while the prefill program
+traces: no option), the call is **one Pallas kernel**
+(``pallas_delta_rule.gated_delta_fused``): grid (rows, head groups, chunks in
+order), the group's state in a VMEM scratch from ``S_0`` at a row's first chunk
+to the result after its last, and ``K K^T``, the decays, ``A``, ``T``, ``W``,
+``U'``, ``U`` never in HBM; its solve substitutes inside diagonal blocks of 16
+steps, two heads side by side in the lanes, and merges them by block products
+(that module's head has the grid, the solve and the edges). Everywhere else (the CPU, the tiny test sizes, the
+rehearsals) and as the kernel's check on the chip, ``gated_delta_chunked`` below
+is **XLA's form**: everything that does not read ``S_0`` computed for all chunks
+of a call at once, a ``lax.scan`` over the chunks carrying the state through
+four small products each, the solve a row at a time. In both, q, k and v arrive
+in bfloat16 and ``K K^T`` and ``Q K^T`` take them so; the decays, the solve, the
+state and every product that reads the solve's result or the state are float32
+(``Precision.HIGHEST``). Padding has ``g = 0`` and ``beta = 0``: it neither
+decays nor writes. ``cfg.linear_chunk_size`` is the one chunk both read. Decode
+is one step of the recurrence a slot (``gated_delta_step``), XLA's in every
+program. Which form a program traced is noted on the batcher's open span as
+``delta`` = ``fused`` | ``chunked`` | ``recurrent``.
 
 **The cut.** ``num_hidden_layers`` keeps the first layers, whole periods of the
 layer pattern only. Every width, the vocabulary included, is as published.
@@ -86,7 +99,7 @@ import jax.numpy as jnp
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
 from daft_tpu.models.decoders import draw, gated_mlp, mm, rms
-from daft_tpu.ops import pallas_attention, pallas_cache_attention, pallas_cache_blocks
+from daft_tpu.ops import pallas_attention, pallas_cache_attention, pallas_cache_blocks, pallas_delta_rule
 
 LINEAR, FULL = "linear_attention", "full_attention"
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
@@ -357,8 +370,12 @@ def _linear(cfg, p, u, st, valid, lengths, single_step: bool):
             o = o[:, None]
             decoders.note_on_serving_span("delta", "recurrent")
         else:
-            o, s = gated_delta_chunked(q, k, v, g, beta, st["S"], cfg.linear_chunk_size)
-            decoders.note_on_serving_span("delta", "chunked")
+            fused = pallas_delta_rule.delta_rule_applies(q.shape, v.shape, cfg.dtype, cfg.linear_chunk_size)
+            decoders.note_on_serving_span("delta", "fused" if fused else "chunked")
+            if fused:  # one kernel; what it needs at its edges (q's and k's pad, the running sum of g) is traced here, in this scope
+                o, s = pallas_delta_rule.gated_delta_fused(q, k, v, g, beta, st["S"], chunk=cfg.linear_chunk_size)
+            else:
+                o, s = gated_delta_chunked(q, k, v, g, beta, st["S"], cfg.linear_chunk_size)
     with jax.named_scope("lin_proj"):
         y = rms(o, p["o_norm"], cfg.rms_norm_eps) * jax.nn.silu(gate)
         return mm(y.reshape(B, T, -1).astype(cfg.dtype), p["o"]), {"S": s, "conv": tail}

@@ -390,3 +390,27 @@ def test_deepseeks_index_kernel_and_selected_attention_compile_for_v5e(one_chip)
     heads = pm._heads_a_step(T, lat, nope, rope, dv, H, 2, ds.KERNEL_HEADS, selects=True)
     assert heads == ds.KERNEL_HEADS and pm._step_bytes(T, lat, nope, rope, dv, heads, 2, selects=True) <= pm.VMEM_BUDGET
     assert pi._step_bytes(T, Hi, Di, 2) <= pi.VMEM_BUDGET
+
+
+def test_the_delta_rule_kernel_compiles_for_v5e_with_no_chunk_tensor_in_hbm(one_chip):
+    """Olmo-Hybrid-7B's gated delta rule of one prefill call at the cell's shapes (4 rows of 512 steps, 30 heads of
+    96 | 192, chunks of 64), compiled by the TPU's compiler (nothing runs): one custom call and no loop; none of the
+    chunked form's tensors a (row, chunk, head) in HBM (``K K^T``, the decays, ``T``: ``f32[4,8,30,64,64]``; ``W``,
+    ``U'``); what XLA adds at the kernel's edges is q and k with each head's 96 columns padded to a lane tile and a
+    few vectors of the size of g; a step's heads fit the budget by the module's own reckoning."""
+    from daft_tpu.ops import pallas_delta_rule as pdr
+
+    B, T, H, dk, dv, C = 4, 512, 30, 96, 192, 64
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, T, H, dk), jnp.bfloat16), ((B, T, H, dk), jnp.bfloat16), ((B, T, H, dv), jnp.bfloat16),
+        ((B, T, H), jnp.float32), ((B, T, H), jnp.float32), ((B, H, dv, dk), jnp.float32))]
+    compiled = jax.jit(lambda *a: pdr.gated_delta_fused(*a, chunk=C)).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and not re.search(r"= \S+ while\(", text)
+    for gone in (f"f32[{B},{T // C},{H},{C},{C}]", f"f32[{B},{T // C},{H},{C},{dk}]", f"f32[{B},{T // C},{H},{C},{dv}]",
+                 f"f32[{T // C},{B},{H},{C},{dv}]"):
+        assert gone not in text, gone
+    assert f"bf16[{B},{T},{H * 128}]" in text                                  # q and k as the kernel reads them
+    assert compiled.memory_analysis().temp_size_in_bytes < 48 << 20           # two of those (15.7 MB each) and small change
+    heads = pdr._heads_a_step(C, H, dk, dv)
+    assert heads == pdr.MAX_HEADS and H % heads == 0 and pdr._step_bytes(C, dk, dv, heads) <= pdr.VMEM_BUDGET
