@@ -15,7 +15,12 @@ covers (the coverage line on standard error).
 ``counters`` adds what ``lm_scopes.tokens`` does not sum: any further counter
 of the window's spans, by name. ``beside`` hands a metric file the reader of an
 accepted one that reads the same spans whatever the decoder (the batcher's, the
-tokenizer's, the set-up's), so that a cell's own entry can list the cell.
+tokenizer's, the set-up's). It is for a cell appended between two ``benchmark``
+PRs: a PR that may only add entries cannot put its cell into an accepted
+entry's ``workloads``, so it brings an entry of its own that lists the cell and
+whose file is one line, ``read = decoder_scopes.beside(__file__, "<accepted
+name>")``; the next ``benchmark`` PR lists the cell in the accepted entry and
+drops that entry and its file (PR 42 did so for 29 of them).
 """
 
 from __future__ import annotations
@@ -117,7 +122,8 @@ def counters(run, span: str, *keys: str) -> Optional[Dict[str, float]]:
 
 
 def beside(metric_file: str, accepted: str) -> Callable:
-    """The ``read`` of the accepted metric ``accepted``, whose file lies beside ``metric_file``."""
+    """The ``read`` of the accepted metric ``accepted``, whose file lies beside ``metric_file``:
+    for an entry that lists a cell the accepted entry does not list yet (see the module's docstring)."""
     return manifest.load_module(os.path.join(os.path.dirname(os.path.abspath(metric_file)), accepted + ".py")).read
 
 

@@ -19,7 +19,9 @@ rule) is reused with this model's classes over every execution, in the traced
 window, of each program the entry hands out as shapes (``entry.lowerables``:
 name of the executions in the trace -> (jitted function, argument shapes)).
 Classes by scope path: ``ssd_scan`` (inside ``mamba``), ``mamba``, ``attn``,
-``experts`` (the grouped products of the routed experts), ``router``,
+``experts`` (the routed experts' two grouped products, whatever computes them:
+on a TPU ``ops/pallas_grouped_matmul.py``'s custom calls, filed by their scope;
+on the CPU XLA's ``ragged-dot`` kernels, filed by kernel name), ``router``,
 ``shared_mlp``, ``head``; all else is ``other``. A loop (``while``) is listed by
 the trace beside the operations of its body and is skipped, so that no time is
 counted twice.
@@ -89,8 +91,11 @@ def tokens(run) -> Optional[SimpleNamespace]:
     """Tokens the traced window processed, from the spans that began in it:
     ``prefill`` (true prompt tokens), ``padded`` (tokens the prefill calls
     ran), ``calls``, ``rows`` and ``row_chunks`` (chunks that held a token, over
-    rows) of prefill; ``decode`` (sum of active slots), ``steps``, ``slots`` and
-    the decode steps' ``moe.*`` counters (``held``, ``assignments``, ``max_load``)."""
+    rows) of prefill; ``decode`` (the positions the decode steps processed: a
+    step's ``tokens`` counter where it carries one, a decoder whose step is not
+    one token a slot, and its ``active`` slots where it does not), ``active``
+    (sum of active slots), ``steps``, ``slots`` and the decode steps' ``moe.*``
+    counters (``held``, ``assignments``, ``max_load``)."""
     if aligned(run) is None:
         return None
     pre = program_spans.in_window(run, "serve.prefill")
@@ -98,10 +103,12 @@ def tokens(run) -> Optional[SimpleNamespace]:
     if not pre or not dec:
         return None
     s = lambda rows, key: float(sum(r[2].get(key, 0) for r in rows))  # noqa: E731
+    processed = float(sum(r[2]["tokens"] if "tokens" in r[2] else r[2].get("active", 0) for r in dec))
     return SimpleNamespace(prefill=s(pre, "tokens"), padded=s(pre, "padded_tokens"), calls=s(pre, "chunks"),
-                           rows=s(pre, "rows"), row_chunks=s(pre, "row_chunks"), decode=s(dec, "active"),
-                           steps=float(len(dec)), slots=s(dec, "slots"), held=s(dec, "moe.held_assignments"),
-                           assignments=s(dec, "moe.assignments"), max_load=s(dec, "moe.max_expert_load"))
+                           rows=s(pre, "rows"), row_chunks=s(pre, "row_chunks"), decode=processed,
+                           active=s(dec, "active"), steps=float(len(dec)), slots=s(dec, "slots"),
+                           held=s(dec, "moe.held_assignments"), assignments=s(dec, "moe.assignments"),
+                           max_load=s(dec, "moe.max_expert_load"))
 
 
 # -- device time by program and by scope -----------------------------------------
@@ -124,11 +131,14 @@ def programs(run) -> Optional[Dict[str, List[float]]]:
 
 
 def classify(scope: Optional[str]) -> str:
-    # XLA turns ``lax.ragged_dot`` into a kernel of its own whose metadata keeps
-    # no scope (``op_name="ragged-dot-none"``, ``"ragged-dot-metadata"``). Filed
-    # by kernel name, on the assumption that the routed experts' grouped products
-    # are the only ``ragged_dot`` of the model: true of ``models/granite_hybrid``;
-    # a model with a second one elsewhere needs a rule of its own here.
+    # Where ``lax.ragged_dot`` computes the routed experts' grouped products (the
+    # CPU rehearsal; on a TPU the cell runs ``ops/pallas_grouped_matmul.py``, whose
+    # custom call carries the scope ``experts``), XLA turns it into a kernel of its
+    # own whose metadata keeps no scope (``op_name="ragged-dot-none"``,
+    # ``"ragged-dot-metadata"``). Filed by kernel name, on the assumption that those
+    # products are the only ``ragged_dot`` of the model: true of
+    # ``models/granite_hybrid``; a model with a second one elsewhere needs a rule of
+    # its own here.
     if (scope or "").startswith("ragged-dot"):
         return "experts"
     parts = (scope or "").split("/")

@@ -1,4 +1,4 @@
-"""The routed experts' grouped products' share of their own roofline: the least time the chip could take for the assignments that reached a held expert (the larger of ``expert_matmul_flops`` over the bf16 peak and ``expert_matmul_bytes`` over the HBM peak: every held expert's weights once a layer and call, each assignment's row in and out; the reference's counts; the MXU bounds a prefill call, HBM a decode step) over the device time under the scope ``experts`` in both programs (sort, gather, the two grouped products, the gated scatter). Assignments of the prefill are reckoned, not counted: the tokens times top-k times the held share the decode steps counted (only decode steps fetch the counters). XLA's ``ragged-dot`` kernels carry no scope and are filed under ``experts`` by kernel name (``lib/lm_scopes.classify``), which assumes the routed experts' are the model's only grouped products."""
+"""The routed experts' grouped products' share of their own roofline: the least time the chip could take for the assignments that reached a held expert (the larger of ``expert_matmul_flops`` over the bf16 peak and ``expert_matmul_bytes`` over the HBM peak: every held expert's weights once a layer and call, each assignment's row in and out; the reference's counts; the MXU bounds a prefill call, HBM a decode step) over the device time under the scope ``experts`` in both programs (sort, gather, the routed experts' two grouped products, whatever computes them, and the gated scatter). Assignments of the prefill are reckoned, not counted: the tokens times top-k times the held share the decode steps counted (only decode steps fetch the counters). On a TPU the two products are ``ops/pallas_grouped_matmul.py``'s custom calls, filed under ``experts`` by their scope; XLA's ``ragged-dot`` kernels, which the CPU rehearsal runs, carry no scope and are filed there by kernel name (``lib/lm_scopes.classify``), which assumes the routed experts' are the model's only grouped products."""
 
 from lib import lm_scopes, peaks
 

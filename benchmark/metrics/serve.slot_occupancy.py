@@ -5,4 +5,4 @@ from lib import lm_scopes
 
 def read(run):
     n = lm_scopes.tokens(run)
-    return None if n is None or not n.slots else 100.0 * n.decode / n.slots
+    return None if n is None or not n.slots else 100.0 * n.active / n.slots

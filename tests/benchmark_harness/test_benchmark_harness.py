@@ -227,6 +227,8 @@ def check_manifest(m: dict, path: str = None, root: str = ROOT) -> None:
     assert all(NAME.match(n) for n in names) and len(set(names)) == len(names)
     assert all(UNIT.match(x["unit"]) for k in ("end_to_end", "per_layer") for x in m[k])
     cell_names = {w["name"] for w in m["workloads"]}
+    # the contract's most, stated here and nowhere else: no test pins a count or a place below it
+    assert len(m["per_layer"]) <= 128, f"per_layer holds {len(m['per_layer'])} entries: the contract's most is 128"
     for p in m["per_layer"]:
         assert p["moves"] in end_to_end and 0 < len(p["layer"]) <= 200 and "\n" not in p["layer"]
         assert p["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
@@ -261,13 +263,16 @@ def _planted(m: dict, fault: str) -> dict:
         del by_name["kernel.attn_core_roofline"]["workloads"]
     elif fault == "two_mfu_in_a_cell":
         m["per_layer"].append(dict(by_name["model.step_mfu"], name="model.attn_core_mfu",
-                                   workloads=[m["workloads"][1]["name"]]))
+                                   workloads=["clip_vit_b16_image.predecoded_224"]))
     elif fault == "no_mfu_in_a_cell":
-        by_name["model.step_mfu"]["workloads"].remove(m["workloads"][2]["name"])
+        by_name["model.step_mfu"]["workloads"].remove("clip_vit_l14_image.jpeg_parquet_laion")
     elif fault == "workloads_names_no_cell":
         by_name["engine.pull_s_per_krow"]["workloads"].append("clip_vit_h14_image.predecoded_224")
     elif fault == "workloads_empty":
         by_name["engine.pull_s_per_krow"]["workloads"] = []
+    elif fault == "one_entry_too_many":  # copies of an entry every cell reports, up to one past the contract's most
+        spare = by_name["device.idle_share"]
+        m["per_layer"] += [dict(spare, name=f"device.idle_share.{k}") for k in range(129 - len(m["per_layer"]))]
     return m
 
 
@@ -277,7 +282,8 @@ def _planted(m: dict, fault: str) -> dict:
     ("two_mfu_in_a_cell", "mfu metrics of clip_vit_b16_image.predecoded_224"),
     ("no_mfu_in_a_cell", "mfu metrics of clip_vit_l14_image.jpeg_parquet_laion"),
     ("workloads_names_no_cell", "workloads of engine.pull_s_per_krow"),
-    ("workloads_empty", "workloads of engine.pull_s_per_krow")])
+    ("workloads_empty", "workloads of engine.pull_s_per_krow"),
+    ("one_entry_too_many", "per_layer holds 129 entries: the contract's most is 128")])
 def test_a_planted_manifest_is_refused(manifest_json, tmp_path, fault, says):
     planted = _planted(manifest_json, fault)
     assert planted != manifest_json
@@ -285,6 +291,147 @@ def test_a_planted_manifest_is_refused(manifest_json, tmp_path, fault, says):
     path.write_text(json.dumps(planted))
     with pytest.raises(AssertionError, match=says):
         check_manifest(planted, str(path))
+
+
+# -- what a ``prompt`` cell's entries have to hold, by name -----------------------------------------------
+GRANITE_CELL = "granite_4_0_h_small_prompt.docs_lognormal_1k_out64"
+LONGCAT_CELL = "longcat_flash_chat_prompt.docs_lognormal_4k_out64"
+OLMO_CELL = "olmo_hybrid_7b_prompt.docs_lognormal_4k_out64"
+DEEPSEEK_CELL = "deepseek_v3_2_exp_prompt.docs_lognormal_8k_out64"
+#: One entry a reader: the batcher's, the prompter's and the set-up's readings read the same spans whatever the
+#: decoder, so every ``prompt`` cell is listed in these (the expert counter in the cells whose decoder has experts).
+FOR_EVERY_PROMPT_CELL = frozenset({
+    "serve.host_exposed_s_per_krow", "serve.prefill_ms_per_ktoken", "serve.decode_step_ms", "serve.prefill_share",
+    "serve.slot_occupancy", "serve.padded_token_share", "prompt.tokenize_s_per_krow", "lm.setup_init_s",
+    "lm.setup_first_prefill_s", "serve.idle_ms_per_step", "serve.dispatch_host_ms_per_step",
+    "serve.fetch_arrays_per_step", "prompt.idle_outside_run_s_per_krow", "serve.idle_unfiled_share",
+    "serve.setup_first_decode_s"})
+FOR_EVERY_CELL_WITH_EXPERTS = frozenset({"moe.held_assignment_share"})
+#: cell -> its decoder's own measurements: the one ``*mfu``, its kernels' rooflines, its scope classes and counters
+#: (``own``), and which of its entries read through ``decoder_scopes.beside`` (none but one, since PR 42). A further
+#: decoder's cell brings a dictionary like these in its own test file and hands it to ``check_prompt_cell``.
+PROMPT_CELLS = {
+    GRANITE_CELL: {
+        "mfu": "lm.step_mfu", "rooflines": {"kernel.ssd_scan_roofline", "kernel.expert_matmul_roofline"},
+        "experts": True, "beside": set(), "entries": {"prompt_text"},
+        "own": {"lm.mamba_ms_per_ktoken", "lm.experts_ms_per_ktoken", "lm.attn_ms_per_ktoken", "lm.head_ms_per_ktoken",
+                "lm.other_ms_per_ktoken", "moe.expert_load_max_over_mean"}},
+    LONGCAT_CELL: {
+        "mfu": "lc.step_mfu", "rooflines": {"kernel.mla_core_roofline", "kernel.scmoe_expert_matmul_roofline"},
+        "experts": True, "beside": set(), "entries": {"prompt_decoder"},
+        "own": {"lc.mla_proj_ms_per_ktoken", "lc.mla_core_ms_per_ktoken", "lc.dense_mlp_ms_per_ktoken",
+                "lc.experts_ms_per_ktoken", "lc.head_ms_per_ktoken", "lc.other_ms_per_ktoken",
+                "lc.zero_assignment_share", "lc.expert_load_max_over_mean", "lc.cache_bytes_per_token"}},
+    OLMO_CELL: {
+        "mfu": "oh.step_mfu", "rooflines": {"kernel.delta_rule_roofline", "kernel.full_attn_core_roofline"},
+        "experts": False, "beside": set(), "entries": {"prompt_decoder"},
+        "own": {"oh.lin_proj_ms_per_ktoken", "oh.delta_rule_ms_per_ktoken", "oh.attn_proj_ms_per_ktoken",
+                "oh.attn_core_ms_per_ktoken", "oh.mlp_ms_per_ktoken", "oh.head_ms_per_ktoken", "oh.other_ms_per_ktoken",
+                "oh.kv_bytes_per_token", "oh.recurrent_mb_per_slot"}},
+    DEEPSEEK_CELL: {
+        "mfu": "ds.step_mfu", "rooflines": {"kernel.dsa_index_roofline", "kernel.dsa_core_roofline",
+                                            "kernel.ds_expert_matmul_roofline"},
+        "experts": True, "beside": {"ds.cache_bytes_per_token"}, "entries": {"prompt_decoder"},
+        "own": {"ds.mla_proj_ms_per_ktoken", "ds.indexer_ms_per_ktoken", "ds.select_ms_per_ktoken",
+                "ds.mla_core_ms_per_ktoken", "ds.dense_mlp_ms_per_ktoken", "ds.experts_ms_per_ktoken",
+                "ds.head_ms_per_ktoken", "ds.other_ms_per_ktoken", "ds.selected_pair_share", "ds.cache_bytes_per_token",
+                "ds.expert_load_max_over_mean"}},
+}
+
+
+def listed_for(m: dict, cell: str) -> dict:
+    """name -> entry, of the per-layer entries whose ``workloads`` names ``cell``."""
+    return {p["name"]: p for p in m["per_layer"] if cell in p.get("workloads", ())}
+
+
+def reported_by(m: dict, cell: str) -> set:
+    """The names a traced run of ``cell`` reports: the entries without a list and those that list it."""
+    return {p["name"] for p in m["per_layer"] if "workloads" not in p or cell in p["workloads"]}
+
+
+def check_prompt_cell(m: dict, cell: str, spec: dict, path: str = None, root: str = ROOT) -> None:
+    """What the manifest ``m`` (at ``path``; None: the repo's own) has to hold of one ``prompt`` cell, all of it
+    membership: these names list this cell, exactly one of what it reports is an ``*mfu``, these are its rooflines.
+    Nothing here depends on where an entry, a cell or a configuration stands in its list, or on how many there are,
+    so it holds as it is of a manifest to which a later cell's configuration, workload and entries were appended."""
+    bench_dir = os.path.join(root, "benchmark")
+    (workload,) = [w for w in m["workloads"] if w["name"] == cell]
+    (config,) = [c for c in m["configs"] if c["name"] == workload["config"]]
+    assert workload["chips"] == 1 and config["file"] == f"benchmark/configs/{config['name']}.json"
+    listed, reported = listed_for(m, cell), reported_by(m, cell)
+    # a cell appended between two ``benchmark`` PRs is in no accepted entry's list yet and says so (``"shared": set()``)
+    shared = spec.get("shared", FOR_EVERY_PROMPT_CELL | (FOR_EVERY_CELL_WITH_EXPERTS if spec["experts"] else frozenset()))
+    mine = spec["own"] | spec["rooflines"] | {spec["mfu"]}
+    assert shared | mine <= set(listed), sorted((shared | mine) - set(listed))
+    if not spec["experts"]:
+        assert not FOR_EVERY_CELL_WITH_EXPERTS & set(listed)
+    # the decoder's own measurements are of this decoder: they list this cell and no other
+    assert all(listed[n]["workloads"] == [cell] for n in mine)
+    # the whole step's share of the peak: one, and this decoder's; its kernels' rooflines among what it reports
+    # (a later PR that writes a kernel for this decoder appends that kernel's)
+    assert [n for n in reported if _is_mfu(n)] == [spec["mfu"]]
+    assert spec["rooflines"] <= {n for n in reported if "roofline" in n}
+    assert all((listed[n]["unit"], listed[n]["source"]) == ("%", "device_trace") for n in spec["rooflines"])
+    for n in shared | mine:
+        e = listed[n]
+        assert os.path.isfile(os.path.join(bench_dir, "metrics", n + ".py")), n
+        assert e["moves"] == ("setup_s" if ".setup_" in n else "rows_per_s_per_chip"), n
+        assert set(e) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}, n
+    # one entry a reader: of what is named here, only these read through another entry's file
+    through_beside = {n for n in shared | mine
+                      if "beside(__file__" in open(os.path.join(bench_dir, "metrics", n + ".py")).read()}
+    assert through_beside == spec["beside"]
+    # every file of the cell is found, and the cell reports at least what is named here and what every cell reports
+    resolved = manifest.resolve(cell, path, bench_dir)
+    assert resolved.chips == 1 and resolved.config["entry"] in spec["entries"] and resolved.traffic["generator"] == "doc_pool"
+    assert {x["name"] for x in resolved.per_layer} == reported >= shared | mine | {p["name"] for p in m["per_layer"] if "workloads" not in p}
+
+
+#: big ``prompt`` cell -> the rehearsal manifest under ``data/`` whose one tiny cell stands for it on the CPU
+TINY_MANIFEST_OF = {GRANITE_CELL: "rehearsal_prompt.json", LONGCAT_CELL: "rehearsal_longcat.json",
+                    OLMO_CELL: "rehearsal_olmo.json", DEEPSEEK_CELL: "rehearsal_deepseek.json"}
+#: Every manifest there is: the one the chip runs, the tiny CLIP cells', and whatever rehearsal manifest lies under
+#: ``data/`` (found, not listed: a later cell's PR adds its own file there and edits nothing here).
+MANIFESTS = [os.path.join(ROOT, "BENCHMARK.json"), REHEARSAL] + sorted(
+    os.path.join(DATA, f) for f in os.listdir(DATA) if f.startswith("rehearsal_") and f.endswith(".json"))
+
+
+def test_every_entry_has_its_reader_file_and_every_reader_file_an_entry():
+    """What stays true of every PR, ``benchmark`` PRs among them: every per-layer and end-to-end entry of
+    ``BENCHMARK.json`` and of the rehearsal manifests has its file under ``benchmark/metrics/``, and no file lies
+    there that none of them names (a reader whose entry went goes with it)."""
+    named = {}
+    for path in MANIFESTS:
+        m = manifest.load_json(path)
+        for e in m["end_to_end"] + m["per_layer"]:
+            named.setdefault(e["name"], []).append(os.path.relpath(path, ROOT))
+    files = {f[:-len(".py")] for f in os.listdir(os.path.join(BENCH, "metrics")) if f.endswith(".py")}
+    assert not set(named) - files, {n: named[n] for n in set(named) - files}
+    assert not files - set(named), sorted(files - set(named))
+
+
+def _reported_entries(m: dict, cell: str) -> set:
+    """What ``cell`` reports, each entry without its ``workloads`` list (and whether it had one)."""
+    return {(json.dumps({k: v for k, v in e.items() if k != "workloads"}, sort_keys=True), "workloads" in e)
+            for e in m["per_layer"] if "workloads" not in e or cell in e["workloads"]}
+
+
+@pytest.mark.parametrize("cell", sorted(TINY_MANIFEST_OF))
+def test_a_tiny_prompt_cell_reports_what_its_big_cell_reports(cell, manifest_json):
+    """The four rehearsal manifests of ``data/``: each tiny ``prompt`` cell reports entries its big cell reports,
+    under the same names, entry for entry but for the cell's name in the ``workloads`` lists: every one that reads
+    the same spans whatever the decoder, and the decoder's own as ``PROMPT_CELLS`` names them (an entry that a later
+    PR appends for the big cell is rehearsed from a manifest that PR brings)."""
+    path = os.path.join(DATA, TINY_MANIFEST_OF[cell])
+    r = manifest.load_json(path)
+    check_manifest(r, path)
+    (tiny,) = [w["name"] for w in r["workloads"]]
+    assert _reported_entries(r, tiny) <= _reported_entries(manifest_json, cell)
+    spec = PROMPT_CELLS[cell]
+    assert reported_by(r, tiny) >= FOR_EVERY_PROMPT_CELL | spec["own"] | spec["rooflines"] | {spec["mfu"]} | (
+        FOR_EVERY_CELL_WITH_EXPERTS if spec["experts"] else frozenset())
+    assert all(e.get("workloads", [tiny]) == [tiny] for e in r["per_layer"])
+    assert r["end_to_end"] == manifest_json["end_to_end"]
 
 
 #: A later PR's files, of another kind than what is here: a query that is no dataframe, answers that
@@ -362,17 +509,18 @@ def test_a_cell_added_as_files_only_is_resolved_and_run(tmp_path):
     path.write_text(json.dumps(m))
     for p in m["paths"]:
         (tmp_path / p).mkdir(parents=True, exist_ok=True)
-    check_manifest(m, str(path), str(tmp_path))  # entries past the thirtieth, each listing one cell, pass
+    check_manifest(m, str(path), str(tmp_path))  # appended entries, each listing one cell, pass
     cell = manifest.resolve("dummy_model.dummy_mix", str(path), str(bench))
     assert cell.config["batch_size"] == 4 and cell.traffic["rows"] == 28
     # the cell reports what every cell reports and its own three, not the embed-only accepted ones
-    unlisted = [p["name"] for p in m["per_layer"] if "workloads" not in p]
-    assert [x["name"] for x in cell.per_layer] == unlisted + ["dummy.rows_seen", "dummy.step_mfu", "kernel.dummy_roofline"]
-    assert not {"model.step_mfu", "preprocess.s_per_krow", "provider.host_s_per_krow", "model.step_ms"} & set(unlisted)
+    unlisted = {p["name"] for p in m["per_layer"] if "workloads" not in p}
+    assert {x["name"] for x in cell.per_layer} == unlisted | {"dummy.rows_seen", "dummy.step_mfu", "kernel.dummy_roofline"}
+    assert not {"model.step_mfu", "preprocess.s_per_krow", "provider.host_s_per_krow", "model.step_ms"} & unlisted
     # the new metrics list their cell, so no other cell has to report them
-    for w in m["workloads"][:3]:
-        other = manifest.resolve(w["name"], str(path), str(bench))
-        assert not {"dummy.rows_seen", "dummy.step_mfu", "kernel.dummy_roofline"} & {x["name"] for x in other.per_layer}
+    for w in m["workloads"]:
+        if w["name"] != "dummy_model.dummy_mix":
+            other = manifest.resolve(w["name"], str(path), str(bench))
+            assert not {"dummy.rows_seen", "dummy.step_mfu", "kernel.dummy_roofline"} & {x["name"] for x in other.per_layer}
     # the copy's own run.py drives the new cell from set-up to verdict: end-to-end, then traced
     run = manifest.load_module(str(bench / "run.py"))
     rec = run.run_cell(cell, seed=2 ** 31 + 5, seconds=0.3, trace_on=False)
@@ -392,6 +540,76 @@ def test_a_cell_added_as_files_only_is_resolved_and_run(tmp_path):
              if f.is_file() and "__pycache__" not in f.parts and "out" not in f.relative_to(bench).parts[:1]}
     assert {k: v for k, v in after.items() if k in before} == before
     assert set(after) - set(before) == set(DUMMY_FILES)
+
+
+#: A further decoder behind ``prompt``, as the next ``model_config`` PR brings it between two ``benchmark`` PRs: a
+#: configuration for the entry that is here (``prompt_decoder``; the tiny Olmo-Hybrid decoder stands in for the
+#: new one), a traffic file for the generator that is here, and entries of its own: one ``*mfu``, one kernel's
+#: roofline, and a batcher's reading taken through ``decoder_scopes.beside`` because the accepted entry cannot
+#: list the cell yet. Data and one-line readers; no file that is here is edited.
+APPENDED_CELL = "appended_decoder_prompt.appended_docs"
+APPENDED_READERS = {
+    "metrics/ap.step_mfu.py": "from lib import trace\ndef read(run):\n    return 40.0 if trace.has_device(run.events) else None\n",
+    "metrics/kernel.appended_core_roofline.py":
+        "from lib import trace\ndef read(run):\n    return 30.0 if trace.has_device(run.events) else None\n",
+    "metrics/ap.tokenize_s_per_krow.py":
+        "from lib import decoder_scopes\n\nread = decoder_scopes.beside(__file__, \"prompt.tokenize_s_per_krow\")\n",
+}
+
+
+def test_a_decoders_cell_appended_as_files_only_leaves_the_four_prompt_cells_as_they_are(tmp_path, monkeypatch):
+    """The criterion the next ``model_config`` PR depends on: an eighth cell behind ``prompt_decoder`` is appended to
+    a copy of the benchmark (configuration, traffic, three entries; no file that is here edited), the enlarged
+    manifest passes ``check_manifest``, the cell resolves and runs traced on the CPU with its wrapper reading the
+    accepted reader's number, and what is held of the four ``prompt`` cells holds against the copy word for word."""
+    from lib import program_spans
+
+    bench = tmp_path / "benchmark"
+    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("out", "__pycache__"))
+    before = {str(f.relative_to(bench)): f.read_bytes() for f in bench.rglob("*") if f.is_file()}
+    files = dict(APPENDED_READERS)
+    files["configs/appended_decoder_prompt.json"] = (bench / "configs" / "rehearsal_tiny_olmo.json").read_text()
+    files["traffic/appended_docs.json"] = (bench / "traffic" / "rehearsal_docs.json").read_text()
+    for rel, text in files.items():
+        (bench / rel).write_text(text)
+    m = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    m["configs"].append({"name": "appended_decoder_prompt", "source": "none", "reduced": [], "why": "test",
+                         "file": "benchmark/configs/appended_decoder_prompt.json"})
+    m["workloads"].append({"name": APPENDED_CELL, "config": "appended_decoder_prompt", "traffic": "appended_docs",
+                           "chips": 1, "why": "test"})
+    mine = {"better": "higher", "source": "device_trace", "layer": "decoder forward (appended)",
+            "moves": "rows_per_s_per_chip", "workloads": [APPENDED_CELL]}
+    m["per_layer"] += [dict(mine, name="ap.step_mfu", unit="%"), dict(mine, name="kernel.appended_core_roofline", unit="%"),
+                       dict(mine, name="ap.tokenize_s_per_krow", unit="s/krow", better="lower", source="program_span",
+                            layer="prompter and tokenizer (ai/flax_provider.py FlaxPrompter)")]
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(m))
+    for p in m["paths"]:
+        (tmp_path / p).mkdir(parents=True, exist_ok=True)
+    check_manifest(m, str(path), str(tmp_path))
+    # what is held of the four prompt cells holds against the copy as against the repo's own; and of the new cell,
+    # which no accepted entry lists yet, what its own dictionary says
+    for cell, spec in PROMPT_CELLS.items():
+        check_prompt_cell(m, cell, spec, str(path), str(tmp_path))
+    check_prompt_cell(m, APPENDED_CELL, {
+        "mfu": "ap.step_mfu", "rooflines": {"kernel.appended_core_roofline"}, "experts": False, "shared": set(),
+        "own": {"ap.tokenize_s_per_krow"}, "beside": {"ap.tokenize_s_per_krow"}, "entries": {"prompt_decoder"}},
+        str(path), str(tmp_path))
+    # the copy's own run.py drives the new cell, traced: the wrapper reads what the accepted reader reads, the
+    # shares of a peak need a device and are left out
+    monkeypatch.setattr(program_spans, "MAX_BRACKET_NS", 50_000_000)  # beside five other test workers: see
+    monkeypatch.setattr(program_spans, "WIDEN_NS", 50_000_000)        # test_prompt_cell.py
+    cell = manifest.resolve(APPENDED_CELL, str(path), str(bench))
+    run = manifest.load_module(str(bench / "run.py"))
+    rec = run.run_cell(cell, seed=2 ** 31 + 41, seconds=0.5, trace_on=True)
+    assert rec["correct"] is True and rec["failed"] == 0, rec["compared"]
+    assert rec["metrics"]["ap.tokenize_s_per_krow"]["value"] > 0
+    assert not {"ap.step_mfu", "kernel.appended_core_roofline"} & set(rec["metrics"])
+    # no file that was there has changed
+    after = {str(f.relative_to(bench)): f.read_bytes() for f in bench.rglob("*")
+             if f.is_file() and "__pycache__" not in f.parts and "out" not in f.relative_to(bench).parts[:1]}
+    assert {k: v for k, v in after.items() if k in before} == before
+    assert set(after) - set(before) == set(files)
 
 
 def test_jpeg_sizes_do_not_depend_on_the_seed():

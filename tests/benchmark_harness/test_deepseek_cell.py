@@ -4,17 +4,15 @@ counts, its readers driven through ``run.py``'s own ``run_cell`` from a manifest
 of its own (``data/rehearsal_deepseek.json``: the tiny decoder under the
 per-layer entries the real cell lists; not appended to
 ``benchmark/rehearsal.json``, which is a file the benchmark has), traced and
-untraced, and a program without selection. No file the benchmark had is edited:
-the enlarged manifest is checked by ``test_benchmark_harness.check_manifest`` as
-it stands, and the files under the benchmark's paths are the parent's byte for
-byte where git can say so."""
+untraced, and a program without selection. What is held of the cell's entries is
+held by name (``test_benchmark_harness.check_prompt_cell``): no place in a list
+and no count."""
 
 from __future__ import annotations
 
 import hashlib
 import json
 import os
-import subprocess
 import sys
 
 import pytest
@@ -33,12 +31,6 @@ CELL = "deepseek_v3_2_exp_prompt.docs_lognormal_8k_out64"
 TINY_CELL = "rehearsal_tiny_deepseek.rehearsal_docs"
 REHEARSAL = os.path.join(DATA, "rehearsal_deepseek.json")
 CUT = {"num_hidden_layers", "first_k_dense_replace", "n_routed_experts", "vocab_size"}
-#: The commit this PR stands on: what the benchmark had.
-PARENT = "51f1d48d91f34afe5beadb06dbae65def543342c"
-#: What this PR brings under the benchmark's paths (new files; ``BENCHMARK.json`` gains entries at the end of its lists).
-NEW_FILES = {"benchmark/configs/deepseek_v3_2_exp_prompt.json", "benchmark/configs/rehearsal_tiny_deepseek.json",
-             "benchmark/reference/deepseek_v32.py", "benchmark/traffic/docs_lognormal_8k_out64.json",
-             "tests/benchmark_harness/test_deepseek_cell.py", "tests/benchmark_harness/data/rehearsal_deepseek.json"}
 
 
 @pytest.fixture(scope="module")
@@ -144,51 +136,54 @@ def test_the_counts_of_the_work_follow_the_shapes(real_cell):
     assert [ref._padded_length(n, 32832) for n in (2799, 8208, 8209, 23976 + 64, 32832)] == [8208, 8208, 16416, 24624, 32832]
 
 
-def test_the_enlarged_manifest_is_consistent_and_the_cell_resolves_from_a_copy(tmp_path):
+def test_the_manifest_holds_the_cells_entries_by_name_and_the_cell_resolves_from_a_copy(tmp_path):
+    """Membership only (``test_benchmark_harness.check_prompt_cell`` has the rules): the accepted batcher's,
+    prompter's, set-up's and expert entries list this cell beside the other ``prompt`` cells, PR 37's six among
+    them, DeepSeek's own measurements list it alone, one of what it reports is an ``*mfu``, three are its kernels'
+    rooflines, and one entry still reads through ``decoder_scopes.beside``. No place in a list and no count."""
     import shutil
 
     harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
     m = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     harness.check_manifest(m)
-    mine = _mine(m, CELL)
-    assert len(mine) == 25 and [p["name"] for p in mine if "mfu" in p["name"]] == ["ds.step_mfu"]
-    rooflines = [p for p in mine if "roofline" in p["name"]]
-    assert {p["name"] for p in rooflines} == {"kernel.dsa_index_roofline", "kernel.dsa_core_roofline", "kernel.ds_expert_matmul_roofline"}
-    assert all((p["unit"], p["source"]) == ("%", "device_trace") for p in rooflines)
-    # the new entries stand at the end of their lists, and no entry the benchmark had lists the cell
-    assert m["per_layer"][-25:] == mine and m["workloads"][-1]["name"] == CELL and m["configs"][-1]["name"] == "deepseek_v3_2_exp_prompt"
-    assert not [p["name"] for p in m["per_layer"] if CELL in p.get("workloads", ()) and len(p["workloads"]) > 1]
-    assert m["workloads"][-1]["chips"] == 1 and len(m["workloads"]) == 7 and len(m["per_layer"]) <= 128
+    spec = harness.PROMPT_CELLS[CELL]
+    assert spec["mfu"] == "ds.step_mfu" and spec["experts"] and spec["beside"] == {"ds.cache_bytes_per_token"}
+    assert spec["rooflines"] == {"kernel.dsa_index_roofline", "kernel.dsa_core_roofline", "kernel.ds_expert_matmul_roofline"}
+    harness.check_prompt_cell(m, CELL, spec)
+    listed = harness.listed_for(m, CELL)
+    assert {p["name"] for p in _mine(m, CELL)} >= spec["own"] | spec["rooflines"] | {spec["mfu"]}
+    assert {"serve.idle_ms_per_step", "serve.idle_unfiled_share", "serve.setup_first_decode_s",
+            "moe.held_assignment_share"} <= set(listed)
     path = tmp_path / "BENCHMARK.json"
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), path)
-    cell = manifest.resolve(CELL, str(path))
-    assert cell.chips == 1 and cell.config["entry"] == "prompt_decoder" and cell.traffic["generator"] == "doc_pool"
-    assert {p["name"] for p in mine} <= {x["name"] for x in cell.per_layer} and len(cell.per_layer) >= 33
+    harness.check_prompt_cell(m, CELL, spec, str(path))
     # the rehearsal manifest lists the same entries for the tiny cell, and is no part of benchmark/rehearsal.json
     rehearsal = manifest.load_json(REHEARSAL)
     harness.check_manifest(rehearsal, REHEARSAL)
-    assert [p["name"] for p in _mine(rehearsal, TINY_CELL)] == [p["name"] for p in mine]
+    assert {p["name"] for p in _mine(rehearsal, TINY_CELL)} <= set(listed)  # a tiny manifest has the one cell
+    assert spec["own"] | {spec["mfu"]} <= harness.reported_by(rehearsal, TINY_CELL) <= harness.reported_by(m, CELL)
     assert "deepseek" not in json.dumps(manifest.load_json(os.path.join(BENCH, "rehearsal.json")))
 
 
-def test_the_files_the_benchmark_had_are_the_parents():
-    """Where the checkout is a git repository that knows the parent commit: under the benchmark's paths this PR
-    adds files and changes none, and ``BENCHMARK.json`` only gains lines."""
-    def git(*args):
-        return subprocess.run(("git", "-C", ROOT) + args, capture_output=True, text=True)
-
-    if git("cat-file", "-e", PARENT + "^{commit}").returncode:
-        pytest.skip("no git history with the parent commit here")
-    changed = git("diff", "--name-status", PARENT, "--", "benchmark", "tests/benchmark_harness").stdout.split("\n")
-    status = {line.split("\t")[1]: line.split("\t")[0] for line in changed if line}
-    untracked = set(git("ls-files", "--others", "--exclude-standard", "--", "benchmark", "tests/benchmark_harness").stdout.split())
-    assert {k: v for k, v in status.items() if v != "A"} == {}
-    added = set(status) | untracked
-    assert NEW_FILES <= added and all(f in NEW_FILES or f.startswith("benchmark/metrics/") for f in added)
-    assert {f for f in added if f.startswith("benchmark/metrics/")} == \
-        {f"benchmark/metrics/{p['name']}.py" for p in _mine(manifest.load_json(os.path.join(ROOT, "BENCHMARK.json")), CELL)}
-    removed = [l for l in git("diff", PARENT, "--", "BENCHMARK.json").stdout.split("\n") if l.startswith("-") and not l.startswith("---")]
-    assert removed == []  # entries are appended: as git tells it, no line the file had goes
+def test_the_wrappers_went_with_their_entries():
+    """What stays true after the ``benchmark`` PR that listed the ``prompt`` cells in the accepted entries (PR 42),
+    where ``test_the_files_the_benchmark_had_are_the_parents`` held what was true of PR 40 alone: no entry of the
+    manifest or of a rehearsal manifest is one of the ten accepted readers under a decoder's prefix, no such file
+    lies under ``benchmark/metrics/``, and of these decoders' files only one still calls ``beside``: for a reading that
+    has no accepted entry to be listed in."""
+    harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
+    wrapped = {"prefill_ms_per_ktoken", "decode_step_ms", "prefill_share", "slot_occupancy", "padded_token_share",
+               "host_exposed_s_per_krow", "tokenize_s_per_krow", "held_assignment_share", "setup_init_s",
+               "setup_first_prefill_s"}
+    is_wrapper = lambda name: name.split(".")[0] in ("lc", "oh", "ds") and name.split(".", 1)[1] in wrapped  # noqa: E731
+    for path in harness.MANIFESTS:
+        assert not [p["name"] for p in manifest.load_json(path)["per_layer"] if is_wrapper(p["name"])], path
+    files = [f[:-3] for f in os.listdir(os.path.join(BENCH, "metrics")) if f.endswith(".py")]
+    assert not [f for f in files if is_wrapper(f)]
+    # of the three decoders' own files one still reads through ``beside`` (a later cell may bring more of its own)
+    through_beside = [f for f in files if f.split(".")[0] in ("lc", "oh", "ds")
+                      and "beside(__file__" in open(os.path.join(BENCH, "metrics", f + ".py")).read()]
+    assert through_beside == ["ds.cache_bytes_per_token"]
 
 
 def _digests():
@@ -221,17 +216,19 @@ def test_the_cell_rehearses_on_the_cpu_and_its_control_reads_not_correct(bench_r
         assert set(m) == {"rows_per_s_per_chip", "setup_s"}
         return
     # the readers of the program's spans and counters find them; the device's need a device
-    assert {"ds.selected_pair_share", "ds.cache_bytes_per_token", "ds.held_assignment_share", "ds.expert_load_max_over_mean",
-            "ds.slot_occupancy", "ds.padded_token_share", "ds.tokenize_s_per_krow", "ds.setup_init_s",
-            "ds.setup_first_prefill_s"} <= set(m)
+    assert {"ds.selected_pair_share", "ds.cache_bytes_per_token", "moe.held_assignment_share", "ds.expert_load_max_over_mean",
+            "serve.slot_occupancy", "serve.padded_token_share", "prompt.tokenize_s_per_krow", "lm.setup_init_s",
+            "lm.setup_first_prefill_s", "serve.fetch_arrays_per_step", "serve.setup_first_decode_s"} <= set(m)
     assert not {"ds.step_mfu", "kernel.dsa_index_roofline", "kernel.dsa_core_roofline", "kernel.ds_expert_matmul_roofline",
-                "ds.decode_step_ms", "ds.select_ms_per_ktoken", "ds.other_ms_per_ktoken", "ds.host_exposed_s_per_krow"} & set(m)
-    assert not any(k.startswith(("lm.", "lc.", "oh.", "serve.", "moe.", "prompt.")) for k in m)  # other cells' entries list other cells
-    assert 0 < m["ds.slot_occupancy"] <= 100 and 0 <= m["ds.padded_token_share"] < 100
+                "serve.decode_step_ms", "ds.select_ms_per_ktoken", "ds.other_ms_per_ktoken", "serve.host_exposed_s_per_krow",
+                "serve.idle_ms_per_step", "serve.idle_unfiled_share"} & set(m)
+    # another decoder's own measurements list that decoder's cell
+    assert not any(k.startswith(("lm.", "lc.", "oh.")) and ".setup_" not in k for k in m) and "moe.expert_load_max_over_mean" not in m
+    assert 0 < m["serve.slot_occupancy"] <= 100 and 0 <= m["serve.padded_token_share"] < 100
     # documents of 4-48 tokens under a top 32: the longest pass it, so fewer pairs are selected than there are, and most are
     assert 80 < m["ds.selected_pair_share"] < 100
     assert m["ds.cache_bytes_per_token"] == pytest.approx(3 * (16 + 16) * 2 * 128 / 57)  # 57 positions asked for, 128 held
-    assert 25 < m["ds.held_assignment_share"] < 75 and m["ds.expert_load_max_over_mean"] >= 1  # 4 of 8 experts held
+    assert 25 < m["moe.held_assignment_share"] < 75 and m["ds.expert_load_max_over_mean"] >= 1  # 4 of 8 experts held
     # the counts hold their identities on every span of the run
     from daft_tpu.models import deepseek_v32 as ds
     from daft_tpu.profiling import recent_device_spans

@@ -101,21 +101,22 @@ def test_each_reader_returns_none_where_the_program_has_no_such_span(name, monke
 
 
 # -- the entries -----------------------------------------------------------------------------
-def test_the_entries_list_the_three_embed_cells_and_stand_last():
+def test_the_two_entries_list_the_embed_cells_and_no_prompt_cell():
+    """Membership, no place and no count: wherever the two stand among however many entries, they list the three
+    embed cells, those cells report them, and a cell whose UDF has no host stage does not."""
     m = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert [p["name"] for p in m["per_layer"][-2:]] == NEW and len(m["per_layer"]) == 51
-    wait, ready = m["per_layer"][-2:]
+    by_name = {p["name"]: p for p in m["per_layer"]}
+    wait, ready = (by_name[n] for n in NEW)
     for e in (wait, ready):
-        assert e["workloads"] == EMBED_CELLS and e["moves"] == "rows_per_s_per_chip"
+        assert set(e["workloads"]) == set(EMBED_CELLS) and e["moves"] == "rows_per_s_per_chip"
         assert e["layer"] == "UDF operator and source (execution/executor.py, scan)"
         assert set(e) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
         assert os.path.isfile(os.path.join(BENCH, "metrics", e["name"] + ".py"))
     assert (wait["unit"], wait["better"], wait["source"]) == ("s/krow", "lower", "program_span")
     assert (ready["unit"], ready["better"], ready["source"]) == ("%", "higher", "program_counter")
-    for cell in EMBED_CELLS:  # the cells resolve with them; the prompt cell, whose UDF has no host stage, without
-        assert NEW == [p["name"] for p in manifest.resolve(cell).per_layer][-2:]
-    prompt = manifest.resolve("granite_4_0_h_small_prompt.docs_lognormal_1k_out64")
-    assert not set(NEW) & {p["name"] for p in prompt.per_layer}
+    for w in m["workloads"]:  # the embed cells resolve with them; the prompt cells, whose UDF has no host stage, without
+        reported = {p["name"] for p in manifest.resolve(w["name"]).per_layer}
+        assert set(NEW) <= reported if w["name"] in EMBED_CELLS else not set(NEW) & reported, w["name"]
 
 
 # -- a traced rehearsal with the host stage ahead ---------------------------------------------

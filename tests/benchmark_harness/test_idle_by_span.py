@@ -1,10 +1,11 @@
 """``lib/idle_by_span.py`` and PR 37's nine entries on the CPU: the leaves'
 idle seconds on a trace and a ring made by hand, the closure of a step's four
 parts and of the window, a ring on two threads, an older program's ring; the
-entries in ``BENCHMARK.json`` and their readers; and the three tiny ``prompt``
-cells driven through ``run.py``'s own ``run_cell`` with the entries laid over
-their rehearsal manifests (``data/rehearsal_*.json``, which stay as they are):
-what needs no device reads a number, what needs one is left out."""
+entries in ``BENCHMARK.json`` and their readers; what is held of each of the
+four ``prompt`` cells' entries, by name; and the four tiny ``prompt`` cells
+driven through ``run.py``'s own ``run_cell`` from their rehearsal manifests
+(``data/rehearsal_*.json``, which list the nine since PR 42): what needs no
+device reads a number, what needs one is left out."""
 
 from __future__ import annotations
 
@@ -26,8 +27,8 @@ for _p in (ROOT, BENCH):
 from lib import idle_by_span, manifest, program_spans  # noqa: E402
 
 PROMPT_CELLS = ["granite_4_0_h_small_prompt.docs_lognormal_1k_out64", "longcat_flash_chat_prompt.docs_lognormal_4k_out64",
-                "olmo_hybrid_7b_prompt.docs_lognormal_4k_out64"]
-#: The six that list the three ``prompt`` cells, in the manifest's order, then the three every cell reports.
+                "olmo_hybrid_7b_prompt.docs_lognormal_4k_out64", "deepseek_v3_2_exp_prompt.docs_lognormal_8k_out64"]
+#: The six that list the ``prompt`` cells (all four since PR 42), then the three every cell reports.
 IDLE = ["serve.idle_ms_per_step", "serve.dispatch_host_ms_per_step", "serve.fetch_arrays_per_step",
         "prompt.idle_outside_run_s_per_krow", "serve.idle_unfiled_share", "serve.setup_first_decode_s"]
 LOG = ["setup.compile_s", "setup.cache_load_s", "setup.trace_lower_s"]
@@ -36,7 +37,8 @@ ON_THE_CPU = {"serve.dispatch_host_ms_per_step", "serve.fetch_arrays_per_step", 
 #: tiny cell -> (its rehearsal manifest, the arrays a decode step of that decoder fetches: tok, logprob, its counts)
 TINY = {"rehearsal_tiny_granite.rehearsal_docs": ("rehearsal_prompt.json", 5),
         "rehearsal_tiny_longcat.rehearsal_docs": ("rehearsal_longcat.json", 7),
-        "rehearsal_tiny_olmo.rehearsal_docs": ("rehearsal_olmo.json", 2)}
+        "rehearsal_tiny_olmo.rehearsal_docs": ("rehearsal_olmo.json", 2),
+        "rehearsal_tiny_deepseek.rehearsal_docs": ("rehearsal_deepseek.json", 6)}
 
 
 # -- a ring and a trace made by hand -----------------------------------------------
@@ -193,24 +195,15 @@ def _manifest():
     return manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
 
 
-def _in_order(names, within):
-    """``names`` stand in ``within`` in that order (other entries may stand between and after them)."""
-    at = [within.index(n) for n in names]
-    return at == sorted(at)
-
-
 def test_the_nine_entries_resolve_in_the_cells_that_list_them():
     harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
     m = _manifest()
     harness.check_manifest(m)
     by = {p["name"]: p for p in m["per_layer"]}
-    names = list(by)
-    assert set(IDLE + LOG) <= set(by) and _in_order(IDLE + LOG, names)
-    # appended: every entry the benchmark had before PR 37 (Olmo-Hybrid's 21 were its last) stands before them
-    assert names.index(IDLE[0]) > max(i for i, p in enumerate(m["per_layer"]) if p.get("workloads") == [PROMPT_CELLS[2]])
+    assert set(IDLE + LOG) <= set(by)
     mine = [by[n] for n in IDLE + LOG]
     assert all(p["better"] == "lower" for p in mine)
-    assert all(by[n]["workloads"] == PROMPT_CELLS for n in IDLE) and not any("workloads" in by[n] for n in LOG)
+    assert all(set(PROMPT_CELLS) <= set(by[n]["workloads"]) for n in IDLE) and not any("workloads" in by[n] for n in LOG)
     assert not any("mfu" in p["name"] or "roofline" in p["name"] for p in mine)
     assert {n for n in IDLE + LOG if by[n]["moves"] == "setup_s"} == {"serve.setup_first_decode_s"} | set(LOG)
     assert {by[n]["layer"] for n in LOG + ["serve.setup_first_decode_s"]} == {"process start and model set-up"}
@@ -221,92 +214,41 @@ def test_the_nine_entries_resolve_in_the_cells_that_list_them():
     assert {by[n]["source"] for n in set(IDLE) - ON_THE_CPU} == {"device_trace"}
     for p in mine:
         assert os.path.isfile(os.path.join(BENCH, "metrics", p["name"] + ".py"))
-    # the three prompt cells report all nine, the three embed cells the log's three alone
+    # a cell that an idle entry lists reports all nine; any other cell (the embed cells) the log's three alone
     for w in m["workloads"]:
-        reported = [x["name"] for x in manifest.resolve(w["name"]).per_layer]
-        if w["name"] in PROMPT_CELLS:
-            assert set(IDLE + LOG) <= set(reported) and _in_order(IDLE + LOG, reported)
+        reported = {x["name"] for x in manifest.resolve(w["name"]).per_layer}
+        if w["name"] in by[IDLE[0]]["workloads"]:
+            assert set(IDLE + LOG) <= reported, w["name"]
         else:
-            assert set(LOG) <= set(reported) and not set(IDLE) & set(reported)
+            assert set(LOG) <= reported and not set(IDLE) & reported, w["name"]
 
 
-@pytest.mark.parametrize("cell, own, values", [(PROMPT_CELLS[0], 19, 27), (PROMPT_CELLS[1], 22, 30), (PROMPT_CELLS[2], 21, 29)])
-def test_the_three_prompt_cells_keep_their_own_entries(cell, own, values):
-    """Every entry that lists a ``prompt`` cell alone is one of that cell's own and the cell still reports it; the
-    entries that list it beside other cells came after its own (PR 37's six among them), and the cell reports at
-    least its accepted values and the nine."""
+@pytest.mark.parametrize("where", ["the_repos_own", "a_copy_elsewhere"])
+@pytest.mark.parametrize("cell", PROMPT_CELLS)
+def test_a_prompt_cells_entries_are_held_by_name(cell, where, tmp_path):
+    """One test over the four ``prompt`` cells (it was three: ``test_the_three_prompt_cells_keep_their_own_entries``
+    and this file's two copies of ``test_longcat_cell.py``'s and ``test_olmo_cell.py``'s manifest tests, which
+    ``tests/conftest.py`` marked for their pins): what ``test_benchmark_harness.check_prompt_cell`` holds of a
+    cell, membership alone, against the repo's manifest and against a copy of it elsewhere, beside which every file
+    of the cell is found; and the nine of this file among what the cell reports."""
+    harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
     m = _manifest()
-    alone = [i for i, p in enumerate(m["per_layer"]) if p.get("workloads") == [cell]]
-    beside = [i for i, p in enumerate(m["per_layer"]) if cell in p.get("workloads", ()) and len(p["workloads"]) > 1]
-    assert len(alone) == own and sum("mfu" in m["per_layer"][i]["name"] for i in alone) == 1
-    assert set(IDLE) <= {m["per_layer"][i]["name"] for i in beside} and min(beside) > max(alone)
-    resolved = manifest.resolve(cell)
-    reported = {x["name"] for x in resolved.per_layer}
-    assert {m["per_layer"][i]["name"] for i in alone} | set(IDLE + LOG) <= reported
-    assert len(resolved.per_layer) >= values + len(IDLE + LOG)
+    path = None
+    if where == "a_copy_elsewhere":
+        path = str(tmp_path / "BENCHMARK.json")
+        shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), path)
+    harness.check_prompt_cell(m, cell, harness.PROMPT_CELLS[cell], path)
+    listed = harness.listed_for(m, cell)
+    alone = {n for n, p in listed.items() if p["workloads"] == [cell]}
+    beside = {n for n, p in listed.items() if len(p["workloads"]) > 1}
+    # the decoder's own measurements list the cell alone, one ``*mfu`` among them; what reads the same spans whatever
+    # the decoder lists it beside the other prompt cells, PR 37's six among them
+    spec = harness.PROMPT_CELLS[cell]
+    assert alone >= spec["own"] | spec["rooflines"] | {spec["mfu"]} and sum("mfu" in n for n in alone) == 1
+    assert set(IDLE) <= beside and all(set(PROMPT_CELLS) <= set(listed[n]["workloads"]) for n in IDLE)
+    resolved = manifest.resolve(cell, path)
+    assert alone | beside | set(LOG) <= {x["name"] for x in resolved.per_layer}
     assert resolved.chips == 1 and resolved.traffic["generator"] == "doc_pool"
-
-
-def test_longcats_enlarged_manifest_is_consistent_and_the_cell_resolves_from_a_copy(tmp_path):
-    """``test_longcat_cell.py``'s test of that name, assertion for assertion, but for the one that appended entries
-    cannot leave true (``tests/conftest.py`` marks it for that): "no entry lists the cell beside another" reads here
-    "none that the benchmark had when the cell came": whatever lists it beside another cell stands after its own."""
-    lc = manifest.load_module(os.path.join(HERE, "test_longcat_cell.py"))
-    harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
-    m = _manifest()
-    harness.check_manifest(m)
-    mine = [p for p in m["per_layer"] if p.get("workloads") == [lc.CELL]]
-    assert len(mine) == 22 and sum("mfu" in p["name"] for p in mine) == 1
-    assert {p["name"] for p in mine if "roofline" in p["name"]} == {"kernel.mla_core_roofline",
-                                                                    "kernel.scmoe_expert_matmul_roofline"}
-    beside = [i for i, p in enumerate(m["per_layer"]) if lc.CELL in p.get("workloads", ()) and len(p["workloads"]) > 1]
-    assert min(beside) > m["per_layer"].index(mine[-1])
-    # from a copy of the manifest elsewhere, every file of the cell is found beside the benchmark's own
-    path = tmp_path / "BENCHMARK.json"
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), path)
-    cell = manifest.resolve(lc.CELL, str(path))
-    assert cell.chips == 1 and cell.config["entry"] == "prompt_decoder" and cell.traffic["generator"] == "doc_pool"
-    assert {p["name"] for p in mine} <= {x["name"] for x in cell.per_layer} and len(cell.per_layer) >= 30
-    # the rehearsal manifest lists the same entries for the tiny cell
-    harness.check_manifest(manifest.load_json(lc.REHEARSAL), lc.REHEARSAL)
-
-
-def test_olmo_hybrids_enlarged_manifests_are_consistent_and_the_cell_resolves_from_a_copy(tmp_path):
-    """``test_olmo_cell.py``'s test of that name, assertion for assertion, but for what appended entries cannot leave
-    true (``tests/conftest.py``): the cell's 21 entries stand where PR 35 appended them, together and in their
-    order, not last; what lists the cell beside another stands after them; the cell reports at least its 29."""
-    oh = manifest.load_module(os.path.join(HERE, "test_olmo_cell.py"))
-    harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
-    m = _manifest()
-    harness.check_manifest(m)
-    mine = [p for p in m["per_layer"] if p.get("workloads") == [oh.CELL]]
-    assert {p["name"] for p in mine} == oh.OWN and len(mine) == 21
-    assert [p["name"] for p in mine if "mfu" in p["name"]] == ["oh.step_mfu"]
-    assert {p["name"] for p in mine if "roofline" in p["name"]} == {"kernel.delta_rule_roofline", "kernel.full_attn_core_roofline"}
-    assert all(p["unit"] == "%" and p["source"] == "device_trace" for p in mine if "roofline" in p["name"])
-    # appended in PR 35: the cell's entries follow the 73 the benchmark had, together; its configuration and
-    # workload are there as they were; no entry the benchmark had then lists the cell
-    assert [p["name"] for p in m["per_layer"][73:94]] == [p["name"] for p in mine]
-    (config,) = [c for c in m["configs"] if c["name"] == "olmo_hybrid_7b_prompt"]
-    (workload,) = [w for w in m["workloads"] if w["name"] == oh.CELL]
-    assert workload["config"] == config["name"] and workload["chips"] == 1 and workload["traffic"] == "docs_lognormal_4k_out64"
-    beside = [i for i, p in enumerate(m["per_layer"]) if oh.CELL in p.get("workloads", ()) and len(p["workloads"]) > 1]
-    assert min(beside) >= 94
-    for p in mine:
-        assert os.path.isfile(os.path.join(BENCH, "metrics", p["name"] + ".py"))
-        assert p["moves"] == ("setup_s" if p["name"].startswith("oh.setup_") else "rows_per_s_per_chip")
-    # from a copy of the manifest elsewhere, every file of the cell is found beside the benchmark's own
-    path = tmp_path / "BENCHMARK.json"
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), path)
-    cell = manifest.resolve(oh.CELL, str(path))
-    assert cell.chips == 1 and cell.config["entry"] == "prompt_decoder" and cell.traffic["generator"] == "doc_pool"
-    assert oh.OWN <= {x["name"] for x in cell.per_layer} and len(cell.per_layer) >= 21 + 8
-    # the cell's own rehearsal manifest lists the same entries for the tiny cell, and the benchmark's is as it was
-    r = manifest.load_json(oh.REHEARSAL)
-    harness.check_manifest(r, oh.REHEARSAL)
-    assert [p for p in r["per_layer"] if p.get("workloads") == [oh.TINY_CELL]] == [dict(p, workloads=[oh.TINY_CELL]) for p in mine]
-    assert [w["name"] for w in r["workloads"]] == [oh.TINY_CELL]
-    assert not [w for w in manifest.load_json(os.path.join(BENCH, "rehearsal.json"))["workloads"] if "olmo" in w["name"]]
 
 
 # -- the tiny cells, driven ---------------------------------------------------------
@@ -316,16 +258,11 @@ def bench_run():
 
 
 @pytest.mark.parametrize("tiny", sorted(TINY))
-def test_a_tiny_prompt_cell_reads_what_needs_no_device(bench_run, tiny, tmp_path, monkeypatch):
+def test_a_tiny_prompt_cell_reads_what_needs_no_device(bench_run, tiny, monkeypatch):
     monkeypatch.setattr(program_spans, "MAX_BRACKET_NS", 50_000_000)  # beside five other test workers: see
     monkeypatch.setattr(program_spans, "WIDEN_NS", 50_000_000)        # test_prompt_cell.py
     file, arrays = TINY[tiny]
-    r = manifest.load_json(os.path.join(HERE, "data", file))
-    full = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    r["per_layer"] += [dict(p, workloads=[tiny]) if "workloads" in p else p for p in full["per_layer"] if p["name"] in IDLE + LOG]
-    path = tmp_path / "rehearsal.json"
-    path.write_text(json.dumps(r))
-    cell = manifest.resolve(tiny, str(path))
+    cell = manifest.resolve(tiny, os.path.join(HERE, "data", file))  # the rehearsal manifests list the nine since PR 42
     assert set(IDLE + LOG) <= {x["name"] for x in cell.per_layer}
     loaded = sum(s for _, kind, s, _ in idle_by_span.compile_log() if kind == "cache_load")  # by this process's other tests
     rec = bench_run.run_cell(cell, seed=2 ** 31 + 37, seconds=0.5, trace_on=True)

@@ -4,8 +4,8 @@ counts, its entry (``entries/prompt_decoder.py``: the decoder found through the
 program's record) and readers driven through ``run.py``'s own ``run_cell`` from
 a manifest of its own (``data/rehearsal_longcat.json``: the tiny decoder under
 the per-layer entries the real cell lists), and a fault planted in the expert
-branch. No file the benchmark had is edited: the enlarged manifest is checked by
-``test_benchmark_harness.check_manifest`` as it stands."""
+branch. The manifest is checked by ``test_benchmark_harness.check_manifest`` and the
+cell's entries by its ``check_prompt_cell``: by name, no place and no count."""
 
 from __future__ import annotations
 
@@ -119,44 +119,30 @@ def test_the_counts_of_the_work_follow_the_shapes(real_cell):
     assert ref.head_flops(cfg, 1.0) == 2 * 6144 * 16384
 
 
-def test_the_enlarged_manifest_is_consistent_and_the_cell_resolves_from_a_copy(tmp_path):
+def test_the_manifest_holds_the_cells_entries_by_name_and_the_cell_resolves_from_a_copy(tmp_path):
+    """Membership only (``test_benchmark_harness.check_prompt_cell`` has the rules): the accepted batcher's,
+    prompter's and set-up's entries list this cell beside the other ``prompt`` cells, LongCat's own measurements
+    list it alone, one of what it reports is an ``*mfu``, two are its kernels' rooflines. No place in a list and
+    no count is held, so a later cell's entries can be appended."""
     harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
     m = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     harness.check_manifest(m)
-    mine = [p for p in m["per_layer"] if p.get("workloads") == [CELL]]
-    assert len(mine) == 22 and sum("mfu" in p["name"] for p in mine) == 1
-    assert {p["name"] for p in mine if "roofline" in p["name"]} == {"kernel.mla_core_roofline",
-                                                                    "kernel.scmoe_expert_matmul_roofline"}
-    # no entry the benchmark had lists the cell: each of the cell's own lists it alone
-    assert not [p["name"] for p in m["per_layer"] if CELL in p.get("workloads", ()) and len(p["workloads"]) > 1]
+    spec = harness.PROMPT_CELLS[CELL]
+    assert spec["mfu"] == "lc.step_mfu" and spec["experts"] and spec["rooflines"] == {
+        "kernel.mla_core_roofline", "kernel.scmoe_expert_matmul_roofline"}
+    harness.check_prompt_cell(m, CELL, spec)
+    # the ten readings the cell once took through wrappers of its own it now takes from the accepted entries
+    listed = harness.listed_for(m, CELL)
+    assert not [n for n in listed if n.startswith("lc.") and n not in spec["own"] | {spec["mfu"]}]
+    assert {"serve.prefill_ms_per_ktoken", "serve.decode_step_ms", "moe.held_assignment_share", "lm.setup_init_s"} <= set(listed)
     # from a copy of the manifest elsewhere, every file of the cell is found beside the benchmark's own
     path = tmp_path / "BENCHMARK.json"
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), path)
-    cell = manifest.resolve(CELL, str(path))
-    assert cell.chips == 1 and cell.config["entry"] == "prompt_decoder" and cell.traffic["generator"] == "doc_pool"
-    assert {p["name"] for p in mine} <= {x["name"] for x in cell.per_layer} and len(cell.per_layer) >= 30
+    harness.check_prompt_cell(m, CELL, spec, str(path))
     # the rehearsal manifest lists the same entries for the tiny cell
-    harness.check_manifest(manifest.load_json(REHEARSAL), REHEARSAL)
-
-
-def test_the_host_stage_entries_list_the_embed_cells_alone():
-    """What ``test_host_stage_metrics.py`` holds of PR 30's two entries, without pinning the list's length or its
-    end (``conftest.py`` has the reason): they list the three embed cells, and neither ``prompt`` cell reports them."""
-    m = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    new = ["engine.host_stage_wait_s_per_krow", "engine.host_stage_ready_share"]
-    embed = [w["name"] for w in m["workloads"] if w["config"].startswith("clip_")]
-    by_name = {p["name"]: p for p in m["per_layer"]}
-    names = [p["name"] for p in m["per_layer"]]
-    assert names.index(new[1]) == names.index(new[0]) + 1 == 50  # where PR 30 appended them; later entries follow
-    for name in new:
-        e = by_name[name]
-        assert e["workloads"] == embed and len(embed) == 3 and e["moves"] == "rows_per_s_per_chip"
-        assert e["layer"] == "UDF operator and source (execution/executor.py, scan)"
-        assert os.path.isfile(os.path.join(BENCH, "metrics", name + ".py"))
-    for cell in embed:
-        assert set(new) <= {p["name"] for p in manifest.resolve(cell).per_layer}
-    for cell in ("granite_4_0_h_small_prompt.docs_lognormal_1k_out64", CELL):
-        assert not set(new) & {p["name"] for p in manifest.resolve(cell).per_layer}
+    r = manifest.load_json(REHEARSAL)
+    harness.check_manifest(r, REHEARSAL)
+    assert set(listed) <= harness.reported_by(r, TINY_CELL) <= harness.reported_by(m, CELL)
 
 
 @pytest.mark.parametrize("seed", [1, 2 ** 31 + 9])
@@ -173,15 +159,16 @@ def test_the_cell_rehearses_on_the_cpu_and_its_control_reads_not_correct(bench_r
     assert rec["control"]["compared"]["logprob_gap"]["value"] > c["logprob_gap"]["limit"] > c["logprob_gap"]["value"]
     # the readers of the program's spans and counters find them; the device's need a device
     m = {k: v["value"] for k, v in rec["metrics"].items()}
-    assert {"lc.tokenize_s_per_krow", "lc.slot_occupancy", "lc.padded_token_share", "lc.held_assignment_share",
+    assert {"prompt.tokenize_s_per_krow", "serve.slot_occupancy", "serve.padded_token_share", "moe.held_assignment_share",
             "lc.zero_assignment_share", "lc.expert_load_max_over_mean", "lc.cache_bytes_per_token",
-            "lc.setup_init_s", "lc.setup_first_prefill_s"} <= set(m)
-    assert not {"lc.step_mfu", "kernel.mla_core_roofline", "kernel.scmoe_expert_matmul_roofline", "lc.decode_step_ms",
-                "lc.mla_core_ms_per_ktoken", "lc.other_ms_per_ktoken", "lc.host_exposed_s_per_krow"} & set(m)
-    assert not any(k.startswith(("lm.", "serve.", "moe.", "prompt.")) for k in m)  # granite's entries list granite's cell
-    assert 0 < m["lc.slot_occupancy"] <= 100 and 0 <= m["lc.padded_token_share"] < 100
+            "lm.setup_init_s", "lm.setup_first_prefill_s", "serve.fetch_arrays_per_step"} <= set(m)
+    assert not {"lc.step_mfu", "kernel.mla_core_roofline", "kernel.scmoe_expert_matmul_roofline", "serve.decode_step_ms",
+                "lc.mla_core_ms_per_ktoken", "lc.other_ms_per_ktoken", "serve.host_exposed_s_per_krow"} & set(m)
+    # another decoder's own measurements list that decoder's cell: none of granite's, Olmo-Hybrid's or DeepSeek's here
+    assert not any(k.startswith(("lm.", "oh.", "ds.")) and ".setup_" not in k for k in m) and "moe.expert_load_max_over_mean" not in m
+    assert 0 < m["serve.slot_occupancy"] <= 100 and 0 <= m["serve.padded_token_share"] < 100
     assert m["lc.cache_bytes_per_token"] == 4 * 16 * 2  # four attentions x (8 + 8) values x 2 B
-    assert 15 < m["lc.zero_assignment_share"] < 55 and 15 < m["lc.held_assignment_share"] < 55  # 4 and 4 of 12 outputs
+    assert 15 < m["lc.zero_assignment_share"] < 55 and 15 < m["moe.held_assignment_share"] < 55  # 4 and 4 of 12 outputs
     assert m["lc.expert_load_max_over_mean"] >= 1
 
 

@@ -6,8 +6,8 @@ manifest of its own (``data/rehearsal_olmo.json``: the tiny decoder under
 ``test_benchmark_harness.check_manifest`` as they stand, the tiny cell driven
 through ``run.py``'s own ``run_cell`` with every value that needs no device
 read, and the fp8 control and a program without the 2 in beta reading not
-correct there. No file the benchmark had is edited, ``benchmark/rehearsal.json``
-neither: entries are appended to ``BENCHMARK.json`` alone."""
+correct there. What is held of the cell's entries is held by name
+(``check_prompt_cell``): no place in a list and no count."""
 
 from __future__ import annotations
 
@@ -30,17 +30,17 @@ from lib import compare, manifest  # noqa: E402
 CELL = "olmo_hybrid_7b_prompt.docs_lognormal_4k_out64"
 TINY_CELL = "rehearsal_tiny_olmo.rehearsal_docs"
 REHEARSAL = os.path.join(HERE, "data", "rehearsal_olmo.json")
-#: The cell's own entries: one ``*mfu``, two rooflines, seven classes of device time, two counters, and the nine
-#: accepted readers of the batcher's, the tokenizer's and the set-up's spans under names that list this cell.
+#: Olmo-Hybrid's own measurements: one ``*mfu``, two rooflines, seven classes of device time, two counters. The
+#: batcher's, the tokenizer's and the set-up's readings are the accepted entries', which list this cell beside the
+#: other ``prompt`` cells since PR 42 (the nine ``oh.*`` wrappers of the same readers went).
 OWN = {"oh.step_mfu", "kernel.delta_rule_roofline", "kernel.full_attn_core_roofline",
        "oh.lin_proj_ms_per_ktoken", "oh.delta_rule_ms_per_ktoken", "oh.attn_proj_ms_per_ktoken",
        "oh.attn_core_ms_per_ktoken", "oh.mlp_ms_per_ktoken", "oh.head_ms_per_ktoken", "oh.other_ms_per_ktoken",
-       "oh.kv_bytes_per_token", "oh.recurrent_mb_per_slot", "oh.prefill_ms_per_ktoken", "oh.decode_step_ms",
-       "oh.prefill_share", "oh.slot_occupancy", "oh.padded_token_share", "oh.host_exposed_s_per_krow",
-       "oh.tokenize_s_per_krow", "oh.setup_init_s", "oh.setup_first_prefill_s"}
-#: Of those, what a run without a device trace reads: the program's spans and counters.
-ON_THE_CPU = {"oh.kv_bytes_per_token", "oh.recurrent_mb_per_slot", "oh.slot_occupancy", "oh.padded_token_share",
-              "oh.tokenize_s_per_krow", "oh.setup_init_s", "oh.setup_first_prefill_s"}
+       "oh.kv_bytes_per_token", "oh.recurrent_mb_per_slot"}
+#: What a run without a device trace reads of the cell's entries: the program's spans and counters.
+ON_THE_CPU = {"oh.kv_bytes_per_token", "oh.recurrent_mb_per_slot", "serve.slot_occupancy", "serve.padded_token_share",
+              "prompt.tokenize_s_per_krow", "lm.setup_init_s", "lm.setup_first_prefill_s",
+              "serve.dispatch_host_ms_per_step", "serve.fetch_arrays_per_step", "serve.setup_first_decode_s"}
 
 
 @pytest.fixture(scope="module")
@@ -124,34 +124,35 @@ def test_the_counts_of_the_work_follow_the_shapes(real_cell):
     assert ref.head_flops(cfg, 1.0) == 2 * 3840 * 100352
 
 
-def test_the_enlarged_manifests_are_consistent_and_the_cell_resolves_from_a_copy(tmp_path):
+def test_the_manifests_hold_the_cells_entries_by_name_and_the_cell_resolves_from_a_copy(tmp_path):
+    """Membership only (``test_benchmark_harness.check_prompt_cell`` has the rules): the accepted batcher's,
+    prompter's and set-up's entries list this cell beside the other ``prompt`` cells (the expert counter does not:
+    no experts), Olmo-Hybrid's own measurements list it alone, one of what it reports is an ``*mfu``, two are its
+    kernels' rooflines. Where the entries, the cell or its configuration stand in their lists, and how many there
+    are, is held nowhere, so a later cell's can be appended."""
     harness = manifest.load_module(os.path.join(HERE, "test_benchmark_harness.py"))
     m = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     harness.check_manifest(m)
-    mine = [p for p in m["per_layer"] if p.get("workloads") == [CELL]]
-    assert {p["name"] for p in mine} == OWN and len(mine) == 21
-    assert [p["name"] for p in mine if "mfu" in p["name"]] == ["oh.step_mfu"]
-    assert {p["name"] for p in mine if "roofline" in p["name"]} == {"kernel.delta_rule_roofline", "kernel.full_attn_core_roofline"}
-    assert all(p["unit"] == "%" and p["source"] == "device_trace" for p in mine if "roofline" in p["name"])
-    # appended: the cell's entries are the manifest's last, its configuration and workload too; no entry the
-    # benchmark had lists the cell
-    assert [p["name"] for p in m["per_layer"][-21:]] == [p["name"] for p in mine]
-    assert m["configs"][-1]["name"] == "olmo_hybrid_7b_prompt" and m["workloads"][-1]["name"] == CELL
-    assert m["workloads"][-1]["chips"] == 1 and m["workloads"][-1]["traffic"] == "docs_lognormal_4k_out64"
-    assert not [p["name"] for p in m["per_layer"] if CELL in p.get("workloads", ()) and len(p["workloads"]) > 1]
-    for p in mine:
-        assert os.path.isfile(os.path.join(BENCH, "metrics", p["name"] + ".py"))
-        assert p["moves"] == ("setup_s" if p["name"].startswith("oh.setup_") else "rows_per_s_per_chip")
+    spec = harness.PROMPT_CELLS[CELL]
+    assert spec["own"] | spec["rooflines"] | {spec["mfu"]} == OWN and spec["mfu"] == "oh.step_mfu" and not spec["experts"]
+    harness.check_prompt_cell(m, CELL, spec)
+    listed = harness.listed_for(m, CELL)
+    assert OWN <= set(listed) and "moe.held_assignment_share" not in listed
+    assert not [n for n in listed if n.startswith("oh.") and n.split(".", 1)[1] in (  # the nine wrappers went
+        "prefill_ms_per_ktoken", "decode_step_ms", "prefill_share", "slot_occupancy", "padded_token_share",
+        "host_exposed_s_per_krow", "tokenize_s_per_krow", "setup_init_s", "setup_first_prefill_s")]
+    (config,) = [c for c in m["configs"] if c["name"] == "olmo_hybrid_7b_prompt"]
+    (workload,) = [w for w in m["workloads"] if w["name"] == CELL]
+    assert workload["config"] == config["name"] and workload["chips"] == 1 and workload["traffic"] == "docs_lognormal_4k_out64"
     # from a copy of the manifest elsewhere, every file of the cell is found beside the benchmark's own
     path = tmp_path / "BENCHMARK.json"
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), path)
-    cell = manifest.resolve(CELL, str(path))
-    assert cell.chips == 1 and cell.config["entry"] == "prompt_decoder" and cell.traffic["generator"] == "doc_pool"
-    assert OWN <= {x["name"] for x in cell.per_layer} and len(cell.per_layer) == 21 + 8
+    harness.check_prompt_cell(m, CELL, spec, str(path))
     # the cell's own rehearsal manifest lists the same entries for the tiny cell, and the benchmark's is as it was
     r = manifest.load_json(REHEARSAL)
     harness.check_manifest(r, REHEARSAL)
-    assert [p for p in r["per_layer"] if p.get("workloads") == [TINY_CELL]] == [dict(p, workloads=[TINY_CELL]) for p in mine]
+    assert OWN <= harness.reported_by(r, TINY_CELL) <= harness.reported_by(m, CELL)
+    assert all(dict(listed[n], workloads=[TINY_CELL]) == e for n, e in harness.listed_for(r, TINY_CELL).items())
     assert [w["name"] for w in r["workloads"]] == [TINY_CELL]
     assert not [w for w in manifest.load_json(os.path.join(BENCH, "rehearsal.json"))["workloads"] if "olmo" in w["name"]]
 
@@ -170,9 +171,9 @@ def test_the_cell_rehearses_on_the_cpu_and_its_control_reads_not_correct(bench_r
     assert rec["control"]["compared"]["logprob_gap"]["value"] > c["logprob_gap"]["limit"] > c["logprob_gap"]["value"]
     # every value that needs no device is read; those that need one are left out, not null
     m = {k: v["value"] for k, v in rec["metrics"].items()}
-    assert {k for k in m if k.startswith(("oh.", "kernel."))} == ON_THE_CPU
-    assert not any(k.startswith(("lm.", "lc.", "serve.", "moe.", "prompt.")) for k in m)  # other cells' entries
-    assert 0 < m["oh.slot_occupancy"] <= 100 and 0 <= m["oh.padded_token_share"] < 100
+    assert {k for k in m if k.startswith(("oh.", "kernel.", "lm.", "serve.", "prompt."))} == ON_THE_CPU
+    assert not any(k.startswith(("lc.", "ds.", "moe.")) for k in m)  # other decoders' own entries, the expert counters
+    assert 0 < m["serve.slot_occupancy"] <= 100 and 0 <= m["serve.padded_token_share"] < 100
     # one attention layer, 4 heads of 16, a slot's rows held in whole tiles of 16: 64 for the 57 positions asked for
     assert m["oh.kv_bytes_per_token"] == pytest.approx(1 * 2 * 4 * 16 * 2 * 64 / 57)
     assert m["oh.recurrent_mb_per_slot"] == pytest.approx(3 * (4 * 4 * 16 * 8 + 2 * 3 * 128) / 1e6)  # three linear layers

@@ -218,3 +218,33 @@ def test_device_time_by_scope_over_two_programs_on_a_hand_made_trace():
     # whole executions in the window only: the third begins inside it and ends outside
     run = type("Run", (), {"events": events})()
     assert lm_scopes.programs(run) == {lm_scopes.PREFILL: [140.0], lm_scopes.DECODE: [40.0]}
+
+
+@pytest.mark.parametrize("step_counts, want_decode", [
+    # every program today: a step is one token for every active slot
+    ([{"active": 3, "slots": 4}, {"active": 2, "slots": 4}], 5.0),
+    # a decoder whose step is not one token a slot says what it processed: a block of 8 positions a slot in the first
+    # step, of which the second commits what was left; ``active`` still counts slots
+    ([{"active": 3, "slots": 4, "tokens": 24}, {"active": 2, "slots": 4, "tokens": 7}], 31.0),
+    # an older program's spans beside a newer one's in one window: each step by what it carries
+    ([{"active": 3, "slots": 4}, {"active": 2, "slots": 4, "tokens": 16}], 19.0)],
+    ids=["active_where_no_tokens", "tokens_where_carried", "step_by_step"])
+def test_a_decode_steps_processed_positions_are_its_tokens_counter_or_its_active_slots(step_counts, want_decode):
+    from types import SimpleNamespace
+
+    from lib import lm_scopes
+
+    steps = [(200 + 100 * k, 280 + 100 * k, dict(c, **{"moe.assignments": 6, "moe.held_assignments": 3}), False)
+             for k, c in enumerate(step_counts)]
+    spans = {"serve.prefill": [(100, 190, {"tokens": 40, "padded_tokens": 64, "chunks": 2, "rows": 3, "row_chunks": 4}, False)],
+             "serve.decode_step": steps + [(5000, 5080, {"active": 4, "slots": 4, "tokens": 99}, False)]}  # began after the window
+    run = SimpleNamespace(events={"window": [0, 1000], "devices": {}, "spans": {}}, _lm_spans_done=True,
+                          _program_spans=SimpleNamespace(offset_ns=0, spans=spans))
+    n = lm_scopes.tokens(run)
+    assert (n.prefill, n.padded, n.calls, n.rows, n.row_chunks) == (40.0, 64.0, 2.0, 3.0, 4.0)
+    assert n.decode == want_decode and (n.active, n.slots, n.steps) == (5.0, 8.0, 2.0)
+    assert (n.assignments, n.held) == (12.0, 6.0)
+    # what is a thousand tokens processed follows the positions; the occupancy of the slots does not
+    assert lm_scopes.per_ktoken_ms(run, 1e6 * (40 + want_decode)) == pytest.approx(1000.0)
+    occupancy = manifest.load_module(os.path.join(BENCH, "metrics", "serve.slot_occupancy.py")).read
+    assert occupancy(run) == pytest.approx(100.0 * 5 / 8)
