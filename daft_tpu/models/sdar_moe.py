@@ -40,14 +40,17 @@ every width, all 128 experts and the whole vocabulary are as published.
 ``decode``), ``copy_state``; ``block_length``, ``denoising_steps`` and
 ``mask_id`` are the configuration's. Slot state is ``{"k", "v" (slots, heads,
 positions in whole tiles of ``ROW_TILE``, head_dim)}`` a layer (the layout of
-``models/olmo_hybrid.py``: no axis padded to a tile). The block step attends
-every position a slot holds, densely and in that layout (``attend_block``); the
-prefill writes its chunk with ``ops/pallas_cache_blocks``.
+``models/olmo_hybrid.py``: no axis padded to a tile). On a TPU the block step
+reads, in that layout, only the blocks of rows below each slot's position
+(``ops/pallas_cache_attention.py``, its block mode: a key/value head's 4 x 8
+queries one tile); elsewhere it attends every position a slot holds, densely
+(``attend_block``). The prefill writes its chunk with ``ops/pallas_cache_blocks``.
 
 Plain functions over a parameter tree, drawn tensor by tensor on the device in
 bfloat16; ``jax.named_scope`` names the parts (``attn_proj``, ``attn_core``,
 ``router``, ``experts``, ``unmask``, ``head``) for the device trace. While the
-block program traces, the model notes ``unmask`` = ``static`` on the batcher's open span.
+block program traces, the model notes ``unmask`` = ``static`` and ``attn`` =
+``fused`` | ``xla`` (the block step's core) on the batcher's open span.
 """
 
 from __future__ import annotations
@@ -63,7 +66,7 @@ import numpy as np
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
 from daft_tpu.models.decoders import draw, mm, rms
-from daft_tpu.ops import pallas_attention, pallas_cache_blocks
+from daft_tpu.ops import pallas_attention, pallas_cache_attention, pallas_cache_blocks
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
 #: ``block_length``, ``denoising_steps`` and ``mask_id`` are not in the published file (the catalog lists block
@@ -268,15 +271,23 @@ def attend_block(q, ck, cv, k, v, starts, scale: float, dtype):
 
 def _attn_block(cfg: SdarMoeConfig, p, u, st, positions, active, commit: bool):
     """One block a slot: u (slots, L, d) at ``positions`` (slots,: the block's first) -> (out, st). Every query
-    attends its slot's committed rows and the block (``attend_block``); the ``commit`` then writes the block's rows
-    where the block lies (an inactive slot keeps what it held), a pass writes nothing."""
+    attends its slot's committed rows and the block: on a TPU at a head size of whole lane tiles
+    ``ops/pallas_cache_attention.py``, which reads only the blocks of rows below each active slot's position (an
+    inactive slot's queries come back as zeros), else ``attend_block`` over every position; the ``commit`` then
+    writes the block's rows where the block lies (an inactive slot keeps what it held), a pass writes nothing."""
     B, T = u.shape[:2]
     KV, hd = cfg.num_key_value_heads, cfg.head_dim
     at = positions[:, None] + jnp.arange(T)[None, :]
     with jax.named_scope("attn_proj"):
         q, k, v = _project(cfg, p, u, at)
     with jax.named_scope("attn_core"):
-        out = attend_block(q, st["k"], st["v"], k, v, positions, hd ** -0.5, cfg.dtype)
+        fused = pallas_cache_attention.cache_attention_applies(q.shape, st["k"].shape, cfg.dtype, own=True)
+        decoders.note_on_serving_span("attn", "fused" if fused else "xla")
+        if fused:  # a kernel that fails to trace or lower fails the program
+            out = pallas_cache_attention.cache_attention(q, st["k"], st["v"], jnp.arange(B), positions,
+                                                         active.astype(jnp.int32), scale=hd ** -0.5, own=(k, v))
+        else:
+            out = attend_block(q, st["k"], st["v"], k, v, positions, hd ** -0.5, cfg.dtype)
         if commit:
             S = st["k"].shape[2]
             where = jnp.where(active[:, None], at, S)[:, None, :]          # (B, 1, T); out of range: dropped

@@ -84,7 +84,9 @@ routed experts' grouped products, ``models/decoders.grouped_mlp``; ``mla`` =
 ``delta`` = ``chunked`` | ``recurrent``: the gated delta rule's, ``models/olmo_hybrid``;
 ``dsa`` = ``masked`` (| ``gathered``, no program yet): how ``models/deepseek_v32``'s attention applies its
 selection: every block a row holds expanded and scored with the keys not kept masked out, or the kept rows gathered;
-``unmask`` = ``static``: how ``models/sdar_moe``'s passes choose the positions they fix).
+``unmask`` = ``static``: how ``models/sdar_moe``'s passes choose the positions they fix; ``attn`` = ``fused`` |
+``xla``: whether ``models/olmo_hybrid``'s full attention and ``models/sdar_moe``'s block step take
+``ops/pallas_cache_attention.py``).
 """
 
 from __future__ import annotations
@@ -226,7 +228,7 @@ class ContinuousBatcher:
     def _repeat_noted(self, sp) -> None:
         """A model notes its choice of path on the open span while its program
         traces (``decoders.grouped_mlp``: ``moe``; ``latent_attention``: ``mla``; ``olmo_hybrid``: ``delta``, ``attn``;
-        ``deepseek_v32``: ``dsa``); a
+        ``deepseek_v32``: ``dsa``; ``sdar_moe``: ``unmask``, ``attn``); a
         call that traces nothing repeats what the trace chose."""
         noted = self._noted.setdefault(sp.name, {})
         noted.update({k: v for k, v in sp.count.items() if isinstance(v, str)})

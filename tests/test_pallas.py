@@ -5,7 +5,8 @@ forward, and its compile for a described v5e chip at the widths the benchmark
 runs; and the same compile of the grouped-matmul kernel
 (``ops/pallas_grouped_matmul.py``, whose other tests are in
 ``tests/test_pallas_grouped_matmul.py``) and of LongCat-Flash's prefill
-attention (``ops/pallas_mla_attention.py``, ``tests/test_pallas_mla_attention.py``). One file, so that the tests that
+attention (``ops/pallas_mla_attention.py``, ``tests/test_pallas_mla_attention.py``), and SDAR's block step through
+``ops/pallas_cache_attention.py`` (``tests/test_pallas_cache_attention.py``). One file, so that the tests that
 describe a TPU topology stay with their fixture (see the on-chip-measurement
 guide)."""
 
@@ -414,3 +415,27 @@ def test_the_delta_rule_kernel_compiles_for_v5e_with_no_chunk_tensor_in_hbm(one_
     assert compiled.memory_analysis().temp_size_in_bytes < 48 << 20           # two of those (15.7 MB each) and small change
     heads = pdr._heads_a_step(C, H, dk, dv)
     assert heads == pdr.MAX_HEADS and H % heads == 0 and pdr._step_bytes(C, dk, dv, heads) <= pdr.VMEM_BUDGET
+
+
+def test_sdars_block_step_attention_compiles_for_v5e_with_no_copy_of_the_cache(one_chip, monkeypatch):
+    """A pass's and the commit's attention of SDAR-30B-A3B-Chat's block step at the cell's sizes (64 slots of
+    2,320 positions, 4 key/value heads of 128 with 8 queries each, blocks of 4), compiled by the TPU's compiler
+    (nothing runs): one custom call, handed the caches with no copy of either, no float32 scores over every
+    position a slot holds (XLA's ``[64,4,8,4,2324]``), and the commit's write in place."""
+    from daft_tpu.models import sdar_moe as sd
+
+    of = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    monkeypatch.setattr(pa, "backend_is_tpu", lambda: True)
+    cfg = sd.SdarMoeConfig.from_name("SDAR-30B-A3B-Chat", num_layers=1)
+    p = jax.tree_util.tree_map(lambda x: of(x.shape, x.dtype), jax.eval_shape(lambda: sd._init_layer(cfg, jax.random.PRNGKey(0))))
+    cache = of((64, 4, 2320, 128), jnp.bfloat16)
+    args = (p, of((64, 4, 2048), jnp.bfloat16), {"k": cache, "v": cache}, of((64,), jnp.int32), of((64,), jnp.bool_))
+    rows = 64 * 4 * 2320 * 128 * 2
+    for commit in (False, True):
+        fn = jax.jit(lambda p, u, st, at, act: sd._attn_block(cfg, p, u, st, at, act, commit), donate_argnums=(2,))
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and "2324]" not in text
+        assert not re.search(r"= bf16\[64,4,2320,128\]\S* (copy|transpose)\(", text)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < rows // 8 and (not commit or mem.alias_size_in_bytes >= 2 * rows)

@@ -1,9 +1,11 @@
 """The attention kernel over a cache of per-head key/value rows
 (``ops/pallas_cache_attention.py``): its arithmetic in interpret mode on the CPU
-against ``decoders.attention_chunk`` (a prefill's chunk) and ``attention_step``
-(a decode step) over the same rows, the walk it is handed, the slot's end, the
-rule that selects it, and a failing kernel. The model on either path and the
-compile for a described v5e at the cell's sizes are in ``tests/test_olmo_hybrid.py``."""
+against ``decoders.attention_chunk`` (a prefill's chunk), ``attention_step``
+(a decode step) and ``sdar_moe.attend_block`` (a block of a diffusion decoder,
+grouped queries) over the same rows, the walk it is handed, the slot's end, the
+rule that selects it, and a failing kernel. The models on either path and the
+compile for a described v5e at the cells' sizes are in ``tests/test_olmo_hybrid.py``
+and ``tests/test_sdar_moe.py``."""
 
 import dataclasses
 import os
@@ -16,7 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from daft_tpu.models import decoders, olmo_hybrid as oh
+from daft_tpu.models import decoders, olmo_hybrid as oh, sdar_moe as sd
 from daft_tpu.models.serving import prefill_schedule
 from daft_tpu.ops import pallas_attention as pa
 from daft_tpu.ops import pallas_cache_attention as pca
@@ -45,6 +47,16 @@ STEPS = {
     "on_a_block_boundary": ([BLOCK, 2 * BLOCK, 1, 40], [1, 1, 1, 1]),
     "in_the_last_partial_block": ([2 * BLOCK + 79, 2 * BLOCK, 2 * BLOCK - 1, 5], [1, 1, 1, 1]),
     "inactive_slots": ([300, 900, 2 * BLOCK + 3, 7], [0, 1, 0, 1]),
+    "nobody_active": ([5, 6, 7, 8], [0, 0, 0, 0]),
+}
+#: A block of a diffusion decoder: L positions a slot, R queries a key/value head (L x R = 16, a sublane tile).
+L, R = 4, 4
+BLOCK_S = 2 * BLOCK + 80                                            # a slot's last key block is partial
+#: name -> (the blocks' first positions, active) of a block call over four slots of ``BLOCK_S`` positions.
+BLOCKS = {
+    "at_zero_on_and_inside_a_block_and_at_the_end": ([0, BLOCK, 700, BLOCK_S - L], [1, 1, 1, 1]),
+    "in_the_first_block": ([4, 16, BLOCK - 4, 1], [1, 1, 1, 1]),
+    "inactive_slots": ([300, 2 * BLOCK, BLOCK_S - L, 0], [0, 1, 0, 1]),
     "nobody_active": ([5, 6, 7, 8], [0, 0, 0, 0]),
 }
 
@@ -77,9 +89,23 @@ def _xla_step(q, ck, cv, positions, dtype):
     return np.asarray(out, np.float32)
 
 
-def _fused(q, ck, cv, slots, starts, lengths):
+def _block_operands(KV, dtype, seed, rows=4, S=BLOCK_S):
+    """A block call's q (rows, L, KV, R, hd), the caches, and the blocks' own keys and values (rows, L, KV, hd)."""
+    rng = np.random.default_rng(seed)
+    ck, cv = _rows(rows, KV, S, dtype, seed + 1)
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), dtype) for shape in
+               ((rows, L, KV, R, HD), (rows, L, KV, HD), (rows, L, KV, HD)))
+    return q, ck, cv, (k, v)
+
+
+def _xla_block(q, ck, cv, own, starts, dtype):
+    """``sdar_moe.attend_block``: every slot over every position it holds and its block's own keys."""
+    return np.asarray(sd.attend_block(q, ck, cv, *own, jnp.asarray(starts, jnp.int32), SCALE, dtype), np.float32)
+
+
+def _fused(q, ck, cv, slots, starts, lengths, own=None):
     return pca.cache_attention(q, ck, cv, jnp.asarray(slots, jnp.int32), jnp.asarray(starts, jnp.int32),
-                               jnp.asarray(lengths, jnp.int32), scale=SCALE, interpret=True)
+                               jnp.asarray(lengths, jnp.int32), scale=SCALE, interpret=True, own=own)
 
 
 def _poisoned(ck, cv, slots, starts, lengths, block, spare=()):
@@ -134,6 +160,26 @@ def test_a_decode_step_matches_attention_over_every_position(layout, precision):
     np.testing.assert_allclose(out[held], ref[held], atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("precision", list(TOL))
+@pytest.mark.parametrize("layout", list(BLOCKS))
+def test_a_block_matches_attend_block_over_every_position(layout, precision):
+    """A block's 4 x 4 queries a key/value head over the committed positions
+    before its start and its own 4 keys, in one softmax: at position 0 (no
+    committed row), on and inside a block, in the partial last block, with
+    inactive slots. NaN stands in every key block at or past the one that holds
+    the start and in the whole slot of an inactive one."""
+    dtype, tol = TOL[precision]
+    starts, active = BLOCKS[layout]
+    q, ck, cv, own = _block_operands(2, dtype, seed=len(layout))
+    ref = _xla_block(q, ck, cv, own, starts, dtype)
+    out = _fused(q, *_poisoned(ck, cv, np.arange(4), np.asarray(starts) - 1, active, BLOCK), np.arange(4), starts, active, own)
+    assert out.shape == q.shape and out.dtype == dtype
+    out = np.asarray(out, np.float32)
+    held = np.asarray(active) > 0
+    assert np.isfinite(out).all() and not out[~held].any()
+    np.testing.assert_allclose(out[held], ref[held], atol=tol, rtol=tol)
+
+
 def test_the_interpreter_pads_with_nan():
     """What the decode step's test leans on: a block that reaches past its array comes
     to the kernel with NaN behind the end (on the chip: whatever the buffer held)."""
@@ -149,55 +195,77 @@ def test_the_interpreter_pads_with_nan():
     assert np.isnan(np.asarray(out[1, 0, 8:])).all() and np.isfinite(np.asarray(out[1, 0, :8])).all()
 
 
-@pytest.mark.parametrize("call", ["chunk", "step"])
+@pytest.mark.parametrize("call", ["chunk", "step", "block"])
 def test_large_scores_do_not_overflow(call):
+    own = None
     if call == "chunk":
         q, ck, cv, slots = _chunk_operands(2, 2, jnp.float32, seed=5)
         starts, lengths = [2 * T, T], [T, T]
         ref = _xla_chunk(q * 30.0, ck, cv, slots, starts, jnp.float32)
-    else:
+    elif call == "step":
         ck, cv = _rows(2, 2, BLOCK + 80, jnp.float32, seed=5)
         q, slots = jnp.asarray(np.random.default_rng(5).standard_normal((2, 1, 2, 1, HD)), jnp.float32), np.arange(2)
         starts, lengths = [BLOCK + 40, 17], [1, 1]
         ref = _xla_step(q * 30.0, ck, cv, starts, jnp.float32)
-    out = np.asarray(_fused(q * 30.0, ck, cv, slots, starts, lengths))
+    else:
+        q, ck, cv, own = _block_operands(2, jnp.float32, seed=5, rows=3)
+        slots, starts, lengths = np.arange(3), [BLOCK + 40, 17, 0], [1, 1, 1]
+        ref = _xla_block(q * 30.0, ck, cv, own, starts, jnp.float32)
+    out = np.asarray(_fused(q * 30.0, ck, cv, slots, starts, lengths, own))
     assert np.isfinite(out).all()
     np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("call", ["chunk", "step"])
+@pytest.mark.parametrize("call", ["chunk", "step", "block"])
 def test_rows_and_heads_do_not_mix(call):
     """Another slot's cache rows change that row's result alone; one head's keys
-    change that head's alone, in every row (six heads are two steps of the grid)."""
-    KV = 6
+    change that head's alone, in every row (six heads are two steps of the grid
+    for a chunk, one for a step or a block)."""
+    KV, own = 6, None
     if call == "chunk":
         q, ck, cv, slots = _chunk_operands(KV, 3, jnp.float32, seed=3)
         starts, lengths = [T, T, T], [T, T, T]
-    else:
+    elif call == "step":
         ck, cv = _rows(3, KV, BLOCK + 80, jnp.float32, seed=3)
         q, slots = jnp.asarray(np.random.default_rng(3).standard_normal((3, 1, KV, 1, HD)), jnp.float32), np.arange(3)
         starts, lengths = [BLOCK + 9, 100, BLOCK - 1], [1, 1, 1]
-    base = np.asarray(_fused(q, ck, cv, slots, starts, lengths))
-    other_slot = np.abs(np.asarray(_fused(q, ck, cv.at[slots[1]].add(1.0), slots, starts, lengths)) - base) > 1e-6
+    else:
+        q, ck, cv, own = _block_operands(KV, jnp.float32, seed=3, rows=3)
+        slots, starts, lengths = np.arange(3), [BLOCK + 9, 100, BLOCK - 1], [1, 1, 1]
+    base = np.asarray(_fused(q, ck, cv, slots, starts, lengths, own))
+    other_slot = np.abs(np.asarray(_fused(q, ck, cv.at[slots[1]].add(1.0), slots, starts, lengths, own)) - base) > 1e-6
     assert other_slot[1].all()
     other_slot[1] = False
     assert not other_slot.any()
-    other_head = np.abs(np.asarray(_fused(q, ck.at[:, 4].multiply(-1.0), cv, slots, starts, lengths)) - base) > 1e-6
+    other_head = np.abs(np.asarray(_fused(q, ck.at[:, 4].multiply(-1.0), cv, slots, starts, lengths, own)) - base) > 1e-6
     assert other_head[:, :, 4].any(axis=(1, 2, 3)).all()
     other_head[:, :, 4] = False
     assert not other_head.any()
+    if own is not None:  # a block's own keys of one slot and head move that slot's and head's results alone
+        moved = np.abs(np.asarray(_fused(q, ck, cv, slots, starts, lengths, (own[0], own[1].at[2, :, 1].add(1.0)))) - base) > 1e-6
+        assert moved[2, :, 1].all()
+        moved[2, :, 1] = False
+        assert not moved.any()
 
 
-@pytest.mark.parametrize("call", ["chunk", "step"])
+@pytest.mark.parametrize("call", ["chunk", "step", "block"])
 def test_a_query_sees_its_own_position_and_none_behind_it(call):
     """Causal by position: a result moves with the cache row at its query's own
-    position and not with the rows behind it."""
+    position and not with the rows behind it. A block's queries see the position
+    before the block and none from its start on (the block's own keys stand
+    there)."""
     if call == "chunk":
         q, ck, cv, slots = _chunk_operands(2, 1, jnp.float32, seed=8)
         base = np.asarray(_fused(q, ck, cv, slots, [T], [T]))
         moved = np.asarray(_fused(q, ck, cv.at[0, :, T + 40:].add(3.0), slots, [T], [T]))
         assert np.array_equal(moved[0, :40], base[0, :40])
         assert (np.abs(moved[0, 40:] - base[0, 40:]) > 1e-6).any(axis=(1, 2, 3)).all()
+    elif call == "block":
+        q, ck, cv, own = _block_operands(2, jnp.float32, seed=8, rows=1)
+        at = [BLOCK + 4]
+        base = np.asarray(_fused(q, ck, cv, [0], at, [1], own))
+        assert np.array_equal(np.asarray(_fused(q, ck, cv.at[0, :, BLOCK + 4:].add(3.0), [0], at, [1], own)), base)
+        assert (np.abs(np.asarray(_fused(q, ck, cv.at[0, :, BLOCK + 3].add(3.0), [0], at, [1], own)) - base) > 1e-6).all()
     else:
         ck, cv = _rows(1, 2, BLOCK + 80, jnp.float32, seed=8)
         q = jnp.asarray(np.random.default_rng(8).standard_normal((1, 1, 2, 1, HD)), jnp.float32)
@@ -215,6 +283,20 @@ def test_a_decode_step_visits_the_blocks_up_to_each_slots_position(positions, ac
     one a single empty visit, whatever the 33 blocks a slot could hold."""
     counts, row, block, visits = pca.row_visits(jnp.asarray(positions, jnp.int32), jnp.asarray(active, jnp.int32), BLOCK, 33)
     want = [p // BLOCK + 1 if a else 0 for p, a in zip(positions, active)]
+    assert counts.tolist() == want and int(visits) == sum(max(c, 1) for c in want)
+    walk = list(zip(row[:int(visits)].tolist(), block[:int(visits)].tolist()))
+    assert walk == [(b, j) for b, c in enumerate(want) for j in range(max(c, 1))]
+
+
+@pytest.mark.parametrize("starts,active", [
+    ([0, 1, 512, 513, 2316], [1, 1, 1, 1, 1]), ([700, 1100, 0, 2304, 64], [1, 0, 1, 0, 1]), ([9, 9, 9, 9, 9], [0, 0, 0, 0, 0]),
+], ids=["block_edges", "half_active", "nobody"])
+def test_a_block_visits_the_key_blocks_below_each_slots_start(starts, active):
+    """A block at start s reads ``ceil(s / block)`` key blocks of the 5 that a
+    slot of 2,320 positions holds (``row_visits`` of ``s - 1``); a slot at 0 and
+    an inactive one cost a single visit that reads none."""
+    counts, row, block, visits = pca.row_visits(jnp.asarray(starts, jnp.int32) - 1, jnp.asarray(active, jnp.int32), BLOCK, 5)
+    want = [-(-s // BLOCK) if a else 0 for s, a in zip(starts, active)]
     assert counts.tolist() == want and int(visits) == sum(max(c, 1) for c in want)
     walk = list(zip(row[:int(visits)].tolist(), block[:int(visits)].tolist()))
     assert walk == [(b, j) for b, c in enumerate(want) for j in range(max(c, 1))]
@@ -241,9 +323,33 @@ CELL = (8, 30, 16464, 128)                                       # Olmo-Hybrid's
 CHUNK_Q, STEP_Q = (4, 512, 30, 1, 128), (8, 1, 30, 1, 128)
 
 
+SDAR_CELL, BLOCK_Q = (64, 4, 2320, 128), (64, 4, 4, 8, 128)     # SDAR's cell: 64 slots, blocks of 4, 8 queries a head
+
+
 def test_cpu_backend_takes_xlas_path():
     assert not pa.backend_is_tpu()
     assert not pca.cache_attention_applies(CHUNK_Q, CELL, jnp.bfloat16) and not pca.cache_attention_applies(STEP_Q, CELL, jnp.bfloat16)
+    assert not pca.cache_attention_applies(BLOCK_Q, SDAR_CELL, jnp.bfloat16, own=True)
+
+
+def test_on_a_tpu_a_block_of_grouped_queries_takes_the_kernel_and_others_do_not(monkeypatch):
+    """With a block's own keys the rule asks for a head's L x R queries in whole
+    sublane tiles (4 x 8 = 32 at SDAR's widths): grouped queries without them
+    stay refused, and so do the tiny decoder's head of 16, float32 and a slot
+    shorter than a key block."""
+    monkeypatch.setattr(pa, "backend_is_tpu", lambda: True)
+    assert pca.cache_attention_applies(BLOCK_Q, SDAR_CELL, jnp.bfloat16, own=True)
+    assert pca.cache_attention_applies((3, L, 2, R, HD), (4, 2, BLOCK_S, HD), jnp.bfloat16, own=True)     # this file's blocks
+    assert not pca.cache_attention_applies(BLOCK_Q, SDAR_CELL, jnp.bfloat16)                             # grouped, no own keys
+    tiny = sd.SdarMoeConfig.from_name("sdar-moe-tiny")
+    R_tiny = tiny.num_attention_heads // tiny.num_key_value_heads
+    assert not pca.cache_attention_applies((3, 4, tiny.num_key_value_heads, R_tiny, tiny.head_dim), (3, 2, 96, 16), jnp.bfloat16, own=True)
+    assert not pca.cache_attention_applies(BLOCK_Q, SDAR_CELL, jnp.float32, own=True)                    # not bfloat16
+    assert not pca.cache_attention_applies((64, 4, 4, 2, 128), SDAR_CELL, jnp.bfloat16, own=True)        # 8 rows: no whole tile
+    assert not pca.cache_attention_applies(BLOCK_Q, (64, 4, 496, 128), jnp.bfloat16, own=True)          # less than a key block
+    heads = pca._heads_a_step(4, 128, 4, 2, 8, True)
+    assert heads == 4 and pca._step_bytes(32, pca.BLOCK, 128, heads, 2, 4) <= pca.VMEM_BUDGET            # one grid step a slot
+    assert pca._call_shape(4, 8, True) == (32, pca.BLOCK, pca.MAX_HEADS_STEP)
 
 
 def test_on_a_tpu_the_published_widths_take_the_kernel_and_others_do_not(monkeypatch):

@@ -140,6 +140,64 @@ def test_in_float32_the_batchers_block_steps_are_the_references_replay(ref, seed
                                                                 for i in range(first, len(st) - 3, 4))
 
 
+@pytest.fixture
+def block_kernel(monkeypatch):
+    """The block step takes ``ops/pallas_cache_attention.py``'s block mode, run interpreted, whatever the backend,
+    dtype and widths (the tiny head of 16, 4 x 2 queries a head); the prefill keeps its path. ``calls`` keeps the
+    shape of q at each call traced."""
+    from daft_tpu.ops import pallas_cache_attention as pca
+
+    calls = []
+    attend = pca.cache_attention
+
+    def interpreted(q, *rest, scale, own):
+        calls.append(q.shape)
+        return attend(q, *rest, scale=scale, own=own, interpret=True)
+
+    monkeypatch.setattr(pca, "cache_attention_applies", lambda *a, own=False: own)
+    monkeypatch.setattr(pca, "cache_attention", interpreted)
+    return calls
+
+
+def test_in_float32_the_block_kernels_steps_are_the_references_replay(ref, block_kernel):
+    """The float32 replay above with the block step's core in the kernel: the same regrets (summation order), and
+    every ``serve.decode_step`` span says ``fused``."""
+    from daft_tpu.profiling import recent_device_spans
+
+    seed = 1
+    prompts = _prompts(seed)
+    answers, logprobs, steps = serve(*program(seed, float32=True), prompts, NEWS)
+    assert block_kernel and all(shape[1:] == (4, 2, 2, 16) for shape in block_kernel)
+    got = ref.forward_denoise(ref_config(), seed, prompts, answers, steps)
+    gap, greedy, order, compared = regrets(got, answers, logprobs, steps)
+    assert gap < F32_GAP_MAX and greedy < F32_GAP_MAX and order < F32_GAP_MAX
+    assert compared >= sum(NEWS) - 3 * len(NEWS)
+    decode = [s.count for s in recent_device_spans() if s.name == "serve.decode_step"]
+    assert decode and all(c["attn"] == "fused" for c in decode[-10:])
+
+
+def test_on_a_tpu_the_published_widths_take_the_block_kernel_and_the_tiny_ones_do_not(monkeypatch):
+    """The block step's attention traced with the backend rule answering as on a TPU (shapes only): at the
+    published widths (4 key/value heads of 128, 8 queries each, 64 slots of 2,320 positions) it calls the kernel
+    with the caches as the state holds them; at the tiny widths (heads of 16) it keeps ``attend_block``."""
+    from daft_tpu.ops import pallas_attention, pallas_cache_attention as pca
+
+    calls = []
+    attend = pca.cache_attention
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pca, "cache_attention", lambda q, k, *rest, **kw: calls.append((q.shape, k.shape)) or attend(q, k, *rest, **kw))
+    for name, slots, positions in (("SDAR-30B-A3B-Chat", 64, 2305), (TINY, 3, 90)):
+        cfg = sd.SdarMoeConfig.from_name(name, num_layers=1)
+        layer = jax.eval_shape(lambda: sd._init_layer(cfg, jax.random.PRNGKey(0)))
+        st = jax.eval_shape(lambda: sd.SdarMoeLM(cfg).init_state(slots, positions))[0]
+        u = jax.ShapeDtypeStruct((slots, cfg.block_length, cfg.hidden_size), cfg.dtype)
+        ints, flags = jax.ShapeDtypeStruct((slots,), jnp.int32), jax.ShapeDtypeStruct((slots,), bool)
+        for commit in (False, True):
+            out, _ = jax.eval_shape(lambda p, u, st, at, act: sd._attn_block(cfg, p, u, st, at, act, commit), layer, u, st, ints, flags)
+            assert out.shape == (slots, cfg.block_length, cfg.hidden_size)
+    assert calls == [((64, 4, 4, 8, 128), (64, 4, 2320, 128))] * 2           # a pass and the commit; the tiny model none
+
+
 def _teacher_forced(model, params, ref_out, prompt, answer, row=0):
     """Every pass's logits of one row from the model's own prefill and ``block_forward``, fed the replay's inputs,
     with a commit after each block. -> [(logits (L, V), reference's), ...] over the replayable passes."""
@@ -230,7 +288,7 @@ def test_the_spans_carry_the_block_steps_counters_and_the_note():
     assert sum(r["tokens"] for r in rounds) == sum(n - n % 4 for n in lengths)
     steps = [s.count for s in spans if s.name == "serve.decode_step"][-b.decode_steps:]
     assert all(s["tokens"] == 4 * s["active"] and s["forwards"] == 5 and s["block"] == 4 and s["unmask"] == "static"
-               for s in steps)
+               and s["attn"] == "xla" for s in steps)                   # the CPU's block step: attend_block
     assert all(s["active"] <= s["masked"] <= 4 * s["active"] and s["keys"] >= 4 * s["active"] for s in steps)
     assert sum(s["masked"] for s in steps) >= 6 * len(lengths)  # every answer token was decided by a pass
     assert all(s["moe.assignments"] == s["active"] * 4 * 5 * 2 * 4 for s in steps)  # 4 positions, 5 forwards, top 2, 4 layers
