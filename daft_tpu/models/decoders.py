@@ -267,8 +267,13 @@ def conv_with_tail(tail, x, w, bias, lengths, dtype):
 # Experts                                                                 #
 # ---------------------------------------------------------------------- #
 def gated_mlp(x, w_in, w_out, dtype):
+    """x times ``w_in``, ``silu(a) * b`` in float32, one cast to ``dtype``, times
+    ``w_out``. The gated activation is written once as its own buffer: left to
+    fuse, XLA recomputed it inside the down projection, tile by tile, from the
+    first product's float32 output, and LongCat-Flash's down projections ran at
+    ≈ 20-65% of the MXU in prefill on a TPU v5e."""
     a, b = jnp.split(mm(x, w_in), 2, axis=-1)
-    return mm((jax.nn.silu(a) * b).astype(dtype), w_out)
+    return mm(jax.lax.optimization_barrier((jax.nn.silu(a) * b).astype(dtype)), w_out)
 
 
 def grouped_mlp(x, w_in, w_out, sizes, dtype):
