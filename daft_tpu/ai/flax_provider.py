@@ -43,7 +43,6 @@ from daft_tpu.ai.provider import Provider
 from daft_tpu.device import setup_compile_cache
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
-from daft_tpu.models import deepseek_v32, granite_hybrid, longcat_flash, olmo_hybrid, sdar_moe  # noqa: F401  (each enters its names in decoders.DECODERS)
 from daft_tpu.profiling import device_span
 from daft_tpu.utils.tokenizer import HashingTokenizer
 
@@ -480,8 +479,7 @@ class FlaxPrompter(_FlaxModelBase):
     """``prompt`` / ``llm_generate`` over a decoder and the continuous batcher.
 
     ``model_name`` is looked up exactly in the record of published decoders
-    (``models/decoders.DECODERS``, which ``models/granite_hybrid``,
-    ``models/longcat_flash``, ``models/olmo_hybrid``, ``models/deepseek_v32`` and ``models/sdar_moe`` enter); such a decoder takes its own cut's options
+    (``models/decoders.DECODERS``); such a decoder takes its own cut's options
     (e.g. ``num_hidden_layers`` or ``num_layers``, ``expert_shard``,
     ``vocab_shard``: one chip's share of a stated deployment). Any other name is
     ``DecoderLMConfig.from_name``'s, and a cut's options with such a name are an
@@ -552,9 +550,8 @@ class FlaxPrompter(_FlaxModelBase):
                     self.model, self.params, num_slots=self.num_slots, max_seq_len=self.max_seq_len,
                     temperature=self.temperature, eos_id=self.eos_id, max_prompt_tokens=self.prompt_len)
             b = self._batcher
-            kinds = decoders.state_bytes_by_kind(b.state)
             with device_span("prompt.run", rows=len(reqs), slots=b.B, positions=b.positions_held,
-                             state_bytes=sum(kinds.values()), **kinds) as sp:
+                             state_bytes=sum(b.state_bytes.values()), **b.state_bytes) as sp:
                 out = b.run(reqs)
                 sp.count["decode_steps"] = b.decode_steps
             logprobs, steps = b.last_logprobs, b.last_unmask_steps

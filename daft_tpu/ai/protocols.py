@@ -79,6 +79,11 @@ class Descriptor:
         """Embedding dimensionality, when known statically."""
         return None
 
+    def yields_blocks(self) -> bool:
+        """True for a prompter whose model generates a block a step: its answers
+        carry the pass that fixed each token. Read when the expression is built."""
+        return False
+
     def runs_beside_host(self) -> bool:
         """True where instances compute on a processor other than the host's
         CPU, so that the host's work for the next batch can run while this one

@@ -344,7 +344,7 @@ def prompt(text: Expression, *, provider: Union[str, object, None] = None,
     With ``logprobs=True`` the column is a struct ``{text, token_ids:
     list[int32], logprobs: list[float32]}``: the chosen tokens and each one's
     log-probability, so that a comparison can hold the answer against logits.
-    For a decoder that generates a block a step (``models/sdar_moe``) the struct
+    For a decoder that generates a block a step (``Descriptor.yields_blocks``) the struct
     also holds ``unmask_steps: list[int32]``, the pass that fixed each token, so
     that a reference can replay the program's order."""
     p = load_provider(provider)
@@ -358,7 +358,7 @@ def prompt(text: Expression, *, provider: Union[str, object, None] = None,
 
     fields = {"text": DataType.string(), "token_ids": DataType.list(DataType.int32()),
               "logprobs": DataType.list(DataType.float32())}
-    if getattr(desc, "yields_blocks", lambda: False)():
+    if desc.yields_blocks():
         fields["unmask_steps"] = DataType.list(DataType.int32())
     dtype = DataType.struct(fields)
 

@@ -1,40 +1,42 @@
-"""What the five published decoders behind ``prompt`` share (``granite_hybrid``,
-``longcat_flash``, ``olmo_hybrid``, ``deepseek_v32``, ``sdar_moe``): the record of them by
-exact name, the rules their random parameters are drawn by, the attention core
-over a cache of per-head keys and values, the causal conv with a carried tail,
-and the expert layer's dispatch after the router. The latent attention that
-``longcat_flash`` and ``deepseek_v32`` share is ``models/latent_attention.py``.
+"""What the decoders behind ``prompt`` share: the record of them by exact name,
+the rules their random parameters are drawn by, the attention over a slot's
+cache of per-head keys and values, the causal conv with a carried tail, and the
+expert layer's dispatch after the router.
 
 **The record.** A decoder module enters its names here (``register``) with how
-a name becomes a configuration, how parameters are drawn, the model class the
-serving protocol talks to, and the options of ``prompt`` that cut it to one
-chip's share. ``ai/flax_provider.FlaxPrompter`` and the benchmark's
-``entries/prompt_decoder.py`` look a name up and know no module by name. A
-decoder whose model declares ``block_length`` generates a block of tokens a
-step (``sdar_moe``: diffusion over blocks; ``Decoder.blocks``), and
-``prompt``'s answers then carry the pass that fixed each token.
+a name becomes a configuration, how parameters are drawn, the model class that
+speaks the serving protocol (``models/serving.ServedModel``), and the options of
+``prompt`` that cut it to one chip's share. ``MODULES`` is the one list of
+those modules: importing this module imports them, so the record is full for
+whoever reads it (``ai/flax_provider.FlaxPrompter``, the benchmark's entries),
+and none of them names a decoder module. A decoder whose model declares
+``block_length`` generates a block of tokens a step (``Decoder.blocks``), and
+``prompt``'s answers then carry the pass that fixed each token. Adding a
+decoder is its module, one entry in ``MODULES``, its tests and its benchmark
+files.
 
-**The attention core** (``attention_core``, ``attention_chunk``): scores,
-softmax and weighted values of grouped queries over a slot's cache rows, given
-the queries, a score scale and where the rows are: one token over every row
-held (decode), or a chunk of queries over the blocks of cache rows that reach
-its last position with a running softmax between them (prefill: the work
-follows the prefix held, not the positions a slot could hold; a query's
-position is the last it sees, so ``sdar_moe`` passes its block's last for a
-block-causal mask). Projections,
-norms, positions and the cache's layout are each model's own: ``granite_hybrid``
-(8 key/value heads x 4 queries, rows gathered a call) runs it on every backend,
-``olmo_hybrid`` (30 x 1, QK-norm) on the CPU and at the tiny sizes: on a TPU it
-takes ``ops/pallas_cache_attention.py``, whose arithmetic is this one's;
-``sdar_moe`` (4 x 8, QK-norm a head, RoPE) in its prefill on every backend.
+**The attention over a slot's cache** (``attend_cache``): grouped queries over
+the rows a slot holds as ``(slots, KV, S, hd)``, for a causal chunk, a chunk
+whose queries see up to other positions than their own (a block-causal mask), a
+token, or a block with its own keys. It alone chooses between
+``ops/pallas_cache_attention.py`` (on a TPU, from
+``cache_attention_applies``) and XLA's forms of the same arithmetic, which stay
+the path on the CPU and at the tiny sizes and the tests' references:
+``attention_chunk`` (a chunk over the blocks of cache rows that reach its last
+position, a running softmax between them), ``attention_core`` /
+``attention_step`` (every row a slot holds, laid ``(slots, S, KV, hd)``) and
+``attention_block`` (a block over the committed rows and its own keys, in one
+softmax). It notes the choice on the batcher's open span as ``attn``.
+Projections, norms, positions and the cache's layout are each model's own; a
+model whose rows are laid otherwise calls ``attention_core`` itself.
 
 **The conv** (``conv_with_tail``): the causal depthwise conv of a recurrent
-mixer over [carried tail | this chunk] with its SiLU, and the tail after each row's last
-valid step (``granite_hybrid``'s Mamba-2 input, ``olmo_hybrid``'s q, k and v).
+mixer over [carried tail | this chunk] with its SiLU, and the tail after each
+row's last valid step.
 
 **The dispatch** (``held_experts_part``): each model routes in its own way
 (which scores, which weights, which experts cost nothing); what follows is the
-same work (four callers: ``granite_hybrid``, ``longcat_flash``, ``deepseek_v32``, ``sdar_moe``, which holds all its experts): mark the assignments that reach an expert this chip holds, sort them
+same work: mark the assignments that reach an expert this chip holds, sort them
 by held expert (absent experts and padded tokens behind every held group), the
 two grouped products of the gated MLPs, and one gated gather back for each of
 the top-k choices. On a TPU at widths that fill lane tiles the two products are
@@ -48,10 +50,13 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Tuple
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 
 from daft_tpu.errors import DaftValueError
+from daft_tpu.ops import pallas_attention, pallas_cache_attention, pallas_cache_blocks
 from daft_tpu.ops import pallas_grouped_matmul as gmm
 from daft_tpu.profiling import open_device_span
 
@@ -73,7 +78,7 @@ class Decoder:
     @property
     def blocks(self) -> bool:
         """The model generates a block a step (it declares ``block_length`` and has a ``block_step``), not a token."""
-        return hasattr(self.model, "block_length")
+        return self.model.block_length is not None
 
 
 DECODERS: Dict[str, Decoder] = {}
@@ -167,26 +172,6 @@ def add_counts(a, b):
     return {k: jnp.maximum(a[k], b[k]) if k == "max_expert_load" else a[k] + b[k] for k in a}
 
 
-def copy_slot(state, src, dst):
-    """Slot ``src``'s state into slot ``dst``, whatever the leaves hold: every leaf's first axis is the slot."""
-    return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
-
-
-#: The names under which a decoder's ``init_state`` holds rows a token (key/value rows, latent rows, the keys of
-#: ``deepseek_v32``'s indexer: they grow with the longest document a slot may hold). A leaf under any other name is recurrent state, the same whatever the length.
-ROW_LEAVES = ("k", "v", "kv", "ik")
-
-
-def state_bytes_by_kind(state) -> Dict[str, int]:
-    """A batcher's slot state in two kinds, by the names the model gave its leaves: ``kv_bytes`` (``ROW_LEAVES``;
-    a leaf under no name, as the toy decoder's (k, v) pairs, is rows too) and ``recurrent_bytes`` (the rest)."""
-    kinds = {"kv_bytes": 0, "recurrent_bytes": 0}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
-        names = [p.key for p in path if isinstance(p, jax.tree_util.DictKey)]
-        kinds["kv_bytes" if not names or names[-1] in ROW_LEAVES else "recurrent_bytes"] += leaf.nbytes
-    return kinds
-
-
 # ---------------------------------------------------------------------- #
 # Attention over a cache of per-head keys and values                      #
 # ---------------------------------------------------------------------- #
@@ -240,6 +225,66 @@ def attention_core(q, rows_k, rows_v, positions, scale: float, dtype):
     rows = lambda j: (jax.lax.dynamic_slice_in_dim(rows_k, j * T, T, axis=1),  # noqa: E731
                       jax.lax.dynamic_slice_in_dim(rows_v, j * T, T, axis=1))
     return attention_chunk(q, rows, positions, jnp.max(positions[:, 0]) // T + 1, scale, dtype)
+
+
+def attention_block(q, ck, cv, k, v, starts, scale: float, dtype):
+    """A block of T queries a slot, q (B, T, KV, R, hd) from ``starts`` (B,), over the slot's committed rows ck, cv
+    (B, KV, S, hd; rows s < start seen) and the block's own keys and values k, v (B, T, KV, hd; all seen), in one
+    softmax: the block-causal mask. -> (B, T, KV, R, hd) float32."""
+    if not pallas_attention.backend_is_tpu():  # the CPU's backend has no bfloat16 product of T > 1 rows
+        ck, cv, k, v = (x.astype(jnp.float32) for x in (ck, cv, k, v))
+    S = ck.shape[2]
+    held = jnp.einsum("btgrd,bgsd->bgrts", q, ck, preferred_element_type=jnp.float32) * scale
+    held = jnp.where((jnp.arange(S)[None, :] < starts[:, None])[:, None, None, None], held, jnp.finfo(jnp.float32).min)
+    own = jnp.einsum("btgrd,bugd->bgrtu", q, k, preferred_element_type=jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.concatenate([held, own], axis=-1), axis=-1).astype(dtype)
+    return (jnp.einsum("bgrts,bgsd->btgrd", probs[..., :S], cv, preferred_element_type=jnp.float32)
+            + jnp.einsum("bgrtu,bugd->btgrd", probs[..., S:], v, preferred_element_type=jnp.float32))
+
+
+def attend_cache(q, cache_k, cache_v, starts, scale: float, dtype, *, slots=None, lengths=None, sees=None,
+                 active=None, view=None, own=None):
+    """Grouped queries q (B, T, KV, R, hd) over cache rows ``(slots, KV, S, hd)`` as the state keeps them (a chunk's
+    or a token's new rows already written). What is attended:
+
+    - **a chunk** (``slots``, ``starts``, ``lengths`` of each row of the call): query t of row b at ``starts[b] + t``
+      over the blocks of T rows up to it, causal; with ``sees`` (B, T) each query sees up to that position instead
+      (a block-causal chunk: its block's last), which the kernel has no form of;
+    - **a token** a slot (``starts`` the slots' positions, ``active``): every row a slot holds, which XLA reads
+      through ``view``, the rows laid (slots, S, KV, hd) as the caller wrote them;
+    - **a block** a slot with its ``own`` keys and values (B, T, KV, hd) (``starts`` the blocks' first positions,
+      ``active``): the committed rows below the start and the block's own keys, in one softmax.
+
+    On a TPU the kernel takes what ``cache_attention_applies`` accepts (an inactive slot's queries come back as
+    zeros), else XLA's form; a kernel that fails to trace or lower fails the program. On the CPU a chunk's blocks
+    are widened to float32, as its backend has no bfloat16 product for them (the same numbers: a bfloat16 value is
+    its float32 value, and the products accumulate in float32 either way). The choice is noted on the batcher's
+    open span as ``attn`` = ``fused`` | ``xla``, where there was one. -> (B, T, KV, R, hd)."""
+    fused = sees is None and pallas_cache_attention.cache_attention_applies(q.shape, cache_k.shape, dtype,
+                                                                             own=own is not None)
+    if sees is None:
+        note_on_serving_span("attn", "fused" if fused else "xla")
+    if fused:
+        if slots is None:  # a token or a block: every slot in order, the active ones with a query
+            slots, lengths = jnp.arange(q.shape[0]), active.astype(jnp.int32)
+        extra = {} if own is None else {"own": own}
+        return pallas_cache_attention.cache_attention(q, cache_k, cache_v, slots, starts, lengths, scale=scale, **extra)
+    if own is not None:  # every slot over every position it holds and its block
+        return attention_block(q, cache_k, cache_v, *own, starts, scale, dtype)
+    if slots is None:  # every slot over every position it could hold
+        return attention_core(q, *view, starts[:, None], scale, dtype)
+    # XLA's loop walks the blocks that the call's deepest row attends, for every row (rows of a call stand at unlike
+    # depths: blocks beyond a row's own weigh 0).
+    T = q.shape[1]
+
+    def block(cache, j):
+        rows = pallas_cache_blocks.read_blocks_xla(cache, slots, j * T, T)
+        return rows if pallas_attention.backend_is_tpu() else rows.astype(jnp.float32)
+
+    if sees is None:
+        sees = starts[:, None] + jnp.arange(T)[None, :]
+    return attention_chunk(q, lambda j: (block(cache_k, j), block(cache_v, j)), sees, jnp.max(starts) // T + 1,
+                           scale, dtype)
 
 
 # ---------------------------------------------------------------------- #
@@ -323,3 +368,12 @@ def held_experts_part(v, idx, gates, valid, first_expert: int, w_in, w_out, dtyp
         gj = g[:, j, None]
         routed = routed + jnp.where(gj > 0, y[back[:, j]].astype(jnp.float32) * gj, 0.0)
     return routed, held, sizes
+
+
+# ---------------------------------------------------------------------- #
+# The decoders on record                                                  #
+# ---------------------------------------------------------------------- #
+#: The modules that enter their names in ``DECODERS`` (each calls ``register`` when imported).
+MODULES = ("granite_hybrid", "longcat_flash", "olmo_hybrid", "deepseek_v32", "sdar_moe")
+for _module in MODULES:
+    importlib.import_module(f"daft_tpu.models.{_module}")

@@ -86,6 +86,7 @@ import numpy as np
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders, latent_attention
 from daft_tpu.models.decoders import draw, gated_mlp, mm, rms
+from daft_tpu.models.serving import ServedModel
 from daft_tpu.ops import pallas_dsa_index
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
@@ -500,7 +501,7 @@ def _moe(cfg, p, u, valid):
 # ---------------------------------------------------------------------- #
 # The model                                                               #
 # ---------------------------------------------------------------------- #
-class DeepseekV32LM:
+class DeepseekV32LM(ServedModel):
     """The decoder over a parameter tree, as the serving protocol sees it."""
 
     def __init__(self, cfg: DeepseekV32Config):
@@ -516,9 +517,6 @@ class DeepseekV32LM:
         positions = -(-positions // POSITION_TILE) * POSITION_TILE
         return [{"kv": jnp.zeros((slots, cfg.cache_row, positions), cfg.dtype),
                  "ik": jnp.zeros((slots, cfg.index_head_dim, positions), cfg.dtype)} for _ in range(cfg.num_hidden_layers)]
-
-    def copy_state(self, state, src, dst):
-        return decoders.copy_slot(state, src, dst)
 
     def prefill_counts(self, lengths) -> Dict[str, int]:
         """Host-side counts of an admission round whose prompts have ``lengths`` tokens, for the batcher's

@@ -26,10 +26,11 @@ the dispatch after the router is ``decoders.held_experts_part``, shared with
 ``vocab_shard`` slices the tied embedding by rows: ids and logits are over the
 slice. Nothing stands in for the other chips.
 
-**Serving protocol** (``models/serving.ContinuousBatcher``): ``init_state``,
+**Serving protocol** (``models/serving.ServedModel``): ``init_state``,
 ``prefill`` (advances some slots' state over one chunk of their prompts, true
 lengths known: padding has delta = 0 and is written neither to the conv tail
-nor to the KV rows), ``decode`` (one token for every slot) and ``copy_state``.
+nor to the KV rows; a chunk is whole chunks of the scan) and ``decode`` (one
+token for every slot).
 Slot state is a list with one entry a layer: ``{"ssm", "conv"}`` for a Mamba
 layer, ``{"k", "v"}`` for an attention layer.
 """
@@ -46,6 +47,7 @@ import jax.numpy as jnp
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
 from daft_tpu.models.decoders import draw, gated_mlp as _gated_mlp, mm as _mm, rms as _rms
+from daft_tpu.models.serving import ServedModel
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as
 #: data: no substring rule.
@@ -347,7 +349,7 @@ def _moe(cfg, p, v, valid):
 # ---------------------------------------------------------------------- #
 # The model                                                               #
 # ---------------------------------------------------------------------- #
-class GraniteHybridLM:
+class GraniteHybridLM(ServedModel):
     """The decoder over a parameter tree, as the serving protocol sees it."""
 
     def __init__(self, cfg: GraniteHybridConfig):
@@ -376,9 +378,6 @@ class GraniteHybridLM:
                 state.append({"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype)})
         return state
 
-    def copy_state(self, state, src, dst):
-        return decoders.copy_slot(state, src, dst)
-
     def _forward(self, params, rows, tokens, positions, valid, lengths, fresh, single_step):
         """The layers over ``rows`` (each layer's state of the rows in play).
         -> (h (B, T, d), new rows, counts summed over the layers)."""
@@ -403,10 +402,7 @@ class GraniteHybridLM:
                 h = (h + cfg.residual_multiplier * out).astype(cfg.dtype)
                 v = _rms(h, p["moe_norm"], cfg.rms_norm_eps)
                 y, counts = _moe(cfg, p, v.reshape(B * T, -1), valid.reshape(-1))
-                totals = counts if totals is None else {
-                    "assignments": totals["assignments"] + counts["assignments"],
-                    "held_assignments": totals["held_assignments"] + counts["held_assignments"],
-                    "max_expert_load": jnp.maximum(totals["max_expert_load"], counts["max_expert_load"])}
+                totals = decoders.add_counts(totals, counts)
                 h = (h + cfg.residual_multiplier * y.reshape(B, T, -1)).astype(cfg.dtype)
         return h, new_rows, totals
 

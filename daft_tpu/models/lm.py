@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from daft_tpu.models.layers import MLP, causal_mask, init_params
+from daft_tpu.models.serving import ServedModel
 
 
 @dataclass(frozen=True)
@@ -99,10 +100,9 @@ class DecoderBlock(nn.Module):
         return x, ck, cv
 
 
-class DecoderLM(nn.Module):
-    """Also the serving protocol of ``models/serving.ContinuousBatcher``
-    (``init_state``, ``prefill``, ``decode``, ``copy_state``): its slot state is
-    the per-layer ``(k, v)`` rows."""
+class DecoderLM(nn.Module, ServedModel):
+    """Also the serving protocol of ``models/serving.ContinuousBatcher``: its
+    slot state is the per-layer ``(k, v)`` rows."""
 
     cfg: DecoderLMConfig
 
@@ -110,13 +110,13 @@ class DecoderLM(nn.Module):
     def vocab_size(self) -> int:
         return self.cfg.vocab_size
 
+    @property
+    def max_seq_len(self) -> int:
+        return self.cfg.max_seq_len
+
     @nn.nowrap
     def init_state(self, slots: int, positions: int):
         return init_caches(self.cfg, slots, positions)
-
-    @nn.nowrap
-    def copy_state(self, state, src, dst):
-        return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
 
     @nn.nowrap
     def prefill(self, params, state, tokens, slots, starts, lengths):

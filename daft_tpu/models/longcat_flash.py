@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders, latent_attention
 from daft_tpu.models.decoders import draw, gated_mlp, mm, rms
+from daft_tpu.models.serving import ServedModel
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
 PUBLISHED: Dict[str, Dict[str, Any]] = {
@@ -311,7 +312,7 @@ def _moe(cfg, p, u, valid):
 # ---------------------------------------------------------------------- #
 # The model                                                               #
 # ---------------------------------------------------------------------- #
-class LongcatFlashLM:
+class LongcatFlashLM(ServedModel):
     """The decoder over a parameter tree, as the serving protocol sees it."""
 
     def __init__(self, cfg: LongcatFlashConfig):
@@ -325,9 +326,6 @@ class LongcatFlashLM:
     def init_state(self, slots: int, positions: int):
         cfg = self.cfg
         return [{"kv": jnp.zeros((slots, cfg.cache_row, positions), cfg.dtype)} for _ in range(2 * cfg.num_layers)]
-
-    def copy_state(self, state, src, dst):
-        return decoders.copy_slot(state, src, dst)
 
     def _forward(self, params, state, tokens, valid, attend):
         """The double layers. ``attend(p, s, x, kv) -> (out, kv)`` is the

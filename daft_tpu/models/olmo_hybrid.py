@@ -60,8 +60,8 @@ program. Which form a program traced is noted on the batcher's open span as
 **The cut.** ``num_hidden_layers`` keeps the first layers, whole periods of the
 layer pattern only. Every width, the vocabulary included, is as published.
 
-**Serving protocol** (``models/serving.ContinuousBatcher``): ``init_state``,
-``prefill``, ``decode``, ``copy_state``. Slot state is one entry a layer:
+**Serving protocol** (``models/serving.ServedModel``): ``init_state``,
+``prefill``, ``decode``; a prefill chunk is whole chunks of the delta rule. Slot state is one entry a layer:
 ``{"S" (slots, heads, value_dim, key_dim) float32, "conv" (slots, K - 1, q | k |
 v channels)}`` for a linear layer, whatever the length; ``{"k", "v" (slots,
 heads, positions in whole tiles of ``ROW_TILE``, head_dim)}`` for an attention
@@ -71,15 +71,14 @@ would be laid out as 32),
 97% of the cache. A prefill writes only its chunk into the slots' rows, in
 place (``ops/pallas_cache_blocks.write_blocks``), and attends, for each row of
 the call, only the blocks up to that row's own chunk; a decode step writes each
-slot's new row and attends the blocks up to each slot's own position. On a TPU at
-widths that fill lane tiles both are ``ops/pallas_cache_attention.py``, one
-kernel over the rows where they lie (scores in VMEM, no block beyond a row's
-depth fetched); on the CPU and at the tiny sizes ``decoders.attention_chunk``
-over XLA's slices (every row of a call as deep as the deepest) and
-``decoders.attention_core`` over every position a slot could hold, the core
-``models/granite_hybrid.py`` runs, as it does the conv with its carried tail.
-Which one a program traced is noted on the batcher's open span as ``attn`` =
-``fused`` | ``xla``. A slot admitted anew starts from zero recurrent state.
+slot's new row and attends the blocks up to each slot's own position, both
+through ``decoders.attend_cache``: on a TPU at widths that fill lane tiles
+``ops/pallas_cache_attention.py``, one kernel over the rows where they lie
+(scores in VMEM, no block beyond a row's depth fetched); on the CPU and at the
+tiny sizes ``decoders.attention_chunk`` over XLA's slices (every row of a call
+as deep as the deepest) and ``decoders.attention_core`` over every position a
+slot could hold. Which one a program traced is noted on the batcher's open span
+as ``attn`` = ``fused`` | ``xla``. A slot admitted anew starts from zero recurrent state.
 
 Plain functions over a parameter tree, drawn tensor by tensor on the device in
 bfloat16; ``jax.named_scope`` names the parts (``lin_proj``, ``delta_rule``,
@@ -99,7 +98,8 @@ import jax.numpy as jnp
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
 from daft_tpu.models.decoders import draw, gated_mlp, mm, rms
-from daft_tpu.ops import pallas_attention, pallas_cache_attention, pallas_cache_blocks, pallas_delta_rule
+from daft_tpu.models.serving import ServedModel
+from daft_tpu.ops import pallas_cache_blocks, pallas_delta_rule
 
 LINEAR, FULL = "linear_attention", "full_attention"
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
@@ -404,7 +404,7 @@ def _attn_prefill(cfg, p, u, st, slots, starts, lengths):
     """One chunk for the rows ``slots`` of the cache st {"k", "v" (slots, KV, S,
     hd)}: write the chunk's valid rows in place (``lengths`` of each, from
     ``starts``), then attend over the blocks held. -> (out (B, T, d) float32, st)."""
-    T, hd = u.shape[1], cfg.head_dim
+    hd = cfg.head_dim
     with jax.named_scope("attn_proj"):
         q, k, v = _attn_project(cfg, p, u)
     with jax.named_scope("attn_core"):
@@ -412,22 +412,7 @@ def _attn_prefill(cfg, p, u, st, slots, starts, lengths):
         # kernel on a TPU); the core then attends them where they lie.
         cache_k = pallas_cache_blocks.write_blocks(st["k"], k, slots, starts, lengths)
         cache_v = pallas_cache_blocks.write_blocks(st["v"], v, slots, starts, lengths)
-        fused = pallas_cache_attention.cache_attention_applies(q.shape, cache_k.shape, cfg.dtype)
-        decoders.note_on_serving_span("attn", "fused" if fused else "xla")
-        if fused:  # every row over the blocks up to its own chunk; a kernel that fails to trace or lower fails the program
-            out = pallas_cache_attention.cache_attention(q, cache_k, cache_v, slots, starts, lengths, scale=hd ** -0.5)
-        else:
-            # XLA's loop walks the blocks that the call's deepest row attends, for every row (rows of a call stand at
-            # unlike depths: blocks beyond a row's own weigh 0). Its CPU backend has no bfloat16 product for a block
-            # and does not widen it itself: it is widened here, which gives the same numbers (a bfloat16 value is
-            # its float32 value; the products accumulate in float32 either way).
-            def block(cache, j):
-                rows = pallas_cache_blocks.read_blocks_xla(cache, slots, j * T, T)
-                return rows if pallas_attention.backend_is_tpu() else rows.astype(jnp.float32)
-
-            positions = starts[:, None] + jnp.arange(T)[None, :]
-            out = decoders.attention_chunk(q, lambda j: (block(cache_k, j), block(cache_v, j)), positions,
-                                           jnp.max(starts) // T + 1, hd ** -0.5, cfg.dtype)
+        out = decoders.attend_cache(q, cache_k, cache_v, starts, hd ** -0.5, cfg.dtype, slots=slots, lengths=lengths)
     with jax.named_scope("attn_proj"):
         return _attn_out(cfg, p, out), {"k": cache_k, "v": cache_v}
 
@@ -452,13 +437,8 @@ def _attn_decode(cfg, p, u, st, positions, active):
 
         view_k, view_v = write(jnp.swapaxes(st["k"], 1, 2), k), write(jnp.swapaxes(st["v"], 1, 2), v)
         cache_k, cache_v = jnp.swapaxes(view_k, 1, 2), jnp.swapaxes(view_v, 1, 2)
-        fused = pallas_cache_attention.cache_attention_applies(q.shape, cache_k.shape, cfg.dtype)
-        decoders.note_on_serving_span("attn", "fused" if fused else "xla")
-        if fused:  # every active slot over the blocks up to its own position, the rows as the state keeps them
-            out = pallas_cache_attention.cache_attention(q, cache_k, cache_v, jnp.arange(B), positions,
-                                                         active.astype(jnp.int32), scale=hd ** -0.5)
-        else:  # every slot over every position it could hold
-            out = decoders.attention_core(q, view_k, view_v, positions[:, None], hd ** -0.5, cfg.dtype)
+        out = decoders.attend_cache(q, cache_k, cache_v, positions, hd ** -0.5, cfg.dtype, active=active,
+                                    view=(view_k, view_v))
     with jax.named_scope("attn_proj"):
         return _attn_out(cfg, p, out), {"k": cache_k, "v": cache_v}
 
@@ -466,7 +446,7 @@ def _attn_decode(cfg, p, u, st, positions, active):
 # ---------------------------------------------------------------------- #
 # The model                                                               #
 # ---------------------------------------------------------------------- #
-class OlmoHybridLM:
+class OlmoHybridLM(ServedModel):
     """The decoder over a parameter tree, as the serving protocol sees it."""
 
     def __init__(self, cfg: OlmoHybridConfig):
@@ -494,9 +474,6 @@ class OlmoHybridLM:
                 kv = (slots, cfg.num_key_value_heads, -(-positions // ROW_TILE) * ROW_TILE, cfg.head_dim)
                 state.append({"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype)})
         return state
-
-    def copy_state(self, state, src, dst):
-        return decoders.copy_slot(state, src, dst)
 
     def _forward(self, params, state, tokens, linear, attend):
         """The layers. ``linear(p, u, st)`` and ``attend(p, u, st)`` -> (out,

@@ -25,26 +25,29 @@ positions still masked the L / steps with the largest c are fixed to x (static
 low-confidence remasking; ties to the earlier position). One more forward of the
 finished block writes its rows: the commit. Every query of a block attends its
 slot's committed rows (the positions before the block) and the block's own L
-keys, in one softmax (``attend_block``); a pass writes nothing, so the cache is
+keys, in one softmax (``decoders.attend_cache``); a pass writes nothing, so the cache is
 no part of the passes' loop. The step returns every block's tokens, each fixed
 token's log c and the pass that fixed it.
 
 **Prefill** writes only whole blocks (the batcher hands ``P - P mod L`` tokens
-in chunks of whole blocks) through ``decoders.attention_chunk``, each query's
-position mask being its block's last position; positions stay true for RoPE.
+in chunks of whole blocks) through ``decoders.attend_cache``, each query seeing
+up to its block's last position (``block_ends``), on XLA's loop on every backend:
+the kernel has no block-causal chunk. Positions stay true for RoPE.
 
 **The cut.** ``num_layers`` keeps the first layers (the period is one layer);
 every width, all 128 experts and the whole vocabulary are as published.
 
-**Serving protocol**: ``init_state``, ``prefill``, ``block_step`` (in place of
-``decode``), ``copy_state``; ``block_length``, ``denoising_steps`` and
-``mask_id`` are the configuration's. Slot state is ``{"k", "v" (slots, heads,
-positions in whole tiles of ``ROW_TILE``, head_dim)}`` a layer (the layout of
-``models/olmo_hybrid.py``: no axis padded to a tile). On a TPU the block step
-reads, in that layout, only the blocks of rows below each slot's position
-(``ops/pallas_cache_attention.py``, its block mode: a key/value head's 4 x 8
-queries one tile); elsewhere it attends every position a slot holds, densely
-(``attend_block``). The prefill writes its chunk with ``ops/pallas_cache_blocks``.
+**Serving protocol** (``models/serving.ServedModel``): ``init_state``,
+``prefill``, ``block_step`` (in place of ``decode``), ``prefill_counts``
+(``tail_tokens``); ``block_length`` (a prefill chunk is whole blocks),
+``denoising_steps`` and ``mask_id`` are the configuration's. Slot state is
+``{"k", "v" (slots, heads, positions in whole tiles of ``ROW_TILE``,
+head_dim)}`` a layer (the layout of ``models/olmo_hybrid.py``: no axis padded to
+a tile). On a TPU the block step reads, in that layout, only the blocks of rows
+below each slot's position (``ops/pallas_cache_attention.py``, its block mode: a
+key/value head's 4 x 8 queries one tile); elsewhere it attends every position a
+slot holds, densely (``decoders.attention_block``). The prefill writes its chunk
+with ``ops/pallas_cache_blocks``.
 
 Plain functions over a parameter tree, drawn tensor by tensor on the device in
 bfloat16; ``jax.named_scope`` names the parts (``attn_proj``, ``attn_core``,
@@ -66,7 +69,8 @@ import numpy as np
 from daft_tpu.errors import DaftValueError
 from daft_tpu.models import decoders
 from daft_tpu.models.decoders import draw, mm, rms
-from daft_tpu.ops import pallas_attention, pallas_cache_attention, pallas_cache_blocks
+from daft_tpu.models.serving import ServedModel
+from daft_tpu.ops import pallas_cache_blocks
 
 #: Published sizes by exact model name (``config.json`` of the source). Kept as data: no substring rule.
 #: ``block_length``, ``denoising_steps`` and ``mask_id`` are not in the published file (the catalog lists block
@@ -242,52 +246,23 @@ def _attn_prefill(cfg: SdarMoeConfig, p, u, st, slots, starts, lengths):
     with jax.named_scope("attn_core"):
         cache_k = pallas_cache_blocks.write_blocks(st["k"], k, slots, starts, lengths)
         cache_v = pallas_cache_blocks.write_blocks(st["v"], v, slots, starts, lengths)
-
-        def block(cache, j):  # widened on the CPU, whose backend has no bfloat16 product for a block (olmo_hybrid)
-            rows = pallas_cache_blocks.read_blocks_xla(cache, slots, j * T, T)
-            return rows if pallas_attention.backend_is_tpu() else rows.astype(jnp.float32)
-
-        out = decoders.attention_chunk(q, lambda j: (block(cache_k, j), block(cache_v, j)),
-                                       block_ends(positions, cfg.block_length), jnp.max(starts) // T + 1,
-                                       hd ** -0.5, cfg.dtype)
+        out = decoders.attend_cache(q, cache_k, cache_v, starts, hd ** -0.5, cfg.dtype, slots=slots, lengths=lengths,
+                                    sees=block_ends(positions, cfg.block_length))
     with jax.named_scope("attn_proj"):
         return _attn_out(cfg, p, out), {"k": cache_k, "v": cache_v}
 
 
-def attend_block(q, ck, cv, k, v, starts, scale: float, dtype):
-    """A block of T queries a slot, q (B, T, KV, R, hd) from ``starts`` (B,), over the slot's committed rows ck, cv
-    (B, KV, S, hd; rows s < start seen) and the block's own keys and values k, v (B, T, KV, hd; all seen), in one
-    softmax: the block-causal mask. -> (B, T, KV, R, hd) float32."""
-    if not pallas_attention.backend_is_tpu():  # the CPU's backend has no bfloat16 product of T > 1 rows
-        ck, cv, k, v = (x.astype(jnp.float32) for x in (ck, cv, k, v))
-    S = ck.shape[2]
-    held = jnp.einsum("btgrd,bgsd->bgrts", q, ck, preferred_element_type=jnp.float32) * scale
-    held = jnp.where((jnp.arange(S)[None, :] < starts[:, None])[:, None, None, None], held, jnp.finfo(jnp.float32).min)
-    own = jnp.einsum("btgrd,bugd->bgrtu", q, k, preferred_element_type=jnp.float32) * scale
-    probs = jax.nn.softmax(jnp.concatenate([held, own], axis=-1), axis=-1).astype(dtype)
-    return (jnp.einsum("bgrts,bgsd->btgrd", probs[..., :S], cv, preferred_element_type=jnp.float32)
-            + jnp.einsum("bgrtu,bugd->btgrd", probs[..., S:], v, preferred_element_type=jnp.float32))
-
-
 def _attn_block(cfg: SdarMoeConfig, p, u, st, positions, active, commit: bool):
     """One block a slot: u (slots, L, d) at ``positions`` (slots,: the block's first) -> (out, st). Every query
-    attends its slot's committed rows and the block: on a TPU at a head size of whole lane tiles
-    ``ops/pallas_cache_attention.py``, which reads only the blocks of rows below each active slot's position (an
-    inactive slot's queries come back as zeros), else ``attend_block`` over every position; the ``commit`` then
-    writes the block's rows where the block lies (an inactive slot keeps what it held), a pass writes nothing."""
+    attends its slot's committed rows and the block (``decoders.attend_cache``); the ``commit`` then writes the
+    block's rows where the block lies (an inactive slot keeps what it held), a pass writes nothing."""
     B, T = u.shape[:2]
     KV, hd = cfg.num_key_value_heads, cfg.head_dim
     at = positions[:, None] + jnp.arange(T)[None, :]
     with jax.named_scope("attn_proj"):
         q, k, v = _project(cfg, p, u, at)
     with jax.named_scope("attn_core"):
-        fused = pallas_cache_attention.cache_attention_applies(q.shape, st["k"].shape, cfg.dtype, own=True)
-        decoders.note_on_serving_span("attn", "fused" if fused else "xla")
-        if fused:  # a kernel that fails to trace or lower fails the program
-            out = pallas_cache_attention.cache_attention(q, st["k"], st["v"], jnp.arange(B), positions,
-                                                         active.astype(jnp.int32), scale=hd ** -0.5, own=(k, v))
-        else:
-            out = attend_block(q, st["k"], st["v"], k, v, positions, hd ** -0.5, cfg.dtype)
+        out = decoders.attend_cache(q, st["k"], st["v"], positions, hd ** -0.5, cfg.dtype, active=active, own=(k, v))
         if commit:
             S = st["k"].shape[2]
             where = jnp.where(active[:, None], at, S)[:, None, :]          # (B, 1, T); out of range: dropped
@@ -329,7 +304,7 @@ def unmask(logits, tokens, masked, logprob, step, p, k: int):
 # ---------------------------------------------------------------------- #
 # The model                                                               #
 # ---------------------------------------------------------------------- #
-class SdarMoeLM:
+class SdarMoeLM(ServedModel):
     """The decoder over a parameter tree, as the serving protocol sees it."""
 
     def __init__(self, cfg: SdarMoeConfig):
@@ -361,8 +336,9 @@ class SdarMoeLM:
         kv = (slots, cfg.num_key_value_heads, -(-positions // ROW_TILE) * ROW_TILE, cfg.head_dim)
         return [{"k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype)} for _ in range(cfg.num_hidden_layers)]
 
-    def copy_state(self, state, src, dst):
-        return decoders.copy_slot(state, src, dst)
+    def prefill_counts(self, lengths) -> Dict[str, int]:
+        """``tail_tokens``: the prompt tokens left to each slot's first block, beside the whole blocks prefilled."""
+        return {"tail_tokens": int((np.asarray(lengths, np.int64) % self.cfg.block_length).sum())}
 
     def _forward(self, params, state, tokens, valid, attend):
         """The layers; ``attend(p, u, st)`` -> (out, st) is this program's attention. -> (h (B, T, d) float32,

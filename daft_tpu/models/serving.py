@@ -14,32 +14,32 @@ step advances every active slot a token per iteration. Mixed-length workloads
 win exactly where vLLM wins: a finished slot is refilled immediately instead
 of idling until the batch's longest sequence completes.
 
-**Slot state is the model's.** The batcher never looks inside it: it asks the
-model for ``init_state(slots, positions)``, for a ``prefill`` that advances
-some slots over one chunk of their prompts with the true lengths known, for a
-``decode`` step over all slots, and for ``copy_state(state, src, dst)``.
-``models/lm.DecoderLM`` keeps key/value rows; ``models/granite_hybrid`` keeps
-SSM state and a conv tail beside them; ``models/longcat_flash`` keeps one
-latent row a token an attention (576 values where 64 heads' keys and values
-would be 20,480), writes only a prefill's chunk into it and reads only the
-blocks a chunk attends; ``models/olmo_hybrid`` keeps a delta rule's state and a
-conv tail in three layers of four and 30 heads' key/value rows in the fourth;
-``models/deepseek_v32`` keeps two row leaves a layer, the latent row and the key
-of the indexer that selects which rows a query attends (128 values beside 576).
+**The serving protocol** (``ServedModel``) is all the batcher knows of a
+model. Slot state is the model's and the batcher never looks inside it: it asks
+for ``init_state(slots, positions)``, a ``prefill`` that advances some slots
+over one chunk of their prompts with the true lengths known, a ``decode`` step
+of one token over all slots, ``copy_state(state, src, dst)`` and
+``vocab_size``. Optional hooks have their defaults on the base class:
+``block_length``, ``prefill_multiple``, ``max_seq_len`` and
+``prefill_counts``. Which leaves of the state are rows a position (an
+attention's keys and values, latent rows) and which are recurrent state is read
+from their shapes: ``init_state`` at two position counts, as shapes only.
 
-**A block a step.** A model that declares ``block_length`` (``models/sdar_moe``:
-generation by diffusion over blocks) gets another decode program under the same
-name: one dispatch runs the model's ``block_step``, ``denoising_steps`` forwards
-of every slot's block over its committed rows, each fixing some of the block's
-masked positions, then the forward that commits the block's rows. It returns
-each slot's L tokens, the log-probability each fixed token had at the pass that
-fixed it, and that pass. A prompt prefills its whole blocks only; its tail (P
-mod L tokens) waits, fixed, in the slot's first block, whose other positions
-hold the model's ``mask_id`` (a slot's block is host state, set at admission, so
-a shared prefill's copy gets it too). The loop appends the tokens a block
-decided, moves the slot on a block, and retires it on its budget (an answer is
-its first ``max_new_tokens``), at an end-of-sequence id or where no further block
-fits. Greedy only.
+**A block a step.** A model that declares ``block_length`` L gets another
+decode program under the same name: one dispatch runs the model's
+``block_step`` (it also gives ``denoising_steps`` and ``mask_id``),
+``denoising_steps`` forwards of every slot's block over its committed rows,
+each fixing some of the block's masked positions, then the forward that commits
+the block's rows. It returns each slot's L tokens, the log-probability each
+fixed token had at the pass that fixed it, and that pass. Greedy only. The host
+loop is one for both kinds: a token model's step is read as a block of one. A
+prompt prefills its whole blocks only; its tail (P mod L tokens; none for a
+token model) waits, fixed, in the slot's first block, whose other positions
+hold the ``mask_id`` (a slot's block is host state, set at admission, so a
+shared prefill's copy gets it too). After each step the loop appends what the
+step decided, moves the slot on, and retires it on its budget (an answer is its
+first ``max_new_tokens``), at an end-of-sequence id or where no further step
+fits.
 
 **Prefill is chunked at one static length**, so prompts of any length share
 one executable: a prompt runs as ceil(P / chunk) calls that carry state, the
@@ -61,10 +61,8 @@ Spans (``profiling.device_span``): ``serve.prefill`` (one admission round's call
 ``slot``, ``rows``: the prompts prefilled, ``tokens``, ``chunks``: the calls, ``padded_tokens`` = calls x prompts
 a call x ``chunk``, ``row_chunks``, ``first``; ``mixed_calls``: the calls whose prompts stood at unlike depths;
 ``pairs``: the causal (query, key) pairs of the round's prompts, an attention's least work; beside it whatever
-the model counts of a round from its prompts' lengths (``prefill_counts``, if it has one: ``models/deepseek_v32``
-gives ``index_pairs``, the pairs its indexer scores, = ``pairs``, and ``selected_pairs``, the pairs its selection
-keeps: sum over prompt positions of min(position + 1, ``index_topk``); for a block model ``tail_tokens``, the
-prompt tokens left to the first block, beside ``tokens``, those the calls ran);
+the model counts of a round from its prompts' lengths (``prefill_counts``; ``tokens`` are those the calls ran,
+and a block model's prompt tails wait for its first block);
 ``block_rows``: the (row, block of ``chunk`` positions) pairs its calls attend in which the row holds a query,
 of ``padded_block_rows`` = sum over calls of rows a call x (the deepest row's block + 1) that calls of static
 shape span),
@@ -78,15 +76,9 @@ from entry to return) and ``serve.fetch`` (the step's one fetch: ``arrays``, the
 first and the last step of a run every instant of the loop lies in one of ``serve.decode_step``, ``serve.prefill``
 and ``serve.copy_state``; what of a step lies in neither child is its own, before the dispatch or after the fetch.
 ``serve.prefill`` and ``serve.decode_step`` also carry what the model noted on
-them while its program traced (``moe`` = ``grouped`` | ``xla``: the path of the
-routed experts' grouped products, ``models/decoders.grouped_mlp``; ``mla`` =
-``fused`` | ``expanded`` | ``absorbed``: the latent attention's, ``models/longcat_flash``;
-``delta`` = ``chunked`` | ``recurrent``: the gated delta rule's, ``models/olmo_hybrid``;
-``dsa`` = ``masked`` (| ``gathered``, no program yet): how ``models/deepseek_v32``'s attention applies its
-selection: every block a row holds expanded and scored with the keys not kept masked out, or the kept rows gathered;
-``unmask`` = ``static``: how ``models/sdar_moe``'s passes choose the positions they fix; ``attn`` = ``fused`` |
-``xla``: whether ``models/olmo_hybrid``'s full attention and ``models/sdar_moe``'s block step take
-``ops/pallas_cache_attention.py``).
+them while its program traced (``decoders.note_on_serving_span``: which path a
+layer took, e.g. ``moe`` = ``grouped`` | ``xla``); each model's docstring says
+which keys it notes.
 """
 
 from __future__ import annotations
@@ -137,6 +129,44 @@ def prefill_schedule(chunks: Sequence[int], rows: int) -> List[List[Tuple[int, i
             done[i] += 1
 
 
+class ServedModel:
+    """The serving protocol: what ``ContinuousBatcher`` asks of a model, with the
+    optional hooks' defaults. Required: ``init_state(slots, positions)`` -> slot
+    state (every leaf's first axis the slot); ``prefill(params, state, tokens,
+    slots, starts, lengths)`` -> (state, next-token logits (B, V) or None,
+    counts); ``decode(params, state, tokens, positions, active)`` -> (state,
+    logits, counts), or for a model with ``block_length``
+    ``block_step(params, state, tokens, tail, positions, active)`` -> (state,
+    {"tok", "logprob", "step"} (slots, L) each, counts); ``vocab_size``."""
+
+    #: None: a token a step (``decode``); L: a block of L tokens a step (``block_step``).
+    block_length: Optional[int] = None
+    #: A prefill chunk is a whole multiple of this many tokens.
+    prefill_multiple: int = 1
+    #: The longest sequence the model takes, if it has a limit of its own.
+    max_seq_len: Optional[int] = None
+
+    def prefill_counts(self, lengths) -> Dict[str, int]:
+        """Host-side counts of an admission round whose prompts have ``lengths`` tokens, for ``serve.prefill``."""
+        return {}
+
+    def copy_state(self, state, src, dst):
+        """Slot ``src``'s state into slot ``dst``, whatever the leaves hold."""
+        return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
+
+
+def state_bytes_by_kind(model: ServedModel, slots: int, positions: int) -> Dict[str, int]:
+    """``model.init_state(slots, positions)`` in two kinds, from shapes alone (nothing is drawn or placed):
+    ``kv_bytes``, the leaves that grow with the positions a slot holds (rows a position, whatever tile a model
+    rounds them up to: 4,096 positions more is more than any), and ``recurrent_bytes``, the rest."""
+    held = jax.eval_shape(lambda: model.init_state(slots, positions))
+    more = jax.eval_shape(lambda: model.init_state(slots, positions + 4096))
+    kinds = {"kv_bytes": 0, "recurrent_bytes": 0}
+    for a, b in zip(jax.tree_util.tree_leaves(held), jax.tree_util.tree_leaves(more)):
+        kinds["kv_bytes" if a.shape != b.shape else "recurrent_bytes"] += a.size * a.dtype.itemsize
+    return kinds
+
+
 class ContinuousBatcher:
     """Slot-based continuous batching over a model's own slot state."""
 
@@ -144,28 +174,26 @@ class ContinuousBatcher:
     #: shorter the chunk, the less of a prompt's last one is padding.
     DEFAULT_CHUNK = 512
     #: Tokens one prefill call aims at, as chunk x prompts. What a held expert
-    #: sees of them is the model's and the cut's: ~280 rows a call under
-    #: granite-4.0-h-small's 72-way top-10 router with half the experts held
-    #: (as in its deployment), ~32 under LongCat-Flash-Chat's 768-way top-12 with
-    #: 16 of 512 held (its deployment's expert would see ~1,024: every chip's
-    #: tokens reach it there, and here only this chip's).
+    #: sees of them is the model's and the cut's: ~280 rows a call under a
+    #: 72-way top-10 router with half the experts held, ~32 under a 768-way
+    #: top-12 with 16 of 512 held (a deployment's expert would see ~1,024 there:
+    #: every chip's tokens reach it, and here only this chip's).
     PREFILL_TOKENS = 2048
 
-    def __init__(self, model, params, num_slots: int = 8,
+    def __init__(self, model: ServedModel, params, num_slots: int = 8,
                  max_seq_len: Optional[int] = None, temperature: float = 0.0,
                  eos_id: Optional[int] = 2, seed: int = 0,
                  prefill_chunk: Optional[int] = None,
                  max_prompt_tokens: Optional[int] = None):
         self.model = model
         self.params = params
-        self.cfg = model.cfg
-        self.L = getattr(model, "block_length", None)  # a block a step, not a token
-        if self.L and temperature > 0.0:
+        L = self.L = model.block_length  # a block a step, not a token
+        if L and temperature > 0.0:
             from daft_tpu.errors import DaftValueError
 
             raise DaftValueError("a model that generates a block a step is served greedily here (temperature 0)")
         self.B = num_slots
-        limit = getattr(self.cfg, "max_seq_len", None)
+        limit = model.max_seq_len
         self.S = min(max_seq_len or limit, limit or max_seq_len)
         self.temperature = temperature
         self.eos_id = eos_id  # None: a sequence ends by its budget alone
@@ -174,21 +202,28 @@ class ContinuousBatcher:
         self.max_prompt = min(int(max_prompt_tokens or self.S - 2), self.S - 2)
         # One static chunk, a multiple of what the model's own scan asks for
         # (``prefill_chunk`` is for tests: a small one makes a short prompt many chunks).
-        mult = getattr(model, "prefill_multiple", 1)
+        mult = model.prefill_multiple
         self.chunk = -(-int(prefill_chunk or min(self.DEFAULT_CHUNK, self.max_prompt)) // mult) * mult
         self.prefill_rows = max(1, min(self.PREFILL_TOKENS // self.chunk, self.B))
         # Positions a slot holds: every prompt chunk lies inside, padding too.
         self.positions_held = max(self.S, -(-self.max_prompt // self.chunk) * self.chunk)
         self.state = model.init_state(self.B, self.positions_held)
+        self.state_bytes = state_bytes_by_kind(model, self.B, self.positions_held)
         # A block model holds no next-token logits: what follows a prompt is its first block.
-        self.cur_logits = None if self.L else jnp.zeros((self.B, model.vocab_size), jnp.float32)
-        # Host-side bookkeeping: handed to each step, never read back.
-        self.positions = np.zeros((self.B,), np.int32)  # a block model's: where its block starts
+        self.cur_logits = None if L else jnp.zeros((self.B, model.vocab_size), jnp.float32)
+        # Host-side bookkeeping: handed to each step, never read back. A token model's step is a block of one.
+        self.per_step = L or 1
+        # A slot whose position passes this retires: a block model's next block would not end inside the S positions;
+        # a token model keeps its last position spare.
+        self.room = self.S - (L or 2)
+        self.positions = np.zeros((self.B,), np.int32)  # where each slot's next step starts
         self.active = np.zeros((self.B,), bool)
         self.slots = [_Slot() for _ in range(self.B)]
-        if self.L:  # each slot's block: its tokens (the prompt's tail, then the mask id) and how many are the prompt's
-            self.block = np.full((self.B, self.L), model.mask_id, np.int32)
-            self.tail = np.zeros((self.B,), np.int32)
+        # Each slot's block: the prompt's tail (its first ``tail`` tokens, fixed), then the mask id. Only a block
+        # model's goes to the device.
+        self.mask_id = model.mask_id if L else 0
+        self.block = np.full((self.B, self.per_step), self.mask_id, np.int32)
+        self.tail = np.zeros((self.B,), np.int32)
         self._prefill = None  # jitted at first use: tests/test_serving.py wraps _prefill_impl on an instance before
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._copy = jax.jit(self._copy_impl, donate_argnums=(0, 1))
@@ -227,9 +262,8 @@ class ContinuousBatcher:
 
     def _repeat_noted(self, sp) -> None:
         """A model notes its choice of path on the open span while its program
-        traces (``decoders.grouped_mlp``: ``moe``; ``latent_attention``: ``mla``; ``olmo_hybrid``: ``delta``, ``attn``;
-        ``deepseek_v32``: ``dsa``; ``sdar_moe``: ``unmask``, ``attn``); a
-        call that traces nothing repeats what the trace chose."""
+        traces (``decoders.note_on_serving_span``); a call that traces nothing
+        repeats what the trace chose."""
         noted = self._noted.setdefault(sp.name, {})
         noted.update({k: v for k, v in sp.count.items() if isinstance(v, str)})
         sp.count.update(noted)
@@ -269,7 +303,7 @@ class ContinuousBatcher:
         its own chunk of its own prompt."""
         g, T = self.prefill_rows, self.chunk
         whole = np.asarray([len(req.tokens) for req, _ in todo], np.int64)
-        lens = whole - whole % self.L if self.L else whole  # a block model prefills whole blocks only
+        lens = whole - whole % self.per_step  # a block model prefills whole blocks only
         row_chunks = -(-lens // T)
         calls = prefill_schedule(row_chunks.tolist(), g)
         with device_span("serve.prefill", slot=todo[0][1], rows=len(todo), tokens=int(lens.sum()),
@@ -280,10 +314,7 @@ class ContinuousBatcher:
                          padded_block_rows=g * sum(max(c for _, c in call) + 1 for call in calls)) as sp:
             if self._prefill is None and calls:
                 sp.count["first"] = 1  # this call traces and compiles (or loads) the program
-            if self.L:
-                sp.count["tail_tokens"] = int((whole - lens).sum())
-            # what the model counts of a round from its prompts' lengths (``deepseek_v32``: the pairs its selection keeps)
-            sp.count.update(getattr(self.model, "prefill_counts", lambda lens: {})(lens))
+            sp.count.update(self.model.prefill_counts(whole))
             fn = self._prefill_fn() if calls else None
             for call in calls:
                 taken = [todo[i][1] for i, _ in call]
@@ -304,15 +335,14 @@ class ContinuousBatcher:
             self._admit_host(req, slot)
 
     def _admit_host(self, req: Request, slot: int) -> None:
+        """The prompt's tail waits, fixed, in the slot's first block; the rest of the block is masked."""
+        n = len(req.tokens) % self.per_step
         self.active[slot] = True
-        self.positions[slot] = len(req.tokens)
+        self.positions[slot] = len(req.tokens) - n
         self.slots[slot] = _Slot(request=req, remaining=req.max_new_tokens)
-        if self.L:  # the prompt's tail waits, fixed, in the slot's first block; the rest of the block is masked
-            n = len(req.tokens) % self.L
-            self.positions[slot] -= n
-            self.tail[slot] = n
-            self.block[slot] = self.model.mask_id
-            self.block[slot, :n] = req.tokens[len(req.tokens) - n:]
+        self.tail[slot] = n
+        self.block[slot] = self.mask_id
+        self.block[slot, :n] = req.tokens[len(req.tokens) - n:]
 
     def _retire(self, slot: int, results: Dict[int, _Slot]) -> None:
         s = self.slots[slot]
@@ -358,9 +388,10 @@ class ContinuousBatcher:
                 if first:
                     sp.count["first"] = 1  # this step traces and compiles (or loads) the program
                 if self.L:  # what a block step commits and what its passes decide
-                    sp.count.update(tokens=self.L * int(self.active.sum()), forwards=self.model.denoising_steps + 1,
-                                    masked=int(((self.L - self.tail) * self.active).sum()), block=self.L,
-                                    keys=int(((self.positions + self.L) * self.active).sum()))
+                    n = self.per_step
+                    sp.count.update(tokens=n * int(self.active.sum()), forwards=self.model.denoising_steps + 1,
+                                    masked=int(((n - self.tail) * self.active).sum()), block=n,
+                                    keys=int(((self.positions + n) * self.active).sum()))
                 self._key, sub = jax.random.split(self._key)
                 block = (self.block, self.tail) if self.L else None
                 with device_span("serve.dispatch"):
@@ -374,40 +405,33 @@ class ContinuousBatcher:
                 sp.count.update({f"moe.{k}": int(v) for k, v in out["counts"].items()})
                 self._repeat_noted(sp)
                 steps += 1
-                if self.L:
-                    self._take_blocks(out, results)
-                    continue
-                self.positions += self.active
-                for slot in np.flatnonzero(self.active):
-                    s = self.slots[slot]
-                    t = int(out["tok"][slot])
-                    s.generated.append(t)
-                    s.logprobs.append(float(out["logprob"][slot]))
-                    s.remaining -= 1
-                    if t == self.eos_id or s.remaining <= 0 \
-                            or self.positions[slot] >= self.S - 1:
-                        self._retire(int(slot), results)
+                self._take(out, results)
         self.decode_steps = steps
         done = [results.get(i, _Slot()) for i in range(len(requests))]
         self.last_logprobs = [s.logprobs for s in done]
         self.last_unmask_steps = [s.unmask_steps for s in done]
         return [s.generated for s in done]
 
-    def _take_blocks(self, out, results: Dict[int, _Slot]) -> None:
-        """A block step's bookkeeping: each active slot appends what its passes decided (the block but the
-        prompt's tail), moves on a block with a masked one, and retires on its budget, at an end-of-sequence id or
-        where no further block fits; an answer is its first ``max_new_tokens`` tokens (and up to its end id)."""
-        L = self.L
+    def _take(self, out, results: Dict[int, _Slot]) -> None:
+        """A step's bookkeeping (a token model's step read as a block of one): each active slot appends what the
+        step decided (its block but the prompt's tail), moves on a step with a masked block, and retires on its
+        budget, at an end-of-sequence id or where its next step does not fit (``room``); an answer is its first
+        ``max_new_tokens`` tokens (and up to its end id)."""
+        n = self.per_step
+        tok, logprob = (np.reshape(out[k], (self.B, n)) for k in ("tok", "logprob"))
+        step = out.get("step")  # a block model's: the pass that fixed each token
         for slot in np.flatnonzero(self.active):
-            s, n = self.slots[slot], int(self.tail[slot])
-            s.generated += out["tok"][slot, n:].tolist()
-            s.logprobs += out["logprob"][slot, n:].tolist()
-            s.unmask_steps += out["step"][slot, n:].tolist()
-            s.remaining -= L - n
-            self.positions[slot] += L
-            self.tail[slot], self.block[slot] = 0, self.model.mask_id
-            ended = self.eos_id in s.generated
-            if ended or s.remaining <= 0 or self.positions[slot] + L > self.S:
+            s, tail = self.slots[slot], int(self.tail[slot])
+            new = tok[slot, tail:].tolist()
+            s.generated += new
+            s.logprobs += logprob[slot, tail:].tolist()
+            if step is not None:
+                s.unmask_steps += step[slot, tail:].tolist()
+            s.remaining -= n - tail
+            self.positions[slot] += n
+            self.tail[slot], self.block[slot] = 0, self.mask_id
+            ended = self.eos_id in new
+            if ended or s.remaining <= 0 or self.positions[slot] > self.room:
                 keep = s.generated.index(self.eos_id) + 1 if ended else s.request.max_new_tokens
                 s.generated, s.logprobs, s.unmask_steps = s.generated[:keep], s.logprobs[:keep], s.unmask_steps[:keep]
                 self._retire(int(slot), results)

@@ -47,7 +47,7 @@ read:
   product is ``NaN``: the value rows past the end are zeroed in VMEM before the
   block is used, so they weigh exactly nothing whatever bits stand there.
 
-The arithmetic is ``attention_chunk``'s (and ``sdar_moe.attend_block``'s):
+The arithmetic is ``attention_chunk``'s (and ``decoders.attention_block``'s):
 operands enter the MXU in bfloat16 as they arrive, scores, max, exp, sum and the
 accumulator are float32, the scale multiplies the float32 scores, the mask is by
 position, the probabilities are cast to bfloat16 for the second product, one
@@ -124,8 +124,8 @@ def cache_attention_applies(q_shape, cache_shape, dtype, own: bool = False) -> b
     a sublane tile, one head's step inside ``VMEM_BUDGET``, and either one query a
     key/value head in a chunk of whole lane tiles (or one token), or, with a
     block's ``own`` keys, a head's ``Tq x R`` queries in whole sublane tiles.
-    Otherwise the caller takes ``decoders.attention_chunk`` / ``attention_core``
-    (``sdar_moe.attend_block`` for a block)."""
+    Otherwise ``decoders.attend_cache``, its one caller, takes XLA's forms
+    (``attention_chunk``, ``attention_core``, ``attention_block``)."""
     _, Tq, KV, R, hd = q_shape
     S = cache_shape[2]
     rows, block, _ = _call_shape(Tq, R, own)

@@ -29,28 +29,7 @@ def pytest_configure(config):
     config.addinivalue_line("markers", "slow: excluded from the tier-1 run")
 
 
-#: Two tests of ``tests/benchmark_harness/`` (the benchmark's files, which a PR that may only append to the
-#: benchmark does not edit, its ``conftest.py`` neither) each hold one thing that PR 37's appended entries cannot
-#: leave true: Olmo-Hybrid's 21 entries stand last in ``per_layer`` and its cell reports exactly 29 values; no entry
-#: lists LongCat's or Olmo-Hybrid's cell beside another cell (ISSUE 37: six entries list all three ``prompt`` cells,
-#: no ``lc.`` / ``oh.`` twins). Marked strictly, as that directory's own ``conftest.py`` marks the pin at 51: the
-#: day a ``benchmark`` PR unpins them they pass, the marker fails, and these lines go (PERF.md section 7 (r)). A
-#: failed assertion ends a test, so the marker costs their other assertions too: those run, one for one, in
-#: ``tests/benchmark_harness/test_idle_by_span.py`` (``test_longcats_enlarged_manifest_...`` and
-#: ``test_olmo_hybrids_enlarged_manifests_...``), with the pins loosened to what an append leaves true.
-PINNED_BEFORE_PR_37 = {
-    ("test_longcat_cell.py", "test_the_enlarged_manifest_is_consistent_and_the_cell_resolves_from_a_copy"),
-    ("test_olmo_cell.py", "test_the_enlarged_manifests_are_consistent_and_the_cell_resolves_from_a_copy"),
-}
-
-
 def pytest_collection_modifyitems(config, items):
-    for item in items:
-        # by file and name, not by node id: that is relative to the directory pytest was started from
-        if (item.path.name, item.name) in PINNED_BEFORE_PR_37 and item.path.parent.name == "benchmark_harness":
-            item.add_marker(pytest.mark.xfail(
-                reason="pins BENCHMARK.json's per_layer as it stood before entries were appended", strict=True,
-                raises=AssertionError))
     # Enforce the `slow` marker's contract instead of trusting every
     # invocation to pass -m 'not slow': a bare `pytest tests/` skips slow
     # tests; any explicit -m expression (e.g. `-m slow`, `-m 'not chaos'`)

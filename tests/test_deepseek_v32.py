@@ -44,7 +44,7 @@ from lib import manifest  # noqa: E402
 
 from daft_tpu.errors import DaftValueError  # noqa: E402
 from daft_tpu.models import decoders, deepseek_v32 as ds, latent_attention as la, longcat_flash as lc  # noqa: E402
-from daft_tpu.models.serving import ContinuousBatcher, Request  # noqa: E402
+from daft_tpu.models.serving import ContinuousBatcher, Request, state_bytes_by_kind  # noqa: E402
 from daft_tpu.ops import pallas_attention, pallas_dsa_index, pallas_mla_attention  # noqa: E402
 
 TINY = "deepseek-v32-tiny"
@@ -496,7 +496,7 @@ def test_names_cuts_and_sizes():
     assert 4.60e9 < n < 4.70e9  # ISSUE 40's arithmetic: 597.4M + 4 x 951.6M + 231.7M, and the norms
     state = jax.eval_shape(lambda: ds.DeepseekV32LM(cfg).init_state(8, 32833))
     assert sorted(leaf.shape for leaf in jax.tree_util.tree_leaves(state)) == [(8, 128, 32896)] * 5 + [(8, 576, 32896)] * 5
-    kinds = decoders.state_bytes_by_kind(ds.DeepseekV32LM(cfg).init_state(2, 100))  # whole tiles of 128 positions
+    kinds = state_bytes_by_kind(ds.DeepseekV32LM(cfg), 2, 100)  # whole tiles of 128 positions
     assert kinds == {"recurrent_bytes": 0, "kv_bytes": 2 * 128 * 5 * (576 + 128) * 2}
     assert TINY in decoders.DECODERS and "DeepSeek-V3.2-Exp" in decoders.DECODERS
     assert ds.selected_pairs([3, 20], 16) == 6 + (136 + 4 * 16) and ds.selected_pairs([5, 16], 16) == 15 + 136

@@ -454,28 +454,51 @@ def _logged_since(t_ns: int):
     return [row for row in profiling.recent_compiles() if row[0] >= t_ns]
 
 
-def test_a_new_shape_is_logged_with_the_open_spans_name_and_counted_on_it():
-    import jax
-    import jax.numpy as jnp
+_NEW_SHAPE_SCRIPT = """
+import jax
+import jax.numpy as jnp
+import pytest
 
-    fn = jax.jit(lambda a: (a * 3 + 1).sum())
-    with device_span("test.outer") as outer:
-        with device_span("test.compiles") as sp:
-            fn(jnp.ones((37,)))
-    mine = _logged_since(outer.start_ns)
-    assert {kind for _, kind, _, _ in mine} == {"trace", "lower", "compile"}
-    assert {name for _, _, _, name in mine} == {"test.compiles"}  # the innermost open span, not its parent
-    assert all(sp.start_ns <= t <= sp.end_ns and seconds > 0 for t, _, seconds, _ in mine)
-    for kind, key in (("trace", "trace_s"), ("lower", "lower_s"), ("compile", "compile_s")):
-        assert sp.count[key] == pytest.approx(sum(s for _, k, s, _ in mine if k == kind))
-    assert sp.count["compiles"] == sum(k == "compile" for _, k, _, _ in mine) >= 1
-    assert "cache_loads" not in sp.count and not outer.count
-    # the same shape again compiles nothing and logs nothing; a new shape does, outside any span under no name
-    with device_span("test.again") as again:
+from daft_tpu import profiling
+from daft_tpu.profiling import device_span
+
+
+def _logged_since(t_ns):
+    return [row for row in profiling.recent_compiles() if row[0] >= t_ns]
+
+
+fn = jax.jit(lambda a: (a * 3 + 1).sum())
+with device_span("test.outer") as outer:
+    with device_span("test.compiles") as sp:
         fn(jnp.ones((37,)))
-    assert not _logged_since(again.start_ns) and not again.count
-    fn(jnp.ones((41,)))
-    assert {name for _, _, _, name in _logged_since(again.end_ns)} == {""}
+mine = _logged_since(outer.start_ns)
+assert {kind for _, kind, _, _ in mine} == {"trace", "lower", "compile"}, mine
+assert {name for _, _, _, name in mine} == {"test.compiles"}, mine  # the innermost open span, not its parent
+assert all(sp.start_ns <= t <= sp.end_ns and seconds > 0 for t, _, seconds, _ in mine), mine
+for kind, key in (("trace", "trace_s"), ("lower", "lower_s"), ("compile", "compile_s")):
+    assert sp.count[key] == pytest.approx(sum(s for _, k, s, _ in mine if k == kind)), (key, sp.count, mine)
+assert sp.count["compiles"] == sum(k == "compile" for _, k, _, _ in mine) >= 1, (sp.count, mine)
+assert "cache_loads" not in sp.count and not outer.count, (sp.count, outer.count)
+# the same shape again compiles nothing and logs nothing; a new shape does, outside any span under no name
+with device_span("test.again") as again:
+    fn(jnp.ones((37,)))
+assert not _logged_since(again.start_ns) and not again.count, (_logged_since(again.start_ns), again.count)
+fn(jnp.ones((41,)))
+assert {name for _, _, _, name in _logged_since(again.end_ns)} == {""}, _logged_since(again.end_ns)
+print("ok")
+"""
+
+
+def test_a_new_shape_is_logged_with_the_open_spans_name_and_counted_on_it():
+    """In a process of its own: the log is the process's, read by time, and what other tests of a worker leave
+    running (a thread that traces, a stream's feeder still inside a partition) logs into the same window."""
+    import os
+    import subprocess
+
+    p = subprocess.run([sys.executable, "-c", _NEW_SHAPE_SCRIPT], capture_output=True, text=True, timeout=120,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.strip().splitlines()[-1] == "ok"
 
 
 def test_a_load_from_the_compile_cache_is_logged_as_a_load_and_not_as_a_compile():

@@ -257,34 +257,44 @@ def test_copy_state_copies_both_kinds():
         assert np.array_equal(np.asarray(new[1:]), np.asarray(old[1:]))
 
 
-@pytest.mark.parametrize("decoder", [TINY, "granite-hybrid-tiny", "longcat-flash-tiny", "toy"])
+def _kinds_by_leaf_names(state):
+    """The rule the counts had before they were read from shapes: rows a token are the leaves a model names ``k``,
+    ``v``, ``kv`` or ``ik`` (and a leaf under no name, the toy decoder's pairs); every other leaf is recurrent."""
+    kinds = {"kv_bytes": 0, "recurrent_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        names = [p.key for p in path if isinstance(p, jax.tree_util.DictKey)]
+        kinds["kv_bytes" if not names or names[-1] in ("k", "v", "kv", "ik") else "recurrent_bytes"] += leaf.nbytes
+    return kinds
+
+
+@pytest.mark.parametrize("decoder", sorted({n for n in decoders.DECODERS if n.endswith("-tiny")}) + ["toy"])
 def test_slot_state_is_counted_in_two_kinds_by_the_names_of_its_leaves(decoder):
-    """``decoders.state_bytes_by_kind``: rows a token are the leaves a model names ``k``, ``v`` or ``kv`` (and a
-    state of unnamed leaves, the toy decoder's); no shape is asked, so positions that equal a width (16 here: this
-    model's head size and value head size) misfile nothing."""
+    """``serving.state_bytes_by_kind``: rows a position are the leaves that grow with the positions a slot holds,
+    read from shapes, and that gives what the leaves' names gave, for every decoder on record and the toy. Positions
+    that equal a width (16 here: olmo's head size and value head size) misfile nothing, and neither do positions
+    that a model rounds up to a tile."""
+    from daft_tpu.models.serving import state_bytes_by_kind
+
     if decoder == "toy":
-        from daft_tpu.models.lm import DecoderLMConfig, init_caches
+        from daft_tpu.models.lm import DecoderLM, DecoderLMConfig
 
-        state = init_caches(DecoderLMConfig.from_name("tiny"), 3, 16)
+        model = DecoderLM(DecoderLMConfig.from_name("tiny"))
     else:
-        from daft_tpu.models import granite_hybrid, longcat_flash  # noqa: F401  (each enters its names in the record)
-
         d = decoders.DECODERS[decoder]
-        state = d.model(d.from_name(decoder)).init_state(3, 16)
-    kinds = decoders.state_bytes_by_kind(state)
-    named = {getattr(path[-1], "key", None): leaf for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]}
-    rows = sum(x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(state))
+        model = d.model(d.from_name(decoder))
+    for slots, positions in ((3, 16), (2, 100)):
+        state = model.init_state(slots, positions)
+        kinds = state_bytes_by_kind(model, slots, positions)
+        assert kinds == _kinds_by_leaf_names(state)
+        assert sum(kinds.values()) == sum(x.nbytes for x in jax.tree_util.tree_leaves(state))
+    kinds = state_bytes_by_kind(model, 3, 16)
     if decoder == TINY:
         per_slot = 3 * (4 * 4 * 16 * 8 + 2 * 3 * 128)                      # three linear layers: S float32, conv tail
-        assert kinds == {"kv_bytes": rows - 3 * per_slot, "recurrent_bytes": 3 * per_slot}
-        assert kinds["kv_bytes"] == 3 * 2 * 4 * 16 * 16 * 2                 # one attention layer: k and v, 16 rows held
-    elif set(named) <= {"k", "v", "kv", None}:                             # latent rows alone; the toy's pairs
-        assert kinds == {"kv_bytes": rows, "recurrent_bytes": 0}
-    else:                                                                   # granite: ssm and conv beside k and v
-        assert 0 < kinds["recurrent_bytes"] < rows and kinds["kv_bytes"] + kinds["recurrent_bytes"] == rows
-        assert kinds["recurrent_bytes"] == sum(x.size * x.dtype.itemsize for path, x in
-                                               jax.tree_util.tree_flatten_with_path(state)[0]
-                                               if path[-1].key in ("ssm", "conv"))
+        assert kinds == {"kv_bytes": 3 * 2 * 4 * 16 * 16 * 2, "recurrent_bytes": 3 * per_slot}  # one attention layer
+    elif decoder in ("granite-hybrid-tiny",):                               # ssm and conv beside k and v
+        assert 0 < kinds["recurrent_bytes"] and kinds["kv_bytes"] > 0
+    else:                                                                   # rows alone
+        assert kinds["recurrent_bytes"] == 0 and kinds["kv_bytes"] > 0
 
 
 # -- variants that leave mathematics out read not correct ----------------------------------------

@@ -1,9 +1,10 @@
 """The attention kernel over a cache of per-head key/value rows
 (``ops/pallas_cache_attention.py``): its arithmetic in interpret mode on the CPU
 against ``decoders.attention_chunk`` (a prefill's chunk), ``attention_step``
-(a decode step) and ``sdar_moe.attend_block`` (a block of a diffusion decoder,
+(a decode step) and ``decoders.attention_block`` (a block of a diffusion decoder,
 grouped queries) over the same rows, the walk it is handed, the slot's end, the
-rule that selects it, and a failing kernel. The models on either path and the
+rule that selects it and the one function that asks it (``decoders.attend_cache``),
+and a failing kernel. The models on either path and the
 compile for a described v5e at the cells' sizes are in ``tests/test_olmo_hybrid.py``
 and ``tests/test_sdar_moe.py``."""
 
@@ -74,7 +75,7 @@ def _chunk_operands(KV, rows, dtype, seed=0):
 
 
 def _xla_chunk(q, ck, cv, slots, starts, dtype):
-    """XLA's path as ``olmo_hybrid._attn_prefill`` takes it on the CPU: every row over the blocks the deepest attends."""
+    """XLA's path as ``decoders.attend_cache`` takes it on the CPU: every row over the blocks the deepest attends."""
     starts = jnp.asarray(starts, jnp.int32)
     block = lambda c, j: pcb.read_blocks_xla(c, slots, j * T, T).astype(jnp.float32)  # noqa: E731
     out = decoders.attention_chunk(q, lambda j: (block(ck, j), block(cv, j)), starts[:, None] + jnp.arange(T)[None, :],
@@ -99,8 +100,8 @@ def _block_operands(KV, dtype, seed, rows=4, S=BLOCK_S):
 
 
 def _xla_block(q, ck, cv, own, starts, dtype):
-    """``sdar_moe.attend_block``: every slot over every position it holds and its block's own keys."""
-    return np.asarray(sd.attend_block(q, ck, cv, *own, jnp.asarray(starts, jnp.int32), SCALE, dtype), np.float32)
+    """``decoders.attention_block``: every slot over every position it holds and its block's own keys."""
+    return np.asarray(decoders.attention_block(q, ck, cv, *own, jnp.asarray(starts, jnp.int32), SCALE, dtype), np.float32)
 
 
 def _fused(q, ck, cv, slots, starts, lengths, own=None):
@@ -350,6 +351,50 @@ def test_on_a_tpu_a_block_of_grouped_queries_takes_the_kernel_and_others_do_not(
     heads = pca._heads_a_step(4, 128, 4, 2, 8, True)
     assert heads == 4 and pca._step_bytes(32, pca.BLOCK, 128, heads, 2, 4) <= pca.VMEM_BUDGET            # one grid step a slot
     assert pca._call_shape(4, 8, True) == (32, pca.BLOCK, pca.MAX_HEADS_STEP)
+
+
+#: What ``decoders.attend_cache`` is handed at each call site: name -> (q, the cache rows as kept, the mode).
+SITES = {
+    "olmo_chunk": (CHUNK_Q, CELL, "chunk"),
+    "olmo_step": (STEP_Q, CELL, "token"),
+    "sdar_block": (BLOCK_Q, SDAR_CELL, "block"),
+    "sdar_prefill": ((4, 512, 4, 8, 128), SDAR_CELL, "block_causal"),
+    "olmo_tiny_chunk": ((4, 8, 4, 1, 16), (4, 4, 64, 16), "chunk"),
+    "olmo_tiny_step": ((4, 1, 4, 1, 16), (4, 4, 64, 16), "token"),
+    "sdar_tiny_block": ((3, 4, 2, 2, 16), (3, 2, 96, 16), "block"),
+    "sdar_tiny_prefill": ((3, 16, 2, 2, 16), (3, 2, 96, 16), "block_causal"),
+}
+#: (site, a TPU's backend rule) -> the ``attn`` the function notes (None: none) and whether it calls the kernel.
+ON_A_TPU = {"olmo_chunk": "fused", "olmo_step": "fused", "sdar_block": "fused", "sdar_prefill": None,
+            "olmo_tiny_chunk": "xla", "olmo_tiny_step": "xla", "sdar_tiny_block": "xla", "sdar_tiny_prefill": None}
+
+
+@pytest.mark.parametrize("tpu", [True, False], ids=["tpu", "cpu"])
+@pytest.mark.parametrize("site", list(SITES))
+def test_attend_cache_alone_chooses_the_kernel_or_xla_and_notes_it(site, tpu, monkeypatch):
+    """The one function that asks ``cache_attention_applies``, traced at each site's shapes (shapes only): on a TPU
+    Olmo-Hybrid's chunk and step and SDAR's block at the cells' widths take the kernel; SDAR's block-causal prefill
+    (no such mode in the kernel) and the tiny widths take XLA's forms; on the CPU everything does. It notes ``attn``
+    where there was a choice, and SDAR's prefill notes nothing."""
+    q_shape, cache_shape, mode = SITES[site]
+    noted, calls = {}, []
+    monkeypatch.setattr(pa, "backend_is_tpu", lambda: tpu)
+    monkeypatch.setattr(decoders, "note_on_serving_span", lambda key, value: noted.update({key: value}))
+    monkeypatch.setattr(pca, "cache_attention", lambda q, *a, **kw: calls.append(sorted(kw)) or jnp.zeros(q.shape, q.dtype))
+    B, T, KV = q_shape[:3]
+    of = jax.ShapeDtypeStruct
+    q, cache, ints = of(q_shape, jnp.bfloat16), of(cache_shape, jnp.bfloat16), of((B,), jnp.int32)
+    own = (of((B, T, KV, q_shape[-1]), jnp.bfloat16),) * 2
+    view = of((cache_shape[0], cache_shape[2], cache_shape[1], cache_shape[3]), jnp.bfloat16)
+    kw = {"chunk": lambda: dict(slots=ints, lengths=ints),
+          "block_causal": lambda: dict(slots=ints, lengths=ints, sees=of((B, T), jnp.int32)),
+          "token": lambda: dict(active=of((B,), bool), view=(view, view)),
+          "block": lambda: dict(active=of((B,), bool), own=own)}[mode]()
+    out = jax.eval_shape(lambda q, c, s, kw: decoders.attend_cache(q, c, c, s, SCALE, jnp.bfloat16, **kw), q, cache, ints, kw)
+    assert out.shape == q_shape
+    want = ON_A_TPU[site] if tpu else (None if mode == "block_causal" else "xla")
+    assert noted == ({} if want is None else {"attn": want})
+    assert calls == ([["own", "scale"] if mode == "block" else ["scale"]] if want == "fused" else [])
 
 
 def test_on_a_tpu_the_published_widths_take_the_kernel_and_others_do_not(monkeypatch):

@@ -179,7 +179,7 @@ def test_in_float32_the_block_kernels_steps_are_the_references_replay(ref, block
 def test_on_a_tpu_the_published_widths_take_the_block_kernel_and_the_tiny_ones_do_not(monkeypatch):
     """The block step's attention traced with the backend rule answering as on a TPU (shapes only): at the
     published widths (4 key/value heads of 128, 8 queries each, 64 slots of 2,320 positions) it calls the kernel
-    with the caches as the state holds them; at the tiny widths (heads of 16) it keeps ``attend_block``."""
+    with the caches as the state holds them; at the tiny widths (heads of 16) it keeps ``decoders.attention_block``."""
     from daft_tpu.ops import pallas_attention, pallas_cache_attention as pca
 
     calls = []
@@ -288,7 +288,7 @@ def test_the_spans_carry_the_block_steps_counters_and_the_note():
     assert sum(r["tokens"] for r in rounds) == sum(n - n % 4 for n in lengths)
     steps = [s.count for s in spans if s.name == "serve.decode_step"][-b.decode_steps:]
     assert all(s["tokens"] == 4 * s["active"] and s["forwards"] == 5 and s["block"] == 4 and s["unmask"] == "static"
-               and s["attn"] == "xla" for s in steps)                   # the CPU's block step: attend_block
+               and s["attn"] == "xla" for s in steps)                   # the CPU's block step: decoders.attention_block
     assert all(s["active"] <= s["masked"] <= 4 * s["active"] and s["keys"] >= 4 * s["active"] for s in steps)
     assert sum(s["masked"] for s in steps) >= 6 * len(lengths)  # every answer token was decided by a pass
     assert all(s["moe.assignments"] == s["active"] * 4 * 5 * 2 * 4 for s in steps)  # 4 positions, 5 forwards, top 2, 4 layers
