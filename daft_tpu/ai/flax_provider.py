@@ -487,7 +487,9 @@ class FlaxPrompter(_FlaxModelBase):
     to ``max_prompt_tokens`` hashed tokens, ``ignore_eos`` for a fixed answer
     length, ``logprobs`` for answers a comparison can hold
     against logits (with a decoder that generates a block a step, also the
-    pass that fixed each token)."""
+    pass that fixed each token, and with a decoder that drafts, the drafts:
+    where each stood in the answer, its id and its log-probability under the
+    drafting block)."""
 
     def __init__(self, model_name: str, weights_path: Optional[str] = None,
                  max_new_tokens: int = 32, temperature: float = 0.0, seed: int = 0,
@@ -534,7 +536,9 @@ class FlaxPrompter(_FlaxModelBase):
         the vLLM streaming sink; see daft_tpu/models/serving.py). -> one
         string of ids a row, or with ``logprobs`` ``(strings, token ids,
         log-probabilities)``, the last two as lists of arrays, and for a model
-        that generates a block a step a fourth, the pass that fixed each token."""
+        that generates a block a step a fourth, the pass that fixed each token,
+        and for a model that drafts three more: each row's drafts' places in its
+        answer, their ids and their log-probabilities under the drafting block."""
         from daft_tpu.models.serving import ContinuousBatcher, Request
 
         with device_span("prompt.tokenize", rows=len(prompts)) as sp:
@@ -554,7 +558,7 @@ class FlaxPrompter(_FlaxModelBase):
                              state_bytes=sum(b.state_bytes.values()), **b.state_bytes) as sp:
                 out = b.run(reqs)
                 sp.count["decode_steps"] = b.decode_steps
-            logprobs, steps = b.last_logprobs, b.last_unmask_steps
+            logprobs, steps, drafts = b.last_logprobs, b.last_unmask_steps, b.last_drafts
         if not self.logprobs:
             return [" ".join(str(t) for t in row if t != 0) for row in out]
         answers = ([" ".join(str(t) for t in row) for row in out],
@@ -562,6 +566,9 @@ class FlaxPrompter(_FlaxModelBase):
                    [np.asarray(row, np.float32) for row in logprobs])
         if b.L:
             answers += ([np.asarray(row, np.int32) for row in steps],)
+        if self.model.draft_length:
+            rows = [np.asarray(row, np.float64).reshape(-1, 3) for row in drafts]
+            answers += tuple([r[:, k].astype(dtype) for r in rows] for k, dtype in ((0, np.int32), (1, np.int32), (2, np.float32)))
         return answers
 
 
@@ -599,6 +606,12 @@ class _FlaxDescriptor(Descriptor):
         Read from the record when the expression is built: nothing is drawn or placed."""
         decoder = decoders.DECODERS.get(self.model)
         return self.kind == "prompter" and decoder is not None and decoder.blocks
+
+    def drafts(self) -> bool:
+        """A prompter over a decoder on record that drafts its own tokens: its answers with ``logprobs`` carry the
+        drafts. Read from the record when the expression is built: nothing is drawn or placed."""
+        decoder = decoders.DECODERS.get(self.model)
+        return self.kind == "prompter" and decoder is not None and decoder.drafts
 
     def runs_beside_host(self) -> bool:
         # On the CPU backend the forward takes the cores a host stage would.

@@ -84,6 +84,12 @@ class Descriptor:
         carry the pass that fixed each token. Read when the expression is built."""
         return False
 
+    def drafts(self) -> bool:
+        """True for a prompter whose model drafts its own tokens and verifies
+        them: its answers with log-probabilities carry the drafts. Read when the
+        expression is built."""
+        return False
+
     def runs_beside_host(self) -> bool:
         """True where instances compute on a processor other than the host's
         CPU, so that the host's work for the next batch can run while this one

@@ -346,7 +346,11 @@ def prompt(text: Expression, *, provider: Union[str, object, None] = None,
     log-probability, so that a comparison can hold the answer against logits.
     For a decoder that generates a block a step (``Descriptor.yields_blocks``) the struct
     also holds ``unmask_steps: list[int32]``, the pass that fixed each token, so
-    that a reference can replay the program's order."""
+    that a reference can replay the program's order. For a decoder that drafts its
+    own tokens (``Descriptor.drafts``) it holds each row's drafts:
+    ``draft_positions`` (where each stood in the answer), ``draft_ids`` and
+    ``draft_logprobs`` (its log-probability under the drafting block), lists of
+    their own length."""
     p = load_provider(provider)
     desc = p.get_prompter(model, **options)
     if not options.get("logprobs"):
@@ -358,23 +362,28 @@ def prompt(text: Expression, *, provider: Union[str, object, None] = None,
 
     fields = {"text": DataType.string(), "token_ids": DataType.list(DataType.int32()),
               "logprobs": DataType.list(DataType.float32())}
+    kinds = [np.int32, np.float32]
     if desc.yields_blocks():
         fields["unmask_steps"] = DataType.list(DataType.int32())
+        kinds.append(np.int32)
+    if desc.drafts():
+        fields.update(draft_positions=DataType.list(DataType.int32()), draft_ids=DataType.list(DataType.int32()),
+                      draft_logprobs=DataType.list(DataType.float32()))
+        kinds += [np.int32, np.int32, np.float32]
     dtype = DataType.struct(fields)
 
     def call_logprobs(inst, series: Series) -> Series:
         import pyarrow as pa
 
-        texts, ids, *per_token = inst.prompt(series.to_pylist())
-        offsets = np.concatenate([[0], np.cumsum([len(r) for r in ids])]).astype(np.int32)
+        texts, *per_row = inst.prompt(series.to_pylist())
 
         def lists(rows, np_dtype):
+            offsets = np.concatenate([[0], np.cumsum([len(r) for r in rows])]).astype(np.int32)
             flat = np.concatenate(rows) if rows else np.zeros(0, np_dtype)
             return pa.ListArray.from_arrays(pa.array(offsets), pa.array(flat.astype(np_dtype)))
 
-        kinds = [np.int32, np.float32, np.int32]
         arr = pa.StructArray.from_arrays(
-            [pa.array(texts, pa.string())] + [lists(rows, k) for rows, k in zip([ids] + per_token, kinds)],
+            [pa.array(texts, pa.string())] + [lists(rows, k) for rows, k in zip(per_row, kinds)],
             names=list(fields))
         return Series.from_arrow(arr, "response", dtype)
 

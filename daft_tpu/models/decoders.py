@@ -80,6 +80,11 @@ class Decoder:
         """The model generates a block a step (it declares ``block_length`` and has a ``block_step``), not a token."""
         return self.model.block_length is not None
 
+    @property
+    def drafts(self) -> bool:
+        """The model drafts tokens a step and verifies them (it declares ``draft_length``)."""
+        return bool(self.model.draft_length)
+
 
 DECODERS: Dict[str, Decoder] = {}
 
@@ -374,6 +379,6 @@ def held_experts_part(v, idx, gates, valid, first_expert: int, w_in, w_out, dtyp
 # The decoders on record                                                  #
 # ---------------------------------------------------------------------- #
 #: The modules that enter their names in ``DECODERS`` (each calls ``register`` when imported).
-MODULES = ("granite_hybrid", "longcat_flash", "olmo_hybrid", "deepseek_v32", "sdar_moe")
+MODULES = ("granite_hybrid", "longcat_flash", "olmo_hybrid", "deepseek_v32", "sdar_moe", "pangu_ultra_moe")
 for _module in MODULES:
     importlib.import_module(f"daft_tpu.models.{_module}")

@@ -470,7 +470,9 @@ def route(cfg, p, u):
     """u (n, d) float32 normed -> (idx (n, k) chosen experts, weights (n, k)
     float32): sigmoid scores in float32, the choice by score + bias inside the
     ``topk_group`` groups whose two best (score + bias) sum highest, the weight
-    by the score alone, renormalised over the chosen, times the scaling factor."""
+    by the score alone, renormalised over the chosen, times the scaling factor.
+    With one group (``n_group`` 1, ``models/pangu_ultra_moe``) the choice is the
+    top ``num_experts_per_tok`` of score + bias over all experts."""
     n, E, G = u.shape[0], cfg.n_routed_experts, cfg.n_group
     r = jnp.einsum("nd,de->ne", u, p["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(r)

@@ -20,7 +20,7 @@ for ``init_state(slots, positions)``, a ``prefill`` that advances some slots
 over one chunk of their prompts with the true lengths known, a ``decode`` step
 of one token over all slots, ``copy_state(state, src, dst)`` and
 ``vocab_size``. Optional hooks have their defaults on the base class:
-``block_length``, ``prefill_multiple``, ``max_seq_len`` and
+``block_length``, ``draft_length``, ``prefill_multiple``, ``max_seq_len`` and
 ``prefill_counts``. Which leaves of the state are rows a position (an
 attention's keys and values, latent rows) and which are recurrent state is read
 from their shapes: ``init_state`` at two position counts, as shapes only.
@@ -40,6 +40,24 @@ shared prefill's copy gets it too). After each step the loop appends what the
 step decided, moves the slot on, and retires it on its budget (an answer is its
 first ``max_new_tokens``), at an end-of-sequence id or where no further step
 fits.
+
+**A draft a step.** A model that declares ``draft_length`` (1: one drafted token
+a step) gets a third decode program under the same name, the speculative step. A
+slot holds a pending token t_m, emitted and not yet run by the model. One
+dispatch runs the model's ``draft`` (a self-drafting block over the positions
+whose hidden state and next token became known since it last ran; it gives the
+draft's logits q), draws the draft d from q, runs the model's ``verify`` (the
+model over [t_m, d] at m and m + 1, writing both rows; it gives p_{m+1} and
+p_{m+2}) and the rejection sampler (``rejection_sample``): d is accepted with
+probability min(1, p_{m+1}(d) / q(d)), and then b ~ p_{m+2} follows it; else c ~
+normalise(max(0, p_{m+1} - q)) is emitted. So a slot gains one or two tokens a
+step, distributed exactly as the model's own p; at temperature 0, d = argmax q is
+accepted where it is argmax p_{m+1}, which emits what greedy decoding one token a
+step would. A slot's first token after its prefill is drawn in its first step,
+from the prefill's logits. The acceptance rule is the batcher's; each emitted
+token's log-probability is log p of the logits it was drawn from, and each step
+also returns the draft and log q(d). The host loop appends what each slot
+emitted, a count of its own, and truncates an answer at its budget.
 
 **Prefill is chunked at one static length**, so prompts of any length share
 one executable: a prompt runs as ceil(P / chunk) calls that carry state, the
@@ -69,7 +87,9 @@ shape span),
 ``serve.copy_state``,
 ``serve.decode_step`` (one turn of the decode loop, from before the key split to after the step's bookkeeping:
 ``active``, ``slots``, the model's counts of the step, e.g. ``moe.held_assignments``, and ``first`` on the step
-that traces and compiles or loads the program; for a block model ``tokens``, the tokens it commits, L x active,
+that traces and compiles or loads the program; for a drafting model ``drafted`` (active),
+``accepted``, ``tokens`` (emitted), ``forwards`` (2), ``verify_positions`` (2 x active) and ``keys`` (the
+positions the active slots' verified queries see); for a block model ``tokens``, the tokens it commits, L x active,
 ``forwards``, ``denoising_steps`` + 1, ``masked``, the positions its passes decided, ``block``, L, and ``keys``, the
 positions the active slots' queries see: their committed rows and the block) with two children, ``serve.dispatch`` (the call of the decode program,
 from entry to return) and ``serve.fetch`` (the step's one fetch: ``arrays``, the leaves it brings). Between the
@@ -108,6 +128,7 @@ class _Slot:
     generated: List[int] = field(default_factory=list)
     logprobs: List[float] = field(default_factory=list)
     unmask_steps: List[int] = field(default_factory=list)  # a block model's: the pass that fixed each token
+    drafts: List[Tuple[int, int, float]] = field(default_factory=list)  # a drafting model's: (answer index, id, log q)
     remaining: int = 0
 
 
@@ -137,10 +158,22 @@ class ServedModel:
     counts); ``decode(params, state, tokens, positions, active)`` -> (state,
     logits, counts), or for a model with ``block_length``
     ``block_step(params, state, tokens, tail, positions, active)`` -> (state,
-    {"tok", "logprob", "step"} (slots, L) each, counts); ``vocab_size``."""
+    {"tok", "logprob", "step"} (slots, L) each, counts); ``vocab_size``. A model
+    with ``draft_length`` also has ``draft(params, state, tokens, positions,
+    pairs, active)`` -> (state, the draft's logits (slots, V), counts): its
+    drafting block over the two positions before each slot's pending token
+    (tokens, positions (slots, 2); ``pairs`` (slots,): how many of the two are
+    new since it last ran, 1 or 2, the first left as it was where 1), reading the
+    hidden states its state kept of the model's last forward; and
+    ``verify(params, state, tokens, positions, active)`` -> (state, logits
+    (slots, draft_length + 1, V), counts): the model over the pending token and
+    the draft at their positions (slots, draft_length + 1), writing their rows
+    and keeping their hidden states."""
 
     #: None: a token a step (``decode``); L: a block of L tokens a step (``block_step``).
     block_length: Optional[int] = None
+    #: None: no draft; D: the model drafts D tokens a step (``draft``) and the batcher verifies them (``verify``).
+    draft_length: Optional[int] = None
     #: A prefill chunk is a whole multiple of this many tokens.
     prefill_multiple: int = 1
     #: The longest sequence the model takes, if it has a limit of its own.
@@ -153,6 +186,31 @@ class ServedModel:
     def copy_state(self, state, src, dst):
         """Slot ``src``'s state into slot ``dst``, whatever the leaves hold."""
         return jax.tree_util.tree_map(lambda a: a.at[dst].set(a[src]), state)
+
+
+def rejection_sample(key, d, p_logits, q_logits, temperature: float):
+    """The verdict on drafts ``d`` (rows,) drawn from q: each accepted with
+    probability min(1, p(d) / q(d)), and where it is not, the correction c ~
+    normalise(max(0, p - q)), so that what is emitted (d where accepted, else c)
+    is distributed as p. p and q are softmax(logits / temperature), (rows, V)
+    each. At temperature 0 (d = argmax q): accepted where d is argmax p, c =
+    argmax p. -> (accepted, c), (rows,) each."""
+    if temperature <= 0.0:
+        c = jnp.argmax(p_logits, axis=-1).astype(jnp.int32)
+        return d == c, c
+    k_accept, k_correct = jax.random.split(key)
+    p, q = (jax.nn.softmax(x / temperature, axis=-1) for x in (p_logits, q_logits))
+    p_d, q_d = (jnp.take_along_axis(x, d[:, None], axis=-1)[:, 0] for x in (p, q))
+    accepted = jax.random.uniform(k_accept, d.shape) * q_d < p_d
+    excess = residual(p, q)
+    # where p equals q nothing is rejected: the correction is then p's own draw
+    odds = jnp.where(jnp.sum(excess, -1, keepdims=True) > 0, jnp.log(excess), p_logits / temperature)
+    return accepted, jax.random.categorical(k_correct, odds, axis=-1).astype(jnp.int32)
+
+
+def residual(p, q):
+    """What a rejection draws from, unnormalised: max(0, p - q)."""
+    return jnp.maximum(p - q, 0.0)
 
 
 def state_bytes_by_kind(model: ServedModel, slots: int, positions: int) -> Dict[str, int]:
@@ -188,10 +246,17 @@ class ContinuousBatcher:
         self.model = model
         self.params = params
         L = self.L = model.block_length  # a block a step, not a token
-        if L and temperature > 0.0:
-            from daft_tpu.errors import DaftValueError
+        D = self.D = model.draft_length  # a drafted token a step, verified
+        from daft_tpu.errors import DaftValueError
 
-            raise DaftValueError("a model that generates a block a step is served greedily here (temperature 0)")
+        if L and D:
+            raise DaftValueError("a model drafts tokens (draft_length) or generates a block a step (block_length), "
+                                 "not both")
+        if D not in (None, 1):
+            raise DaftValueError(f"draft_length={D}: the speculative step verifies one drafted token a slot")
+        if L and temperature > 0.0:
+            raise DaftValueError("a model that generates a block a step is served greedily here (temperature 0); "
+                                 "sampled answers need a decoder of one token a step or one that drafts")
         self.B = num_slots
         limit = model.max_seq_len
         self.S = min(max_seq_len or limit, limit or max_seq_len)
@@ -224,6 +289,12 @@ class ContinuousBatcher:
         self.mask_id = model.mask_id if L else 0
         self.block = np.full((self.B, self.per_step), self.mask_id, np.int32)
         self.tail = np.zeros((self.B,), np.int32)
+        # A drafting model's: each slot's last two tokens (the second pending), how many of the pairs before it its
+        # drafting block has not run (1 or 2), and whether its first token is still to be drawn from the prefill's
+        # logits. Only a drafting model's go to the device.
+        self.last_two = np.zeros((self.B, 2), np.int32)
+        self.pairs = np.ones((self.B,), np.int32)
+        self.fresh = np.zeros((self.B,), bool)
         self._prefill = None  # jitted at first use: tests/test_serving.py wraps _prefill_impl on an instance before
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2))
         self._copy = jax.jit(self._copy_impl, donate_argnums=(0, 1))
@@ -246,19 +317,50 @@ class ContinuousBatcher:
         state = self.model.copy_state(state, src, dst)
         return state, None if cur_logits is None else cur_logits.at[dst].set(cur_logits[src])
 
-    def _decode_impl(self, params, state, cur_logits, positions, active, key, block=None):
+    def _decode_impl(self, params, state, cur_logits, positions, active, key, block=None, spec=None):
         if block is not None:  # a block model (no logits): the slots' blocks, (tokens, tail)
             state, out, counts = self.model.block_step(params, state, *block, positions, active)
             return state, cur_logits, dict(out, counts=counts)
-        if self.temperature <= 0.0:
-            tok = jnp.argmax(cur_logits, axis=-1).astype(jnp.int32)
-        else:
-            tok = jax.random.categorical(
-                key, cur_logits / self.temperature, axis=-1).astype(jnp.int32)
-        tok = jnp.where(active, tok, 0)
+        if spec is not None:  # a drafting model: (last two tokens, new pairs, fresh)
+            return self._speculate(params, state, cur_logits, positions, active, key, *spec)
+        tok = jnp.where(active, self._choose(key, cur_logits), 0)
         logprob = jnp.take_along_axis(jax.nn.log_softmax(cur_logits, axis=-1), tok[:, None], axis=-1)[:, 0]
         state, logits, counts = self.model.decode(params, state, tok, positions, active)
         return state, logits, {"tok": tok, "logprob": logprob, "counts": counts}
+
+    def _speculate(self, params, state, cur_logits, positions, active, key, last_two, pairs, fresh):
+        """The speculative step: a fresh slot's pending token t_m drawn from its prefill's logits, the draft d from
+        the drafting block, the model over [t_m, d], the rejection sampler. -> (state, cur_logits, {"tok", "logprob"
+        (slots, 3): t_m, the draft or its correction, the token after an accepted draft; "accepted", "draft",
+        "draft_logprob", "counts"})."""
+        from daft_tpu.models.decoders import add_counts  # here: decoders imports the models, which import this module
+
+        k_first, k_draft, k_judge, k_next = jax.random.split(key, 4)
+        with jax.named_scope("sample"):
+            pending = jnp.where(fresh, self._choose(k_first, cur_logits), last_two[:, 1])
+        m = positions[:, None]
+        state, q_logits, drafted = self.model.draft(
+            params, state, jnp.stack([last_two[:, 0], pending], 1), m + jnp.asarray([-2, -1]), pairs, active)
+        with jax.named_scope("sample"):
+            d = self._choose(k_draft, q_logits)
+        state, logits, verified = self.model.verify(
+            params, state, jnp.stack([pending, d], 1), m + jnp.arange(2)[None, :], active)
+        with jax.named_scope("sample"):
+            accepted, c = rejection_sample(k_judge, d, logits[:, 0], q_logits, self.temperature)
+            accepted = accepted & active
+            bonus = self._choose(k_next, logits[:, 1])
+            tok = jnp.stack([pending, jnp.where(accepted, d, c), bonus], 1)
+            lp = jax.nn.log_softmax(jnp.concatenate([cur_logits[:, None], logits], 1), axis=-1)  # (slots, 3, V)
+            logprob = jnp.take_along_axis(lp, tok[..., None], axis=-1)[..., 0]
+            draft_logprob = jnp.take_along_axis(jax.nn.log_softmax(q_logits, -1), d[:, None], axis=-1)[:, 0]
+        return state, cur_logits, {"tok": tok, "logprob": logprob, "accepted": accepted, "draft": d,
+                                   "draft_logprob": draft_logprob, "counts": add_counts(drafted, verified)}
+
+    def _choose(self, key, logits):
+        """A token a row from ``logits``: argmax at temperature 0, else drawn from softmax(logits / temperature)."""
+        if self.temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jax.random.categorical(key, logits / self.temperature, axis=-1).astype(jnp.int32)
 
     def _repeat_noted(self, sp) -> None:
         """A model notes its choice of path on the open span while its program
@@ -343,6 +445,8 @@ class ContinuousBatcher:
         self.tail[slot] = n
         self.block[slot] = self.mask_id
         self.block[slot, :n] = req.tokens[len(req.tokens) - n:]
+        # a drafting model's slot: its first token is drawn from the prefill's logits, and one pair is new
+        self.last_two[slot], self.pairs[slot], self.fresh[slot] = (req.tokens[-1], 0), 1, True
 
     def _retire(self, slot: int, results: Dict[int, _Slot]) -> None:
         s = self.slots[slot]
@@ -392,17 +496,26 @@ class ContinuousBatcher:
                     sp.count.update(tokens=n * int(self.active.sum()), forwards=self.model.denoising_steps + 1,
                                     masked=int(((n - self.tail) * self.active).sum()), block=n,
                                     keys=int(((self.positions + n) * self.active).sum()))
+                if self.D:  # what a speculative step drafts and verifies
+                    active = int(self.active.sum())
+                    sp.count.update(drafted=active, forwards=2, verify_positions=2 * active,
+                                    keys=int(((self.positions + 2) * self.active).sum()))
                 self._key, sub = jax.random.split(self._key)
                 block = (self.block, self.tail) if self.L else None
+                spec = (self.last_two, self.pairs, self.fresh) if self.D else None
                 with device_span("serve.dispatch"):
                     self.state, self.cur_logits, out = self._decode(
-                        self.params, self.state, self.cur_logits, self.positions, self.active, sub, block)
+                        self.params, self.state, self.cur_logits, self.positions, self.active, sub, block, spec)
                 with device_span("serve.fetch") as fetch:
                     out = jax.device_get(out)  # the step's one wait for the device
                     if first:
                         self._fetched_arrays = len(jax.tree_util.tree_leaves(out))
                     fetch.count["arrays"] = self._fetched_arrays
                 sp.count.update({f"moe.{k}": int(v) for k, v in out["counts"].items()})
+                if self.D:  # what the step accepted and emitted (a fresh slot its first token too)
+                    taken = out["accepted"][self.active]
+                    sp.count.update(accepted=int(taken.sum()),
+                                    tokens=int((1 + taken + self.fresh[self.active]).sum()))
                 self._repeat_noted(sp)
                 steps += 1
                 self._take(out, results)
@@ -410,30 +523,44 @@ class ContinuousBatcher:
         done = [results.get(i, _Slot()) for i in range(len(requests))]
         self.last_logprobs = [s.logprobs for s in done]
         self.last_unmask_steps = [s.unmask_steps for s in done]
+        self.last_drafts = [s.drafts for s in done]
         return [s.generated for s in done]
 
     def _take(self, out, results: Dict[int, _Slot]) -> None:
         """A step's bookkeeping (a token model's step read as a block of one): each active slot appends what the
-        step decided (its block but the prompt's tail), moves on a step with a masked block, and retires on its
-        budget, at an end-of-sequence id or where its next step does not fit (``room``); an answer is its first
-        ``max_new_tokens`` tokens (and up to its end id)."""
+        step decided (its block but the prompt's tail; a drafting model's: its pending token where the slot is
+        fresh, the draft or its correction, and the token after an accepted draft), moves on by that, and retires
+        on its budget, at an end-of-sequence id or where its next step does not fit (``room``); an answer is its
+        first ``max_new_tokens`` tokens (and up to its end id)."""
         n = self.per_step
-        tok, logprob = (np.reshape(out[k], (self.B, n)) for k in ("tok", "logprob"))
+        tok, logprob = (np.reshape(out[k], (self.B, -1)) for k in ("tok", "logprob"))
         step = out.get("step")  # a block model's: the pass that fixed each token
+        accepted = out.get("accepted")  # a drafting model's
         for slot in np.flatnonzero(self.active):
             s, tail = self.slots[slot], int(self.tail[slot])
-            new = tok[slot, tail:].tolist()
+            lo, hi = tail, n
+            if accepted is not None:
+                lo, hi = int(not self.fresh[slot]), 2 + int(accepted[slot])
+                # the draft stood at the position after the pending token's
+                at = int(self.positions[slot]) + 1 - len(s.request.tokens)
+                s.drafts.append((at, int(out["draft"][slot]), float(out["draft_logprob"][slot])))
+            new = tok[slot, lo:hi].tolist()
             s.generated += new
-            s.logprobs += logprob[slot, tail:].tolist()
+            s.logprobs += logprob[slot, lo:hi].tolist()
             if step is not None:
-                s.unmask_steps += step[slot, tail:].tolist()
-            s.remaining -= n - tail
-            self.positions[slot] += n
-            self.tail[slot], self.block[slot] = 0, self.mask_id
+                s.unmask_steps += step[slot, lo:hi].tolist()
+            s.remaining -= hi - lo
+            if accepted is not None:
+                self.positions[slot] += hi - 1
+                self.last_two[slot], self.pairs[slot], self.fresh[slot] = tok[slot, hi - 2:hi], hi - 1, False
+            else:
+                self.positions[slot] += n
+                self.tail[slot], self.block[slot] = 0, self.mask_id
             ended = self.eos_id in new
             if ended or s.remaining <= 0 or self.positions[slot] > self.room:
                 keep = s.generated.index(self.eos_id) + 1 if ended else s.request.max_new_tokens
                 s.generated, s.logprobs, s.unmask_steps = s.generated[:keep], s.logprobs[:keep], s.unmask_steps[:keep]
+                s.drafts = [d for d in s.drafts if d[0] < len(s.generated)]
                 self._retire(int(slot), results)
 
 

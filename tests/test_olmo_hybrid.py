@@ -293,6 +293,8 @@ def test_slot_state_is_counted_in_two_kinds_by_the_names_of_its_leaves(decoder):
         assert kinds == {"kv_bytes": 3 * 2 * 4 * 16 * 16 * 2, "recurrent_bytes": 3 * per_slot}  # one attention layer
     elif decoder in ("granite-hybrid-tiny",):                               # ssm and conv beside k and v
         assert 0 < kinds["recurrent_bytes"] and kinds["kv_bytes"] > 0
+    elif decoder == "pangu-ultra-moe-tiny":                                 # two kept hidden states beside the rows
+        assert kinds["recurrent_bytes"] == 3 * 2 * 64 * 4 and kinds["kv_bytes"] > 0
     else:                                                                   # rows alone
         assert kinds["recurrent_bytes"] == 0 and kinds["kv_bytes"] > 0
 

@@ -230,7 +230,7 @@ def _float32_decoder(name):
     return (*d.init(dataclasses.replace(d.from_name(name), dtype=jnp.float32), 0), 8)
 
 
-DECODER_NAMES = ["lm", "granite-hybrid-tiny", "longcat-flash-tiny", "olmo-hybrid-tiny"]
+DECODER_NAMES = ["lm", "granite-hybrid-tiny", "longcat-flash-tiny", "olmo-hybrid-tiny", "pangu-ultra-moe-tiny"]
 
 
 @pytest.mark.parametrize("name", DECODER_NAMES)
@@ -408,3 +408,45 @@ def test_a_one_token_decoders_decode_program_is_the_one_before_blocks(name, temp
     assert str(now) == str(before)
     text = b._decode.lower(*args).as_text()
     assert text.startswith("module @jit__decode_impl") and b._decode.lower(*args, None).as_text() == text
+
+
+# -- what the batcher refuses at construction ------------------------------------------------------------------------
+def _block_model(draft_length=None):
+    from daft_tpu.models import sdar_moe
+
+    class Drafting(sdar_moe.SdarMoeLM):
+        pass
+
+    Drafting.draft_length = draft_length
+    model, params = sdar_moe.init_sdar_params(sdar_moe.SdarMoeConfig.from_name("sdar-moe-tiny"), 0)
+    return Drafting(model.cfg), params
+
+
+def test_a_block_model_is_refused_a_temperature_and_told_what_samples():
+    from daft_tpu.errors import DaftValueError
+
+    model, params = _block_model()
+    with pytest.raises(DaftValueError, match="served greedily here .* need a decoder of one token a step or one that drafts"):
+        ContinuousBatcher(model, params, num_slots=2, max_seq_len=40, temperature=0.7)
+    assert ContinuousBatcher(model, params, num_slots=2, max_seq_len=40).L == 4
+
+
+def test_a_model_that_drafts_and_generates_blocks_is_refused():
+    from daft_tpu.errors import DaftValueError
+
+    model, params = _block_model(draft_length=1)
+    for temperature in (0.0, 1.0):
+        with pytest.raises(DaftValueError, match="drafts tokens .draft_length. or generates a block a step .block_length., not both"):
+            ContinuousBatcher(model, params, num_slots=2, max_seq_len=40, temperature=temperature)
+
+
+def test_a_draft_of_more_than_one_token_is_refused():
+    from daft_tpu.errors import DaftValueError
+    from daft_tpu.models import pangu_ultra_moe as pg
+
+    class TwoDrafts(pg.PanguUltraMoeLM):
+        draft_length = 2
+
+    with pytest.raises(DaftValueError, match="draft_length=2"):
+        ContinuousBatcher(TwoDrafts(pg.PanguUltraMoeConfig.from_name("pangu-ultra-moe-tiny")), None, num_slots=2,
+                          max_seq_len=40)
