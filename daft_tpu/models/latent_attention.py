@@ -31,10 +31,15 @@ time (``c W_kvb`` -> per-head keys and values, a running softmax between blocks)
 on a TPU at widths that fill lane tiles one Pallas kernel
 (``ops/pallas_mla_attention.py``), elsewhere ``core_expanded``, XLA's loop over
 the blocks the call's deepest row attends. Decode *absorbs* ``W_kvb`` into the
-query and the output (``core_absorbed``) and never expands a key.
-``attend_chunk`` decides between kernel and loop when the program traces, from
-backend, dtype and shapes, and notes it on the batcher's open span as ``mla``
-= ``fused`` | ``expanded``; ``attend_token`` notes ``absorbed``.
+query and the output (``core_absorbed``) and never expands a key: the softmax
+over a slot's rows is, without a selection on a TPU at widths of whole tiles,
+the same module's absorbed kernel, which reads only the blocks of rows at or
+below each slot's position and keeps the scores in VMEM; elsewhere (a
+selection, the CPU, the tiny widths) XLA's form over every position a slot
+could hold. Both ask when the program traces, from backend, dtype and shapes,
+and note the path on the batcher's open span as ``mla`` = ``fused`` |
+``expanded`` (``attend_chunk``) and ``absorbed_fused`` | ``absorbed``
+(``core_absorbed``).
 
 **A selection** is what ``deepseek_v32`` brings: for a chunk, the index scores
 ``(B, T, blocks * T)`` float32 of each query against the positions of its slot
@@ -134,16 +139,29 @@ def write_token(cache, rows, positions, active):
 # ---------------------------------------------------------------------- #
 # The core                                                                #
 # ---------------------------------------------------------------------- #
-def core_absorbed(cfg, w_kvb, q, cache, positions, scale: float = None, keep=None):
+def core_absorbed(cfg, w_kvb, q, cache, positions, scale: float = None, keep=None, active=None):
     """``W_kvb`` absorbed into the query and the output: q (B, T, H, nope + rope)
-    against every position of ``cache`` (B, cache_row, S), causal by ``positions``
+    against the rows of ``cache`` (B, cache_row, S), causal by ``positions``
     (B, T) and, with ``keep`` (B, T, S), over the kept positions alone; no key or
-    value is expanded. -> (B, T, H, v) float32."""
+    value is expanded. ``active`` (B,): the slots that hold a query (``None``:
+    all; the kernel gives an inactive slot zeros). -> (B, T, H, v) float32.
+
+    Without a selection, on a TPU at widths of whole tiles, the softmax over the
+    rows is ``ops/pallas_mla_attention.mla_absorbed_attention``, which reads only
+    the blocks of rows at or below each slot's position (``mla`` =
+    ``absorbed_fused``); otherwise XLA's form over every position of every slot
+    (``absorbed``)."""
     nope, lat = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    scale = cfg.qk_head_dim ** -0.5 if scale is None else scale
     q_lat = jnp.einsum("bthd,chd->bthc", q[..., :nope], w_kvb[..., :nope], preferred_element_type=jnp.float32)
     q_abs = jnp.concatenate([q_lat.astype(cfg.dtype), q[..., nope:]], -1)          # (B, T, H, cache_row)
-    scores = jnp.einsum("bhtc,bcs->bhts", jnp.swapaxes(q_abs, 1, 2), cache, preferred_element_type=jnp.float32) \
-        * (cfg.qk_head_dim ** -0.5 if scale is None else scale)
+    if pallas_mla_attention.mla_absorbed_applies(q_abs.shape, q_abs.dtype, cache.shape[2], lat, keep is not None):
+        decoders.note_on_serving_span("mla", "absorbed_fused")
+        active = jnp.ones(q.shape[:1], bool) if active is None else active
+        o_lat = pallas_mla_attention.mla_absorbed_attention(q_abs, cache, positions, active, lat=lat, scale=float(scale))
+        return jnp.einsum("bthc,chd->bthd", o_lat.astype(cfg.dtype), w_kvb[..., nope:], preferred_element_type=jnp.float32)
+    decoders.note_on_serving_span("mla", "absorbed")
+    scores = jnp.einsum("bhtc,bcs->bhts", jnp.swapaxes(q_abs, 1, 2), cache, preferred_element_type=jnp.float32) * scale
     seen = jnp.arange(cache.shape[2])[None, None, :] <= positions[:, :, None]       # (B, T, S)
     if keep is not None:
         seen = seen & keep
@@ -225,8 +243,8 @@ def attend_chunk(cfg, w_kvb, q, kv, slots, starts, lengths, scale: float = None,
     return expanded_over_slots(cfg, w_kvb, q, kv, slots, starts, scale, keep)
 
 
-def attend_token(cfg, w_kvb, q, kv, positions, scale: float = None, keep=None):
+def attend_token(cfg, w_kvb, q, kv, positions, scale: float = None, keep=None, active=None):
     """One token a slot: q (slots, 1, H, nope + rope) over each slot's own rows
-    (its token's row already written); ``keep`` (slots, 1, S). -> (slots, 1, H, v)."""
-    decoders.note_on_serving_span("mla", "absorbed")
-    return core_absorbed(cfg, w_kvb, q, kv, positions[:, None], scale, keep)
+    (its token's row already written); ``keep`` (slots, 1, S); ``active``
+    (slots,). -> (slots, 1, H, v)."""
+    return core_absorbed(cfg, w_kvb, q, kv, positions[:, None], scale, keep, active)

@@ -26,8 +26,9 @@ operands and accumulate in float32):
 ``{"kv": (slots, row, positions)}`` an attention, two a layer, positions minor;
 prefill *expanded*, on a TPU at the published widths one Pallas kernel,
 ``ops/pallas_mla_attention.py``, elsewhere XLA's loop over blocks; decode
-*absorbed*; noted on the batcher's open span as ``mla`` = ``fused`` |
-``expanded`` | ``absorbed``) is ``models/latent_attention.py``, shared with
+*absorbed*, on a TPU at the published widths that module's absorbed kernel,
+elsewhere XLA's form; noted on the batcher's open span as ``mla`` = ``fused`` |
+``expanded`` | ``absorbed_fused`` | ``absorbed``) is ``models/latent_attention.py``, shared with
 ``models/deepseek_v32``: this model calls it with both LoRA scales, plain rotary
 frequencies (theta ``rope_theta``), the softmax scale ``(nope + rope) ** -0.5``
 and no selection.
@@ -273,7 +274,7 @@ def _mla_decode(cfg, p, s, x, kv, positions, active):
         q, rows = mla_project(cfg, p, s, x, positions[:, None])
     with jax.named_scope("mla_core"):
         kv = latent_attention.write_token(kv, rows[:, 0], positions, active)
-        out = latent_attention.attend_token(cfg, latent_attention.kv_b(cfg, p, s), q, kv, positions)
+        out = latent_attention.attend_token(cfg, latent_attention.kv_b(cfg, p, s), q, kv, positions, active=active)
     with jax.named_scope("mla_proj"):
         return mm(out.astype(cfg.dtype).reshape(B, 1, -1), p["o" + s]), kv
 

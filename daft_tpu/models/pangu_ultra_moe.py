@@ -59,8 +59,10 @@ bfloat16 (the router's bias in float32); ``jax.named_scope`` names the parts
 (``mla_proj``, ``mla_core``, ``dense_mlp``, ``router``, ``experts``, ``head``) for
 the device trace, and ``mtp`` all of the MTP block (its parts carry the same
 names with the prefix ``mtp_``, so that no class inside it takes its time). The
-programs note ``mla`` = ``fused`` | ``expanded`` (prefill) | ``absorbed`` (the
-steps) and ``moe`` on the batcher's open span.
+programs note ``mla`` = ``fused`` | ``expanded`` (prefill) | ``absorbed_fused`` |
+``absorbed`` (the steps: ``ops/pallas_mla_attention.mla_absorbed_attention`` on
+a TPU at the published widths, XLA's form elsewhere) and ``moe`` on the
+batcher's open span.
 """
 
 from __future__ import annotations
@@ -287,7 +289,8 @@ def _attend_prefill(cfg, p, x, kv, slots, starts, lengths, pre: str = ""):
 
 def _attend_tokens(cfg, p, x, kv, positions, writes, pre: str = ""):
     """T tokens a slot, x (slots, T, d) at ``positions`` (slots, T): the rows ``writes`` (slots, T) marks are written,
-    then every query attends its slot's rows up to its position in the absorbed form. -> (out, kv)."""
+    then every query attends its slot's rows up to its position in the absorbed form (a slot that writes no row is
+    inactive). -> (out, kv)."""
     B, T, _ = x.shape
     inv = latent_attention.frequencies(cfg.rope_theta, cfg.qk_rope_head_dim)
     with _scope(pre, "mla_proj"):
@@ -295,8 +298,8 @@ def _attend_tokens(cfg, p, x, kv, positions, writes, pre: str = ""):
     with _scope(pre, "mla_core"):
         for t in range(T):
             kv = latent_attention.write_token(kv, rows[:, t], positions[:, t], writes[:, t])
-        decoders.note_on_serving_span("mla", "absorbed")
-        out = latent_attention.core_absorbed(cfg, latent_attention.kv_b(cfg, p, ""), q, kv, positions, cfg.softmax_scale)
+        out = latent_attention.core_absorbed(cfg, latent_attention.kv_b(cfg, p, ""), q, kv, positions, cfg.softmax_scale,
+                                             active=writes.any(axis=1))
     with _scope(pre, "mla_proj"):
         return mm(out.astype(cfg.dtype).reshape(B, T, -1), p["o"]), kv
 

@@ -42,6 +42,25 @@ written back once, after its last visit. Which path a prefill takes is decided
 when it traces, from what can be seen (``mla_prefill_applies``): there is no
 switch. A kernel that fails to lower fails the program. Forward only. Tests run
 the kernel in interpret mode on the CPU.
+
+**The absorbed form** (``mla_absorbed_attention``: a decode step's or a
+speculative step's few queries a slot, ``models/latent_attention.core_absorbed``
+with ``W_kvb`` absorbed into the query and the output): ``q_abs (B, T, H, lat +
+rope)`` against the cache rows themselves, every slot of ``kv`` its row of the
+call, ``positions (B, T)`` and ``active (B,)``. A visit is one slot's ``T x H``
+query rows (one tile) against one block of ``ABSORBED_BLOCK`` positions, by the
+same walk: slot ``b`` visits ``max_t positions[b, t] // ABSORBED_BLOCK + 1``
+blocks, an inactive slot one that writes zeros. The scores ``q_abs . block``
+(float32, the rotary part a product of its own over a lane tile padded with
+zeros), the mask by each query's own position, the running max, sum and the
+``(rows, lat)`` accumulator of the probabilities times the block's latent
+columns stay in VMEM; what crosses HBM is ``q_abs``, the rows a slot holds
+(rounded up to whole blocks) and the float32 ``(B, T, H, lat)`` result, which
+XLA turns to values by ``W_kvb``'s value half. A slot's rows need not be whole
+blocks (2,432 = 4 x 512 + 384): the latent columns past the end are zeroed in
+VMEM before the block is used (``0 x NaN`` is ``NaN``), and the scores there are
+masked. The arithmetic is ``core_absorbed``'s, the division by the sum once at
+the end.
 """
 
 from __future__ import annotations
@@ -65,6 +84,11 @@ VMEM_BUDGET = pallas_attention.VMEM_BUDGET
 #: compile in 14 s more at 8 than at 2 (my chip run and my compile for a
 #: described v5e, PR 34).
 MAX_HEADS = 2
+#: Positions a visit of the absorbed form fetches: 576 KB of rows at openPangu's and LongCat's widths. On a TPU v5e a
+#: visit of 256 query rows takes 2.55 µs (0.3 GFLOP, ≈ 1.5 µs at the MXU's peak) and one of 64 rows 1.23 µs, bound by
+#: the fetch. Half the block would halve the rows fetched past a slot's position (≈ 256 of a mean of ≈ 770 in
+#: openPangu's cell) for twice the grid steps, each with a fixed cost of its own, and a fetch half as large.
+ABSORBED_BLOCK = 512
 _LOW = float(np.finfo(np.float32).min)
 
 
@@ -248,3 +272,131 @@ def mla_prefill_attention(q: jax.Array, kv: jax.Array, w_kvb: jax.Array, slots: 
         interpret=interpret,
     )(slots.astype(jnp.int32), starts.astype(jnp.int32), counts, row, block, q, w_t, kv, *selection)
     return out.reshape(B, T, H, dv)
+
+
+# ---------------------------------------------------------------------- #
+# The absorbed form: a step's few queries a slot over the rows it holds    #
+# ---------------------------------------------------------------------- #
+def _absorbed_step_bytes(rows: int, lat: int, rope: int, itemsize: int) -> int:
+    """VMEM of one visit: the q block (the rotary part padded to a lane tile),
+    the cache block and the float32 result block, double-buffered; the running
+    max and sum (a lane tile wide each), the accumulator and the rotary keys'
+    tile; and the float32 scores, exponentials and their cast in flight."""
+    rope_w, block = pallas_attention._round_up(rope, _LANES), ABSORBED_BLOCK
+    blocks = 2 * (itemsize * rows * (lat + rope_w) + itemsize * (lat + rope) * block + 4 * rows * lat)
+    scratch = rows * (2 * _LANES + lat) * 4 + rope_w * block * itemsize
+    return blocks + scratch + rows * block * (4 + 4 + itemsize)
+
+
+def mla_absorbed_applies(q_shape, dtype, held: int, lat: int, selects: bool = False) -> bool:
+    """Whether ``mla_absorbed_attention`` serves this step: no selection, a TPU
+    backend, bfloat16, the latent in whole lane tiles and the rotary width in
+    whole sublane tiles, a slot's ``T x H`` query rows in whole sublane tiles, a
+    slot that holds a block at least, and one visit inside ``VMEM_BUDGET``.
+    Otherwise ``latent_attention.core_absorbed`` takes XLA's form."""
+    _, T, H, width = q_shape
+    rope = width - lat
+    return (not selects
+            and pallas_attention.backend_is_tpu()
+            and jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16)
+            and lat % _LANES == 0 and rope > 0 and rope % 16 == 0 and (T * H) % 16 == 0
+            and held >= ABSORBED_BLOCK
+            and _absorbed_step_bytes(T * H, lat, rope, 2) <= VMEM_BUDGET)
+
+
+def _absorbed_kernel(positions, counts, row, block, q_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref, k_ref,
+                     *, lat: int, rope: int, queries: int, heads: int, held: int, scale: float):
+    """One visit: blocks are q ``(1, T * H, lat + rope_w)`` (rows ``t * H + h``),
+    the cache rows ``(1, lat + rope, S)`` and the result ``(1, T * H, lat)``;
+    scratch is the running max and sum ``(T * H, 128)``, the accumulator ``(T *
+    H, lat)`` and the rotary keys' tile ``(rope_w, S)`` (zeros below the rotary
+    rows). ``positions`` is ``(B * T,)``: query ``t`` of slot ``b`` at ``b * T +
+    t``."""
+    from jax.experimental import pallas as pl
+
+    rows, S = q_ref.shape[1], kv_ref.shape[2]
+    dtype = q_ref.dtype
+    visit = pl.program_id(0)
+    b, j = row[visit], block[visit]
+    blocks = counts[b]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _LOW, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    if held % S:  # the slot ends inside its last block: what the fetch left behind the end weighs nothing
+        @pl.when(j == held // S)
+        def _():
+            at = j * S + jax.lax.broadcasted_iota(jnp.int32, (lat, S), 1)
+            kv_ref[0, :lat, :] = jnp.where(at < held, kv_ref[0, :lat, :], jnp.zeros((lat, S), dtype))
+
+    @pl.when(blocks > 0)
+    def _():
+        k_ref[:rope, :] = kv_ref[0, lat:, :]
+        k_ref[rope:, :] = jnp.zeros((k_ref.shape[0] - rope, S), dtype)
+        c = kv_ref[0, :lat, :]                                             # (lat, S): positions are lanes
+        s = (jnp.dot(q_ref[0, :, :lat], c, preferred_element_type=jnp.float32)
+             + jnp.dot(q_ref[0, :, lat:], k_ref[...], preferred_element_type=jnp.float32)) * scale
+        at = jax.lax.broadcasted_iota(jnp.int32, (rows, S), 0)
+        reach = jnp.full((rows, S), positions[b * queries + queries - 1], jnp.int32)
+        for t in range(queries - 2, -1, -1):  # query t's rows are t * H .. (t + 1) * H - 1
+            reach = jnp.where(at < (t + 1) * heads, positions[b * queries + t], reach)
+        key = j * S + jax.lax.broadcasted_iota(jnp.int32, (rows, S), 1)
+        s = jnp.where(key <= reach, s, _LOW)
+        m_old = m_ref[...]                                                 # (rows, 128), lanes alike
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new[:, :1])
+        shrink = jnp.exp(m_old - m_new)
+        l_ref[...] = l_ref[...] * shrink + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(p.astype(dtype), c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * shrink[:, :1] + pv
+        m_ref[...] = m_new
+
+    @pl.when((blocks > 0) & (j == blocks - 1))
+    def _():
+        o_ref[0] = acc_ref[...] / l_ref[...][:, :1]
+
+    @pl.when(blocks == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+# Jitted so that the attentions of a step share one trace and one lowering of the kernel (as ``mla_prefill_attention``).
+@functools.partial(jax.jit, static_argnames=("lat", "scale", "interpret"))
+def mla_absorbed_attention(q_abs: jax.Array, kv: jax.Array, positions: jax.Array, active: jax.Array, lat: int,
+                           scale: float, interpret: bool = False) -> jax.Array:
+    """``q_abs (B, T, H, lat + rope)`` (``W_kvb``'s key half absorbed into the
+    query, the rotary part turned), ``kv (B, lat + rope, S)`` (slot ``b`` is row
+    ``b``), ``positions (B, T)`` and ``active (B,)``. Returns ``(B, T, H, lat)``
+    float32: query ``t`` of slot ``b`` over the latent rows at positions ``<=
+    positions[b, t]``, softmax-weighted by ``q_abs . row * scale``; zeros for an
+    inactive slot. The caller has asked ``mla_absorbed_applies``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H, width = q_abs.shape
+    rope, rows, held = width - lat, T * H, kv.shape[2]
+    rope_w = pallas_attention._round_up(rope, _LANES)
+    S = ABSORBED_BLOCK
+    counts, row, block, visits = row_visits(jnp.max(positions, axis=1), active.astype(jnp.int32), S, -(-held // S))
+    q = jnp.pad(q_abs.reshape(B, rows, width), ((0, 0), (0, 0), (0, rope_w - rope)))
+    here = lambda v, positions, counts, row, block: (row[v], 0, 0)  # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_absorbed_kernel, lat=lat, rope=rope, queries=T, heads=H, held=held, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(visits,),
+            in_specs=[pl.BlockSpec((1, rows, lat + rope_w), here),
+                      pl.BlockSpec((1, width, S), lambda v, positions, counts, row, block: (row[v], 0, block[v]))],
+            out_specs=pl.BlockSpec((1, rows, lat), here),
+            scratch_shapes=[pltpu.VMEM((rows, _LANES), jnp.float32), pltpu.VMEM((rows, _LANES), jnp.float32),
+                            pltpu.VMEM((rows, lat), jnp.float32), pltpu.VMEM((rope_w, S), q_abs.dtype)]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, lat), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_BUDGET),
+        interpret=interpret,
+    )(positions.reshape(-1).astype(jnp.int32), counts, row, block, q, kv)
+    return out.reshape(B, T, H, lat)

@@ -479,6 +479,24 @@ def test_on_a_tpu_the_published_widths_take_both_kernels_and_the_model_runs_thro
     assert calls.count("index") == 4 and np.max(np.abs(fused - xla)) <= LOGIT_GAP_MAX  # rounding of a result, and a key at a threshold
 
 
+
+def test_on_a_tpu_the_masked_decode_keeps_xlas_absorbed_form(monkeypatch):
+    """A decode step at widths the absorbed kernel serves (16 heads over a latent of one lane tile, slots of 512
+    positions) with the backend rule answering as on a TPU: the selection keeps the step on XLA's masked form, the
+    kernel is never called, and the span says ``absorbed``; the same step's shapes without a selection would take it."""
+    from daft_tpu.profiling import device_span
+
+    model, params = program(0, num_attention_heads=16, kv_lora_rank=128, qk_rope_head_dim=16)
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pallas_mla_attention, "mla_absorbed_attention",
+                        lambda *a, **k: pytest.fail("the kernel was called"))
+    with device_span("serve.decode_step") as sp:
+        _, logits, _ = jax.jit(model.decode)(params, model.init_state(2, 512), jnp.asarray([5, 7], jnp.int32),
+                                             jnp.asarray([0, 3], jnp.int32), jnp.asarray([True, True]))
+    assert (sp.count["dsa"], sp.count["mla"]) == ("masked", "absorbed") and np.isfinite(np.asarray(logits)).all()
+    assert pallas_mla_attention.mla_absorbed_applies((2, 1, 16, 128 + 16), jnp.bfloat16, 512, 128)
+    assert not pallas_mla_attention.mla_absorbed_applies((2, 1, 16, 128 + 16), jnp.bfloat16, 512, 128, selects=True)
+
 # the configuration, the cut and the serving protocol
 def test_names_cuts_and_sizes():
     with pytest.raises(DaftValueError, match="DeepSeek-V3.2-Exp"):

@@ -74,12 +74,15 @@ def program(seed: int, rank: int = 0, size: int = 2, **sizes):
 
 @pytest.fixture
 def fused_prefill(monkeypatch):
-    """The backend rule answers as on a TPU and the prefill kernel it then
-    selects runs interpreted (the experts stay too narrow for theirs)."""
-    real = pallas_mla_attention.mla_prefill_attention
+    """The backend rule answers as on a TPU and the prefill kernel and the decode
+    step's absorbed kernel it then selects run interpreted (the experts stay too
+    narrow for theirs)."""
+    real, absorbed = pallas_mla_attention.mla_prefill_attention, pallas_mla_attention.mla_absorbed_attention
     monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: True)
     monkeypatch.setattr(pallas_mla_attention, "mla_prefill_attention",
                         lambda *a, nope: real(*a, nope=nope, interpret=True))
+    monkeypatch.setattr(pallas_mla_attention, "mla_absorbed_attention",
+                        lambda *a, **k: absorbed(*a, interpret=True, **k))
 
 
 def prompts(seed: int, lengths):
@@ -189,22 +192,26 @@ def test_chunked_prefill_through_the_kernel_then_decode_agrees_with_the_referenc
 def test_the_batcher_at_lane_tile_widths_prefills_through_the_kernel_and_counts_its_visits(monkeypatch, fused_prefill):
     """Three prompts of unlike length in one group of four rows: the span says
     which attention the prefill program traced and how many (row, block) pairs
-    held a query, of those a call of static shape spans."""
+    held a query, of those a call of static shape spans. At 16 heads a slot's
+    one query is a sublane tile of rows and its 520 positions a block at least,
+    so the decode step takes the absorbed kernel too."""
     from daft_tpu.profiling import newest_device_span
 
-    model, params = program(1, **LANE_TILE)
-    b = ContinuousBatcher(model, params, num_slots=4, max_seq_len=400, eos_id=None, prefill_chunk=128)
+    model, params = program(1, **dict(LANE_TILE, num_attention_heads=16))
+    b = ContinuousBatcher(model, params, num_slots=4, max_seq_len=520, eos_id=None, prefill_chunk=128)
     out = b.run([Request(tokens=t, max_new_tokens=2) for t in prompts(1, [300, 100, 140])])
     assert all(len(o) == 2 for o in out)
-    assert b._noted == {"serve.prefill": {"mla": "fused", "moe": "xla"}, "serve.decode_step": {"mla": "absorbed", "moe": "xla"}}
+    assert b._noted == {"serve.prefill": {"mla": "fused", "moe": "xla"},
+                        "serve.decode_step": {"mla": "absorbed_fused", "moe": "xla"}}
     count = newest_device_span("serve.prefill").count
     assert (count["mla"], count["chunks"], count["row_chunks"]) == ("fused", 3, 3 + 1 + 2)
     assert (count["block_rows"], count["padded_block_rows"]) == (6 + 1 + 3, 4 * 6)
     # the same prompts on XLA's path choose the same tokens
     monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: False)
-    x = ContinuousBatcher(model, params, num_slots=4, max_seq_len=400, eos_id=None, prefill_chunk=128)
+    x = ContinuousBatcher(model, params, num_slots=4, max_seq_len=520, eos_id=None, prefill_chunk=128)
     assert x.run([Request(tokens=t, max_new_tokens=2) for t in prompts(1, [300, 100, 140])]) == out
-    assert x._noted["serve.prefill"]["mla"] == "expanded"
+    assert x._noted == {"serve.prefill": {"mla": "expanded", "moe": "xla"},
+                        "serve.decode_step": {"mla": "absorbed", "moe": "xla"}}
 
 
 def _served(seed, lengths=(5, 17, 33, 40, 9, 20), new=8, **kw):

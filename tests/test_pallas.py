@@ -439,3 +439,36 @@ def test_sdars_block_step_attention_compiles_for_v5e_with_no_copy_of_the_cache(o
         assert not re.search(r"= bf16\[64,4,2320,128\]\S* (copy|transpose)\(", text)
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < rows // 8 and (not commit or mem.alias_size_in_bytes >= 2 * rows)
+
+
+@pytest.mark.parametrize("B,T,H,S", [(64, 2, 128, 2432), (16, 1, 64, 16449)], ids=["pangu_verify", "longcat_decode"])
+def test_the_absorbed_core_compiles_for_v5e_with_no_score_tensor_and_no_copy_of_the_cache(one_chip, monkeypatch, B, T, H, S):
+    """One attention of openPangu's speculative step (64 slots, two queries of 128 heads, 2,432 positions held) and
+    of LongCat's decode step (16 slots, one query of 64 heads, 16,449 positions), as the models run it: the step's
+    rows written (``latent_attention.write_token``), then ``core_absorbed`` with the backend rule answering as on a
+    TPU. Compiled by the TPU's compiler (nothing runs): one custom call, the absorbed kernel's; no float32 scores over
+    every position a slot holds (XLA's ``(B, H, T, S)``) and no copy of the cache."""
+    from types import SimpleNamespace
+
+    from daft_tpu.models import longcat_flash  # noqa: F401 (decoders first: it imports latent_attention)
+    from daft_tpu.models import latent_attention as la
+    from daft_tpu.ops import pallas_mla_attention as pm
+
+    lat, rope, nope, dv = 512, 64, 128, 128
+    cfg = SimpleNamespace(qk_nope_head_dim=nope, qk_rope_head_dim=rope, qk_head_dim=nope + rope, v_head_dim=dv,
+                          kv_lora_rank=lat, dtype=jnp.bfloat16)
+    monkeypatch.setattr(pa, "backend_is_tpu", lambda: True)
+
+    def step(q, kv, w_kvb, rows, positions, active):
+        for t in range(T):
+            kv = la.write_token(kv, rows[:, t], positions[:, t], active)
+        return la.core_absorbed(cfg, w_kvb, q, kv, positions, 0.07, active=active), kv
+
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, T, H, nope + rope), jnp.bfloat16), ((B, lat + rope, S), jnp.bfloat16), ((lat, H, nope + dv), jnp.bfloat16),
+        ((B, T, lat + rope), jnp.bfloat16), ((B, T), jnp.int32), ((B,), jnp.bool_))]
+    text = jax.jit(step, donate_argnums=(1,)).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "mla_absorbed_attention" in text
+    assert f"f32[{B},{H},{T},{S}]" not in text and f"bf16[{B},{H},{T},{S}]" not in text
+    assert not re.search(r"= bf16\[%d,%d,%d\]\S* (copy|transpose)\(" % (B, lat + rope, S), text)
+    assert pm.mla_absorbed_applies((B, T, H, lat + rope), jnp.bfloat16, S, lat)

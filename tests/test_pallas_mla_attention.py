@@ -1,9 +1,11 @@
 """The prefill attention kernel of LongCat-Flash (``ops/pallas_mla_attention.py``):
 its arithmetic in interpret mode on the CPU against ``longcat_flash.
 mla_core_expanded`` over the same cache, the walk it is handed, the rule that
-selects it, and ``LongcatFlashLM.prefill`` on either path. Its compile for a
-described v5e at the benchmark's shapes is in ``tests/test_pallas.py``, with the
-fixture that describes the chip."""
+selects it, and ``LongcatFlashLM.prefill`` on either path; and the absorbed form
+of a step's few queries a slot (``mla_absorbed_attention``) against
+``latent_attention.core_absorbed``'s XLA form, through ``core_absorbed``'s own
+choice. Their compile for a described v5e at the benchmark's shapes is in
+``tests/test_pallas.py``, with the fixture that describes the chip."""
 
 import dataclasses
 from types import SimpleNamespace
@@ -15,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from daft_tpu.models import longcat_flash as lc
+from daft_tpu.models import latent_attention as la  # noqa: I100 (after a decoder: decoders imports this module)
 from daft_tpu.ops import pallas_attention as pa
 from daft_tpu.ops import pallas_mla_attention as pm
 
@@ -236,3 +239,72 @@ def test_kernel_failure_propagates(monkeypatch):
     # the decode step never takes it
     state, logits, _ = model.decode(params, args[1], jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool))
     assert np.isfinite(np.asarray(logits)).all()
+
+
+# -- the absorbed form of a step ----------------------------------------------------
+A_BLOCK = pm.ABSORBED_BLOCK
+A_HELD = 2 * A_BLOCK + 80                                           # a slot's last block is partial: the interpreter
+#                                                                     pads it with NaN, as the chip leaves what it holds
+#: name -> (queries a slot, heads, latent, rotary width, dtype, a selection, the path ``core_absorbed`` takes). The
+#: first two are the published query tiles at a latent of one lane tile: LongCat's decode step (one query of 64 heads),
+#: openPangu's verify and draft (two of 128); the others are what the rule refuses.
+ABSORBED = {
+    "one_query_of_64_heads": (1, 64, 128, 16, jnp.bfloat16, False, "absorbed_fused"),
+    "two_queries_of_128_heads": (2, 128, 128, 16, jnp.bfloat16, False, "absorbed_fused"),
+    "a_selection": (1, 64, 128, 16, jnp.bfloat16, True, "absorbed"),
+    "float32": (2, 128, 128, 16, jnp.float32, False, "absorbed"),
+    "a_latent_off_the_lane_tile": (1, 64, 120, 16, jnp.bfloat16, False, "absorbed"),
+    "a_rotary_width_off_the_sublane_tile": (1, 64, 128, 8, jnp.bfloat16, False, "absorbed"),
+}
+#: Each slot's last query (the others stand just before it): at position 0, inside a block, on a block's first and
+#: last positions, at the slot's last held position, and an inactive slot.
+A_LAST = [0, 300, A_BLOCK, 2 * A_BLOCK - 1, A_HELD - 1, 700]
+A_ACTIVE = [True, True, True, True, True, False]
+
+
+@pytest.mark.parametrize("case", list(ABSORBED))
+def test_the_absorbed_kernel_agrees_with_xlas_form_where_the_rule_takes_it(monkeypatch, case):
+    """Through ``core_absorbed`` with the backend rule answering as on a TPU and
+    the kernel interpreted: where the rule takes the kernel, the cache it reads
+    holds NaN in every block behind a slot's last one, in the whole of an
+    inactive slot and (the interpreter's fetch) past the slot's end, and still
+    each active slot's result is XLA's over the clean cache, an inactive one's
+    zeros; where the rule refuses, the kernel is not called. The note says which."""
+    from daft_tpu.profiling import device_span
+
+    T, H, lat, rope, dtype, selects, path = ABSORBED[case]
+    B, nope, dv = len(A_LAST), 16, 16
+    cfg = SimpleNamespace(qk_nope_head_dim=nope, qk_rope_head_dim=rope, qk_head_dim=nope + rope, v_head_dim=dv,
+                          kv_lora_rank=lat, dtype=dtype)
+    rng = np.random.default_rng(len(case))
+    q = jnp.asarray(rng.standard_normal((B, T, H, nope + rope)), dtype)
+    w = jnp.asarray(rng.standard_normal((lat, H, nope + dv)) * lat ** -0.5, dtype)
+    kv = jnp.asarray(rng.standard_normal((B, lat + rope, A_HELD)), dtype)
+    positions = jnp.asarray(A_LAST)[:, None] - (T - 1) + jnp.arange(T)[None, :]
+    positions = jnp.maximum(positions, 0)
+    active = jnp.asarray(A_ACTIVE)
+    keep = jnp.asarray(rng.random((B, T, A_HELD)) < 0.5) | (jnp.arange(A_HELD) == 0) if selects else None
+    want = np.asarray(la.core_absorbed(cfg, w, q, kv, positions, 0.3, keep), np.float32)
+
+    poisoned = kv
+    for b in range(B):
+        behind = (A_LAST[b] // A_BLOCK + 1) * A_BLOCK if A_ACTIVE[b] else 0
+        poisoned = poisoned.at[b, :, behind:].set(jnp.nan)
+    calls, real = [], pm.mla_absorbed_attention
+    monkeypatch.setattr(pa, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pm, "mla_absorbed_attention",
+                        lambda *a, **k: calls.append(a[0].shape) or real(*a, interpret=True, **k))
+    with device_span("serve.decode_step") as sp:
+        got = la.core_absorbed(cfg, w, q, poisoned if path == "absorbed_fused" else kv, positions, 0.3, keep, active)
+    assert sp.count["mla"] == path and calls == ([(B, T, H, lat + rope)] if path == "absorbed_fused" else [])
+    got = np.asarray(got, np.float32)
+    assert got.shape == (B, T, H, dv) and np.isfinite(got).all()
+    held = np.asarray(A_ACTIVE)
+    if path == "absorbed_fused":
+        assert not got[~held].any()
+        scale = float(np.abs(want[held]).max())
+        # the probabilities rounded before or after the sum: read 0.004
+        assert float(np.abs(got[held] - want[held]).max()) <= 2e-2 * scale
+    else:
+        np.testing.assert_array_equal(got, want)
+

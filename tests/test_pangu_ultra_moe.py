@@ -245,6 +245,36 @@ def test_the_host_loop_truncates_a_two_token_step_at_the_budget_and_refills_slot
     assert sum(s.count["mtp_positions"] for s in prefills) == sum(len(p) - 1 for p in prompts)  # the twin, a round later, too
 
 
+
+def test_on_a_tpu_the_draft_and_the_verify_take_the_absorbed_kernel(monkeypatch):
+    """The backend rule answers as on a TPU and the absorbed kernel runs interpreted, at 8 heads over a latent of one
+    lane tile (a slot's two queries are a sublane tile of rows) and slots of 512 positions: the speculative program's
+    MTP attention and its three main ones are the kernel's (the prefill's chunk of 16 is no lane tile: XLA's loop),
+    every step's span says ``absorbed_fused``, and at temperature 0 the answers are XLA's path's."""
+    from daft_tpu.ops import pallas_attention, pallas_mla_attention as pm
+    from daft_tpu.profiling import recent_device_spans
+    from daft_tpu.tracing import span_clock_ns
+
+    model, params = program(3, num_attention_heads=8, kv_lora_rank=128, qk_rope_head_dim=16)
+    prompts = prompts_of(3, (37, 5, 70))
+
+    def run():
+        b = ContinuousBatcher(model, params, num_slots=3, max_seq_len=512, temperature=0.0, eos_id=None, prefill_chunk=T)
+        return b, b.run([Request(tokens=p, max_new_tokens=6) for p in prompts])
+
+    calls, real = [], pm.mla_absorbed_attention
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: True)
+    monkeypatch.setattr(pm, "mla_absorbed_attention",
+                        lambda *a, **k: calls.append(a[0].shape) or real(*a, interpret=True, **k))
+    began = span_clock_ns()
+    fused, out = run()
+    steps = [s for s in recent_device_spans() if s.start_ns >= began and s.name == "serve.decode_step"]
+    assert steps and all(s.count["mla"] == "absorbed_fused" for s in steps)
+    assert calls == [(3, 2, 8, 128 + 16)] * 4 and fused._noted["serve.prefill"]["mla"] == "expanded"
+    monkeypatch.setattr(pallas_attention, "backend_is_tpu", lambda: False)
+    xla, greedy = run()
+    assert greedy == out and xla._noted["serve.decode_step"]["mla"] == "absorbed" and len(calls) == 4
+
 def test_a_rejected_drafts_row_is_overwritten_before_it_is_read(ref):
     """At temperature 1 with a drafting block whose logits are 30 times as wide (each draft its own argmax, which p
     gives a few percent), nearly every draft is rejected: the rows verified for the drafts are each overwritten by
